@@ -1,10 +1,12 @@
 """Secondary benchmark: ResNet50 training MFU (BASELINE.md north-star 2).
 
-Prints one JSON line like bench.py (the driver contract runs bench.py; this
-script is the training-side evidence). Measures the steady-state jitted
+Prints one JSON line like bench.py. Measures the steady-state jitted
 train step — bf16 ResNet50, SGD+momentum, device-resident batch — and
 reports MFU via the framework's own StepMeter/compiled_flops meters
 (observability.metrics), against the >=50% target from BASELINE.md.
+TPU only (runtime/chip.py ``require_tpu``); under an exported
+``JAX_PLATFORMS=cpu`` it is a contract smoke that prints no MFU, no
+per-chip rate and no ``vs_baseline``.
 """
 
 import json
@@ -16,9 +18,6 @@ import numpy as np
 
 def main() -> None:
     import jax
-
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     import jax.numpy as jnp
     import optax
 
@@ -29,8 +28,15 @@ def main() -> None:
         make_vision_train_step,
     )
 
+    from sparkdl_tpu.runtime.chip import (
+        configure_compile_cache,
+        require_tpu,
+        smoke_label,
+    )
+
+    on_accel = require_tpu(explicit_cpu_ok=True)
+    configure_compile_cache()
     platform = jax.default_backend()
-    on_accel = platform not in ("cpu",)
     batch = int(os.environ.get("BENCH_BATCH", 256 if on_accel else 8))
     steps = int(os.environ.get("BENCH_STEPS", 10 if on_accel else 2))
     repeats = int(os.environ.get("BENCH_REPEATS", 3 if on_accel else 1))
@@ -98,13 +104,17 @@ def main() -> None:
     flops_per_step = compiled_flops(
         flops_step, params, batch_stats, opt_state, x, y
     )
+    if flops_per_step is None:
+        raise SystemExit(
+            "compiled_flops returned None (XLA gave no cost analysis for "
+            "the train step): no MFU can be reported")
     meter = StepMeter(flops_per_step=flops_per_step, n_chips=1)
 
     # The benched unit chains `steps` train steps inside one jit via
-    # lax.scan (state-carried, so iterations can't collapse): this chip's
-    # ~2.4 ms per-dispatch overhead and ~70 ms trailing-read RTT would
-    # otherwise understate MFU (PERF.md measurement discipline). State is
-    # donated per dispatch — the steady-state production shape.
+    # lax.scan (state-carried, so iterations can't collapse), so the
+    # per-dispatch overhead and the trailing read are amortized over
+    # `steps`. State is donated per dispatch — the steady-state
+    # production shape.
     from jax import lax
 
     def _step(carry, batch):
@@ -133,9 +143,8 @@ def main() -> None:
 
     scanned = jax.jit(scanned, donate_argnums=(0, 1, 2))
 
-    # warmup / compile; the forced scalar read (not block_until_ready, whose
-    # readiness signal is unreliable for large output trees on relayed
-    # backends) drains the queue before timing starts.
+    # warmup / compile; the forced scalar read drains the queue before
+    # timing starts.
     params, batch_stats, opt_state, loss = scanned(
         params, batch_stats, opt_state, x, y
     )
@@ -169,15 +178,28 @@ def main() -> None:
         record_dispatch("train_bench", steps, total_wall / repeats)
     gap = calibrate_dispatch_gap()
     n_dispatches = dispatch_count("train_bench")
+    if on_accel:
+        # a TPU always has a peak (an unknown device_kind raised in the
+        # meter), so the MFU is a number here
+        headline = {
+            "metric": f"ResNet50 train MFU ({platform}, {size}px, "
+                      f"batch {batch})",
+            "value": round(mfu, 4),
+            "unit": "MFU",
+            "vs_baseline": round(mfu / target, 4),
+            "examples_per_sec_per_chip": s.get("examples_per_sec_per_chip"),
+        }
+    else:
+        headline = {
+            "metric": smoke_label(False) + "ResNet50 train step seconds "
+                      f"({platform}, {size}px, batch {batch})",
+            "value": round(step_time, 4),
+            "unit": "s/step",
+        }
     print(
         json.dumps(
             {
-                "metric": f"ResNet50 train MFU ({platform}, {size}px, "
-                          f"batch {batch})",
-                "value": round(mfu, 4) if mfu is not None else None,
-                "unit": "MFU",
-                "vs_baseline": round(mfu / target, 4) if mfu else None,
-                "examples_per_sec_per_chip": s.get("examples_per_sec_per_chip"),
+                **headline,
                 "dispatch_count": n_dispatches,
                 "dispatch_gap_ms": round(gap * 1e3, 4),
                 "overhead_share": round(

@@ -26,7 +26,7 @@ def train_fn(steps: int = 3, batch_per_device: int = 2, size: int = 32):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from sparkdl_tpu.models.resnet import ResNet50
-    from sparkdl_tpu.runtime.mesh import data_parallel_mesh, mesh_context
+    from sparkdl_tpu.runtime.mesh import data_parallel_mesh
     from sparkdl_tpu.train.vision import make_vision_train_step
 
     mesh = data_parallel_mesh()  # every device across every process on dp
@@ -44,7 +44,7 @@ def train_fn(steps: int = 3, batch_per_device: int = 2, size: int = 32):
     rng = np.random.default_rng(jax.process_index())
     data = NamedSharding(mesh, P(("dp", "fsdp")))
     repl = NamedSharding(mesh, P())
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params = jax.device_put(params, repl)
         batch_stats = jax.device_put(batch_stats, repl)
         opt_state = jax.device_put(tx.init(params), repl)

@@ -15,6 +15,9 @@ def main(payload_path: str, result_path: str) -> int:
     with open(payload_path, "rb") as f:
         payload = cloudpickle.load(f)
     objective, params = payload["objective"], payload["params"]
+    from sparkdl_tpu.runtime.chip import configure_compile_cache
+
+    configure_compile_cache()
     try:
         out = objective(params)
         loss = out["loss"] if isinstance(out, dict) else float(out)

@@ -141,9 +141,11 @@ def _run_trials_processes(objective, candidates, parallelism,
 
     from sparkdl_tpu.runner.backends import (
         local_pinnable_chips,
+        require_parent_off_chip,
         tpu_chip_pin_overrides,
     )
 
+    require_parent_off_chip("fmin(trial_runner='processes')")
     if pin_devices is None:
         pin_devices = local_pinnable_chips()
     if pin_devices and parallelism > len(pin_devices):

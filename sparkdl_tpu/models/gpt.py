@@ -23,6 +23,7 @@ dominant modern model shape. Design:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Optional
 
@@ -759,6 +760,28 @@ def generate(
     return jnp.concatenate([prompt_ids, toks.swapaxes(0, 1)], axis=1)
 
 
+@functools.lru_cache(maxsize=8)
+def _sp_prefill_program(model: GPTLMHeadModel, mesh: Any):
+    """The jitted sp-sharded forward of :func:`sp_prefill`, one per
+    (model, mesh): compiled, because an eager ``shard_map`` dispatches
+    every primitive of the model as its own SPMD program."""
+    from jax.sharding import PartitionSpec as P
+
+    axis = model.config.sp_axis
+
+    def local(variables, ids_l, pos_l):
+        logits, kv = model.apply(
+            variables, ids_l, positions=pos_l, return_kv=True)
+        return logits, kv["k"], kv["v"]
+
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(P(), P(None, axis), P(None, axis)),
+        out_specs=(P(None, axis), P(None, None, axis),
+                   P(None, None, axis)),
+    ))
+
+
 def sp_prefill(
     model: GPTLMHeadModel,
     variables: Any,
@@ -778,8 +801,9 @@ def sp_prefill(
       fp accumulation order.
     - ``"allgather"`` — gather the K/V shards once, dense masked
       softmax per query shard: **bitwise-identical** logits to the
-      unsharded forward (the serving parity contract), right for small
-      ``sp`` where the gathered keys fit.
+      jitted unsharded forward (the serving parity contract: the
+      engine's programs are all compiled), right for small ``sp``
+      where the gathered keys fit.
 
     Requires ``config.attn_impl == "ring"``. Prompts whose length does
     not divide ``sp`` are right-padded internally (pad keys sit causally
@@ -789,10 +813,6 @@ def sp_prefill(
     :func:`init_cache`-shaped pytree holding the prompt's K/V (k/v
     ``[layers, B, L, H, D]``, ``idx = L``) — ready to seed decode.
     """
-    from jax.sharding import PartitionSpec as P
-
-    from sparkdl_tpu.compat import shard_map
-
     c = model.config
     axis = c.sp_axis
     if c.attn_impl != "ring":
@@ -814,18 +834,12 @@ def sp_prefill(
     # mask globally and RoPE must agree with it (model docstring)
     positions = jnp.broadcast_to(jnp.arange(lpad)[None, :], (b, lpad))
 
-    def local(variables, ids_l, pos_l):
-        logits, kv = model.apply(
-            variables, ids_l, positions=pos_l, return_kv=True)
-        return logits, kv["k"], kv["v"]
-
-    fn = shard_map(
-        local, mesh=mesh,
-        in_specs=(P(), P(None, axis), P(None, axis)),
-        out_specs=(P(None, axis), P(None, None, axis),
-                   P(None, None, axis)),
-    )
-    logits, ks, vs = fn(variables, ids, positions)
+    # unbox OUTSIDE the manual region: the params enter replicated
+    # (in_specs P()), and flax would otherwise turn each boxed param's
+    # tp metadata into a sharding constraint inside shard_map, where
+    # every mesh axis is Manual and no constraint may name one
+    logits, ks, vs = _sp_prefill_program(model, mesh)(
+        nn.meta.unbox(variables), ids, positions)
     cache = {"k": ks[:, :, :l], "v": vs[:, :, :l],
              "idx": jnp.asarray(l, jnp.int32)}
     return logits[:, :l], cache

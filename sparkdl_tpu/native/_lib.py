@@ -1,16 +1,22 @@
 """Build-on-first-import loaders + ctypes signatures for the native libs.
 
 No pybind11 in the image, so the binding layer is ctypes over a plain C
-ABI. Each .so is compiled lazily with g++ and cached under ``_build/``;
-environments without a toolchain (or without a lib's link dependencies)
-simply get ``lib() -> None`` for that library and the pure-Python
-fallbacks take over — the staging ring (csrc/sdl_bridge.cc) and the image
-decoder (csrc/sdl_decode.cc, links libjpeg/libpng) fail independently.
+ABI. Each .so is compiled lazily with g++ and cached under ``_build/``,
+named by a hash of its source and build command — a library built from
+other source (a stale ``_build/`` riding along in a copied tree, where
+mtimes say nothing) is simply never found. Environments without a
+toolchain (or without a lib's link dependencies) get ``lib() -> None``
+for that library and the pure-Python fallbacks take over — the staging
+ring (csrc/sdl_bridge.cc) and the image decoder (csrc/sdl_decode.cc,
+links libjpeg/libpng) fail independently. Callers that must not fall
+back (``chip_smoke.py``) assert :func:`available` /
+:func:`decode_available` themselves.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -31,27 +37,48 @@ class NativeLib:
                  link_flags: Sequence[str] = ()):
         self._name = name
         self._src = os.path.join(_HERE, "csrc", source)
-        self._so = os.path.join(_BUILD_DIR, f"lib{name}.so")
         self._declare = declare
         self._link_flags = list(link_flags)
         self._lock = threading.Lock()
         self._lib: ctypes.CDLL | None = None
         self._tried = False
+        #: True once THIS process compiled the library (vs. found the
+        #: hash-named build of the same source already cached).
+        self.built_here = False
+        #: the hash-named file the library was loaded from (None: not loaded)
+        self.path: "str | None" = None
 
-    def _compile(self) -> bool:
+    def _command(self, out: str, src: str) -> "list[str]":
+        # libraries after the source: the linker resolves left to right
+        return [
+            os.environ.get("CXX", "g++"),
+            "-O3", "-std=c++17", "-fPIC", "-shared", "-pthread",
+            "-o", out, src, *self._link_flags,
+        ]
+
+    def so_path(self) -> str:
+        """``_build/lib<name>-<hash>.so``: the hash covers the source
+        bytes, the compiler and its flags — what the build is made
+        from, not where the checkout lives — so the name identifies
+        the build."""
+        h = hashlib.sha256()
+        with open(self._src, "rb") as f:
+            h.update(f.read())
+        h.update("\0".join(self._command("", "")).encode())
+        return os.path.join(
+            _BUILD_DIR, f"lib{self._name}-{h.hexdigest()[:16]}.so")
+
+    def _compile(self, so: str) -> bool:
         os.makedirs(_BUILD_DIR, exist_ok=True)
         # per-process tmp name: concurrent first imports (several executor
         # processes on one host) must not write through the same tmp inode;
         # whichever os.replace lands last wins, both are valid builds.
-        tmp = f"{self._so}.tmp.{os.getpid()}"
-        cmd = [
-            os.environ.get("CXX", "g++"),
-            "-O3", "-std=c++17", "-fPIC", "-shared", "-pthread",
-            "-o", tmp, self._src, *self._link_flags,
-        ]
+        tmp = f"{so}.tmp.{os.getpid()}"
         try:
-            subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-            os.replace(tmp, self._so)  # atomic publish
+            subprocess.run(self._command(tmp, self._src), check=True,
+                           capture_output=True, timeout=120)
+            os.replace(tmp, so)  # atomic publish
+            self.built_here = True
             return True
         except (OSError, subprocess.SubprocessError) as e:
             detail = getattr(e, "stderr", b"") or b""
@@ -74,27 +101,22 @@ class NativeLib:
                     "%s disabled via SPARKDL_TPU_DISABLE_NATIVE", self._name
                 )
                 return None
-            # Rebuild when the cached .so predates the source (git pull with
-            # a persisting _build/), not only when it is absent. A deployment
-            # may ship the prebuilt .so without csrc/ — a missing source is
-            # simply "not stale", never an error.
             try:
-                stale = (
-                    os.path.exists(self._so)
-                    and os.path.getmtime(self._so) < os.path.getmtime(self._src)
-                )
-            except OSError:
-                stale = False
-            if (not os.path.exists(self._so) or stale) and not self._compile():
-                if not os.path.exists(self._so):
-                    return None  # no cached build to fall back to
+                so = self.so_path()
+            except OSError as e:
+                logger.warning("%s source unreadable (%s); using "
+                               "pure-Python fallback", self._name, e)
+                return None
+            if not os.path.exists(so) and not self._compile(so):
+                return None
             try:
-                self._lib = self._declare(ctypes.CDLL(self._so))
+                self._lib = self._declare(ctypes.CDLL(so))
+                self.path = so
             except (OSError, AttributeError) as e:
-                # OSError: corrupt/foreign .so. AttributeError: a cached
-                # build missing a newer export — either way fall back to
-                # pure Python instead of erroring in every batch assembly.
-                logger.warning("could not load %s: %s", self._so, e)
+                # a truncated or foreign file under the right name: fall
+                # back to pure Python instead of erroring in every batch
+                # assembly
+                logger.warning("could not load %s: %s", so, e)
                 self._lib = None
             return self._lib
 
@@ -180,3 +202,13 @@ def decode_lib() -> ctypes.CDLL | None:
 
 def decode_available() -> bool:
     return _DECODE.available()
+
+
+def build_report() -> "dict[str, dict]":
+    """Per library: loaded?, the hash-named file, and whether this
+    process compiled it (what ``chip_smoke.py`` prints)."""
+    return {
+        n._name: {"loaded": n.available(), "path": n.path,
+                  "built_here": n.built_here}
+        for n in (_BRIDGE, _DECODE)
+    }

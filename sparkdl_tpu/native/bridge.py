@@ -133,7 +133,7 @@ class StagingRing:
     Producer: ``idx = acquire_write(); slot_view(idx)[...] = ...;
     commit_write(idx, n_rows)``. Consumer: ``idx = acquire_read();
     use slot_view(idx); release_read(idx)``. ``close()`` ends the stream;
-    readers then drain and get ``None``.
+    readers then drain and get ``None`` with :attr:`drained` set.
     """
 
     def __init__(self, slot_bytes: int, n_slots: int = 3):
@@ -147,6 +147,11 @@ class StagingRing:
         self._h = l.sdl_ring_create(slot_bytes, n_slots)
         if not self._h:
             raise MemoryError(f"could not allocate {n_slots}x{slot_bytes} ring")
+        #: end of stream, as decided INSIDE the ring's lock by a read that
+        #: found it closed and empty. A reader that instead pairs a
+        #: timed-out read with a later ``closed`` check can race the
+        #: producer's final commit+close and drop the last batch.
+        self.drained = False
         self.slot_bytes = slot_bytes
         self.n_slots = n_slots
 
@@ -166,8 +171,10 @@ class StagingRing:
 
     def acquire_read(self, timeout_s: float = -1.0) -> int | None:
         """Next committed slot index; None on timeout or end-of-stream
-        (distinguish via :meth:`closed`)."""
+        (distinguish via :attr:`drained`, never via :attr:`closed`)."""
         r = self._l.sdl_ring_acquire_read(self._h, timeout_s)
+        if r == -2:  # closed AND empty, atomically
+            self.drained = True
         return None if r < 0 else int(r)
 
     def slot_rows(self, idx: int) -> int:
@@ -450,7 +457,7 @@ class DeviceFeeder:
                 while not stop.is_set():
                     idx = ring.acquire_read(timeout_s=0.1)
                     if idx is None:
-                        if ring.closed:
+                        if ring.drained:
                             break
                         continue
                     m = meta.pop(idx)

@@ -55,6 +55,7 @@ from sparkdl_tpu.observability.metrics import (
     StepMeter,
     aggregate_across_hosts,
     compiled_flops,
+    device_peak,
     device_peak_flops,
     percentile,
 )
@@ -98,6 +99,7 @@ __all__ = [
     "check_health",
     "compiled_flops",
     "current_context",
+    "device_peak",
     "device_peak_flops",
     "disable_tracing",
     "enable_tracing",

@@ -16,25 +16,35 @@ the caller supplies the analytic count.
 from __future__ import annotations
 
 import collections
+import dataclasses
 import statistics
 import time
 from typing import Any, Callable
 
 import jax
 
-#: Peak dense matmul FLOP/s per chip. bf16 figures from public TPU/GPU
-#: datasheets; fp32 is the bf16 number /2 on TPU (the MXU computes in bf16
-#: with fp32 accumulate; pure-fp32 runs at half rate on v4/v5).
-_PEAK_FLOPS: dict[str, float] = {
-    # TPU generations (per chip, bf16)
-    "v6e": 918e12,
-    "v5p": 459e12,
-    "v5e": 197e12,
-    "v5": 197e12,
-    "v4": 275e12,
-    "v3": 123e12,
-    "v2": 46e12,
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeak:
+    """Published per-chip peaks: dense bf16 matmul FLOP/s, HBM bytes/s."""
+
+    bf16_flops: float
+    hbm_bytes_per_s: float
+
+
+#: Peaks keyed by ``jax.Device.device_kind`` EXACTLY as the chip prints
+#: it — one row per chip this repository has run on. A kind that is not
+#: here is an error, never a default: a guessed peak makes every MFU and
+#: roofline share derived from it wrong without saying so.
+DEVICE_PEAKS: dict[str, ChipPeak] = {
+    # TPU v5e (Google Cloud documentation, "TPU v5e"): 197 TFLOP/s bf16,
+    # 819 GB/s HBM. device_kind as printed by chip_smoke.py on the v5e.
+    "TPU v5 lite": ChipPeak(bf16_flops=197e12, hbm_bytes_per_s=819e9),
 }
+
+
+class UnknownDeviceError(LookupError):
+    """A TPU whose ``device_kind`` has no row in :data:`DEVICE_PEAKS`."""
 
 
 def percentile(values: "list[float] | tuple[float, ...]",
@@ -62,22 +72,37 @@ def _percentile_sorted(s: "list[float]", p: float) -> float | None:
     return float(s[lo] * (1.0 - frac) + s[hi] * frac)
 
 
-def device_peak_flops(device: "jax.Device | None" = None,
-                      dtype: str = "bf16") -> float | None:
-    """Best-effort peak FLOP/s of one chip; None when unknown (CPU, etc.)."""
+def device_peak(device: "jax.Device | None" = None) -> "ChipPeak | None":
+    """The :data:`DEVICE_PEAKS` row of ``device`` (default: the first
+    device). None off-TPU — a CPU run has no device peak and its MFU is
+    "not measured"; a TPU kind without a row raises
+    :class:`UnknownDeviceError`."""
     if device is None:
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    peak = None
-    for tag, flops in _PEAK_FLOPS.items():
-        if tag in kind.replace(" ", ""):
-            peak = flops
-            break
-    if peak is None and "tpu" in kind:
-        peak = _PEAK_FLOPS["v5e"]  # conservative default for unknown TPUs
-    if peak is not None and dtype in ("f32", "fp32", "float32"):
-        peak /= 2
-    return peak
+    if device.platform != "tpu":
+        return None
+    try:
+        return DEVICE_PEAKS[device.device_kind]
+    except KeyError:
+        raise UnknownDeviceError(
+            f"no peak FLOP/s / HBM bandwidth on record for device_kind "
+            f"{device.device_kind!r} (known: {sorted(DEVICE_PEAKS)}); add "
+            "its published peaks to observability.metrics.DEVICE_PEAKS "
+            "with their source"
+        ) from None
+
+
+def device_peak_flops(device: "jax.Device | None" = None,
+                      dtype: str = "bf16") -> float | None:
+    """Peak FLOP/s of one chip from :func:`device_peak` (None off-TPU).
+    fp32 is the bf16 number /2: the MXU computes in bf16 with fp32
+    accumulate, and pure-fp32 runs at half rate."""
+    peak = device_peak(device)
+    if peak is None:
+        return None
+    if dtype in ("f32", "fp32", "float32"):
+        return peak.bf16_flops / 2
+    return peak.bf16_flops
 
 
 def compiled_flops(fn: Callable, *args: Any, **kwargs: Any) -> float | None:
@@ -90,10 +115,7 @@ def compiled_flops(fn: Callable, *args: Any, **kwargs: Any) -> float | None:
     try:
         jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
         compiled = jitted.lower(*args, **kwargs).compile()
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-            cost = cost[0] if cost else {}
-        flops = cost.get("flops")
+        flops = compiled.cost_analysis().get("flops")
         return float(flops) if flops and flops > 0 else None
     except Exception:
         return None

@@ -5,8 +5,6 @@ mode) lands everywhere at once."""
 
 from __future__ import annotations
 
-import jax
-
 
 def vmem(shape, dtype):
     from jax.experimental.pallas import tpu as pltpu
@@ -27,6 +25,11 @@ def smem_space():
 
 
 def auto_interpret() -> bool:
-    """Pallas interpreter mode anywhere that is not a real TPU backend
-    (the CPU test harness and the virtual mesh)."""
-    return jax.default_backend() != "tpu"
+    """Compiled Mosaic on a TPU backend; the Pallas interpreter only
+    under the explicit-CPU harness (``JAX_PLATFORMS=cpu``: the tests and
+    the virtual mesh). Any other backend raises — in particular jax's
+    own silent drop to CPU when no TPU answers must not turn a kernel
+    into an interpreted one."""
+    from sparkdl_tpu.runtime.chip import require_tpu
+
+    return not require_tpu(explicit_cpu_ok=True)

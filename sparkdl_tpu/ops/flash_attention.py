@@ -37,6 +37,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from sparkdl_tpu.ops._pallas import auto_interpret
+
 _NEG_INF = -1e30  # large-negative, not -inf: keeps exp()/where() NaN-free
 _LANES = 128  # TPU lane width: last-dim tile size
 
@@ -359,7 +361,8 @@ def flash_attention(
     """Fused flash attention over [B, L, H, D] tensors.
 
     kv_mask: optional [B, Lk] bool — False key positions (padding) are
-    excluded. interpret=None auto-selects Pallas interpreter mode off-TPU.
+    excluded. interpret=None compiles on a TPU and selects the Pallas
+    interpreter only under the explicit-CPU harness (``auto_interpret``).
     Differentiable in q/k/v (blockwise-recomputed backward kernels).
     q_offset (static): global position of query row 0 for the causal
     mask — cached prefill places L queries at [q_offset, q_offset+L)
@@ -376,7 +379,7 @@ def flash_attention(
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = auto_interpret()
 
     # Pad: L to block multiples (block shrinks to the padded length for
     # short sequences), D to the 128-lane tile. Padded keys are masked;

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from sparkdl_tpu.compat import shard_map
 
 def stack_stage_params(per_stage_params: list[Any]) -> Any:
     """Stack per-stage param pytrees along a new leading (pp) dim."""
@@ -107,7 +106,7 @@ def pipeline_apply(
         params = jax.tree.map(lambda p: jnp.squeeze(p, 0), params)
         return _pipeline_local(stage_fn, params, x_mb, axis_name=axis_name)
 
-    out_mb = shard_map(
+    out_mb = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis_name), P()),

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from sparkdl_tpu.compat import shard_map
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp()/where() NaN-free
 
 
@@ -93,15 +92,12 @@ def _ring_attention_local(
     l0 = jnp.zeros((b, h, lq), jnp.float32)
     # Accumulators become device-varying inside the loop (they mix in q/k/v,
     # which vary over the mesh axes of the enclosing shard_map); the scan
-    # carry type must declare that up front. Older jax has no
-    # varying-manual-axes typing (jax.typeof/.vma/pcast) — there the carry
-    # needs no declaration, so skip.
-    if hasattr(jax, "typeof"):
-        vma = tuple(jax.typeof(q).vma)
-        if vma:
-            o0, m0, l0 = (
-                lax.pcast(t, vma, to="varying") for t in (o0, m0, l0)
-            )
+    # carry type must declare that up front.
+    vma = tuple(jax.typeof(q).vma)
+    if vma:
+        o0, m0, l0 = (
+            lax.pcast(t, vma, to="varying") for t in (o0, m0, l0)
+        )
     masked = kv_mask is not None
 
     def step(carry, i):
@@ -219,9 +215,9 @@ def ring_attention(
         _ring_attention_local, axis_name=axis_name, causal=causal, scale=scale
     )
     if kv_mask is None:
-        return shard_map(
+        return jax.shard_map(
             fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
         )(q, k, v)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec, mask_spec), out_specs=spec
     )(q, k, v, kv_mask)

@@ -30,21 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sparkdl_tpu.runtime.mesh import mesh_context
-
 Dtype = Any
-
-
-def _active_mesh():
-    """The mesh in scope, across jax versions: ``get_abstract_mesh`` when
-    the runtime has it (jax >= 0.5), else the thread-local physical mesh
-    (0.4.x spells the same 'which mesh am I under' question that way)."""
-    try:
-        return jax.sharding.get_abstract_mesh()
-    except AttributeError:  # jax < 0.5
-        from jax._src import mesh as mesh_lib
-
-        return mesh_lib.thread_resources.env.physical_mesh
 
 
 def constrain_dim(x: jax.Array, axis: str, dim: int = -1) -> jax.Array:
@@ -54,14 +40,14 @@ def constrain_dim(x: jax.Array, axis: str, dim: int = -1) -> jax.Array:
     leading expert dim. No-op outside a mesh context (single-device tests)
     or under shard_map over the axis (arrays are already per-device blocks);
     a mesh without the axis is a real error and propagates."""
-    mesh = _active_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty:
         return x
     if axis not in mesh.axis_names:
         raise ValueError(
             f"axis {axis!r} not in the active mesh axes {mesh.axis_names}"
         )
-    if axis in getattr(mesh, "manual_axes", ()):
+    if axis in mesh.manual_axes:
         return x
     parts: list = [P.UNCONSTRAINED] * x.ndim
     parts[dim] = axis
@@ -182,5 +168,5 @@ def init_sharded(
         variables = module.init(r, *sample_inputs)
         return nn.meta.unbox(variables)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         return jax.jit(_init, out_shardings=shardings)(rng)

@@ -16,8 +16,7 @@ the exemplar codebases, SNIPPETS [2]):
   its shardings from *inside* the traced function, so the same wrapped
   step works under plain ``jit`` and under ``chain_carry``'s scan; and
   :meth:`wrap_apply` jits an inference forward with **explicit
-  in/out shardings** — the form that dodges the jax 0.4.x implicit-GSPMD
-  miscompile the dp+tp GPT oracle documents),
+  in/out shardings**),
 - **how state leaves the mesh** (:meth:`gather_for_checkpoint`).
 
 Implementations:
@@ -50,7 +49,7 @@ from sparkdl_tpu.partition.zero import (
     export_opt_state_bytes,
     zero_partition_specs,
 )
-from sparkdl_tpu.runtime.mesh import MeshShapeError, mesh_context
+from sparkdl_tpu.runtime.mesh import MeshShapeError
 
 __all__ = [
     "Partitioner",
@@ -182,10 +181,9 @@ class Partitioner:
         """Place ``tree`` on ``shardings`` with buffers the RESULT owns.
 
         Train state is DONATED on the fused-dispatch path (chain_carry),
-        and jax 0.4's ``device_put`` aliases same-device shards even
-        under ``may_alias=False`` — donation would then delete the
-        caller's own arrays. A jitted identity with ``out_shardings``
-        always materializes fresh buffers."""
+        and ``device_put`` may alias same-device shards — donation
+        would then delete the caller's own arrays. A jitted identity
+        with ``out_shardings`` always materializes fresh buffers."""
         return jax.jit(lambda t: t, out_shardings=shardings)(tree)
 
     def shard_params(self, params: Any) -> Any:
@@ -243,13 +241,7 @@ class Partitioner:
     def wrap_apply(self, apply_fn: Callable, params: Any) -> Callable:
         """Jit ``apply_fn(params, batch)`` with **explicit** in/out
         shardings: params on their specs, batch and every output leaf
-        split over the data axes.
-
-        Explicitness is load-bearing on jax 0.4.x: the implicit form
-        (committed arrays + bare ``jit``) miscompiles dp+tp-sharded
-        transformer forwards (PARITY.md repro); spelling the shardings
-        on the jit boundary compiles correctly on 0.4.x and 0.5+ both.
-        """
+        split over the data axes."""
         return jax.jit(
             apply_fn,
             in_shardings=(self.param_shardings(_unbox(params)),
@@ -274,7 +266,7 @@ class Partitioner:
     def mesh_context(self):
         if self.mesh is None:
             return contextlib.nullcontext()
-        return mesh_context(self.mesh)
+        return jax.set_mesh(self.mesh)
 
     def describe(self) -> "dict[str, Any]":
         """Operator/bench view: kind, axis sizes, batch/zero policy."""

@@ -1,0 +1,116 @@
+"""The chip this process runs on: insist on it, and keep its compiles.
+
+Two decisions every chip-facing entry point (``chip_smoke.py``, the
+bench scripts, the worker children) must make the same way live here so
+that none of them can quietly measure or run something else:
+
+* :func:`require_tpu` — a program written for the TPU runs on the TPU.
+  jax itself drops to CPU with a warning when no TPU answers; a script
+  that then prints a number has measured XLA:CPU under a device
+  metric's name. The only CPU run a chip script may make is one the
+  caller asked for by exporting ``JAX_PLATFORMS=cpu`` (the contract
+  smokes in ``run-tests.sh``), and it is told so it can label its
+  output.
+* :func:`configure_compile_cache` — the one site that touches jax's
+  persistent compilation cache. A chip-tool call starts cold and the
+  serving engine alone compiles dozens of small programs, so every
+  process that compiles shares one directory.
+"""
+
+from __future__ import annotations
+
+import os
+
+__all__ = [
+    "COMPILE_CACHE_DIR",
+    "NoAcceleratorError",
+    "cache_entry_count",
+    "configure_compile_cache",
+    "explicit_cpu",
+    "require_tpu",
+    "smoke_label",
+]
+
+#: Where compiled programs persist when the environment names no place:
+#: one fixed directory at the root of the checkout (git-ignored). The
+#: path is part of jax's cache key, so it must never be derived from a
+#: pid, a clock or a tempdir — a directory that moves never hits.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_compile_cache",
+)
+
+
+class NoAcceleratorError(RuntimeError):
+    """A chip-only entry point found no TPU backend."""
+
+
+def explicit_cpu() -> bool:
+    """True when the caller exported ``JAX_PLATFORMS=cpu`` — the CPU
+    test harness and contract smokes, never a silent jax fallback."""
+    return os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+
+
+def require_tpu(*, explicit_cpu_ok: bool = False) -> bool:
+    """True when the default jax backend is a TPU; otherwise raise.
+
+    ``explicit_cpu_ok=True`` (bench contract smokes) returns False
+    instead of raising when the caller exported ``JAX_PLATFORMS=cpu``;
+    the script must then print no per-chip metric name and no
+    ``vs_baseline``. Initialises the backend — call it from the process
+    that is meant to hold the chip.
+    """
+    import jax
+
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return True
+    if explicit_cpu_ok and explicit_cpu():
+        return False
+    raise NoAcceleratorError(
+        f"no TPU backend (jax.default_backend() == {backend!r}, "
+        f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}): this entry "
+        "point measures the chip and does not fall back to another "
+        "device"
+    )
+
+
+def smoke_label(on_chip: bool) -> str:
+    """Prefix for a bench script's ``metric`` string: empty on the chip;
+    under the explicit-CPU contract smoke it says, in the record itself,
+    that the value beside it is not a device measurement."""
+    return "" if on_chip else "contract smoke, not a device measurement: "
+
+
+def configure_compile_cache() -> "str | None":
+    """Point jax's persistent compilation cache somewhere durable; call
+    before the first jit. Returns the directory in use (None: no cache).
+
+    * ``JAX_COMPILATION_CACHE_DIR`` set: jax already honours it —
+      nothing is set in code, so an operator's directory and thresholds
+      stand exactly as exported.
+    * explicit-CPU harness: no cache, so test runs leave nothing in the
+      checkout for the chip tool to copy.
+    * otherwise: :data:`COMPILE_CACHE_DIR`, with the minimum compile
+      time and entry size dropped to zero so the engine's many
+      sub-second programs persist too.
+    """
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    if explicit_cpu():
+        return None
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return COMPILE_CACHE_DIR
+
+
+def cache_entry_count(path: "str | None") -> int:
+    """Files under the cache directory (0 when absent or no cache)."""
+    if not path or not os.path.isdir(path):
+        return 0
+    return sum(len(files) for _, _, files in os.walk(path))

@@ -137,16 +137,10 @@ def mesh_axis_size(mesh: Mesh, axis: str) -> int:
     return mesh.shape[axis]
 
 
-def mesh_context(mesh: Mesh):
-    """``with mesh_context(mesh):`` across jax versions.
+def shard_device_ids(tree) -> "list[int]":
+    """Sorted ids of the devices the addressable shards of ``tree``'s
+    arrays live on — what a multi-chip check asserts the size of (code
+    that has only seen one device may put everything on the first)."""
+    return sorted({s.device.id for leaf in jax.tree.leaves(tree)
+                   for s in leaf.addressable_shards})
 
-    jax >= 0.5 spells the ambient-mesh scope ``jax.set_mesh(mesh)``; on
-    0.4.x the Mesh object itself is the context manager that installs the
-    thread-local physical mesh (which ``with_sharding_constraint`` and
-    ``parallel.tensor_parallel.constrain_dim`` resolve axis names
-    against). One call site, either runtime.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh

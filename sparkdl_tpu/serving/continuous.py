@@ -2278,7 +2278,7 @@ class ContinuousGPTEngine:
             # of the token ids is enqueued the moment the decode dispatch
             # is — it rides behind the compute instead of waiting for the
             # host to come back with a blocking np.asarray after the
-            # program retires (one relay RTT saved per decode dispatch).
+            # program retires.
             # block_until_ready splits compute from collection so
             # sparkdl_fetch_wait_seconds{path="decode"} meters ONLY the
             # residual copy wait, not the decode program itself.

@@ -121,11 +121,6 @@ class BatchedRunner:
     #: local devices when ``data_parallel`` resolves on. Pass one
     #: explicitly to run this runner over a custom data-parallel mesh
     #: layout (the chunk/bucket sizes round to its data-axis size).
-    #: Model-axis (tp/fsdp-on-params) layouts are rejected on jax 0.4.x:
-    #: this runner's bare-jit compile relies on implicit GSPMD
-    #: propagation, which 0.4.x miscompiles for such params (PARITY.md)
-    #: — inference through sharded params goes via
-    #: ``Partitioner.wrap_apply``'s explicit shardings instead.
     partitioner: Any = None
     #: Online autotuning of the ingest knobs (sparkdl_tpu/ingest): the
     #: staging depth, the dispatch chain K, and the native packer
@@ -196,28 +191,6 @@ class BatchedRunner:
                     "data_parallel=True would be silently overridden — "
                     "leave it at None and encode dp in the partitioner's "
                     "mesh instead"
-                )
-            mesh = getattr(self.partitioner, "mesh", None)
-            model_ways = (
-                mesh.devices.size // self.partitioner.data_axis_size
-                if mesh is not None else 1
-            )
-            if model_ways > 1 and not hasattr(jax, "set_mesh"):
-                # this runner compiles apply_fn with a bare jit (params
-                # are closure constants), i.e. implicit GSPMD
-                # propagation — the form measured to miscompile
-                # tp/model-axis-sharded params on jax 0.4.x (PARITY.md).
-                # Refuse loudly rather than serve silently wrong logits;
-                # per-replica SPMD serving sub-meshes are a ROADMAP
-                # follow-on that will route through wrap_apply's
-                # explicit shardings.
-                raise ValueError(
-                    f"partitioner shards {model_ways}-way over model "
-                    "(non-batch) mesh axes, which this jax 0.4.x "
-                    "runner's implicit-propagation jit miscompiles "
-                    "(PARITY.md) — use a data-parallel layout here, or "
-                    "Partitioner.wrap_apply for explicit-sharding "
-                    "inference"
                 )
             self._partitioner = self.partitioner
             self._round_to_data_axes(self._partitioner.data_axis_size)

@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sparkdl_tpu.compat import shard_map
 from sparkdl_tpu.models.bert import (
     BertConfig,
     BertForSequenceClassification,
@@ -107,7 +106,7 @@ def test_ring_attention_bert_matches_full():
                 vars_, ids_l, mask_l, position_ids=pos_l
             )[0]
 
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(P("dp", "sp"), P("dp", "sp"), P("dp", "sp")),

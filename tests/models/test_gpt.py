@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sparkdl_tpu.compat import shard_map
 from sparkdl_tpu.models.gpt import (
     GPTConfig,
     GPTLMHeadModel,
@@ -15,7 +14,7 @@ from sparkdl_tpu.models.gpt import (
     init_cache,
 )
 from sparkdl_tpu.parallel.tensor_parallel import init_sharded
-from sparkdl_tpu.runtime.mesh import MeshSpec, mesh_context
+from sparkdl_tpu.runtime.mesh import MeshSpec
 
 
 @pytest.fixture(scope="module")
@@ -264,7 +263,7 @@ def test_ring_gpt_matches_full(tiny):
     def local(ids_l, pos_l):
         return ring_model.apply(params, ids_l, positions=pos_l)[0]
 
-    got = shard_map(
+    got = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("dp", "sp"), P("dp", "sp")),
         out_specs=P("dp", "sp"),
@@ -291,13 +290,8 @@ def test_eager_cache_overflow_raises(tiny):
 
 
 def test_tp_sharded_matches_unsharded(tiny):
-    """dp+tp forward through SPMDPartitioner's EXPLICIT shardings.
-
-    Un-skipped from PR 1: the implicit form (committed params + bare
-    jit, relying on GSPMD propagation) miscompiles on jax 0.4.x — see
-    test_tp_implicit_propagation_miscompile below and PARITY.md. With
-    the partitioner spelling in/out shardings on the jit boundary the
-    same dp=2 x tp=4 forward is exact on 0.4.37 and 0.5+ both."""
+    """dp+tp forward through SPMDPartitioner's EXPLICIT shardings on
+    the jit boundary matches the unsharded forward."""
     cfg, model, params, ids = tiny
     from sparkdl_tpu.partition import GPT_RULES, SPMDPartitioner, make_mesh
 
@@ -313,36 +307,23 @@ def test_tp_sharded_matches_unsharded(tiny):
     )
 
 
-def test_tp_implicit_propagation_miscompile(tiny):
-    """Pin the 0.4.x repro the skip used to paper over: the IMPLICIT
-    dp+tp form (committed params, bare jit, GSPMD propagation)
-    miscompiles — jitted output diverges from the eager forward by >1
-    abs on the SAME committed params (measured 2.89 on 0.4.37;
-    tp-only meshes are exact). Runs on every jax: 0.5+ (where
-    propagation compiles correctly) asserts exactness instead, so the
-    PARITY.md caveat is version-pinned in both directions. If a 0.4.x
-    point release fixes propagation, the >1 assert fails loudly — then
-    delete this repro and the explicit-only caveat in PARITY.md."""
+def test_tp_implicit_propagation_matches_eager(tiny):
+    """The IMPLICIT dp+tp form (committed params, bare jit, GSPMD
+    propagation) agrees with the eager forward on the same committed
+    params — BatchedRunner's bare-jit compile over a model-sharded
+    partitioner relies on it."""
     cfg, model, params, ids = tiny
     mesh = MeshSpec(dp=2, tp=4).build()
     sharded = init_sharded(model, jax.random.PRNGKey(0), [ids], mesh)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         logits_tp, _ = jax.jit(lambda p, x: model.apply(p, x))(sharded, ids)
     logits_local, _ = model.apply(jax.tree.map(jnp.asarray, sharded), ids)
     err = float(np.max(np.abs(np.asarray(logits_tp)
                               - np.asarray(logits_local))))
-    if hasattr(jax, "set_mesh"):  # 0.5+: propagation compiles correctly
-        assert err < 1e-4, (
-            f"jax >= 0.5 implicit GSPMD propagation regressed (max abs "
-            f"err {err}): the 0.4.x-only caveat in PARITY.md no longer "
-            "holds on this version"
-        )
-    else:
-        assert err > 1.0, (
-            f"implicit GSPMD propagation now agrees with eager (max abs "
-            f"err {err}): the 0.4.x miscompile is fixed on this jax — "
-            "drop this repro test and the PARITY.md caveat"
-        )
+    assert err < 1e-4, (
+        f"implicit GSPMD propagation diverges from eager (max abs err "
+        f"{err})"
+    )
 
 
 def test_hf_gpt2_weight_fidelity():
@@ -389,7 +370,7 @@ def test_moe_gpt_forward_backward():
         tgt = ids[:, 1:]
         return -jnp.mean(jnp.take_along_axis(logp, tgt[..., None], -1))
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         val, g = jax.jit(jax.value_and_grad(loss))(params)
     assert np.isfinite(float(val))
     assert all(np.all(np.isfinite(np.asarray(l))) for l in jax.tree.leaves(g))

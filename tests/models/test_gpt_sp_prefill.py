@@ -41,8 +41,12 @@ def bundle():
         rng.integers(1, cfg.vocab_size, (2, PROMPT_LEN)), jnp.int32)
     even_ids = jnp.asarray(
         rng.integers(1, cfg.vocab_size, (2, EVEN_LEN)), jnp.int32)
-    ref_logits, _ = model.apply(variables, ids)
-    even_ref, _ = model.apply(variables, even_ids)
+    # the references are COMPILED forwards, like sp_prefill's program
+    # and every program the serving engine runs: the bitwise tier is
+    # sharded-vs-unsharded under one compiler, not jit-vs-eager
+    forward = jax.jit(lambda v, x: model.apply(v, x)[0])
+    ref_logits = forward(variables, ids)
+    even_ref = forward(variables, even_ids)
     return (cfg, variables, ids, np.asarray(ref_logits),
             even_ids, np.asarray(even_ref))
 
@@ -93,7 +97,9 @@ def test_sp_prefill_kv_matches_cached_prefill(bundle):
         _sp_model(cfg, "allgather"), variables, even_ids, mesh)
     model = GPTLMHeadModel(cfg)
     dense_cache = init_cache(cfg, even_ids.shape[0], EVEN_LEN)
-    _, dense_cache = model.apply(variables, even_ids, cache=dense_cache)
+    _, dense_cache = jax.jit(
+        lambda v, x, c: model.apply(v, x, cache=c))(
+            variables, even_ids, dense_cache)
     np.testing.assert_array_equal(
         np.asarray(cache["k"]), np.asarray(dense_cache["k"]))
     np.testing.assert_array_equal(

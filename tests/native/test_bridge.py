@@ -48,7 +48,26 @@ def test_ring_blocking_and_close():
     ring.release_read(r)
     ring.close()
     assert ring.acquire_read(timeout_s=1.0) is None
-    assert ring.closed
+    assert ring.closed and ring.drained
+    ring.destroy()
+
+
+def test_timed_out_read_then_commit_and_close_keeps_the_last_batch():
+    """The feed's end-of-stream race: a read times out, THEN the producer
+    commits its final batch and closes. ``closed`` is already true with
+    a batch still queued — a reader that broke on it dropped the batch
+    (DeepImageFeaturizer then yielded fewer rows than it was fed).
+    ``drained`` only turns true once a read finds the ring empty."""
+    ring = StagingRing(slot_bytes=16, n_slots=2)
+    assert ring.acquire_read(timeout_s=0.01) is None  # timed out
+    w = ring.acquire_write()
+    ring.commit_write(w, 1, 4)
+    ring.close()
+    assert ring.closed and not ring.drained
+    r = ring.acquire_read(timeout_s=1.0)
+    assert r == w
+    ring.release_read(r)
+    assert ring.acquire_read(timeout_s=1.0) is None and ring.drained
     ring.destroy()
 
 
@@ -69,7 +88,7 @@ def test_ring_cross_thread():
     while True:
         r = ring.acquire_read(timeout_s=2.0)
         if r is None:
-            assert ring.closed
+            assert ring.drained
             break
         got.append(int(ring.slot_view(r)[:4].view(np.int32)[0]))
         ring.release_read(r)
@@ -195,3 +214,34 @@ def test_device_feeder_single_slot_python_fallback_bounded(monkeypatch):
     got = [np.asarray(b) for b in feeder]
     assert len(got) == 6
     np.testing.assert_array_equal(got[3], batches[3])
+
+
+def test_a_library_built_from_other_source_is_never_loaded(
+        tmp_path, monkeypatch):
+    """The built file is named by a hash of its source and build command:
+    a ``_build/`` left over from other source (copied trees keep no
+    mtime relation) is simply not found, and the library rebuilds."""
+    import shutil
+
+    src = tmp_path / "csrc"
+    src.mkdir()
+    shutil.copy(_lib._BRIDGE._src, src / "sdl_bridge.cc")
+    monkeypatch.setattr(_lib, "_HERE", str(tmp_path))
+    monkeypatch.setattr(_lib, "_BUILD_DIR", str(tmp_path / "_build"))
+    first = _lib.NativeLib("sdlbridge", "sdl_bridge.cc", _lib._declare_bridge)
+    assert first.available() and first.built_here
+    built = first.so_path()
+
+    # same source, new process state: the cached build is reused
+    again = _lib.NativeLib("sdlbridge", "sdl_bridge.cc", _lib._declare_bridge)
+    assert again.available() and not again.built_here
+    assert again.so_path() == built
+
+    # the source changes under a stale _build/: different name, the old
+    # file is not loaded, a new one is built
+    with open(src / "sdl_bridge.cc", "a") as f:
+        f.write("\n// edited\n")
+    edited = _lib.NativeLib("sdlbridge", "sdl_bridge.cc",
+                            _lib._declare_bridge)
+    assert edited.so_path() != built
+    assert edited.available() and edited.built_here

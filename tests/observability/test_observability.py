@@ -125,6 +125,25 @@ class TestCompiledFlops:
     def test_peak_flops_unknown_on_cpu(self):
         assert device_peak_flops() is None  # tests run on fake CPU devices
 
+    def test_peak_table_is_keyed_by_the_printed_device_kind(self):
+        import types
+
+        from sparkdl_tpu.observability.metrics import (
+            UnknownDeviceError,
+            device_peak,
+        )
+
+        v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+        assert device_peak(v5e).bf16_flops == 197e12
+        assert device_peak(v5e).hbm_bytes_per_s == 819e9
+        assert device_peak_flops(v5e, "float32") == 197e12 / 2
+        # an unknown TPU is an error, never a default (and never the
+        # substring accident that read "TPU v5 lite" as the "v5" row)
+        for kind in ("TPU v5", "TPU v9", "tpu v5 lite"):
+            with pytest.raises(UnknownDeviceError, match="DEVICE_PEAKS"):
+                device_peak(types.SimpleNamespace(platform="tpu",
+                                                  device_kind=kind))
+
 
 class TestAggregation:
     def test_single_process_identity(self):

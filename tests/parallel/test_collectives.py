@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from sparkdl_tpu.compat import shard_map
 from sparkdl_tpu.parallel.collectives import (
     all_gather_params,
     cross_replica_mean,
@@ -20,7 +19,7 @@ def test_cross_replica_mean_is_horovod_allreduce():
     mesh = MeshSpec(dp=8).build()
     x = jnp.arange(8.0).reshape(8, 1)  # one value per dp peer
 
-    out = shard_map(
+    out = jax.shard_map(
         lambda t: cross_replica_mean({"g": t}, "dp")["g"],
         mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
     )(x)
@@ -39,7 +38,7 @@ def test_reduce_scatter_then_all_gather_roundtrip():
 
     # all_gather output is value-replicated but VMA-inferred as varying;
     # check_vma=False is the documented escape hatch.
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False
     )(g)
     np.testing.assert_allclose(np.asarray(out), np.asarray(g) * 8, rtol=1e-6)
@@ -59,7 +58,7 @@ def test_rs_ag_roundtrip_preserves_non_divisible_leaves():
         shard = reduce_scatter_grads(t, "fsdp")
         return all_gather_params(shard, "fsdp", full_shapes=full_shapes)
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False
     )(tree)
     assert out["b"].shape == (3,)
@@ -76,7 +75,7 @@ def test_psum_and_global_norm():
         n = global_norm({"g": t}, "dp")
         return s, jnp.broadcast_to(n, (1,))
 
-    s, n = shard_map(
+    s, n = jax.shard_map(
         body, mesh=mesh, in_specs=P("dp"), out_specs=(P("dp"), P("dp")),
     )(x)
     np.testing.assert_allclose(np.asarray(s), np.full((8, 3), 8.0))

@@ -13,7 +13,7 @@ from sparkdl_tpu.parallel.expert_parallel import (
     top_k_dispatch,
 )
 from sparkdl_tpu.parallel.tensor_parallel import init_sharded
-from sparkdl_tpu.runtime.mesh import MeshSpec, mesh_context
+from sparkdl_tpu.runtime.mesh import MeshSpec
 
 
 def _gates(g=2, s=16, e=4, seed=0):
@@ -112,7 +112,7 @@ class TestMoEMlpBlock:
     def test_sharded_matches_single_device_oracle(self):
         mesh = MeshSpec(dp=2, ep=4).build()
         model, params, x = self._build(mesh)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             data = jax.device_put(x, NamedSharding(mesh, P(("dp", "fsdp"))))
             y_sharded = jax.jit(lambda p, x: model.apply(p, x))(params, data)
         # Oracle: identical params applied on one device, no mesh.
@@ -129,7 +129,7 @@ class TestMoEMlpBlock:
         model = MoEMlpBlock(num_experts=2, hidden_features=16, k=1)
         x = jnp.ones((10, 8))
         params = init_sharded(model, jax.random.PRNGKey(0), [x], mesh)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             y = jax.jit(lambda p, x: model.apply(p, x))(params, x)
         assert y.shape == x.shape
 
@@ -146,7 +146,7 @@ class TestMoEMlpBlock:
                 + 0.001 * aux["router_z_loss"]
             )
 
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             val, g = jax.jit(jax.value_and_grad(loss))(params)
         assert np.isfinite(float(val))
         leaves = jax.tree.leaves(g)

@@ -12,7 +12,7 @@ from sparkdl_tpu.parallel.tensor_parallel import (
     init_sharded,
     param_shardings,
 )
-from sparkdl_tpu.runtime.mesh import MeshSpec, mesh_context
+from sparkdl_tpu.runtime.mesh import MeshSpec
 
 
 def test_tp_mlp_matches_plain_mlp():
@@ -29,7 +29,7 @@ def test_tp_mlp_matches_plain_mlp():
     assert up.sharding.spec == P(None, "tp")
     assert down.sharding.spec == P("tp", None)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         y = jax.jit(lambda p, x: model.apply(p, x))(params, x)
 
     # Oracle: same params, plain matmul math on one device.
@@ -58,7 +58,7 @@ def test_tp_grads_flow():
     def loss(p):
         return jnp.mean(model.apply(p, x) ** 2)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         g = jax.jit(jax.grad(loss))(params)
     leaves = jax.tree.leaves(g)
     assert leaves and all(np.all(np.isfinite(np.asarray(l))) for l in leaves)

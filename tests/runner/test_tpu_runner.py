@@ -104,3 +104,36 @@ def test_metrics_summary_logs_cross_host_rollup(caplog):
     with caplog.at_level(logging.INFO, logger="sparkdl_tpu.metrics"):
         TPURunner(np=-1, backend=_InlineBackend()).run(main, n=1)
     assert not [r for r in caplog.records if "all-host metrics" in r.message]
+
+
+def test_tpu_launch_refuses_a_parent_that_holds_the_chip(monkeypatch):
+    """A chip belongs to one process: a parent whose jax backend is a
+    live TPU must not start ranks that need it (they would hang until
+    timeout_s) — the launch raises at once. A CPU parent launches."""
+    import jax
+
+    from sparkdl_tpu.runner.backends import require_parent_off_chip
+
+    jax.devices()  # this process has an initialised (CPU) backend
+    require_parent_off_chip("cpu parent")  # silent
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="holds its chip"):
+        TPURunner(np=-4, local_platform="tpu").run(lambda: None)
+
+
+def test_tpu_rank_pins_form_one_job_layout():
+    from sparkdl_tpu.runner.backends import tpu_rank_overrides
+
+    env = tpu_rank_overrides(2, 4, [8476, 8477, 8478, 8479])
+    assert env == {
+        "TPU_VISIBLE_DEVICES": "2",
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "2,2,1",
+        "TPU_PROCESS_ADDRESSES": "localhost:8476,localhost:8477,"
+                                 "localhost:8478,localhost:8479",
+        "TPU_PROCESS_PORT": "8478",
+        "CLOUD_TPU_TASK_ID": "2",
+    }
+    # a rank count with no layout that has run on hardware: loud
+    with pytest.raises(ValueError, match="no single-host TPU process"):
+        tpu_rank_overrides(0, 3, [1, 2, 3])

@@ -1,0 +1,70 @@
+"""runtime/chip.py: the TPU-or-fail check and the compile-cache site."""
+
+import jax
+import pytest
+
+from sparkdl_tpu.runtime import chip
+
+
+def test_require_tpu_raises_without_a_tpu_backend():
+    # the harness is on an explicit CPU: chip-only entry points fail ...
+    with pytest.raises(chip.NoAcceleratorError, match="no TPU backend"):
+        chip.require_tpu()
+    # ... and contract smokes are told they are NOT on the chip
+    assert chip.require_tpu(explicit_cpu_ok=True) is False
+
+
+def test_silent_cpu_fallback_is_not_an_explicit_cpu(monkeypatch):
+    # jax dropped to CPU on its own (no JAX_PLATFORMS): never accepted
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert not chip.explicit_cpu()
+    with pytest.raises(chip.NoAcceleratorError):
+        chip.require_tpu(explicit_cpu_ok=True)
+
+
+def test_require_tpu_accepts_a_tpu_backend(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert chip.require_tpu() is True
+
+
+@pytest.fixture
+def config_updates(monkeypatch):
+    seen = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: seen.__setitem__(k, v))
+    return seen
+
+
+def test_compile_cache_is_inert_when_the_environment_places_it(
+        monkeypatch, config_updates):
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert chip.configure_compile_cache() == "/somewhere/else"
+    assert config_updates == {}
+
+
+def test_compile_cache_defaults_to_one_fixed_dir_in_the_checkout(
+        monkeypatch, config_updates):
+    import os
+
+    import sparkdl_tpu
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(sparkdl_tpu.__file__))
+    assert chip.configure_compile_cache() == chip.COMPILE_CACHE_DIR
+    assert chip.COMPILE_CACHE_DIR == os.path.join(repo, ".jax_compile_cache")
+    assert config_updates == {
+        "jax_compilation_cache_dir": chip.COMPILE_CACHE_DIR,
+        "jax_persistent_cache_min_compile_time_secs": 0.0,
+        "jax_persistent_cache_min_entry_size_bytes": -1,
+    }
+
+
+def test_compile_cache_stays_off_in_the_cpu_harness(
+        monkeypatch, config_updates):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert chip.explicit_cpu()
+    assert chip.configure_compile_cache() is None
+    assert config_updates == {}
+    assert chip.cache_entry_count(None) == 0

@@ -159,10 +159,15 @@ def test_chain_carry_matches_sequential_steps():
     chained = chain_carry(step, donate=False)
     stacked = jax.tree.map(lambda *a: jnp.stack(a), *xs_list)
     s_got, ms = chained(state0, stacked)
+    # the carried state is the contract: bitwise across scan/sequential
     np.testing.assert_array_equal(np.asarray(s_got["w"]),
                                   np.asarray(s_ref["w"]))
-    np.testing.assert_array_equal(
-        np.asarray(ms["norm"]), np.asarray(norms_ref, np.float32)
+    # the reported metric is a 16-element float32 reduction that XLA:CPU
+    # may order differently inside a scan body than in the standalone
+    # program: equal to float32 rounding, not bitwise
+    np.testing.assert_allclose(
+        np.asarray(ms["norm"]), np.asarray(norms_ref, np.float32),
+        rtol=2 * np.finfo(np.float32).eps,
     )
 
 

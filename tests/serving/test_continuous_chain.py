@@ -96,10 +96,12 @@ def test_budget_bound_never_delays_retirement(bundle):
 
 def test_eos_mid_chain_truncates_and_frees_slot(bundle):
     cfg, model, variables = bundle
-    want = _oracle(model, variables, [5, 3, 9, 2, 7], 8)
+    prompt = [16, 93, 39, 11, 38]  # its greedy stream opens on distinct ids
+    want = _oracle(model, variables, prompt, 8)
     eos = int(want[2])  # fires mid-chain at chain_tokens=4
+    assert eos not in want[:2], want  # the premise: eos FIRST fires at 3
     eng = _engine(cfg, variables, eos_id=eos, chain_tokens=4)
-    fut = eng.submit([5, 3, 9, 2, 7], 8)
+    fut = eng.submit(prompt, 8)
     while not fut.done():
         eng.tick()
     np.testing.assert_array_equal(fut.result(timeout=0), want[:3])

@@ -108,10 +108,12 @@ def test_threaded_engine_oracle_and_drain(bundle):
 
 def test_eos_frees_slot_early(bundle):
     cfg, model, variables = bundle
-    want = _oracle(model, variables, [5, 3, 9, 2, 7], 8)
+    prompt = [16, 93, 39, 11, 38]  # its greedy stream opens on distinct ids
+    want = _oracle(model, variables, prompt, 8)
     eos = int(want[2])  # third generated token becomes the stop token
+    assert eos not in want[:2], want  # the premise: eos FIRST fires at 3
     eng = _engine(cfg, variables, eos_id=eos)
-    fut = eng.submit([5, 3, 9, 2, 7], 8)
+    fut = eng.submit(prompt, 8)
     while not fut.done():
         eng.tick()
     got = fut.result(timeout=0)

@@ -177,12 +177,14 @@ def test_eos_inside_accepted_span_truncates_and_frees(bundle):
     it are dropped, the Future resolves at the EOS, and the slot frees
     in that same tick — one verify dispatch end to end."""
     cfg, model, variables = bundle
-    want = _oracle(model, variables, [5, 3, 9, 2, 7], 8)
+    prompt = [16, 93, 39, 11, 38]  # its greedy stream opens on distinct ids
+    want = _oracle(model, variables, prompt, 8)
     eos = int(want[3])  # inside the first spec_k=8 accepted span
+    assert eos not in want[:3], want  # the premise: eos FIRST fires at 4
     eng = _engine(cfg, variables, eos_id=eos, spec_k=8,
                   draft_source=_OracleDraft(model, variables))
     before = dispatch_count("decode")
-    fut = eng.submit([5, 3, 9, 2, 7], 8)
+    fut = eng.submit(prompt, 8)
     _drain(eng, [fut])
     np.testing.assert_array_equal(fut.result(timeout=0), want[:4])
     assert eng.active_slots == 0

@@ -126,6 +126,19 @@ def test_process_trials_pin_disjoint_devices():
     assert res[0]["chip"] is None
 
 
+def test_process_trials_refuse_a_parent_that_holds_the_chip(monkeypatch):
+    """The parent-holds-the-chip check (runner.backends): a driver that
+    already ran something on a TPU backend would launch pinned trials
+    that hang on the chip it holds — fmin raises at launch instead."""
+    import jax
+
+    jax.devices()  # initialised backend (CPU here) ...
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # ... a TPU
+    with pytest.raises(RuntimeError, match="holds its chip"):
+        fmin(lambda p: p["x"], {"x": hp.uniform("x", 0, 1)}, max_evals=2,
+             use_hyperopt=False, trial_runner="processes")
+
+
 def test_local_pinnable_chips_detection(monkeypatch):
     """Chip detection never initializes jax (the driver would acquire
     every chip): it honors an existing TPU_VISIBLE_DEVICES restriction,

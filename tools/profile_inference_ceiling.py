@@ -2,10 +2,10 @@
 
 Traces the exact program bench.py runs (merged-head InceptionV3, batch
 128, preprocess fold), then for every TPU op >= 50us/step computes its
-bandwidth-bound and MXU-bound minimum time on v5e (197 TFLOP/s bf16,
-819 GB/s HBM) from the HLO buffer shapes, and prints the table PERF.md
-needs: measured vs max(bound) per fusion, summed ceiling vs measured
-program.
+bandwidth-bound minimum time on this chip (peaks from
+``observability.metrics.DEVICE_PEAKS``) from the HLO buffer shapes, and
+prints the table PERF.md needs: measured vs bound per fusion, summed
+ceiling vs measured program.
 """
 import os
 import re
@@ -14,9 +14,6 @@ import tempfile
 from collections import defaultdict
 
 import numpy as np
-
-PEAK_FLOPS = 197e12
-PEAK_BW = 819e9
 
 _SHAPE_RE = re.compile(r"(bf16|f32|s32|u8|pred|s8)\[([0-9,]*)\]")
 _BYTES = {"bf16": 2, "f32": 4, "s32": 4, "u8": 1, "pred": 1, "s8": 1}
@@ -39,8 +36,11 @@ def main():
     import jax
     import jax.numpy as jnp
 
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+    from sparkdl_tpu.observability.metrics import device_peak
+    from sparkdl_tpu.runtime.chip import require_tpu
+
+    require_tpu()
+    peak_bw = device_peak().hbm_bytes_per_s
 
     from sparkdl_tpu.models.inception_fused import (
         fused_inception_v3_features,
@@ -95,7 +95,7 @@ def main():
             if ms < 0.05:
                 continue
             b = op_bytes(nm)
-            bw_ms = b / PEAK_BW * 1e3
+            bw_ms = b / peak_bw * 1e3
             rows.append((ms, bw_ms, nm))
         rows.sort(reverse=True)
         print(f"== plane {plane.name}: program ops sum "

@@ -12,11 +12,11 @@ def main():
     import jax.numpy as jnp
     import optax
 
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
     from sparkdl_tpu.models.resnet import ResNet50
+    from sparkdl_tpu.runtime.chip import require_tpu
     from sparkdl_tpu.train.vision import make_resnet50_fused_train_step
+
+    require_tpu()
 
     batch = int(sys.argv[1]) if len(sys.argv) > 1 else 256
     size = 224
