@@ -169,6 +169,7 @@ class PrefillWorker(ContinuousGPTEngine):
             host=self.host_id, blocks=nbp, bytes=h.wire_bytes)
         now = time.monotonic()
         self._record_request_span(st.req, now, ok=True, tokens=1)
+        self.metrics.record_tokens(1, phase="prefill")
         st.req.future.set_result(h)
         self.metrics.record_request(now - st.req.enqueued, ok=True)
 

@@ -51,6 +51,9 @@ def trace(logdir: str | os.PathLike,
 
     Remember to ``jax.block_until_ready`` the last output inside the block,
     otherwise async dispatch leaks device work past the capture window.
+    With tracing on (``observability.tracing``), every live span of the
+    program lies in the capture's host plane under its own name, on the
+    clock of the device operations.
     """
     jax.profiler.start_trace(
         os.fspath(logdir), create_perfetto_trace=create_perfetto_trace
@@ -64,15 +67,6 @@ def trace(logdir: str | os.PathLike,
 def start_trace_server(port: int = 9999):
     """Start the live profiling server on this host (one per process)."""
     return jax.profiler.start_server(port)
-
-
-def annotate(name: str):
-    """Named region that shows up on the trace timeline (host + device).
-
-    Use around logical phases of a step (decode / infeed / apply) so the
-    Perfetto view maps back to framework stages.
-    """
-    return jax.profiler.TraceAnnotation(name)
 
 
 class StackProfile:

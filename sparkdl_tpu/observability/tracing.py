@@ -13,6 +13,14 @@ manager (< 1µs per use — guarded by a test) so the serving hot loop pays
 nothing. Enable with ``SPARKDL_TPU_TRACE=1`` in the environment or
 :func:`enable_tracing` in code.
 
+One clock with the profiler: a live span also enters a
+``jax.profiler.TraceAnnotation`` of its name for its extent (scalar
+attributes passed through), so in ANY ``jax.profiler`` capture taken
+while tracing is on (``observability.profiling.trace``, the benchmark's
+traced run) the spans lie in the host plane of the same ``.xplane.pb``
+as the device operations. Retroactive spans (:func:`record_span`) are
+already over when they are recorded and have no mirror.
+
 Cross-thread propagation: parentage rides a :mod:`contextvars` var inside
 a thread; across threads (a submitting caller → the MicroBatcher worker)
 the producer captures :func:`current_context` and the consumer re-roots
@@ -310,36 +318,69 @@ class _NoopSpan:
     def set_attr(self, **attrs: Any) -> None:
         pass
 
+    def discard(self) -> None:
+        pass
+
 
 _NOOP = _NoopSpan()
 
 
+_SCALARS = (int, float, bool, str)
+
+_annotation = None
+
+
+def _profiler_annotation(name: str, attrs: "dict[str, Any]"):
+    """The profiler's own host annotation for a live span (module
+    docstring, "One clock"). jax is resolved at the first live span, so
+    importing this module stays free of it; outside a capture an
+    annotation costs well under a microsecond."""
+    global _annotation
+    if _annotation is None:
+        import jax
+
+        _annotation = jax.profiler.TraceAnnotation
+    return _annotation(name, **{k: v for k, v in attrs.items()
+                                if isinstance(v, _SCALARS)})
+
+
 class _Span:
-    __slots__ = ("name", "attrs", "context", "_parent", "_token", "_start")
+    __slots__ = ("name", "attrs", "context", "_parent", "_token", "_start",
+                 "_mirror", "_keep")
 
     def __init__(self, name: str, parent: "SpanContext | None",
                  attrs: "dict[str, Any]"):
         self.name = name
         self.attrs = attrs
         self._parent = parent
+        self._keep = True
         trace_id = parent.trace_id if parent is not None else _next_id()
         self.context = SpanContext(trace_id, _next_id())
 
     def set_attr(self, **attrs: Any) -> None:
         self.attrs.update(attrs)
 
+    def discard(self) -> None:
+        """Leave no finished span behind (an engine tick that found
+        nothing to do: 200 a second on an idle engine)."""
+        self._keep = False
+
     def __enter__(self) -> "_Span":
         self._token = _current.set(self.context)
+        self._mirror = _profiler_annotation(self.name, self.attrs)
+        self._mirror.__enter__()
         self._start = _now()
         return self
 
     def __exit__(self, exc_type, *exc) -> bool:
         end = _now()
+        self._mirror.__exit__(exc_type, *exc)
         _current.reset(self._token)
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
-        _finish(self.name, self._start, end, self.context,
-                self._parent, self.attrs)
+        if self._keep:
+            _finish(self.name, self._start, end, self.context,
+                    self._parent, self.attrs)
         return False
 
 
@@ -384,10 +425,10 @@ def _finish(name: str, start_s: float, end_s: float, ctx: SpanContext,
     if parent is not None:
         args["parent_id"] = parent.span_id
     for k, v in attrs.items():
-        if isinstance(v, (int, float, bool, str)):
+        if isinstance(v, _SCALARS):
             args[k] = v
         elif isinstance(v, (list, tuple)) and all(
-                isinstance(i, (int, float, bool, str)) for i in v):
+                isinstance(i, _SCALARS) for i in v):
             # link lists (rider request ids on batch spans) stay
             # structured: spans_for_trace matches against them
             args[k] = list(v)

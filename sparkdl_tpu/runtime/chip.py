@@ -15,20 +15,28 @@ that none of them can quietly measure or run something else:
   persistent compilation cache. A chip-tool call starts cold and the
   serving engine alone compiles dozens of small programs, so every
   process that compiles shares one directory.
+* :func:`watch_compiles` — the one listener on what jax traces, lowers,
+  compiles and loads: counters an operator reads on ``/metrics`` ("did
+  something compile while serving") and, with tracing on, ``xla.*``
+  spans under whatever span paid for the program.
 """
 
 from __future__ import annotations
 
 import os
+import threading
+import time
 
 __all__ = [
     "COMPILE_CACHE_DIR",
+    "COMPILE_EVENTS",
     "NoAcceleratorError",
     "cache_entry_count",
     "configure_compile_cache",
     "explicit_cpu",
     "require_tpu",
     "smoke_label",
+    "watch_compiles",
 ]
 
 #: Where compiled programs persist when the environment names no place:
@@ -83,9 +91,79 @@ def smoke_label(on_chip: bool) -> str:
     return "" if on_chip else "contract smoke, not a device measurement: "
 
 
+#: jax's duration events (``jax.monitoring``) and the ``kind`` each is
+#: counted and spanned under (``xla.<kind>``). Every one is emitted by
+#: jax 0.9.0 (``jax/_src/dispatch.py``, ``jax/_src/compiler.py``); the
+#: first three carry ``fun_name``. jax times ``cache_load`` INSIDE
+#: ``compile``: a program loaded from the persistent cache has both.
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+
+_watch_lock = threading.Lock()
+_watching = False
+
+
+def watch_compiles() -> None:
+    """Register, once a process, the listener behind
+    ``sparkdl_compiles_total{kind}`` / ``sparkdl_compile_seconds_total
+    {kind}`` and the ``xla.trace`` / ``xla.lower`` / ``xla.compile`` /
+    ``xla.cache_load`` spans. jax reports an event when it ENDS, with
+    its duration, on the thread that compiled: the span runs back from
+    now and hangs under that thread's ambient span, so a decode depth or
+    prefill width first seen in the middle of serving shows under the
+    ``serving.decode_step`` / ``serving.prefill_chunk`` that stalled for
+    it. Called by :func:`configure_compile_cache` and by the serving
+    engine's constructor; with tracing off an event costs two counter
+    adds."""
+    global _watching
+    with _watch_lock:
+        if _watching:
+            return
+        _watching = True
+    import jax
+    import jax.monitoring
+
+    from sparkdl_tpu.observability import tracing
+    from sparkdl_tpu.observability.registry import registry
+
+    count = registry().counter(
+        "sparkdl_compiles_total",
+        "programs jax traced, lowered, compiled (or loaded) and read "
+        "from the persistent cache", labels=("kind",))
+    seconds = registry().counter(
+        "sparkdl_compile_seconds_total",
+        "host seconds in each of those stages", labels=("kind",))
+    bound = {kind: (count.labels(kind=kind), seconds.labels(kind=kind))
+             for kind in COMPILE_EVENTS.values()}
+
+    def on_duration(event: str, secs: float, **kw) -> None:
+        kind = COMPILE_EVENTS.get(event)
+        # jax also reports the trace of every jitted function it meets
+        # INSIDE a trace (each jnp call of a 48-layer model: thousands a
+        # program); only a program's own, outermost trace is counted
+        if kind is None or (kind == "trace"
+                            and not jax.core.trace_ctx.is_top_level()):
+            return
+        n, s = bound[kind]
+        n.inc()
+        s.inc(secs)
+        if tracing.tracing_enabled():
+            now = time.monotonic()
+            tracing.record_span("xla." + kind, now - secs, now,
+                                parent=tracing.current_context(),
+                                event=event, **kw)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+
 def configure_compile_cache() -> "str | None":
     """Point jax's persistent compilation cache somewhere durable; call
     before the first jit. Returns the directory in use (None: no cache).
+    Also starts :func:`watch_compiles`.
 
     * ``JAX_COMPILATION_CACHE_DIR`` set: jax already honours it —
       nothing is set in code, so an operator's directory and thresholds
@@ -96,6 +174,7 @@ def configure_compile_cache() -> "str | None":
       time and entry size dropped to zero so the engine's many
       sub-second programs persist too.
     """
+    watch_compiles()
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir

@@ -106,6 +106,7 @@ from sparkdl_tpu.observability import tracing
 from sparkdl_tpu.observability.registry import registry
 from sparkdl_tpu.observability.tracing import span
 from sparkdl_tpu.reliability.faults import fault_point
+from sparkdl_tpu.runtime.chip import watch_compiles
 from sparkdl_tpu.runtime.completion import start_fetch
 from sparkdl_tpu.runtime.dispatch import (
     ChainPolicy,
@@ -177,6 +178,26 @@ _EXHAUST_DUMP_STREAK = 3
 #: tick (ISSUE 20): the ladder's hysteresis counts these evaluations,
 #: so the stride — not the tick rate — sets its reaction time.
 _OVERLOAD_STRIDE_S = 0.25
+
+
+def _with_init_span(init):
+    """``serving.engine_init`` over the whole constructor, under the
+    caller's ambient span: the pool's allocation and whatever jax
+    compiles for it (``xla.*`` spans) hang beneath it. The sizes are read
+    off the built engine, so ``kv_blocks`` is the resolved pool and not
+    the argument's None; a constructor that raises leaves the span with
+    ``error`` alone."""
+    @functools.wraps(init)
+    def traced_init(self, *args, **kwargs):
+        watch_compiles()
+        with span("serving.engine_init") as sp:
+            init(self, *args, **kwargs)
+            sp.set_attr(
+                n_slots=self.n_slots, max_len=self.max_len,
+                kv_layout=self.kv_layout,
+                kv_blocks=(self._pool.n_blocks
+                           if self.kv_layout == "paged" else 0))
+    return traced_init
 
 
 @dataclasses.dataclass
@@ -293,6 +314,7 @@ class ContinuousGPTEngine:
     stays at the model dtype.
     """
 
+    @_with_init_span
     def __init__(self, config, variables, *, n_slots: int = 8,
                  max_len: int = 512, max_queue_depth: int = 256,
                  eos_id: Optional[int] = None,
@@ -1269,10 +1291,13 @@ class ContinuousGPTEngine:
         retire finished rows. Returns True if any work happened (False =
         idle tick). Thread-safe; the background loop is just
         ``while True: tick()``."""
-        with self._lock:
+        # one span a WORKING tick, in a trace of its own that links its
+        # riders; an idle engine ticks 200 times a second and leaves none
+        with self._lock, span("serving.tick") as tick_span:
             now = time.monotonic()
             self._overload_tick(now)
             self._expire_inflight(now)
+            admitted = 0
             free = [s for s in range(self.n_slots)
                     if s not in self._inflight
                     and s not in self._prefilling]
@@ -1291,7 +1316,7 @@ class ContinuousGPTEngine:
                 for i, req in enumerate(reqs):
                     slot = free.pop(0)
                     try:
-                        admitted = self._admit(slot, req)
+                        placed = self._admit_traced(slot, req)
                     except Exception as e:
                         # take() already moved this Future to RUNNING, so
                         # nobody else can resolve it: a failed admission
@@ -1301,7 +1326,7 @@ class ContinuousGPTEngine:
                         free.insert(0, slot)
                         self._fail_request(req, e, tokens=0)
                         continue
-                    if not admitted:
+                    if not placed:
                         # pool exhausted: defer this request AND every
                         # later one taken this tick back to the queue
                         # head, in order — deferral never reorders
@@ -1311,6 +1336,7 @@ class ContinuousGPTEngine:
                         self._defer(reqs[i:])
                         deferred = True
                         break
+                    admitted += 1
                 if (not deferred and self.kv_layout == "paged"
                         and (self._pool.deferral_streak
                              or (self.sp > 1
@@ -1335,6 +1361,16 @@ class ContinuousGPTEngine:
             if self._inflight:
                 self._decode_step()
                 did_work = True
+            if not (did_work or admitted):
+                tick_span.discard()
+            elif tick_span.context is not None:
+                tick_span.set_attr(
+                    inflight=len(self._inflight),
+                    prefilling=len(self._prefilling), admitted=admitted,
+                    links=[f.req.request_id
+                           for f in self._inflight.values()]
+                    + [st.req.request_id
+                       for st in self._prefilling.values()])
             return did_work
 
     def _defer(self, reqs: "list[Request]") -> None:
@@ -1452,6 +1488,25 @@ class ContinuousGPTEngine:
             burn_rate=burn,
             queue_frac=self.queue.depth / self.queue.max_depth)
 
+    def _admit_traced(self, slot: int, req: Request) -> bool:
+        """:meth:`_admit` inside the request's ``serving.admit`` span:
+        what admission itself costs a tick (prefix match, block
+        allocation, a dense prefill) and what it found."""
+        with span("serving.admit", parent=req.trace_ctx,
+                  request_id=req.request_id, slot=slot,
+                  prompt_len=len(req.payload.prompt)) as sp:
+            placed = self._admit(slot, req)
+            if sp.context is not None:
+                st = self._prefilling.get(slot) if placed else None
+                flight = self._inflight.get(slot) if placed else None
+                sp.set_attr(
+                    deferred=not placed,
+                    cached_tokens=st.hit if st is not None else 0,
+                    blocks=(len(st.all_blocks()) if st is not None
+                            else len(flight.blocks or ())
+                            if flight is not None else 0))
+            return placed
+
     def _admit(self, slot: int, req: Request) -> bool:
         """Place one taken request into ``slot``. Returns False when the
         paged block pool cannot back it right now (caller defers)."""
@@ -1481,8 +1536,10 @@ class ContinuousGPTEngine:
             self._cache = self._scatter_fn(
                 self._cache, row, jnp.asarray(slot, jnp.int32)
             )
-            first = int(tok[0])
+            with self._first_token_span(req, slot):
+                first = int(tok[0])
         self._prefill_seconds += time.perf_counter() - t0
+        self.metrics.record_tokens(1, phase="prefill")
         self._start[slot] = lp - len(gen.prompt)
         self._last_tok[slot] = first
         flight = _InFlight(req, [first], gen.max_new_tokens)
@@ -1902,10 +1959,15 @@ class ContinuousGPTEngine:
         cols = pow2_bucket(c0 + wc, 8, self._wp)
         idx = jnp.asarray(c0, jnp.int32)
         ids = jnp.asarray(ids)
+        program = ("chunk_one" if first and final else "chunk_first"
+                   if first else "chunk_final" if final else "chunk_mid")
         t0 = time.perf_counter()
+        # width, cols and program name the compiled shape: an xla.compile
+        # under this span says which one was first seen while serving
         with span("serving.prefill_chunk", parent=st.req.trace_ctx,
                   request_id=st.req.request_id, slot=slot,
-                  start=c0, tokens=r, first=first, final=final):
+                  start=c0, tokens=r, first=first, final=final,
+                  width=wc, cols=cols, program=program):
             if first and final:
                 logits, self._pool_kv = self._chunk_one_fn(
                     self.variables, self._pool_kv,
@@ -1935,8 +1997,19 @@ class ContinuousGPTEngine:
         if final:
             # the chunk's last REAL column seeds decode (argmax on
             # device: the same op the oracle's generate uses)
-            self._finish_prefill(slot, st, int(jnp.argmax(logits[0, r - 1])))
+            with self._first_token_span(st.req, slot):
+                tok = int(jnp.argmax(logits[0, r - 1]))
+            self._finish_prefill(slot, st, tok)
         self._prefill_seconds += time.perf_counter() - t0
+
+    def _first_token_span(self, req: Request, slot: int):
+        """Around the blocking read that ends a prefill: the host waits
+        there for the prefill program (the chunk's own span closes when
+        its DISPATCH returns) and for the copy of one id. The span's end
+        is the instant the request's first token reached the host."""
+        return span("serving.first_token", parent=req.trace_ctx,
+                    request_id=req.request_id, slot=slot,
+                    prompt_len=len(req.payload.prompt))
 
     def _finish_prefill(self, slot: int, st: _Prefill,
                         first: int) -> None:
@@ -1954,6 +2027,7 @@ class ContinuousGPTEngine:
         )
         self._pidx[slot] = plen
         self._last_tok[slot] = first
+        self.metrics.record_tokens(1, phase="prefill")
         del self._prefilling[slot]
         flight = _InFlight(st.req, [first], st.max_new,
                            blocks=st.shared + st.owned,
@@ -2208,8 +2282,10 @@ class ContinuousGPTEngine:
         t0 = time.perf_counter()
         links = ([f.req.request_id for f in self._inflight.values()]
                  if tracing.tracing_enabled() else ())
+        # the span runs on over the acceptance loop, so that it can say
+        # how many tokens the verify made (its ``tokens``)
         with span("serving.spec_verify", slots=len(self._inflight),
-                  k=k, links=links):
+                  k=k, links=links) as verify:
             out, self._pool_kv = self._paged_verify_fn(
                 self.variables, self._pool_kv,
                 jnp.asarray(self._table), jnp.asarray(self._pidx),
@@ -2219,34 +2295,39 @@ class ContinuousGPTEngine:
             jax.block_until_ready(out)
             # sparkdl-lint: disable=blocking-in-hot-loop -- block_until_ready above completed the dispatch; only the already-enqueued D2H copy remains
             out = np.asarray(fetch.result())
-        wall = time.perf_counter() - t0
-        record_dispatch("decode", k, wall)
-        # the deadline bound's per-token estimate: a width-k verify is
-        # ~ONE model pass (weight-bound regime), so record it as one
-        # step — recording k would shrink program_s k-fold and let
-        # _bounded_tokens fuse plain chains far past a deadline's real
-        # headroom. Slightly overestimating per-token cost (L=k costs
-        # ~1.2x L=1) only makes the deadline caps more conservative.
-        self._chain_policy.record(wall, 1)
-        self.metrics.record_batch(len(self._inflight), self.n_slots)
-        self._spec_dispatches += 1
-        accepted = 0
-        for slot in list(self._inflight):
-            flight = self._inflight[slot]
-            m = greedy_accept(drafts[slot], out[slot, :k - 1])
-            accepted += min(m, real_len.get(slot, 0))
-            # outputs [:m+1] are real greedy tokens (m accepted drafts
-            # + the bonus/correction); append with the SAME per-token
-            # retire semantics as the chained path — eos or budget
-            # mid-span drops the rest and frees the slot now
-            for j in range(m + 1):
-                flight.produced.append(int(out[slot, j]))
-                self._last_tok[slot] = out[slot, j]
-                self._pidx[slot] += 1
-                self._spec_tokens += 1
-                if self._is_done(flight):
-                    self._complete(slot)
-                    break
+            wall = time.perf_counter() - t0
+            record_dispatch("decode", k, wall)
+            # the deadline bound's per-token estimate: a width-k verify
+            # is ~ONE model pass (weight-bound regime), so record it as
+            # one step — recording k would shrink program_s k-fold and
+            # let _bounded_tokens fuse plain chains far past a deadline's
+            # real headroom. Slightly overestimating per-token cost (L=k
+            # costs ~1.2x L=1) only makes the deadline caps more
+            # conservative.
+            self._chain_policy.record(wall, 1)
+            self.metrics.record_batch(len(self._inflight), self.n_slots)
+            self._spec_dispatches += 1
+            accepted = tokens = 0
+            for slot in list(self._inflight):
+                flight = self._inflight[slot]
+                m = greedy_accept(drafts[slot], out[slot, :k - 1])
+                accepted += min(m, real_len.get(slot, 0))
+                # outputs [:m+1] are real greedy tokens (m accepted
+                # drafts + the bonus/correction); append with the SAME
+                # per-token retire semantics as the chained path — eos
+                # or budget mid-span drops the rest and frees the slot
+                # now
+                for j in range(m + 1):
+                    flight.produced.append(int(out[slot, j]))
+                    self._last_tok[slot] = out[slot, j]
+                    self._pidx[slot] += 1
+                    tokens += 1
+                    if self._is_done(flight):
+                        self._complete(slot)
+                        break
+            verify.set_attr(tokens=tokens)
+        self._spec_tokens += tokens
+        self.metrics.record_tokens(tokens, phase="decode")
         self._spec_proposed += proposed
         self._spec_accepted += accepted
         self._spec_policy.record(proposed, accepted)
@@ -2261,19 +2342,34 @@ class ContinuousGPTEngine:
         return True
 
     def _decode_step(self) -> None:
+        import jax
         import jax.numpy as jnp
 
         if (self.spec_k is not None and self.kv_layout == "paged"
                 and self._spec_step()):
             return
         k = self._decode_chain_len(time.monotonic())
+        paged = self.kv_layout == "paged"
+        shape = {}
+        if paged:
+            from sparkdl_tpu.runtime.batching import pow2_bucket
+
+            # static gather width: blocks covering the deepest live
+            # row through this whole chain (idx advances k), bucketed
+            # to a power of two for compile reuse, capped at the
+            # table width. It decides what the tick costs, so it rides
+            # on the spans.
+            need = max((self._pidx[s] for s in self._inflight),
+                       default=0) + k
+            nb = pow2_bucket(-(-need // self._kv_bs), 1, self._mb)
+            shape["nb"] = nb
         t0 = time.perf_counter()
         # decode ticks are batch-level: their spans link every rider's
         # request id so each request's trace pulls in its decode steps
         links = ([f.req.request_id for f in self._inflight.values()]
                  if tracing.tracing_enabled() else ())
         with span("serving.decode_step", slots=len(self._inflight),
-                  chain=k, links=links):
+                  chain=k, links=links, **shape):
             # Async token readback (runtime/completion.py): the D2H copy
             # of the token ids is enqueued the moment the decode dispatch
             # is — it rides behind the compute instead of waiting for the
@@ -2281,69 +2377,64 @@ class ContinuousGPTEngine:
             # program retires.
             # block_until_ready splits compute from collection so
             # sparkdl_fetch_wait_seconds{path="decode"} meters ONLY the
-            # residual copy wait, not the decode program itself.
-            import jax
-
-            if self.kv_layout == "paged":
-                from sparkdl_tpu.runtime.batching import pow2_bucket
-
-                # static gather width: blocks covering the deepest live
-                # row through this whole chain (idx advances k), bucketed
-                # to a power of two for compile reuse, capped at the
-                # table width
-                need = max((self._pidx[s] for s in self._inflight),
-                           default=0) + k
-                nb = pow2_bucket(-(-need // self._kv_bs), 1, self._mb)
-                toks, self._pool_kv = self._paged_step_fn(
-                    self.variables, self._pool_kv,
-                    jnp.asarray(self._table), jnp.asarray(self._pidx),
-                    jnp.asarray(self._last_tok), k, nb,
-                )
-                fetch = start_fetch(toks, path="decode")
+            # residual copy wait, not the decode program itself. The two
+            # child spans split the same way: the host's own work to
+            # launch the program, then its wait for the device.
+            with span("serving.decode_dispatch", k=k, **shape):
+                if paged:
+                    toks, self._pool_kv = self._paged_step_fn(
+                        self.variables, self._pool_kv,
+                        jnp.asarray(self._table), jnp.asarray(self._pidx),
+                        jnp.asarray(self._last_tok), k, nb,
+                    )
+                elif k == 1:
+                    toks, self._cache = self._step_fn(
+                        self.variables, self._cache,
+                        jnp.asarray(self._last_tok),
+                        jnp.asarray(self._start),
+                    )
+                else:
+                    toks, self._cache = self._step_chain_fn(
+                        self.variables, self._cache,
+                        jnp.asarray(self._last_tok), k,
+                        jnp.asarray(self._start),
+                    )
+            fetch = start_fetch(toks, path="decode")
+            with span("serving.decode_wait"):
                 jax.block_until_ready(toks)
-                # sparkdl-lint: disable=blocking-in-hot-loop -- block_until_ready above completed the dispatch; only the already-enqueued D2H copy remains
-                toks = np.asarray(fetch.result())
-            elif k == 1:
-                tok, self._cache = self._step_fn(
-                    self.variables, self._cache,
-                    jnp.asarray(self._last_tok), jnp.asarray(self._start),
-                )
-                fetch = start_fetch(tok, path="decode")
-                jax.block_until_ready(tok)
-                # sparkdl-lint: disable=blocking-in-hot-loop -- block_until_ready above completed the dispatch; only the already-enqueued D2H copy remains
-                toks = np.asarray(fetch.result())[None]
-            else:
-                toks, self._cache = self._step_chain_fn(
-                    self.variables, self._cache,
-                    jnp.asarray(self._last_tok), k,
-                    jnp.asarray(self._start),
-                )
-                fetch = start_fetch(toks, path="decode")
-                jax.block_until_ready(toks)
-                # sparkdl-lint: disable=blocking-in-hot-loop -- block_until_ready above completed the dispatch; only the already-enqueued D2H copy remains
-                toks = np.asarray(fetch.result())
+            # sparkdl-lint: disable=blocking-in-hot-loop -- block_until_ready above completed the dispatch; only the already-enqueued D2H copy remains
+            toks = np.asarray(fetch.result())
+            if toks.ndim == 1:  # the unchained dense step: [S] -> [1, S]
+                toks = toks[None]
         wall = time.perf_counter() - t0
         record_dispatch("decode", k, wall)
         self._chain_policy.record(wall, k)
         self.metrics.record_batch(len(self._inflight), self.n_slots)
-        paged = self.kv_layout == "paged"
-        for j in range(k):
-            live = [s for s in self._inflight]
-            if not live:
-                break
-            for slot in live:
-                flight = self._inflight[slot]
-                flight.produced.append(int(toks[j, slot]))
-                self._last_tok[slot] = toks[j, slot]
-                if paged:
-                    # one column written per decoded token: keep the
-                    # host block-table cursor in lockstep
-                    self._pidx[slot] += 1
-                if self._is_done(flight):
-                    # eos (or budget) mid-chain: any later tokens the
-                    # chain decoded for this row are simply dropped —
-                    # rows are independent, so they influenced nobody
-                    self._complete(slot)
+        # what the tick made: rows times k, less what an eos or a spent
+        # budget dropped mid-chain
+        with span("serving.retire", links=links) as retire:
+            tokens = completed = 0
+            for j in range(k):
+                live = [s for s in self._inflight]
+                if not live:
+                    break
+                for slot in live:
+                    flight = self._inflight[slot]
+                    flight.produced.append(int(toks[j, slot]))
+                    self._last_tok[slot] = toks[j, slot]
+                    tokens += 1
+                    if paged:
+                        # one column written per decoded token: keep the
+                        # host block-table cursor in lockstep
+                        self._pidx[slot] += 1
+                    if self._is_done(flight):
+                        # eos (or budget) mid-chain: any later tokens the
+                        # chain decoded for this row are simply dropped —
+                        # rows are independent, so they influenced nobody
+                        self._complete(slot)
+                        completed += 1
+            retire.set_attr(tokens=tokens, completed=completed)
+        self.metrics.record_tokens(tokens, phase="decode")
 
     def _is_done(self, flight: _InFlight) -> bool:
         return (len(flight.produced) >= flight.max_new

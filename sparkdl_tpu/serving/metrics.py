@@ -32,6 +32,13 @@ _M_BATCHES = registry().counter(
 _M_OCCUPANCY = registry().histogram(
     "sparkdl_serving_batch_occupancy_pct",
     "live rows per dispatch as % of capacity", buckets=PERCENT_BUCKETS)
+_M_TOKENS = registry().counter(
+    "sparkdl_serving_tokens_total",
+    "tokens appended to live requests: a request's first by its prefill, "
+    "the rest by decode ticks (its rate is the engine's tokens/s)",
+    labels=("phase",))
+_M_TOKENS_BY_PHASE = {phase: _M_TOKENS.labels(phase=phase)
+                      for phase in ("prefill", "decode")}
 
 
 def default_host_id() -> str:
@@ -84,8 +91,8 @@ class ServingMetrics:
     ``snapshot()`` is the structured dict an operator scrapes: admission
     (submitted/rejected/expired/cancelled, straight off the queue's own
     counters), outcomes (completed/failed), queue depth, mean
-    batch-occupancy %, dispatch count, and request latency p50/p95/p99
-    (seconds, submit -> result).
+    batch-occupancy %, dispatch count, tokens generated, and request
+    latency p50/p95/p99 (seconds, submit -> result).
     """
 
     def __init__(self, window: int = 1024):
@@ -97,6 +104,15 @@ class ServingMetrics:
         self.completed = 0
         self.failed = 0
         self.batches = 0
+        self.tokens = 0
+
+    def record_tokens(self, n: int, *, phase: str) -> None:
+        """``n`` tokens appended to live requests, counted where the
+        engine appends them (``phase``: "prefill" for a request's first
+        token, "decode" for a tick's)."""
+        with self._lock:
+            self.tokens += n
+        _M_TOKENS_BY_PHASE[phase].inc(n)
 
     def record_request(self, latency_s: float, *, ok: bool) -> None:
         with self._lock:
@@ -133,6 +149,7 @@ class ServingMetrics:
                 "completed": self.completed,
                 "failed": self.failed,
                 "batches": self.batches,
+                "tokens": self.tokens,
                 "batch_occupancy_pct": self._occupancy.mean_step_time(),
                 "latency_s": self._latency.step_time_percentiles((50, 95, 99)),
                 "latency_mean_s": self._latency.mean_step_time(),

@@ -19,6 +19,7 @@ from typing import Any, Callable, Iterator
 import jax
 import numpy as np
 
+from sparkdl_tpu.observability import tracing
 from sparkdl_tpu.observability.tracing import span
 from sparkdl_tpu.reliability.faults import fault_point
 from sparkdl_tpu.runtime.batching import (
@@ -581,22 +582,32 @@ def run_partition_with_passthrough(
             )
     feeds: list[dict[str, np.ndarray] | None] = []
     first_error: Exception | None = None
-    for r in rows:
-        feed, err = try_extract(extract, r)
-        first_error = first_error or err
-        feeds.append(feed)
+    with span("surface.extract", rows=len(rows)):
+        for r in rows:
+            feed, err = try_extract(extract, r)
+            first_error = first_error or err
+            feeds.append(feed)
     valid = [f for f in feeds if f is not None]
     if rows and not valid and first_error is not None:
         logging.getLogger(__name__).warning(
             "all %d rows in partition failed extraction (output=None); "
             "first error: %r", len(rows), first_error,
         )
+    # surface.run: from handing the runner its rows to the last output
+    # taken from it. Recorded when that is over, and not held open as a
+    # live span, because this generator yields to its consumer in between
+    # and an ambient span must not leak into the consumer's code.
+    parent, t_run = tracing.current_context(), time.monotonic()
     outputs = runner.run(iter(valid)) if valid else iter(())
-    for r, f in zip(rows, feeds):
-        out_row = dict(r)
-        if f is None:
-            out_row[output_col] = None
-        else:
-            o = next(outputs)
-            out_row[output_col] = postprocess(o) if postprocess else o
-        yield out_row
+    try:
+        for r, f in zip(rows, feeds):
+            out_row = dict(r)
+            if f is None:
+                out_row[output_col] = None
+            else:
+                o = next(outputs)
+                out_row[output_col] = postprocess(o) if postprocess else o
+            yield out_row
+    finally:
+        tracing.record_span("surface.run", t_run, time.monotonic(),
+                            parent=parent, rows=len(valid))

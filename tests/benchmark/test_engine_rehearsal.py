@@ -1,0 +1,41 @@
+"""ONE test, so that xdist (``--dist loadfile`` hands out files by their
+number of tests) starts it last: the traced rehearsal of the serving cell,
+a process that compiles, names every per-layer metric of the manifest."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+from benchmark import manifest as mf
+
+ROOT = mf.repo_root()
+
+
+def test_the_traced_rehearsal_names_all_eighteen_metrics():
+    cmd = [sys.executable, "-m", "benchmark.run", "--workload",
+           "gpt2xl-backlog", "--seed", str(2**31 + 24), "--seconds", "2",
+           "--trace", "1", "--rehearse"]
+    taskset = shutil.which("taskset")
+    if taskset is not None:  # one core, as tests/benchmark/test_benchmark.py
+        cmd = [taskset, "-c", str(max(os.sched_getaffinity(0))), *cmd]
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "BENCH_RUN")}
+    env.update(JAX_PLATFORMS="cpu", TF_CPP_MIN_LOG_LEVEL="2",
+               PYTHONPATH=ROOT)
+    proc = subprocess.run(cmd, cwd=ROOT, env=env, capture_output=True,
+                          text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    names = [m["name"] for m in mf.load_manifest(ROOT)["per_layer"]]
+    assert len(names) == 18
+    for name in names:
+        assert name in proc.stdout, name
+    # the engine's own spans and the compile listener were there to read:
+    # of the eighteen only the five that need a device trace are left out
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True
+    assert sorted(set(names) - set(line["metrics"])) == sorted([
+        "tick_host_ms.backlog", "decode_device_ms.backlog",
+        "decode_roofline_share.backlog", "device_idle_share.backlog",
+        "prefill_device_share.backlog"])

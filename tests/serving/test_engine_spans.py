@@ -1,0 +1,390 @@
+"""The engine's own span tree, compile to retire (ISSUE 24): one
+``serving.tick`` a working tick with its phases inside it, token counts
+that reconcile three ways (spans, futures, registry counter), ``xla.*``
+spans under the span that paid for the program, the profiler mirror, and
+the guard on the tracing-off path. CPU only, a two-layer GPT.
+"""
+
+import contextlib
+import glob
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel
+from sparkdl_tpu.observability import profiling, tracing
+from sparkdl_tpu.observability.registry import registry
+from sparkdl_tpu.serving import ContinuousGPTEngine
+
+#: (prompt tokens, max new tokens): two slots, so requests queue, overlap,
+#: prefill in several chunks (chunk 8) and retire at different ticks
+REQUESTS = ((5, 4), (20, 6), (9, 1), (3, 7), (17, 3))
+PHASES = ("serving.admit", "serving.prefill_chunk", "serving.first_token",
+          "serving.decode_step", "serving.retire")
+
+
+@pytest.fixture(scope="module")
+def bundle():
+    cfg = GPTConfig.tiny()
+    variables = GPTLMHeadModel(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    return cfg, variables
+
+
+@pytest.fixture
+def traced():
+    tracing.clear_trace()
+    tracing.enable_tracing()
+    try:
+        yield
+    finally:
+        tracing.disable_tracing()
+        tracing.clear_trace()
+
+
+def _engine(bundle, **kw):
+    cfg, variables = bundle
+    kw.setdefault("n_slots", 2)
+    kw.setdefault("max_len", 64)
+    kw.setdefault("prefill_chunk", 8)
+    kw.setdefault("auto_start", False)
+    return ContinuousGPTEngine(cfg, variables, **kw)
+
+
+def _prompts(cfg, requests=REQUESTS, seed=0):
+    rng = np.random.default_rng(seed)
+    return [(rng.integers(0, cfg.vocab_size, n, np.int32), m)
+            for n, m in requests]
+
+
+def _serve(eng, prompts):
+    futs = [eng.submit(p, m) for p, m in prompts]
+    deadline = time.monotonic() + 120
+    while not all(f.done() for f in futs):
+        assert time.monotonic() < deadline, "engine did not finish"
+        eng.tick()
+    return futs, [f.result(timeout=0) for f in futs]
+
+
+def _tokens_counter() -> float:
+    fam = registry().get("sparkdl_serving_tokens_total")
+    return sum(fam.snapshot_values().values()) if fam else 0.0
+
+
+def _spans(*names, events=None):
+    return [e for e in (tracing.trace_events() if events is None else events)
+            if e["name"] in names]
+
+
+def _end(e):
+    return e["ts"] + e["dur"]
+
+
+@pytest.fixture(scope="module")
+def served(bundle):
+    """One traced run of REQUESTS through a manual-tick engine, and the
+    spans it left (the ring is copied, then cleared for the next test)."""
+    tracing.clear_trace()
+    tracing.enable_tracing()
+    try:
+        before = _tokens_counter()
+        eng = _engine(bundle)
+        try:
+            futs, outs = _serve(eng, _prompts(bundle[0]))
+            snap = eng.snapshot()
+        finally:
+            eng.close()
+        events = tracing.trace_events()
+        return {"futs": futs, "outs": outs, "snap": snap,
+                "counted": _tokens_counter() - before,
+                "spans": lambda *names: _spans(*names, events=events)}
+    finally:
+        tracing.disable_tracing()
+        tracing.clear_trace()
+
+
+class TestTickTree:
+    def test_every_working_tick_is_one_span_with_its_phases_inside(
+            self, served):
+        ticks = sorted(served["spans"]("serving.tick"), key=lambda e: e["ts"])
+        assert ticks
+        # ticks of one engine thread never overlap
+        for a, b in zip(ticks, ticks[1:]):
+            assert _end(a) <= b["ts"]
+        phases = sorted(served["spans"](*PHASES), key=lambda e: e["ts"])
+        for p in phases:
+            inside = [t for t in ticks
+                      if t["ts"] <= p["ts"] and _end(p) <= _end(t)]
+            assert len(inside) == 1, (p["name"], len(inside))
+        # ...and inside one tick the phases follow one another
+        for t in ticks:
+            mine = [p for p in phases
+                    if t["ts"] <= p["ts"] and _end(p) <= _end(t)]
+            for a, b in zip(mine, mine[1:]):
+                assert _end(a) <= b["ts"], (a["name"], b["name"])
+
+    def test_decode_step_splits_into_dispatch_and_wait(self, served):
+        steps = {e["args"]["span_id"]: e
+                 for e in served["spans"]("serving.decode_step")}
+        kids = served["spans"]("serving.decode_dispatch", "serving.decode_wait")
+        assert len(kids) == 2 * len(steps)
+        for k in kids:
+            step = steps[k["args"]["parent_id"]]
+            assert step["ts"] <= k["ts"] and _end(k) <= _end(step)
+        for step in steps.values():
+            assert step["args"]["nb"] >= 1 and step["args"]["chain"] == 1
+        for d in served["spans"]("serving.decode_dispatch"):
+            assert d["args"]["nb"] == steps[d["args"]["parent_id"]][
+                "args"]["nb"] and d["args"]["k"] == 1
+
+    def test_retire_and_decode_hang_under_their_tick(self, served):
+        ticks = {e["args"]["span_id"]: e for e in served["spans"]("serving.tick")}
+        for e in served["spans"]("serving.retire", "serving.decode_step"):
+            assert e["args"]["parent_id"] in ticks, e["name"]
+        for t in ticks.values():
+            assert {"inflight", "prefilling", "admitted",
+                    "links"} <= set(t["args"])
+
+    def test_tokens_reconcile_spans_futures_and_counter(self, served):
+        returned = sum(len(o) for o in served["outs"])
+        from_spans = (sum(e["args"]["tokens"]
+                          for e in served["spans"]("serving.retire"))
+                      + len(served["spans"]("serving.first_token")))
+        assert returned == sum(m for _, m in REQUESTS)
+        assert from_spans == returned
+        assert served["counted"] == returned
+        assert served["snap"]["tokens"] == returned
+        assert (sum(e["args"]["completed"]
+                    for e in served["spans"]("serving.retire"))
+                + sum(1 for _, m in REQUESTS if m == 1)) == len(REQUESTS)
+
+    def test_every_token_has_a_time_and_they_do_not_decrease(self, served):
+        for fut, out in zip(served["futs"], served["outs"]):
+            rid = fut.request_id
+            first = [e for e in served["spans"]("serving.first_token")
+                     if e["args"]["request_id"] == rid]
+            steps = [e for e in served["spans"]("serving.decode_step")
+                     if rid in e["args"]["links"]]
+            assert len(first) == 1
+            times = [_end(first[0])] + sorted(_end(e) for e in steps)
+            assert len(times) == len(out)
+            assert times == sorted(times)
+            queued = [e for e in served["spans"]("serving.queue_wait")
+                      if e["args"]["request_id"] == rid]
+            assert len(queued) == 1 and queued[0]["ts"] <= times[0]
+
+    @pytest.mark.parametrize("name,keys", [
+        ("serving.engine_init",
+         {"n_slots", "max_len", "kv_blocks", "kv_layout"}),
+        ("serving.admit", {"request_id", "slot", "prompt_len",
+                           "cached_tokens", "blocks", "deferred"}),
+        ("serving.prefill_chunk", {"width", "cols", "program", "tokens"}),
+        ("serving.first_token", {"request_id", "slot", "prompt_len"}),
+        ("serving.decode_step", {"slots", "chain", "links", "nb"}),
+        ("serving.retire", {"completed", "tokens", "links"}),
+    ])
+    def test_span_carries_its_attributes(self, served, name, keys):
+        found = served["spans"](name)
+        assert found, name
+        for e in found:
+            assert keys <= set(e["args"]), (name, sorted(e["args"]))
+
+    def test_chunk_programs_are_named_by_their_place(self, served):
+        by_request = {}
+        for e in sorted(served["spans"]("serving.prefill_chunk"),
+                        key=lambda e: e["ts"]):
+            by_request.setdefault(e["args"]["request_id"], []).append(
+                e["args"]["program"])
+        assert sorted(by_request.values()) == sorted([
+            ["chunk_one"], ["chunk_first", "chunk_mid", "chunk_final"],
+            ["chunk_first", "chunk_final"], ["chunk_one"],
+            ["chunk_first", "chunk_mid", "chunk_final"]])
+
+
+@pytest.mark.parametrize("layout", ["paged", "dense"])
+def test_an_idle_engine_adds_no_span(bundle, traced, layout):
+    eng = _engine(bundle, kv_layout=layout, auto_start=True)
+    try:
+        tracing.clear_trace()
+        time.sleep(0.2)  # some 40 idle ticks
+        assert [e["name"] for e in tracing.trace_events()] == []
+    finally:
+        eng.close()
+
+
+def test_dense_layout_reconciles_too(bundle, traced):
+    before = _tokens_counter()
+    eng = _engine(bundle, kv_layout="dense")
+    try:
+        _, outs = _serve(eng, _prompts(bundle[0]))
+    finally:
+        eng.close()
+    returned = sum(len(o) for o in outs)
+    assert (sum(e["args"]["tokens"] for e in _spans("serving.retire"))
+            + len(_spans("serving.first_token"))) == returned
+    assert _tokens_counter() - before == returned
+    assert all("nb" not in e["args"] for e in _spans("serving.decode_step"))
+
+
+def test_chained_decode_counts_dropped_tokens_out(bundle, traced):
+    """Budgets of 5 and 3 under chains of up to 4: what a chain decoded
+    past a spent budget is dropped and not counted."""
+    eng = _engine(bundle, chain_tokens=4)
+    try:
+        _, outs = _serve(eng, _prompts(bundle[0], ((6, 5), (4, 3))))
+    finally:
+        eng.close()
+    assert [len(o) for o in outs] == [5, 3]
+    assert sum(e["args"]["tokens"]
+               for e in _spans("serving.retire")) == 8 - 2
+
+
+def test_speculative_verify_counts_its_tokens(bundle, traced):
+    before = _tokens_counter()
+    eng = _engine(bundle, spec_k=4)
+    try:
+        # a repetitive prompt, so that the n-gram proposer has drafts
+        prompt = np.tile(np.arange(1, 5, dtype=np.int32), 4)
+        _, outs = _serve(eng, [(prompt, 12)])
+    finally:
+        eng.close()
+    made = (sum(e["args"]["tokens"] for e in _spans(
+        "serving.retire", "serving.spec_verify"))
+        + len(_spans("serving.first_token")))
+    assert made == len(outs[0]) == 12
+    assert _tokens_counter() - before == 12
+
+
+def test_a_new_prompt_width_compiles_under_the_chunk_that_paid(
+        bundle, traced):
+    """A fresh engine has fresh jitted programs, so its first chunk of a
+    width compiles whatever ran earlier in this process."""
+    eng = _engine(bundle)
+    try:
+        futs, _ = _serve(eng, _prompts(bundle[0], ((13, 2),)))
+    finally:
+        eng.close()
+    rid = futs[0].request_id
+    chunks = {e["args"]["span_id"]: e
+              for e in _spans("serving.prefill_chunk")
+              if e["args"]["request_id"] == rid}
+    compiles = [e for e in _spans("xla.compile")
+                if e["args"].get("parent_id") in chunks]
+    assert compiles, [e["args"] for e in _spans("xla.compile")]
+    for e in compiles:
+        assert e["args"]["trace_id"] == rid
+        assert e["args"]["event"].endswith("backend_compile_duration")
+        assert "_chunk_" in e["args"]["fun_name"]
+    # the decode depth it first reached compiled under the dispatch of ITS
+    # decode step, whose nb and k say which shape it was
+    dispatches = {e["args"]["span_id"]
+                  for e in _spans("serving.decode_dispatch")}
+    assert any(e["args"].get("parent_id") in dispatches
+               and "_paged_step" in e["args"]["fun_name"]
+               for e in _spans("xla.compile"))
+    for kind in ("xla.trace", "xla.lower"):
+        assert any(e["args"].get("parent_id") in chunks
+                   for e in _spans(kind)), kind
+
+
+def test_compile_counters_count_with_tracing_off(bundle):
+    tracing.disable_tracing()
+    count = registry().counter("sparkdl_compiles_total", labels=("kind",))
+    secs = registry().counter("sparkdl_compile_seconds_total",
+                              labels=("kind",))
+
+    def read(fam):
+        return dict(fam.snapshot_values())
+
+    eng = _engine(bundle)
+    try:
+        n0, s0 = read(count), read(secs)
+        _serve(eng, _prompts(bundle[0], ((11, 2),)))
+    finally:
+        eng.close()
+    n1, s1 = read(count), read(secs)
+    for kind in ("trace", "lower", "compile"):
+        key = f'kind="{kind}"'
+        assert n1[key] - n0.get(key, 0) >= 2, (kind, n0, n1)
+        assert s1[key] > s0.get(key, 0.0)
+    assert tracing.trace_events() == []
+
+
+def test_a_tick_with_tracing_off_allocates_nothing(bundle, monkeypatch):
+    """The guard on the off path: no ``_Span``, no profiler annotation,
+    an empty ring."""
+    tracing.disable_tracing()
+    tracing.clear_trace()
+    made = []
+
+    class Loud(tracing._Span):
+        def __init__(self, *a, **kw):
+            made.append("span")
+            super().__init__(*a, **kw)
+
+    def loud_annotation(*a, **kw):
+        made.append("annotation")
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(tracing, "_Span", Loud)
+    monkeypatch.setattr(tracing, "_profiler_annotation", loud_annotation)
+    eng = _engine(bundle)
+    try:
+        _, outs = _serve(eng, _prompts(bundle[0]))
+    finally:
+        eng.close()
+    assert sum(len(o) for o in outs) == sum(m for _, m in REQUESTS)
+    assert made == []
+    assert tracing.trace_events() == []
+    # and the guard itself can see: with tracing on the same calls fire
+    tracing.enable_tracing()
+    try:
+        with tracing.span("loud"):
+            pass
+    finally:
+        tracing.disable_tracing()
+        tracing.clear_trace()
+    assert made == ["span", "annotation"]
+
+
+def test_a_discarded_span_leaves_nothing_and_restores_the_ambient(traced):
+    with tracing.span("outer") as outer:
+        with tracing.span("idle") as idle:
+            idle.discard()
+        assert tracing.current_context() == outer.context
+    assert [e["name"] for e in tracing.trace_events()] == ["outer"]
+
+
+def test_a_profiler_capture_holds_the_engines_spans(bundle, traced,
+                                                    tmp_path):
+    """One clock: a capture through ``observability.profiling.trace`` of an
+    engine with tracing on has the spans in its host plane, with their
+    scalar attributes, beside whatever the device ran."""
+    eng = _engine(bundle)
+    try:
+        _serve(eng, _prompts(bundle[0], ((6, 2),)))  # compile outside
+        with profiling.trace(tmp_path):
+            _serve(eng, _prompts(bundle[0], ((6, 3),), seed=1))
+    finally:
+        eng.close()
+    paths = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert len(paths) == 1
+    data = jax.profiler.ProfileData.from_file(paths[0])
+    host = {}
+    for plane in data.planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("serving."):
+                    host.setdefault(ev.name, []).append(dict(ev.stats))
+    assert {"serving.tick", "serving.decode_step", "serving.decode_dispatch",
+            "serving.decode_wait", "serving.retire", "serving.admit",
+            "serving.prefill_chunk", "serving.first_token"} <= set(host)
+    assert len(host["serving.decode_step"]) == 2
+    assert all(s["chain"] == 1 and s["nb"] >= 1
+               for s in host["serving.decode_step"])
+    assert host["serving.prefill_chunk"][0]["program"] == "chunk_one"
