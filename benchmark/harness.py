@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import importlib.util
 import json
@@ -36,6 +37,11 @@ class Comparison:
             return False
         return (self.value <= self.limit if self.higher_is_worse
                 else self.value >= self.limit)
+
+    def said(self) -> str:
+        return (f"compared {self.name} = {self.value:.6g} against limit "
+                f"{'<=' if self.higher_is_worse else '>='} {self.limit:.6g}: "
+                f"{'ok' if self.ok else 'NOT CORRECT'}")
 
 
 @dataclasses.dataclass
@@ -86,6 +92,22 @@ class Run:
 
 def say(msg: str) -> None:
     print(f"[benchmark] {msg}", flush=True)
+
+
+@contextlib.contextmanager
+def after_window(phase: str):
+    """Times one phase of what a run does once its window has closed and
+    prints it as the phase ends, with the counts that set its cost (the
+    caller fills them into the dict this yields): a run stopped at its time
+    limit has then said which phases it got through, and the next is where
+    it was."""
+    counts: dict = {}
+    t0 = time.monotonic()
+    yield counts
+    detail = "; ".join(f"{k} {v:,}" if isinstance(v, int) else f"{k} {v}"
+                       for k, v in counts.items())
+    say(f"after the window: {phase} {time.monotonic() - t0:.3f} s"
+        + (f" ({detail})" if detail else ""))
 
 
 def load_module(path: str, name: str):
@@ -154,6 +176,7 @@ class DeviceTracer(threading.Thread):
         self.run_, self.out_dir = run, out_dir
         self.error: "Exception | None" = None
         self.mark: "tuple[float, float] | None" = None
+        self.stop_s: "float | None" = None   # what stop_trace itself took
 
     def run(self) -> None:
         import jax
@@ -174,7 +197,9 @@ class DeviceTracer(threading.Thread):
                     time.sleep(min(TRACE_FOR_S, secs / 2))
                 self.mark = (t0, time.monotonic())
             finally:
+                t_stop = time.monotonic()
                 jax.profiler.stop_trace()
+                self.stop_s = round(time.monotonic() - t_stop, 3)
         except Exception as e:  # a thread boundary: the main thread reports it
             self.error = e
 
@@ -229,7 +254,12 @@ def program_spans(run: Run) -> "list[dict]":
 
 
 def reduce_device_trace(run: Run, tracer: DeviceTracer, trace_dir: str) -> None:
-    planes = trace_reduce.load_xplane(trace_reduce.find_xplane(trace_dir))
+    path = trace_reduce.find_xplane(trace_dir)
+    with after_window("load_xplane") as n:
+        n["bytes"] = os.path.getsize(path)
+        planes = trace_reduce.load_xplane(path)
+        n["events kept"] = sum(len(ln["events"]) for p in planes
+                               for ln in p["lines"])
     mark = trace_reduce.find_mark(planes)
     if mark is None or tracer.mark is None:
         raise RuntimeError("the trace holds no window annotation")
@@ -238,8 +268,15 @@ def reduce_device_trace(run: Run, tracer: DeviceTracer, trace_dir: str) -> None:
     def to_ns(t: float) -> int:
         return int(mark[0] + (t - m0) * 1e9)
 
-    host = [(s["name"], to_ns(s["t0"]), to_ns(s["t1"])) for s in run.spans]
-    run.trace_summary = trace_reduce.reduce_trace(planes, mark, host)
+    with after_window("reduce_trace") as n:
+        host = [(s["name"], to_ns(s["t0"]), to_ns(s["t1"]))
+                for s in run.spans]
+        run.trace_summary, counts = trace_reduce.reduce_trace_counted(
+            planes, mark, host)
+        n.update({"device operations": counts["device_ops"],
+                  "idle gaps": counts["gaps"], "spans": counts["spans"],
+                  "spans touching the traced stretch":
+                      counts["spans_in_window"]})
     run.traced_window = tracer.mark
 
 
@@ -328,42 +365,54 @@ def main(argv: "list[str] | None" = None,
         "CPU")
     try:
         runner.window(run, state)
+        drain_s = time.monotonic() - run.window[1]
         setup_s = run.window[0] - run.t_process
-        if tracer is not None:
-            tracer.join(timeout=300)
-            if tracer.error is not None or tracer.is_alive():
-                raise RuntimeError(f"device trace failed: {tracer.error!r}")
         say(f"window {run.window[1] - run.window[0]:.3f} s after set-up "
             f"{setup_s:.3f} s; compiled inside the window: "
             f"{compiles.inside(run.window)} programs; cache now "
             f"{chip.cache_entry_count(cache_dir)} entries")
+        say(f"after the window: drain {drain_s:.3f} s (requests attempted "
+            f"{run.attempted:,})")
+        if tracer is not None:
+            t_join = time.monotonic()
+            tracer.join(timeout=300)
+            say(f"after the window: stop_trace {time.monotonic() - t_join:.3f}"
+                " s (the tracer thread's join; the call itself was made "
+                f"inside the window and took {tracer.stop_s} s)")
+            if tracer.error is not None or tracer.is_alive():
+                raise RuntimeError(f"device trace failed: {tracer.error!r}")
         if run.trace:
             run.spans = program_spans(run)
         metrics = runner.end_to_end(run, state)
         metrics["setup_s"] = setup_s
         peak = memory_peak()  # before the reference puts anything on the chip
-        checks = runner.check(run, state)
+        with after_window("correctness check"):
+            checks = runner.check(run, state)
     finally:
         runner.teardown(state)
 
     correct = all(c.ok for c in checks)
     for c in checks:
-        say(f"compared {c.name} = {c.value:.6g} against limit "
-            f"{'<=' if c.higher_is_worse else '>='} {c.limit:.6g}: "
-            f"{'ok' if c.ok else 'NOT CORRECT'}")
+        say(c.said())
 
     units = {m["name"]: m["unit"] for m in cell.end_to_end}
     line = {"correct": bool(correct), "attempted": int(run.attempted),
             "failed": int(run.failed), "device": device_report(peak),
             "compiles_in_window": compiles.inside(run.window)}
     if run.trace:
-        if tracer is not None:
+        if tracer is None:
+            say("after the window: stop_trace, load_xplane and reduce_trace "
+                "not run (a rehearsal takes no device trace)")
+        else:
             reduce_device_trace(run, tracer, trace_dir)
             s = run.trace_summary
             line["device"].update(busy_s=s["busy_s"], window_s=s["window_s"])
             line["breakdown"] = {"device_ops": s["device_ops"],
                                  "idle_gaps": s["idle_gaps"]}
-        line["metrics"] = layer_metrics(run)
+        with after_window("per-layer readers") as n:
+            line["metrics"] = layer_metrics(run)
+            n.update({"metrics": len(line["metrics"]),
+                      "spans": len(run.spans)})
     else:
         missing = set(units) - set(metrics)
         if missing:
@@ -375,6 +424,15 @@ def main(argv: "list[str] | None" = None,
         for m in line["metrics"].values():
             m["value"] = None
         line["rehearsal"] = "CPU run at rehearsal sizes: not measured"
+    say(f"after the window: {time.monotonic() - run.window[1]:.3f} s in all, "
+        "to the result line")
+    # each number compared beside its limit: last in the line, and the last
+    # lines of standard error (what the driver keeps of a run not correct)
+    line["compared"] = {c.name: {
+        "value": None if c.value != c.value else c.value,   # NaN is no JSON
+        "limit": c.limit, "ok": c.ok} for c in checks}
+    for c in checks:
+        print(c.said(), file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -13,6 +13,7 @@ down in PERF.md ("Reading a trace"); the names matched here come from there.
 
 from __future__ import annotations
 
+import heapq
 import os
 
 #: a device plane, the line holding one event per executed operation, and
@@ -107,6 +108,36 @@ def find_mark(planes: "list[dict]", mark: str = WINDOW_MARK):
     return None
 
 
+def gap_owners(mids: "list[int]", spans: "list[tuple[str, int, int]]",
+               window: "tuple[int, int]") -> "tuple[list[str], int]":
+    """For each of ``mids`` (instants inside ``window``) the name of the
+    shortest of ``spans`` with ``start <= mid < end``, spans of one length in
+    the order they were handed in, ``"no span"`` where none covers it; and
+    how many spans touch the window at all (the others can own nothing).
+
+    One sweep over the instants in order: a span enters a heap, keyed by its
+    length and its place in ``spans``, once an instant has reached its start,
+    and leaves from the top once an instant has reached its end. The cost is
+    ``(instants + spans) x log``, however many spans cover each instant."""
+    w0, w1 = window
+    live = sorted((s, e - s, i, e, n) for i, (n, s, e) in enumerate(spans)
+                  if s < w1 and e > w0)
+    owners = ["no span"] * len(mids)
+    heap: "list[tuple[int, int, int, str]]" = []
+    nxt = 0
+    for k in sorted(range(len(mids)), key=mids.__getitem__):
+        mid = mids[k]
+        while nxt < len(live) and live[nxt][0] <= mid:
+            _, length, i, e, n = live[nxt]
+            heapq.heappush(heap, (length, i, e, n))
+            nxt += 1
+        while heap and heap[0][2] <= mid:
+            heapq.heappop(heap)
+        if heap:
+            owners[k] = heap[0][3]
+    return owners, len(live)
+
+
 def reduce_trace(planes: "list[dict]", window: "tuple[int, int] | None" = None,
                  host_spans: "list[tuple[str, int, int]] | None" = None,
                  top: int = 10) -> dict:
@@ -118,6 +149,16 @@ def reduce_trace(planes: "list[dict]", window: "tuple[int, int] | None" = None,
 
     Busy is the union of the operation intervals of a device, averaged over
     the devices that ran anything."""
+    return reduce_trace_counted(planes, window, host_spans, top)[0]
+
+
+def reduce_trace_counted(planes: "list[dict]",
+                         window: "tuple[int, int] | None" = None,
+                         host_spans: "list[tuple[str, int, int]] | None" = None,
+                         top: int = 10) -> "tuple[dict, dict]":
+    """:func:`reduce_trace`'s result, and beside it the counts that set what
+    the reduction costs: device operations inside the window, idle gaps,
+    spans handed in and spans that touch the window."""
     devices = [p for p in planes if p["name"].startswith(DEVICE_PLANE_PREFIX)]
 
     def line(p, name):
@@ -134,13 +175,14 @@ def reduce_trace(planes: "list[dict]", window: "tuple[int, int] | None" = None,
             raise ValueError("the trace holds no device operation")
         window = (min(starts), max(ends))
     w0, w1 = window
-    busy_ns, op_ns, programs = [], {}, {}
+    busy_ns, op_ns, programs, n_ops = [], {}, {}, 0
     gaps: "list[tuple[int, int]]" = []
     for p in devices:
         ops = [ev for ln in line(p, OP_LINE)
                for ev in _clip(ln["events"], w0, w1)]
         if not ops:
             continue
+        n_ops += len(ops)
         merged = _union([(s, e) for _, s, e in ops])
         busy_ns.append(sum(e - s for s, e in merged))
         for name, s, e in ops:
@@ -156,16 +198,18 @@ def reduce_trace(planes: "list[dict]", window: "tuple[int, int] | None" = None,
     if not busy_ns:
         raise ValueError("no operation ran on a device inside the window")
     idle_by: "dict[str, float]" = {}
-    spans = sorted(host_spans or (), key=lambda sp: sp[2] - sp[1])
-    for g0, g1 in gaps:
-        mid = (g0 + g1) // 2
-        owner = next((n for n, s, e in spans if s <= mid < e), "no span")
+    spans = list(host_spans or ())
+    owners, n_live = gap_owners([(g0 + g1) // 2 for g0, g1 in gaps], spans,
+                                (w0, w1))
+    for (g0, g1), owner in zip(gaps, owners):
         idle_by[owner] = idle_by.get(owner, 0.0) + (g1 - g0) / 1e9 / len(busy_ns)
 
     def ranked(d):
         return [[k, v] for k, v in
                 sorted(d.items(), key=lambda kv: -kv[1])[:top]]
 
+    counts = {"device_ops": n_ops, "gaps": len(gaps), "spans": len(spans),
+              "spans_in_window": n_live}
     return {
         "window_s": (w1 - w0) / 1e9,
         "busy_s": sum(busy_ns) / len(busy_ns) / 1e9,
@@ -175,7 +219,7 @@ def reduce_trace(planes: "list[dict]", window: "tuple[int, int] | None" = None,
                               for k, v in op_ns.items()}),
         "idle_gaps": ranked(idle_by),
         "longest_gap_s": max((g1 - g0 for g0, g1 in gaps), default=0) / 1e9,
-    }
+    }, counts
 
 
 def program_seconds(summary: dict, *needles: str) -> "tuple[float, int]":

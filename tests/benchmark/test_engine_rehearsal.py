@@ -1,6 +1,7 @@
 """ONE test, so that xdist (``--dist loadfile`` hands out files by their
 number of tests) starts it last: the traced rehearsal of the serving cell,
-a process that compiles, names every per-layer metric of the manifest."""
+a process that compiles, names every per-layer metric of the manifest
+and says what each phase after its window took."""
 
 import json
 import os
@@ -35,6 +36,25 @@ def test_the_traced_rehearsal_names_all_eighteen_metrics():
     # of the eighteen only the five that need a device trace are left out
     line = json.loads(proc.stdout.strip().splitlines()[-1])
     assert line["correct"] is True
+    # the keys the line had, and last the numbers compared beside their
+    # limits, which are the last lines of standard error too
+    assert list(line) == ["correct", "attempted", "failed", "device",
+                          "compiles_in_window", "metrics", "rehearsal",
+                          "compared"]
+    assert all(c["ok"] and c["value"] <= c["limit"]
+               for c in line["compared"].values())
+    assert proc.stderr.strip().splitlines()[-1].startswith(
+        "compared token_gap_mean_over_logit_std = ")
+    # each phase after the window says what it took, as it ends, on a
+    # [benchmark] line; a rehearsal takes no device trace and says so
+    said = [ln for ln in proc.stdout.splitlines()
+            if ln.startswith("[benchmark] after the window: ")]
+    phases = ["drain ", "correctness check ", "stop_trace, load_xplane and "
+              "reduce_trace not run", "per-layer readers ", ""]
+    assert len(said) == len(phases), said
+    for ln, phase in zip(said, phases):
+        assert ln.startswith("[benchmark] after the window: " + phase), ln
+    assert "spans " in said[3] and said[4].endswith("to the result line")
     assert sorted(set(names) - set(line["metrics"])) == sorted([
         "tick_host_ms.backlog", "decode_device_ms.backlog",
         "decode_roofline_share.backlog", "device_idle_share.backlog",
