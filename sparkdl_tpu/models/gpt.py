@@ -152,9 +152,11 @@ def init_block_pool(config: GPTConfig, n_blocks: int,
     ``batch x max_len`` whether or not tokens exist), the pool's
     capacity is ``n_blocks x block_size`` TOKENS shared by every slot: a
     slot maps its logical columns onto pool blocks through a block
-    table, the serving engine gathers a virtual dense cache per decode
-    step, and the same physical block can back the shared prompt prefix
-    of many slots (``serving.prefix_cache``). Bookkeeping (free list,
+    table, the cached step reads K/V through that table one layer at a
+    time (a paged cache, :class:`GPTLMHeadModel`: the pool itself is
+    never gathered whole nor copied, only the new columns are written
+    into it), and the same physical block can back the shared prompt
+    prefix of many slots (``serving.prefix_cache``). Bookkeeping (free list,
     refcounts, tables) is host-side and lives in
     :class:`~sparkdl_tpu.serving.kv_blocks.KVBlockPool`.
 
@@ -214,6 +216,32 @@ def dequantize_kv(q: jax.Array, scale: jax.Array,
     return (q.astype(jnp.float32) * scale[..., None, None]).astype(dtype)
 
 
+def _paged_layer_kv(cache: dict, layer: int,
+                    dtype: Any) -> "tuple[jax.Array, jax.Array]":
+    """One layer's K and V of a PAGED cache as dense per-slot rows.
+
+    A paged cache is ``{"k", "v"[, "k_scale", "v_scale"], "table",
+    "idx"}``: ``k``/``v`` the ``[layers, n_blocks, block_size, H, D]``
+    pool of :func:`init_block_pool` in its storage dtype, ``table`` the
+    ``[S, nb]`` live head of the block table, ``idx`` the ``[S]`` depths.
+    Gathers ``pool[layer][table]`` -> ``[S, nb*block_size, H, D]`` and
+    dequantizes that slice alone to ``dtype`` (the rule of
+    :func:`dequantize_kv`; a bf16 pool is cast). Entries past the pool
+    (the table's sentinel) clip to a block whose columns the causal mask
+    hides, exactly as the whole-pool gather did.
+    """
+    table = cache["table"]
+
+    def rows(name):
+        x = cache[name][layer][table]
+        scale = cache.get(name + "_scale")
+        x = (x.astype(dtype) if scale is None
+             else dequantize_kv(x, scale[layer][table], dtype))
+        return x.reshape(table.shape[0], -1, *x.shape[3:])
+
+    return rows("k"), rows("v")
+
+
 class GPTAttention(nn.Module):
     config: GPTConfig
     layer_idx: int
@@ -253,7 +281,17 @@ class GPTAttention(nn.Module):
             # Overflow past the buffer would silently clamp the write while
             # the mask keeps advancing — catch it whenever idx is concrete
             # (eager streaming drivers; generate() pre-validates its scan).
-            max_len = cache["k"].shape[2]
+            paged = "table" in cache
+            if paged:
+                # paged cache: this layer's live blocks only, through
+                # the block table (_paged_layer_kv) — never a dense
+                # all-layer view of the pool
+                layer_k, layer_v = _paged_layer_kv(
+                    cache, self.layer_idx, c.dtype)
+            else:
+                layer_k = cache["k"][self.layer_idx]
+                layer_v = cache["v"][self.layer_idx]
+            max_len = layer_k.shape[1]
             if (not per_slot and not isinstance(idx, jax.core.Tracer)
                     and int(idx) + l > max_len):
                 raise ValueError(
@@ -273,20 +311,22 @@ class GPTAttention(nn.Module):
                 # control owns capacity, not this kernel.
                 rows = jnp.arange(b)[:, None]
                 cols = idx[:, None] + jnp.arange(l)[None, :]
-                ck = cache["k"][self.layer_idx].at[rows, cols].set(
+                ck = layer_k.at[rows, cols].set(
                     k.astype(c.dtype), mode="drop")
-                cv = cache["v"][self.layer_idx].at[rows, cols].set(
+                cv = layer_v.at[rows, cols].set(
                     v.astype(c.dtype), mode="drop")
             else:
                 ck = jax.lax.dynamic_update_slice(
-                    cache["k"][self.layer_idx], k.astype(c.dtype),
-                    (0, idx, 0, 0),
+                    layer_k, k.astype(c.dtype), (0, idx, 0, 0),
                 )
                 cv = jax.lax.dynamic_update_slice(
-                    cache["v"][self.layer_idx], v.astype(c.dtype),
-                    (0, idx, 0, 0),
+                    layer_v, v.astype(c.dtype), (0, idx, 0, 0),
                 )
-            new_entry = (ck, cv)
+            # a dense cache hands back its updated layer; a paged one
+            # only this call's L new columns — the caller owns the pool
+            # and writes them at (block, offset) itself
+            new_entry = ((k.astype(c.dtype), v.astype(c.dtype))
+                         if paged else (ck, cv))
             if (c.attn_impl == "flash" and l == 1 and c.flash_decode
                     and not per_slot):
                 # opt-in single-query flash decode (see GPTConfig:
@@ -439,6 +479,15 @@ class GPTLMHeadModel(nn.Module):
     a whole speculative draft span in one pass — which is what lets
     ``serving.continuous`` admit and retire rows mid-stream and verify
     k drafted tokens per dispatch.
+
+    A PAGED cache (it holds a ``table`` entry: ``{"k", "v"[, "k_scale",
+    "v_scale"], "table", "idx"}``, the :func:`init_block_pool` pool with
+    the ``[S, nb]`` live head of the block table and per-slot ``idx``)
+    runs the same per-slot step, but every layer gathers only its own
+    live blocks through the table (:func:`_paged_layer_kv`) and the
+    returned ``k``/``v`` are THIS call's new columns,
+    ``[layers, S, L, H, D]`` at the compute dtype — the caller writes
+    them into its pool at (block, offset); the pool is never returned.
 
     ``positions``: optional [B, L] global token positions for RoPE.
     REQUIRED under ``attn_impl='ring'`` (sequence sharded on ``sp``): each

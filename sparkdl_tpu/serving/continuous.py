@@ -24,10 +24,14 @@ KV layouts (``kv_layout=``, ROADMAP item 4):
 
 - ``"paged"`` (default) — block-paged KV pool
   (:mod:`~sparkdl_tpu.serving.kv_blocks`): each slot maps its columns
-  onto refcounted ``block_size``-token blocks through a block table,
-  the jitted decode step gathers a virtual dense cache from the table
-  and scatters the written column back, so persistent KV memory is
-  bounded by allocated tokens, not ``n_slots x max_len``. Admission
+  onto refcounted ``block_size``-token blocks through a block table.
+  The jitted decode step hands the model the pool and the table's live
+  head as a PAGED cache (models/gpt.py): each layer gathers only its
+  own live blocks, one layer at a time, and the step writes the new
+  column of every row into the donated pool in place, in the layout
+  the device stores it in — no dense view over all layers, no copy of
+  the pool on any tick — so persistent KV memory is bounded by
+  allocated tokens, not ``n_slots x max_len``. Admission
   against an exhausted pool DEFERS (re-queues in order) instead of
   erroring. Prompts are prefilled right-aligned in bounded CHUNKS
   (``prefill_chunk`` tokens per engine tick, interleaved with decode
@@ -63,8 +67,9 @@ from a consumed cache).
 
 Quantized KV blocks (``kv_dtype=``): the paged pool can store
 ``"bf16"`` or ``"int8"`` (one fp32 scale per written column) instead
-of the compute dtype — quantize-on-scatter / dequantize-on-gather are
-fused into the existing paged gather/scatter programs, so pool
+of the compute dtype — quantize-on-write / dequantize-on-gather are
+fused into the paged programs (decode dequantizes one layer's gathered
+slice at a time, never the pool), so pool
 capacity (and deferred-admission pressure) improves 2-4x
 (:func:`~sparkdl_tpu.serving.kv_blocks.kv_capacity_ratio`) while
 compute still runs at the model dtype; bench_serving's dense-vs-paged
@@ -467,6 +472,8 @@ class ContinuousGPTEngine:
         model = self._model
 
         if kv_layout == "paged":
+            from jax.experimental.layout import with_layout_constraint
+
             from sparkdl_tpu.models.gpt import dequantize_kv, quantize_kv
             from sparkdl_tpu.serving.kv_blocks import KVBlockPool
             from sparkdl_tpu.serving.prefix_cache import PrefixCache
@@ -546,6 +553,16 @@ class ContinuousGPTEngine:
             self._table = np.full((n_slots, mb), self._pool.sentinel,
                                   np.int32)
             self._pidx = np.zeros((n_slots,), np.int32)
+            # the layout the device keeps each pool array in (on the
+            # v5e the BLOCK axis is the minor one, not the head
+            # dimension): the decode programs' column writes are held to
+            # it, so the pool is updated where it lies. Read when a
+            # decode program is traced. TO GO with ROADMAP A8: a pool
+            # stored block-major is written in place by a plain
+            # scatter, and this, the pin and the loop in _q_scatter
+            # leave with it.
+            self._kv_stored = {name: a.format.layout
+                               for name, a in self._pool_kv.items()}
             n_layers = config.num_layers
             nh = config.num_heads
             hd = config.hidden_size // config.num_heads
@@ -567,71 +584,104 @@ class ContinuousGPTEngine:
                         x, pool[name + "_scale"][:, ids], cdt)
                 return x if kv_dtype == "fp32" else x.astype(cdt)
 
+            def _stored_as(pool, name, vals):
+                # THE quantize-on-write rule, the one every pool write
+                # applies (so column writes and installs can never
+                # desynchronize): what K/V values become in the pool,
+                # by array name. int8 stores values + their per-column
+                # scales; bf16/fp32 a cast.
+                if kv_dtype == "int8":
+                    q, s = quantize_kv(vals)
+                    return {name: q, name + "_scale": s}
+                return {name: vals.astype(pool[name].dtype)}
+
             def _q_write(pool, where, newk, newv):
-                # THE quantize-on-write path (every pool write goes
-                # through here, so scatter and install can never
-                # desynchronize): ``where`` is the advanced index after
-                # the layer axis — (blk, off) column tuples for decode/
-                # verify scatter, (ids,) whole blocks for the prefill
-                # install. int8 writes values + their per-column scales;
-                # sentinel entries drop — no block corrupted.
+                # whole blocks into the pool (the prefill install, the
+                # sp and disagg handoffs): ``where`` is the advanced
+                # index after the layer axis, (ids,). Sentinel entries
+                # drop — no block corrupted.
                 ix = (slice(None),) + where
                 out = dict(pool)
                 for name, vals in (("k", newk), ("v", newv)):
-                    if kv_dtype == "int8":
-                        q, s = quantize_kv(vals)
-                        out[name] = pool[name].at[ix].set(
-                            q, mode="drop")
-                        sc = name + "_scale"
-                        out[sc] = pool[sc].at[ix].set(s, mode="drop")
-                    else:
-                        out[name] = pool[name].at[ix].set(
-                            vals.astype(pool[name].dtype), mode="drop")
+                    for key, x in _stored_as(pool, name, vals).items():
+                        out[key] = pool[key].at[ix].set(x, mode="drop")
                 return out
 
             def _q_scatter(pool, blk, off, newk, newv):
-                # freshly written columns; blk/off share any index
-                # shape ([S] decode, [S,k] verify)
-                return _q_write(pool, (blk, off), newk, newv)
+                # freshly written columns ([layers, *blk.shape, H, D];
+                # blk/off share any index shape: [S] decode, [S,k]
+                # verify) into the DONATED pool, one column at a time
+                # and in place: a loop of dynamic-update-slices that
+                # carries the pool, each HELD to the layout the pool is
+                # stored in. Left to itself the compiler re-lays the
+                # whole pool out to suit the update and back again (for
+                # ``.at[:, blk, off].set`` and for this loop alike: four
+                # passes over the pool a tick on the v5e, PERF.md
+                # section 5). A sentinel block rewrites what is there
+                # — no block corrupted. TO GO with ROADMAP A8 (the
+                # block-major pool takes ``.at[:, blk, off].set`` in
+                # 0.3 ms, PERF.md section 6): the loop, the pin and
+                # ``_kv_stored`` are a workaround for the stored layout.
+                blk, off = blk.reshape(-1), off.reshape(-1)
+                cols = {}
+                for name, vals in (("k", newk), ("v", newv)):
+                    cols.update(_stored_as(
+                        pool, name, vals.reshape(n_layers, -1, nh, hd)))
+                live = blk < kv_blocks
+                blk = jnp.minimum(blk, kv_blocks - 1)
+
+                def body(c, pool):
+                    out = dict(pool)
+                    for name, vals in cols.items():
+                        col = lax.dynamic_slice_in_dim(
+                            vals, c, 1, axis=1)[:, :, None]
+                        at = (0, blk[c], off[c]) + (0,) * (col.ndim - 3)
+                        old = lax.dynamic_slice(pool[name], at, col.shape)
+                        out[name] = with_layout_constraint(
+                            lax.dynamic_update_slice(
+                                pool[name], jnp.where(live[c], col, old),
+                                at),
+                            self._kv_stored[name])
+                    return out
+
+                return lax.fori_loop(0, blk.shape[0], body, pool)
 
             @functools.partial(jax.jit, donate_argnums=(1,),
                                static_argnums=(5, 6))
             def _paged_step(variables, pool, table, idx, tok, k, nb):
-                # k tokens for every slot over the BLOCK TABLE: each
-                # step gathers the table's blocks into a virtual dense
-                # [S, nb*bs] cache (same math as the dense layout, so
-                # greedy tokens stay bitwise-identical), runs the
-                # per-slot decode, then scatters the one written column
-                # back to its pool block. ``nb`` (static, bucketed) is
-                # the block count covering the DEEPEST live row through
-                # this chain — the gather and attention touch only the
-                # live head of the table, often FEWER columns than the
-                # dense layout's fixed max_len (masked-width invariance
-                # keeps tokens bitwise). Rows are right-aligned (no
-                # left pad: column i holds real token i), so the causal
-                # mask alone masks garbage columns and positions need
-                # no start offset. Sentinel table entries clip on
-                # gather (masked garbage) and drop on scatter (no block
-                # corrupted).
+                # k tokens for every slot THROUGH the block table: the
+                # model takes the pool itself as a paged cache (a
+                # ``table`` entry, models/gpt.py), so each layer gathers
+                # only its own live blocks into an [S, nb*bs] slice —
+                # same math over the same width as the dense layout, so
+                # greedy tokens stay bitwise-identical — and hands back
+                # the one new column per row, which is the ONLY write to
+                # the donated pool (in place, at (block, offset)): no
+                # all-layer dense view, no copy of the pool. ``nb``
+                # (static, bucketed) is the block count covering the
+                # DEEPEST live row through this chain — the gather and
+                # attention touch only the live head of the table, often
+                # FEWER columns than the dense layout's fixed max_len
+                # (masked-width invariance keeps tokens bitwise). Rows
+                # are right-aligned (no left pad: column i holds real
+                # token i), so the causal mask alone masks garbage
+                # columns and positions need no start offset. Sentinel
+                # table entries clip on gather (masked garbage) and write
+                # nothing (_q_scatter: no block corrupted).
                 sub = table[:, :nb]
 
                 def body(carry, _):
                     pool, idx, tok = carry
-                    kbuf = _dq_gather(pool, "k", sub).reshape(
-                        n_layers, n_slots, nb * bs_kv, nh, hd)
-                    vbuf = _dq_gather(pool, "v", sub).reshape(
-                        n_layers, n_slots, nb * bs_kv, nh, hd)
-                    cache = {"k": kbuf, "v": vbuf, "idx": idx}
-                    logits, cache = model.apply(
-                        variables, tok[:, None], cache=cache,
+                    logits, new = model.apply(
+                        variables, tok[:, None],
+                        cache=dict(pool, table=sub, idx=idx),
                     )
                     ntok = jnp.argmax(logits[:, -1], axis=-1)
                     rows = jnp.arange(n_slots)
                     blk = table[rows, idx // bs_kv]
                     off = idx % bs_kv
-                    newk = cache["k"][:, rows, idx]
-                    newv = cache["v"][:, rows, idx]
-                    pool = _q_scatter(pool, blk, off, newk, newv)
+                    pool = _q_scatter(pool, blk, off,
+                                      new["k"][:, :, 0], new["v"][:, :, 0])
                     return (pool, idx + 1, ntok), ntok
 
                 (pool, _, _), toks = lax.scan(
@@ -649,28 +699,23 @@ class ContinuousGPTEngine:
                 # all k columns at [idx[s], idx[s]+k) and the per-row
                 # causal mask conditions position j on the real context
                 # plus drafts [:j] — exactly the logits greedy
-                # acceptance needs, same gather/scatter shape as
+                # acceptance needs, through the same paged cache as
                 # _paged_step so greedy tokens stay bitwise. Columns of
                 # REJECTED drafts scatter back as garbage PAST the
                 # accepted frontier (the host advances pidx only over
                 # accepted inputs): they sit causally masked until the
                 # next dispatch's own writes overwrite them — the same
                 # garbage-but-finite contract as retired-slot columns.
-                sub = table[:, :nb]
-                kbuf = _dq_gather(pool, "k", sub).reshape(
-                    n_layers, n_slots, nb * bs_kv, nh, hd)
-                vbuf = _dq_gather(pool, "v", sub).reshape(
-                    n_layers, n_slots, nb * bs_kv, nh, hd)
-                cache = {"k": kbuf, "v": vbuf, "idx": idx}
-                logits, cache = model.apply(variables, toks, cache=cache)
+                logits, new = model.apply(
+                    variables, toks,
+                    cache=dict(pool, table=table[:, :nb], idx=idx),
+                )
                 out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 rows = jnp.arange(n_slots)[:, None]
                 pos = idx[:, None] + jnp.arange(k)[None, :]
                 blk = table[rows, pos // bs_kv]
                 off = pos % bs_kv
-                newk = cache["k"][:, rows, pos]
-                newv = cache["v"][:, rows, pos]
-                return out, _q_scatter(pool, blk, off, newk, newv)
+                return out, _q_scatter(pool, blk, off, new["k"], new["v"])
 
             def _gathered(pool, ids):
                 # cached-prefix blocks -> the head of a private prefill
@@ -2193,6 +2238,18 @@ class ContinuousGPTEngine:
             return 1
         return cap
 
+    def _count_kv_read(self, nb: int, steps: int = 1) -> "dict[str, int]":
+        """Count what a paged dispatch gathers through the block table,
+        per layer, and hand it back as span arguments: every slot's
+        ``nb`` blocks at each of the dispatch's ``steps`` model passes
+        (``kv_cols_read``), and how much of that is a live row's context,
+        which deepens by one a pass (``kv_cols_live``)."""
+        depths = [int(self._pidx[s]) for s in self._inflight]
+        read = self.n_slots * nb * self._kv_bs * steps
+        live = steps * sum(depths) + len(depths) * steps * (steps - 1) // 2
+        self.metrics.record_kv_read(read, live)
+        return {"kv_cols_read": read, "kv_cols_live": live}
+
     def _decode_chain_len(self, now: float) -> int:
         """Tokens to fuse into the next plain decode dispatch: the
         configured/auto cap under the shared budget/deadline bound,
@@ -2284,8 +2341,9 @@ class ContinuousGPTEngine:
                  if tracing.tracing_enabled() else ())
         # the span runs on over the acceptance loop, so that it can say
         # how many tokens the verify made (its ``tokens``)
+        cols = self._count_kv_read(nb)  # one pass, k wide
         with span("serving.spec_verify", slots=len(self._inflight),
-                  k=k, links=links) as verify:
+                  k=k, links=links, **cols) as verify:
             out, self._pool_kv = self._paged_verify_fn(
                 self.variables, self._pool_kv,
                 jnp.asarray(self._table), jnp.asarray(self._pidx),
@@ -2350,7 +2408,7 @@ class ContinuousGPTEngine:
             return
         k = self._decode_chain_len(time.monotonic())
         paged = self.kv_layout == "paged"
-        shape = {}
+        shape, cols = {}, {}
         if paged:
             from sparkdl_tpu.runtime.batching import pow2_bucket
 
@@ -2363,13 +2421,14 @@ class ContinuousGPTEngine:
                        default=0) + k
             nb = pow2_bucket(-(-need // self._kv_bs), 1, self._mb)
             shape["nb"] = nb
+            cols = self._count_kv_read(nb, k)
         t0 = time.perf_counter()
         # decode ticks are batch-level: their spans link every rider's
         # request id so each request's trace pulls in its decode steps
         links = ([f.req.request_id for f in self._inflight.values()]
                  if tracing.tracing_enabled() else ())
         with span("serving.decode_step", slots=len(self._inflight),
-                  chain=k, links=links, **shape):
+                  chain=k, links=links, **shape, **cols):
             # Async token readback (runtime/completion.py): the D2H copy
             # of the token ids is enqueued the moment the decode dispatch
             # is — it rides behind the compute instead of waiting for the

@@ -39,6 +39,15 @@ _M_TOKENS = registry().counter(
     labels=("phase",))
 _M_TOKENS_BY_PHASE = {phase: _M_TOKENS.labels(phase=phase)
                       for phase in ("prefill", "decode")}
+_M_KV_COLS_READ = registry().counter(
+    "sparkdl_serving_kv_cols_read_total",
+    "K/V columns the paged decode programs gathered through the block "
+    "table, per layer: slots x blocks under the deepest live row x block "
+    "size, for every step of a dispatch")
+_M_KV_COLS_LIVE = registry().counter(
+    "sparkdl_serving_kv_cols_live_total",
+    "of those columns, the ones that held a live row's context (the sum "
+    "of the live rows' depths); the rest is bucket padding and idle slots")
 
 
 def default_host_id() -> str:
@@ -91,7 +100,8 @@ class ServingMetrics:
     ``snapshot()`` is the structured dict an operator scrapes: admission
     (submitted/rejected/expired/cancelled, straight off the queue's own
     counters), outcomes (completed/failed), queue depth, mean
-    batch-occupancy %, dispatch count, tokens generated, and request
+    batch-occupancy %, dispatch count, tokens generated, K/V columns the
+    paged decode gathered and how many of them were live, and request
     latency p50/p95/p99 (seconds, submit -> result).
     """
 
@@ -105,6 +115,18 @@ class ServingMetrics:
         self.failed = 0
         self.batches = 0
         self.tokens = 0
+        self.kv_cols_read = 0
+        self.kv_cols_live = 0
+
+    def record_kv_read(self, read: int, live: int) -> None:
+        """One paged decode dispatch gathered ``read`` K/V columns (per
+        layer) through the block table, ``live`` of them a live row's
+        context."""
+        with self._lock:
+            self.kv_cols_read += read
+            self.kv_cols_live += live
+        _M_KV_COLS_READ.inc(read)
+        _M_KV_COLS_LIVE.inc(live)
 
     def record_tokens(self, n: int, *, phase: str) -> None:
         """``n`` tokens appended to live requests, counted where the
@@ -150,6 +172,8 @@ class ServingMetrics:
                 "failed": self.failed,
                 "batches": self.batches,
                 "tokens": self.tokens,
+                "kv_cols_read": self.kv_cols_read,
+                "kv_cols_live": self.kv_cols_live,
                 "batch_occupancy_pct": self._occupancy.mean_step_time(),
                 "latency_s": self._latency.step_time_percentiles((50, 95, 99)),
                 "latency_mean_s": self._latency.mean_step_time(),

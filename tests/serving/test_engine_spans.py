@@ -388,3 +388,83 @@ def test_a_profiler_capture_holds_the_engines_spans(bundle, traced,
     assert all(s["chain"] == 1 and s["nb"] >= 1
                for s in host["serving.decode_step"])
     assert host["serving.prefill_chunk"][0]["program"] == "chunk_one"
+
+
+# -- what the paged decode reads through the table (ISSUE 27) ----------------
+
+def _hand_count(slots, block, steps_of):
+    """(read, live) of a script: ``steps_of`` lists, a dispatch, the
+    depths of its live rows, the blocks under the deepest and its model
+    passes."""
+    read = live = 0
+    for depths, nb, steps in steps_of:
+        read += slots * nb * block * steps
+        live += sum(d + j for d in depths for j in range(steps))
+    return read, live
+
+
+def test_kv_cols_read_and_live_equal_a_hand_count(bundle, traced):
+    """Three requests on two slots, blocks of 4: each decode step says how
+    many K/V columns it gathers through the table (every slot's ``nb``
+    blocks) and how many of them are a live row's context; the totals are
+    in ``snapshot()`` and in the registry."""
+    eng = _engine(bundle, kv_block_size=4, prefill_chunk=16)
+    fam = registry().get("sparkdl_serving_kv_cols_read_total")
+    before = sum(fam.snapshot_values().values()) if fam else 0.0
+    try:
+        # (prompt, new): the first token comes from the prefill, so a
+        # request decodes new - 1 times, at depths prompt, prompt + 1, ...
+        _, outs = _serve(eng, _prompts(bundle[0], ((5, 3), (9, 4), (2, 2))))
+        snap = eng.snapshot()
+    finally:
+        eng.close()
+    assert [len(o) for o in outs] == [3, 4, 2]
+    # tick 1 admits (5, 3) and (9, 4) and decodes both: depths 5 and 9
+    # under 3 blocks, bucketed to 4; tick 2: 6 and 10, after which the
+    # first is done; tick 3 admits (2, 2) beside the second at 11; its one
+    # decode step ends it, and the second has ended too
+    want = _hand_count(2, 4, [([5, 9], 4, 1), ([6, 10], 4, 1),
+                              ([11, 2], 4, 1)])
+    steps = sorted(_spans("serving.decode_step"), key=lambda e: e["ts"])
+    assert [(e["args"]["kv_cols_read"], e["args"]["kv_cols_live"])
+            for e in steps] == [(32, 14), (32, 16), (32, 13)]
+    assert (sum(e["args"]["kv_cols_read"] for e in steps),
+            sum(e["args"]["kv_cols_live"] for e in steps)) == want
+    assert (snap["kv_cols_read"], snap["kv_cols_live"]) == want
+    assert sum(registry().get("sparkdl_serving_kv_cols_read_total")
+               .snapshot_values().values()) - before == want[0]
+
+
+def test_kv_cols_count_every_pass_of_a_chain(bundle, traced):
+    """A chain of 4 gathers four times, a row deepening by one a pass; a
+    verify span gathers once, however wide."""
+    eng = _engine(bundle, kv_block_size=4, prefill_chunk=16, chain_tokens=4)
+    try:
+        _serve(eng, _prompts(bundle[0], ((6, 5),)))
+        snap = eng.snapshot()
+    finally:
+        eng.close()
+    (step,) = _spans("serving.decode_step")
+    assert step["args"]["chain"] == 4 and step["args"]["nb"] == 4
+    assert (step["args"]["kv_cols_read"], step["args"]["kv_cols_live"]) == \
+        _hand_count(2, 4, [([6], 4, 4)]) == (128, 6 + 7 + 8 + 9)
+    assert snap["kv_cols_live"] == 30
+    tracing.clear_trace()
+    eng = _engine(bundle, kv_block_size=4, prefill_chunk=16, spec_k=4)
+    try:
+        prompt = np.tile(np.arange(1, 5, dtype=np.int32), 4)
+        _serve(eng, [(prompt, 12)])
+        snap = eng.snapshot()
+    finally:
+        eng.close()
+    verifies = _spans("serving.spec_verify")
+    assert verifies
+    passes = verifies + _spans("serving.decode_step")
+    for e in passes:
+        # one pass a dispatch: both slots' blocks (8 under a row of 16 to
+        # 27 tokens), of which the one live row's depth is live
+        assert e["args"]["kv_cols_read"] == 2 * 8 * 4
+        assert 16 <= e["args"]["kv_cols_live"] < 16 + 12
+    assert snap["kv_cols_read"] == 64 * len(passes)
+    assert snap["kv_cols_live"] == sum(
+        e["args"]["kv_cols_live"] for e in passes)

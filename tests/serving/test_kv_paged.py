@@ -350,3 +350,150 @@ def test_soak_mixed_long_short_chunk_budget(bundle):
     assert eng._max_tick_prefill_tokens <= chunk
     assert eng._prefix.hit_tokens > 0  # the shared prefix got reused
     assert eng.snapshot()["completed"] == 20
+
+
+# -- decode through the block table (ISSUE 27) -------------------------------
+
+def walk_jaxpr(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from walk_jaxpr(sub)
+
+
+def decode_program(eng, which, k=2, nb=2):
+    """``_paged_step`` or ``_paged_verify`` of ``eng`` with the arguments
+    a tick would hand it (k tokens a row, ``nb`` blocks of the table)."""
+    fn = {"step": eng._paged_step_fn, "verify": eng._paged_verify_fn}[which]
+    tok = (jnp.asarray(eng._last_tok) if which == "step"
+           else jnp.zeros((eng.n_slots, k), jnp.int32))
+    return fn, (eng.variables, eng._pool_kv, jnp.asarray(eng._table),
+                jnp.asarray(eng._pidx), tok, k, nb)
+
+
+def program_arrays(fn, args):
+    """(primitive, shape, dtype) of everything the traced program makes."""
+    closed = jax.make_jaxpr(fn, static_argnums=(5, 6))(*args)
+    return [(eqn.primitive.name, tuple(v.aval.shape), v.aval.dtype)
+            for eqn in walk_jaxpr(closed.jaxpr) for v in eqn.outvars]
+
+
+#: the only ways a decode program may make an array of the pool's shape:
+#: the column update in place, the loops and calls that carry it, and the
+#: pin to the layout it is stored in
+IN_PLACE = {"dynamic_update_slice", "while", "scan", "pjit", "jit",
+            "closed_call", "core_call", "layout_constraint"}
+
+
+@pytest.mark.parametrize("which", ["step", "verify"])
+def test_decode_program_holds_no_dense_view_and_no_second_pool(bundle, which):
+    """The lowered program reads K/V through the table a layer at a time:
+    no array of the all-layer gathered view's shape, nothing of the pool's
+    shape but the in-place column update, and the compiled program gives
+    the pool's input buffers back as its outputs."""
+    cfg, _, variables = bundle
+    # 3 slots x 2 blocks of the table against a pool of 11 blocks, so that
+    # no two of the shapes below coincide
+    eng = _engine(cfg, variables, n_slots=3, kv_block_size=4, kv_blocks=11,
+                  spec_k=2)
+    try:
+        fn, args = decode_program(eng, which, k=2, nb=2)
+        pool_shape = eng._pool_kv["k"].shape
+        layers, _, bs, nh, hd = pool_shape
+        view = (layers, eng.n_slots, 2 * bs, nh, hd)
+        made = program_arrays(fn, args)
+        assert made, "the program traced to nothing"
+        assert [m for m in made if m[1] == view] == []
+        # one layer's slice of the view is what a layer gathers
+        assert [m for m in made if m[1] == view[1:]]
+        assert {m[0] for m in made if m[1] == pool_shape} <= IN_PLACE
+        text = fn.lower(*args).compile().as_text()
+        aliases = text.split("input_output_alias={", 1)[1].split(
+            "entry_computation_layout", 1)[0]
+        assert aliases.count("-alias") == len(eng._pool_kv)
+    finally:
+        eng.close()
+
+
+def _dense_rows(pool, table, layer):
+    """pool[layer] gathered into per-slot rows, the oracle's way."""
+    g = np.asarray(pool)[layer][np.minimum(table, pool.shape[1] - 1)]
+    return g.reshape(table.shape[0], -1, *g.shape[3:])
+
+
+@pytest.mark.parametrize("width", [1, 3])
+def test_model_reads_a_paged_cache_like_the_dense_one(bundle, width):
+    """``GPTLMHeadModel`` on a paged cache (pool + table) against the same
+    K/V laid out as a per-slot dense cache: logits bitwise equal, and the
+    columns handed back are the ones the dense cache wrote. Rows sit at
+    different depths, one row's table is all sentinel (an idle slot) and
+    two rows share their first block (a copy-on-write prefix)."""
+    cfg, model, variables = bundle
+    layers, bs, n_blocks = cfg.num_layers, 4, 9
+    nh, hd = cfg.num_heads, cfg.hidden_size // cfg.num_heads
+    rng = np.random.default_rng(width)
+    pool = {n: jnp.asarray(rng.normal(size=(layers, n_blocks, bs, nh, hd)),
+                           jnp.float32) for n in ("k", "v")}
+    sentinel = n_blocks
+    table = np.asarray([[0, 1, 2], [0, 3, sentinel],
+                        [sentinel, sentinel, sentinel]], np.int32)
+    idx = np.asarray([9, 5, 0], np.int32)
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (3, width)), jnp.int32)
+    dense = {n: jnp.stack([jnp.asarray(_dense_rows(pool[n], table, l))
+                           for l in range(layers)]) for n in ("k", "v")}
+    want, wrote = model.apply(variables, toks,
+                              cache=dict(dense, idx=jnp.asarray(idx)))
+    got, new = model.apply(
+        variables, toks,
+        cache=dict(pool, table=jnp.asarray(table), idx=jnp.asarray(idx)))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert new["k"].shape == (layers, 3, width, nh, hd)
+    np.testing.assert_array_equal(np.asarray(new["idx"]), idx + width)
+    for n in ("k", "v"):
+        for row, at in enumerate(idx):
+            np.testing.assert_array_equal(
+                np.asarray(new[n][:, row]),
+                np.asarray(wrote[n][:, row, at:at + width]))
+
+
+TABLE_CASES = {
+    "one_token": {},
+    "chain_tokens_4": {"chain_tokens": 4},
+    "spec_k_4": {"spec_k": 4},
+}
+
+
+@pytest.mark.parametrize("case", list(TABLE_CASES))
+def test_tokens_through_the_table_are_bitwise_the_dense_engines(bundle, case):
+    """Greedy tokens of the paged engine (one token a tick, chains of 4,
+    speculative verify spans of 4) against ``kv_layout="dense"``: three
+    slots for four requests, so rows sit at different depths in one batch
+    and a slot idles on sentinel entries; the second request shares a
+    partial block with the first while the first still decodes into it."""
+    cfg, model, variables = bundle
+    prefix = [5, 3, 9, 2, 7, 11]
+    # the repetitive last prompt gives the n-gram proposer drafts
+    cases = [(prefix, 14), (prefix + [1, 4], 6), ([6, 8, 6], 12),
+             ([1, 2, 3, 4] * 3, 10)]
+    outs = {}
+    for layout, kw in (("paged", dict(kv_block_size=4, prefill_chunk=8,
+                                      **TABLE_CASES[case])),
+                       ("dense", {})):
+        eng = _engine(cfg, variables, kv_layout=layout, n_slots=3, **kw)
+        futs = [eng.submit(*cases[0])]
+        eng.tick()
+        eng.tick()
+        assert not futs[0].done()
+        futs += [eng.submit(p, n) for p, n in cases[1:]]
+        _drain(eng, futs)
+        if layout == "paged":
+            assert eng._prefix.hit_tokens >= 4 + 2  # a block and a part
+            if "spec_k" in kw:
+                assert eng._spec_dispatches > 0
+        eng.close()
+        outs[layout] = [f.result(timeout=0) for f in futs]
+    for (prompt, n), got, want in zip(cases, outs["paged"], outs["dense"]):
+        assert len(got) == n
+        np.testing.assert_array_equal(
+            got, want, err_msg=f"{case}: paged diverged from dense: {prompt}")

@@ -292,3 +292,72 @@ def test_healthz_degraded_clears_on_release_not_admission(bundle):
     eng.close()
     np.testing.assert_array_equal(
         fb.result(timeout=0), _oracle(model, variables, [1, 4], 4))
+
+
+# -- compressed pools through the block table (ISSUE 27) ---------------------
+
+@pytest.mark.parametrize("which", ["step", "verify"])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_compressed_pool_is_never_dequantized_whole(bundle, kv_dtype, which):
+    """The decode programs dequantize one layer's gathered slice at a
+    time: nothing of the pool's shape, nor of the all-layer view's, at a
+    wider type than the pool is stored in (the int8 step once made a
+    float32 copy of the whole pool: 16.65 GB of the chip's 15.75 at 8 x
+    1024), and the scales ride along in place."""
+    from tests.serving.test_kv_paged import (
+        IN_PLACE,
+        decode_program,
+        program_arrays,
+    )
+
+    cfg, _, variables = bundle
+    eng = _engine(cfg, variables, n_slots=3, kv_blocks=11, kv_dtype=kv_dtype,
+                  spec_k=2)
+    try:
+        pool = eng._pool_kv
+        fn, args = decode_program(eng, which, k=2, nb=2)
+        made = program_arrays(fn, args)
+        layers, _, bs, nh, hd = pool["k"].shape
+        view = (layers, eng.n_slots, 2 * bs, nh, hd)
+        stored = pool["k"].dtype
+        assert stored == {"bf16": jnp.bfloat16, "int8": jnp.int8}[kv_dtype]
+        whole = [m for m in made if m[1] in (pool["k"].shape, view)]
+        assert whole, "the program never touches the pool"
+        assert {m[2] for m in whole} == {stored}
+        assert {m[0] for m in whole} <= IN_PLACE
+        # a layer's slice at the compute dtype is what attention reads
+        assert [m for m in made if m[1] == view[1:] and m[2] == cfg.dtype]
+        if kv_dtype == "int8":
+            scales = [m for m in made if m[1] == pool["k_scale"].shape]
+            assert scales and {m[0] for m in scales} <= IN_PLACE
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_columns_written_through_the_loop_are_the_scatters(bundle, kv_dtype):
+    """What one decode tick leaves in a compressed pool: the new column of
+    every live row, quantized by the rule every other pool write uses,
+    at its (block, offset) and nowhere else; an idle slot's sentinel
+    entry writes nothing."""
+    cfg, _, variables = bundle
+    eng = _engine(cfg, variables, n_slots=3, kv_blocks=11, kv_dtype=kv_dtype)
+    try:
+        fut = eng.submit([5, 3, 9, 2, 7, 11], 4)
+        eng.tick()  # admitted and prefilled: one live row, two idle
+        before = {n: np.asarray(a) for n, a in eng._pool_kv.items()}
+        table, pidx = eng._table.copy(), eng._pidx.copy()
+        (slot,) = list(eng._inflight)
+        eng.tick()  # one decode step
+        after = {n: np.asarray(a) for n, a in eng._pool_kv.items()}
+        blk = table[slot, pidx[slot] // 4]
+        off = pidx[slot] % 4
+        for n in before:
+            changed = np.argwhere(
+                (before[n] != after[n]).reshape(*before[n].shape[:3], -1)
+                .any(axis=-1))
+            assert {tuple(c[1:]) for c in changed} == {(blk, off)}, n
+            assert {c[0] for c in changed} == set(range(cfg.num_layers)), n
+        _drain(eng, [fut])
+    finally:
+        eng.close()
