@@ -21,31 +21,11 @@ def spans(run, *names: str, **attrs) -> "list[dict]":
             and all(s["args"].get(k) == v for k, v in attrs.items())]
 
 
-def _overlap(s: dict, w: "tuple[float, float]") -> float:
-    return max(0.0, min(s["t1"], w[1]) - max(s["t0"], w[0]))
-
-
-def span_share(run, *names: str) -> "float | None":
-    """Share (%) of the window spent inside the named spans."""
-    found = spans(run, *names)
-    if not run.spans:
-        return None
-    return 100.0 * sum(_overlap(s, run.window) for s in found) / window_s(run)
-
-
 def device_idle_share(run) -> "float | None":
     s = run.trace_summary
     if not s:
         return None
     return 100.0 * (1.0 - s["busy_s"] / s["window_s"])
-
-
-def traced_spans(run, *names: str, **attrs) -> "list[dict]":
-    """The named spans that START inside the traced stretch of the window."""
-    if not run.traced_window:
-        return []
-    t0, t1 = run.traced_window
-    return [s for s in spans(run, *names, **attrs) if t0 <= s["t0"] < t1]
 
 
 # -- serve -----------------------------------------------------------------------
@@ -58,33 +38,20 @@ def batch_occupancy(run) -> "float | None":
             / run.raw["n_slots"])
 
 
-def prefill_share(run) -> "float | None":
-    return span_share(run, "serving.prefill", "serving.prefill_chunk")
-
-
 def _decode_device(run) -> "tuple[float, int] | None":
+    """Device seconds and count of the decode program's WHOLE executions in
+    the traced stretch: one cut by an edge of the stretch would add a part
+    of a tick to the seconds and a whole tick to the count."""
     if not run.trace_summary:
         return None
     secs, count = trace_reduce.program_seconds(run.trace_summary,
-                                               "paged_step")
+                                               "paged_step", whole=True)
     return (secs, count) if count else None
 
 
 def decode_device_ms(run) -> "float | None":
     got = _decode_device(run)
     return None if got is None else 1e3 * got[0] / got[1]
-
-
-def tick_host_ms(run) -> "float | None":
-    """The mean decode tick as the host saw it, less the decode program's
-    mean device time, both over the traced stretch (the decode program's
-    cost changes with the depth it gathers, so the two are taken over the
-    same ticks): dispatch, token read-back and the engine's bookkeeping."""
-    ticks = traced_spans(run, "serving.decode_step")
-    dev = decode_device_ms(run)
-    if not ticks or dev is None:
-        return None
-    return 1e3 * statistics.fmean(s["t1"] - s["t0"] for s in ticks) - dev
 
 
 def live_contexts(run, n: int = 200) -> "tuple[float, float] | None":

@@ -3,8 +3,10 @@
 What is checked here: the manifest against the contract's static rules; that
 the seeded generators repeat; that each runner's rehearsal ends in the
 contract's result line and that a run without a TPU ends without one; that a
-cell, configuration, mix and per-layer metric can be ADDED as files and
-entries; the trace reduction on a trace built by hand; the needed-FLOP and
+cell, configuration, mix, kind of runner and per-layer metric can be ADDED
+as files and entries, and that this file's and its neighbours' tests of the
+manifest then pass on the copy that gained them; the trace reduction on a
+trace built by hand; the needed-FLOP and
 needed-byte counts against hand counts; and that the correctness comparison
 fails the lower-precision controls and a broken timed path.
 
@@ -48,14 +50,21 @@ def on_one_core(cmd):
     return [taskset, "-c", str(max(os.sched_getaffinity(0))), *cmd]
 
 
-def run_cell(args, cwd=ROOT, timeout=600):
+def child_env(*pythonpath):
+    """A child's environment: the CPU, none of this process's device count,
+    of the driver's ``BENCH_RUN`` or of pytest's own variables."""
     env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "BENCH_RUN")}
+           if k not in ("XLA_FLAGS", "BENCH_RUN")
+           and not k.startswith("PYTEST")}
     env.update(JAX_PLATFORMS="cpu", TF_CPP_MIN_LOG_LEVEL="2",
-               PYTHONPATH=os.pathsep.join([cwd, ROOT]))
+               PYTHONPATH=os.pathsep.join(map(str, pythonpath)))
+    return env
+
+
+def run_cell(args, cwd=ROOT, timeout=600):
     return subprocess.run(
         on_one_core([sys.executable, "-m", "benchmark.run", *args]),
-        cwd=cwd, env=env,
+        cwd=cwd, env=child_env(cwd, ROOT),
         capture_output=True, text=True, timeout=timeout)
 
 
@@ -78,13 +87,13 @@ def check_paths_and_command_are_exact():
                              "workloads", "end_to_end", "per_layer"}
 
 
-def check_the_issues_names_are_there_in_order():
-    # of the issue's three cells one is proved; the other two wait in
-    # PERF.md section 7 (rows 0 and 0b) with their parameters
-    assert CELLS == ["gpt2xl-backlog"]
-    assert {e["name"] for e in MANIFEST["end_to_end"]} == {
-        "tokens_per_s", "setup_s"}
-    assert {c["name"] for c in MANIFEST["configs"]} == {"gpt2-xl-serve"}
+def check_the_accepted_names_are_there_and_lead_their_lists():
+    # what was accepted is PRESENT and comes first, in the order it was
+    # accepted; what a later PR adds comes after it and fails nothing here
+    assert CELLS[0] == "gpt2xl-backlog"
+    assert {"tokens_per_s", "setup_s"} <= {
+        e["name"] for e in MANIFEST["end_to_end"]}
+    assert [c["name"] for c in MANIFEST["configs"]][:1] == ["gpt2-xl-serve"]
 
 
 def check_cell_resolves_to_its_files_and_metrics(cell):
@@ -122,7 +131,7 @@ def check_per_layer_metric_has_a_reader(metric):
 def test_the_manifest_its_cells_and_its_readers():
     check_manifest_passes_the_static_rules()
     check_paths_and_command_are_exact()
-    check_the_issues_names_are_there_in_order()
+    check_the_accepted_names_are_there_and_lead_their_lists()
     for cell in CELLS:
         check_cell_resolves_to_its_files_and_metrics(cell)
     check_every_per_layer_metric_of_the_manifest_has_a_reader_file()
@@ -260,26 +269,78 @@ def test_a_run_ends_in_the_contracts_line_or_in_none(tmp_path):
     check_in_a_directory_with_only_the_benchmark_a_run_fails(tmp_path)
 
 
-def test_a_cell_config_mix_and_metric_are_added_as_files(tmp_path):
+#: a second KIND of cell as a ``model_config`` PR brings it: a new file that
+#: imports what it shares with the serving runner and has a set-up and a
+#: check of its own
+OTHER_RUNNER = '''"""A runner kind added by a test."""
+from benchmark.harness import Comparison, say
+from benchmark.runners import serve
+from benchmark.runners.serve import end_to_end, teardown, window  # noqa: F401
+
+
+def setup(run):
+    say("set-up of the runner kind serve_other")
+    return serve.setup(run)
+
+
+def check(run, state):
+    return serve.check(run, state) + [
+        Comparison("the_new_kinds_own_number", 0.0, 0.0)]
+'''
+NEW_READER = '''def compute(run):
+    ticks = [s for s in run.spans if s["name"] == "serving.decode_step"]
+    return float(len(ticks)) if ticks else None
+'''
+TESTS_DIR = os.path.join(ROOT, "tests", "benchmark")
+#: the manifest-level tests of the three files that used to pin the manifest
+#: to one cell, one configuration and eighteen metrics, as ``pytest -k``
+#: finds them in this tree and found them in PR 27's
+PINNING_FILES = ["test_benchmark.py", "test_engine_readers.py",
+                 "test_engine_rehearsal.py"]
+PINNING_TESTS = "test_the_manifest or rehearsal"
+
+
+def run_the_copied_tests(tmp_path):
+    """The copy's own tests, in a child whose ``benchmark`` is the copy's
+    (every ``ROOT`` there is the copy): the static ones and the traced
+    rehearsal of the accepted cell."""
+    return subprocess.run(
+        on_one_core([sys.executable, "-m", "pytest", "-q", "-p",
+                     "no:cacheprovider", "-p", "no:xdist", "-k", PINNING_TESTS,
+                     *(os.path.join("tests", "benchmark", f)
+                       for f in PINNING_FILES)]),
+        cwd=tmp_path, env=child_env(tmp_path), capture_output=True,
+        text=True, timeout=900)
+
+
+def test_a_cell_config_mix_and_metric_are_added_as_files(
+        tmp_path, tests_from=TESTS_DIR):
     """Later PRs may add files and entries and edit no file that is there:
-    a copy of the benchmark gains a configuration, a mix, a per-layer reader
-    and a cell, and runs it."""
+    a copy of the repository gains what a ``model_config`` PR brings (a
+    configuration, a mix, a KIND of runner, a per-layer reader that only
+    the new cell reports, an end-to-end metric and a cell, whose name is
+    APPENDED to the lists of metrics that are there), runs the cell, and
+    the copy's own tests of the manifest stay green (``tests_from``: PR
+    28's proof that they did not was this test on PR 27's three files)."""
     shutil.copytree(os.path.join(ROOT, "benchmark"), tmp_path / "benchmark",
                     ignore=shutil.ignore_patterns("__pycache__"))
-    os.makedirs(tmp_path / "tests" / "benchmark")
-    before = {p: p.read_bytes() for p in (tmp_path / "benchmark").rglob("*")
-              if p.is_file()}
+    shutil.copytree(tests_from, tmp_path / "tests" / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    # the program under test is in the copy as it is in a checkout
+    os.symlink(os.path.join(ROOT, "sparkdl_tpu"), tmp_path / "sparkdl_tpu")
+    before = {p: p.read_bytes() for d in ("benchmark", "tests")
+              for p in (tmp_path / d).rglob("*") if p.is_file()}
+    assert any(p.name == "test_benchmark.py" for p in before)
     bench = tmp_path / "benchmark"
     cfg = json.loads((bench / "configs" / "gpt2-xl-serve.json").read_text())
     cfg["rehearse"]["hf_config"]["n_layer"] = 3
     (bench / "configs" / "gpt2-three-layers.json").write_text(json.dumps(cfg))
     mix = json.loads((bench / "traffic" / "backlog-chat.json").read_text())
-    mix.update(loop="open", arrivals={"rate_per_s": 20.0})
+    mix.update(runner="serve_other", loop="open",
+               arrivals={"rate_per_s": 20.0})
     (bench / "traffic" / "open-loop.json").write_text(json.dumps(mix))
-    (bench / "layer_metrics" / "decode_ticks.new.py").write_text(
-        "def compute(run):\n"
-        "    return float(sum(1 for s in run.spans\n"
-        "                     if s['name'] == 'serving.decode_step'))\n")
+    (bench / "runners" / "serve_other.py").write_text(OTHER_RUNNER)
+    (bench / "layer_metrics" / "decode_ticks.new.py").write_text(NEW_READER)
     m = json.loads(json.dumps(MANIFEST))
     m["configs"].append({
         "name": "gpt2-three-layers", "source": "a test's own",
@@ -296,35 +357,66 @@ def test_a_cell_config_mix_and_metric_are_added_as_files(tmp_path):
         "name": "decode_ticks.new", "unit": "ticks", "better": "higher",
         "source": "program_span", "layer": "Serving engine",
         "moves": "latency_per_token_p50_ms", "workloads": ["new.cell"]})
+    # the new cell reports three metrics that are there, one of them read
+    # from the device trace: its name goes at the end of their lists
+    shared = ["batch_occupancy.backlog", "device_idle_share.backlog"]
+    for e in m["end_to_end"] + m["per_layer"]:
+        if e["name"] in ["tokens_per_s", *shared]:
+            e["workloads"].append("new.cell")
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(m))
     assert mf.validate(str(tmp_path)) == []
     proc = run_cell(["--workload", "new.cell", "--seed", "5", "--seconds",
                      "2", "--trace", "1", "--rehearse"], cwd=str(tmp_path))
     assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "set-up of the runner kind serve_other" in proc.stdout
     line = last_json(proc)
     assert line["correct"] is True
-    assert set(line["metrics"]) == {"decode_ticks.new"}
+    assert "the_new_kinds_own_number" in line["compared"]
+    # a rehearsal has no device trace, so that reader is silent
+    assert set(line["metrics"]) == {"decode_ticks.new", shared[0]}
+    assert "per-layer " + shared[1] + ": nothing to read" in proc.stdout
     proc = run_cell(["--workload", "new.cell", "--seed", "5", "--seconds",
                      "2", "--trace", "0", "--rehearse"], cwd=str(tmp_path))
     assert proc.returncode == 0, proc.stderr[-3000:]
-    assert set(last_json(proc)["metrics"]) == {"latency_per_token_p50_ms",
-                                               "setup_s"}
+    assert set(last_json(proc)["metrics"]) == {
+        "tokens_per_s", "latency_per_token_p50_ms", "setup_s"}
+    # the accepted cell reports what it reported
+    names = lambda root: [e["name"] for e in mf.resolve_cell(
+        CELLS[0], root).per_layer]
+    assert names(str(tmp_path)) == names(ROOT)
+    proc = run_the_copied_tests(tmp_path)
+    assert proc.returncode == 0, proc.stdout[-6000:] + proc.stderr[-2000:]
+    assert "3 passed" in proc.stdout and "failed" not in proc.stdout
     assert all(p.read_bytes() == b for p, b in before.items())
 
 
 # -- the trace reduction, on a trace built by hand -----------------------------------------
 
+SLAB = "bf16[1,512,16,25,64]"
+STORED = "{1,4,3,2,0:T(8,128)(2,1)}"
+
+
+def slab_copy(serial):
+    """A device operation as the chip's trace names it."""
+    return (f"%copy.{serial} = {SLAB}{{4,3,2,1,0}} copy({SLAB}{STORED} "
+            f"%slab.{serial})")
+
+
 def synthetic_planes():
     ms = 1_000_000
-    dev0_ops = [("%fusion.1", 10 * ms, 20 * ms), ("%conv.2", 30 * ms, 10 * ms),
-                ("%fusion.1", 60 * ms, 20 * ms)]
-    dev1_ops = [("%fusion.1", 10 * ms, 40 * ms), ("%copy.3", 70 * ms, 20 * ms)]
+    dev0_ops = [("%fusion.1", 10 * ms, 20 * ms),
+                (slab_copy(8), 30 * ms, 10 * ms),
+                ("%fusion.4", 60 * ms, 20 * ms)]
+    dev1_ops = [("%fusion.1", 10 * ms, 40 * ms),
+                (slab_copy(3), 70 * ms, 20 * ms)]
     return [
         {"name": "/device:TPU:0", "lines": [
             {"name": "XLA Ops", "events": dev0_ops},
             {"name": "XLA Modules", "events": [
                 ("jit__paged_step(1)", 10 * ms, 30 * ms),
-                ("jit__chunk_one(2)", 60 * ms, 20 * ms)]},
+                ("jit__chunk_one(2)", 60 * ms, 20 * ms),
+                # cut by the end of the window: 10 of its 30 ms are inside
+                ("jit__paged_step(1)", 90 * ms, 30 * ms)]},
             {"name": "Steps", "events": [("0", 0, 100 * ms)]}]},
         {"name": "/device:TPU:1", "lines": [
             {"name": "XLA Ops", "events": dev1_ops},
@@ -338,28 +430,52 @@ def synthetic_planes():
 
 
 def check_trace_reduction_on_a_hand_built_trace():
+    from benchmark import harness, readers
+
     ms = 1_000_000
-    host = [("serving.prefill_chunk", 40 * ms, 60 * ms),   # covers 40-60 gaps
-            ("outer", 0, 100 * ms)]
+    host = [("serving.prefill_chunk", 40 * ms, 60 * ms),
+            ("outer", 0, 90 * ms)]    # the last 10 ms are under no span
     s = trace_reduce.reduce_trace(synthetic_planes(), host_spans=host)
     assert s["window_s"] == pytest.approx(0.1)
     assert s["devices"] == 2
     # device 0 busy 10-40 and 60-80 (50 ms), device 1 busy 10-50 and 70-90
     # (60 ms): mean 55 ms
     assert s["busy_s"] == pytest.approx(0.055)
-    assert s["programs"]["jit__paged_step(1)"] == {
-        "seconds": pytest.approx(0.07), "count": 2}
+    # three executions of the decode program touch the window, two are whole
+    step = "jit__paged_step(1)"
+    assert s["programs"][step] == {"seconds": pytest.approx(0.08), "count": 3}
+    assert s["whole_programs"][step] == {"seconds": pytest.approx(0.07),
+                                         "count": 2}
     assert trace_reduce.program_seconds(s, "paged_step") == (
+        pytest.approx(0.08), 3)
+    assert trace_reduce.program_seconds(s, "paged_step", whole=True) == (
         pytest.approx(0.07), 2)
     assert trace_reduce.program_seconds(s, "no_such_program") == (0.0, 0)
-    ops = dict(s["device_ops"])
-    assert ops["%fusion.1"] == pytest.approx(0.04)   # (40 + 40) / 2 devices
-    assert s["device_ops"][0][0] == "%fusion.1"
+    # decode_device_ms is the whole executions' 70 ms over their count, not
+    # 80 ms over the three that touch the stretch
+    run = harness.Run(cell=mf.resolve_cell(CELLS[0], ROOT), seed=0,
+                      seconds=1.0, trace=True, rehearse=True, t_process=0.0,
+                      window=(0.0, 1.0), trace_summary=s)
+    assert readers.decode_device_ms(run) == pytest.approx(35.0)
+    # operations by KIND: fusion.1 and fusion.4 are one kind (3 runs on 2
+    # devices, 80 ms), the two slab copies another (2 runs, 30 ms)
+    assert s["device_ops"] == [
+        ["2x fusion in 1.5 runs", pytest.approx(0.04)],
+        [f"2x copy {SLAB}{{4,3,2,1,0}} in 1 runs", pytest.approx(0.015)]]
+    # a tuple of results: its index remarks dropped, a run of one shape once
+    assert trace_reduce.op_kind(
+        f"%fusion.2951 = ({SLAB}{STORED}, {SLAB}{STORED}, /*index=2*/{SLAB}"
+        f"{{4,3,2,1,0}}) fusion(bf16[48,512,16,25,64]{STORED} %pool), "
+        "kind=kLoop, calls=%fused.7") == (
+            f"fusion (2x {SLAB}{STORED}, {SLAB}{{4,3,2,1,0}})")
+    assert trace_reduce.op_kind("%while.14.clone") == "while"
     gaps = dict(s["idle_gaps"])
-    # device 0 idle 0-10, 40-60, 80-100; device 1 idle 0-10, 50-70, 90-100;
-    # the innermost covering span owns a gap by its middle
-    assert gaps["serving.prefill_chunk"] == pytest.approx(0.01)
-    assert gaps["outer"] == pytest.approx(0.035)
+    # device 0 idle 0-10, 40-60, 80-100; device 1 idle 0-10, 50-70, 90-100.
+    # Each part of a gap goes to the innermost span over it: 50-70 is half
+    # the chunk's and half outer's, 80-100 half outer's and half no span's
+    assert gaps["serving.prefill_chunk"] == pytest.approx(0.015)
+    assert gaps["outer"] == pytest.approx(0.02)
+    assert gaps["no span"] == pytest.approx(0.01)
     assert s["longest_gap_s"] == pytest.approx(0.02)
     assert sum(gaps.values()) + s["busy_s"] == pytest.approx(s["window_s"])
 
@@ -452,14 +568,11 @@ def test_the_probe_reads_the_control_engine_and_the_engine_as_configured():
     """``python -m benchmark.probe`` at rehearsal sizes: one reading per
     engine and seed, the configured engine's beside both lower-precision
     references; a float32 engine reads 0 against the float32 reference."""
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "BENCH_RUN")}
-    env.update(JAX_PLATFORMS="cpu", TF_CPP_MIN_LOG_LEVEL="2", PYTHONPATH=ROOT)
     proc = subprocess.run(
         on_one_core([sys.executable, "-m", "benchmark.probe", "--workloads",
                      "gpt2xl-backlog", "--seeds", str(2**31 + 21),
                      "--control-seeds", "22", "--seconds", "1.5",
-                     "--rehearse"]), cwd=ROOT, env=env,
+                     "--rehearse"]), cwd=ROOT, env=child_env(ROOT),
         capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
     lines = [json.loads(ln) for ln in proc.stdout.splitlines()

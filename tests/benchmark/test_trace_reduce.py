@@ -1,11 +1,14 @@
-"""The trace reduction's idle-gap ownership: the same answers as the loop it
-replaced, at a cost that does not grow with gaps x spans.
+"""The trace reduction against a plain oracle: idle time shared out over the
+host spans that cover it, device time summed by kind of operation, programs
+counted whole and clipped; at a cost that does not grow with gaps x spans.
 
-``oracle_reduce_trace`` is ``benchmark.trace_reduce.reduce_trace`` as PR 24
-left it, copied verbatim (its ownership loop walks every span for every
-gap); the tests hold the sweep that took its place to it on seeded random
-traces, key for key, and to one wall-clock ceiling on a trace the size a
-21 ms tick leaves. Pure Python, no jax, no child process.
+``oracle_reduce_trace`` says the same thing the slow way: for every gap it
+walks every span (PR 24's loop did; it gave a whole gap to the shortest span
+over its MIDDLE, and since PR 28 each part of a gap goes to the shortest
+span over that part), and it looks a kind up in the table the random names
+were drawn from. The tests hold the sweep to it on seeded random traces, key
+for key, and to one wall-clock ceiling on a trace the size a 21 ms tick
+leaves. Pure Python, no jax, no child process.
 """
 
 import json
@@ -17,6 +20,40 @@ import pytest
 from benchmark import trace_reduce
 from benchmark.trace_reduce import (DEVICE_PLANE_PREFIX, MODULE_LINE, OP_LINE,
                                     _clip, _union, find_mark)
+
+#: operation names as the chip's trace prints them, and the kind of each:
+#: the serial number is no part of a kind, the result's shape and layout are
+#: (a tuple of results with each run of one shape written once)
+KINDS = {
+    "%fusion.11 = bf16[8,1600]{1,0:T(8,128)(2,1)} fusion(bf16[8,1600]{1,0} "
+    "%p.1), kind=kLoop, calls=%fused.11": "fusion bf16[8,1600]{1,0:T(8,128)(2,1)}",
+    "%fusion.12 = bf16[8,1600]{1,0:T(8,128)(2,1)} fusion(bf16[8,6400]{1,0} "
+    "%p.2), kind=kLoop, calls=%fused.12": "fusion bf16[8,1600]{1,0:T(8,128)(2,1)}",
+    "%fusion.13 = bf16[8,1600]{1,0} fusion(bf16[8,1600]{1,0} %p.3), "
+    "kind=kLoop": "fusion bf16[8,1600]{1,0}",
+    "%fusion.2951 = (bf16[1,512,16,25,64]{1,4,3,2,0:T(8,128)(2,1)}, "
+    "bf16[1,512,16,25,64]{1,4,3,2,0:T(8,128)(2,1)}) fusion(bf16[48,512,16,25,"
+    "64]{1,4,3,2,0:T(8,128)(2,1)} %pool), kind=kLoop":
+        "fusion (2x bf16[1,512,16,25,64]{1,4,3,2,0:T(8,128)(2,1)})",
+    "%fusion.2953 = (bf16[1,512,16,25,64]{1,4,3,2,0:T(8,128)(2,1)}, "
+    "bf16[1,512,16,25,64]{1,4,3,2,0:T(8,128)(2,1)}) fusion(bf16[48,512,16,25,"
+    "64]{1,4,3,2,0:T(8,128)(2,1)} %pool), kind=kLoop":
+        "fusion (2x bf16[1,512,16,25,64]{1,4,3,2,0:T(8,128)(2,1)})",
+    "%copy.1036 = bf16[1,512,16,25,64]{4,3,2,1,0} copy(bf16[1,512,16,25,64]"
+    "{1,4,3,2,0:T(8,128)(2,1)} %slab.1)": "copy bf16[1,512,16,25,64]{4,3,2,1,0}",
+    "%copy.1037 = bf16[1,512,16,25,64]{4,3,2,1,0} copy(bf16[1,512,16,25,64]"
+    "{1,4,3,2,0:T(8,128)(2,1)} %slab.2)": "copy bf16[1,512,16,25,64]{4,3,2,1,0}",
+    "%copy.5 = f32[8]{0} copy(f32[8]{0} %x)": "copy f32[8]{0}",
+    "%multiply_reduce_fusion.3 = f32[8,1024,25]{2,1,0} fusion(f32[8] %q)":
+        "multiply_reduce_fusion f32[8,1024,25]{2,1,0}",
+    "%while.14 = (s32[]{:T(128)}, bf16[48,512]{1,0}, bf16[48,512]{1,0}, "
+    "/*index=3*/bf16[8]{0:T(8,128)(2,1)S(1)}, s32[]{:T(128)}) while((s32[], "
+    "bf16[48,512]) %t), body=%b":
+        "while (s32[]{:T(128)}, 2x bf16[48,512]{1,0}, "
+        "bf16[8]{0:T(8,128)(2,1)S(1)}, s32[]{:T(128)})",
+    "%op.7": "op", "%op.9.clone": "op", "plain": "plain",
+}
+NAMES = sorted(KINDS)
 
 
 def oracle_reduce_trace(planes, window=None, host_spans=None, top=10):
@@ -36,7 +73,8 @@ def oracle_reduce_trace(planes, window=None, host_spans=None, top=10):
             raise ValueError("the trace holds no device operation")
         window = (min(starts), max(ends))
     w0, w1 = window
-    busy_ns, op_ns, programs = [], {}, {}
+    busy_ns, programs, whole = [], {}, {}
+    kinds: "dict[str, dict]" = {}
     gaps: "list[tuple[int, int]]" = []
     for p in devices:
         ops = [ev for ln in line(p, OP_LINE)
@@ -46,36 +84,55 @@ def oracle_reduce_trace(planes, window=None, host_spans=None, top=10):
         merged = _union([(s, e) for _, s, e in ops])
         busy_ns.append(sum(e - s for s, e in merged))
         for name, s, e in ops:
-            op_ns[name] = op_ns.get(name, 0) + (e - s)
+            rec = kinds.setdefault(KINDS[name],
+                                   {"names": set(), "runs": 0, "ns": 0})
+            rec["names"].add(name)
+            rec["runs"] += 1
+            rec["ns"] += e - s
         for ln in line(p, MODULE_LINE):
-            for name, s, e in _clip(ln["events"], w0, w1):
+            for name, s, d in ln["events"]:
+                s2, e2 = max(s, w0), min(s + d, w1)
+                if e2 <= s2:
+                    continue
                 rec = programs.setdefault(name, {"seconds": 0.0, "count": 0})
-                rec["seconds"] += (e - s) / 1e9
+                rec["seconds"] += (e2 - s2) / 1e9
                 rec["count"] += 1
+                if w0 <= s and s + d <= w1:
+                    rec = whole.setdefault(name, {"seconds": 0.0, "count": 0})
+                    rec["seconds"] += d / 1e9
+                    rec["count"] += 1
         edges = [w0] + [t for s, e in merged for t in (s, e)] + [w1]
         gaps += [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
                  if edges[i + 1] > edges[i]]
     if not busy_ns:
         raise ValueError("no operation ran on a device inside the window")
-    idle_by: "dict[str, float]" = {}
-    spans = sorted(host_spans or (), key=lambda sp: sp[2] - sp[1])
+    idle_ns: "dict[str, int]" = {}
+    spans = sorted((sp for sp in host_spans or () if sp[2] > sp[1]),
+                   key=lambda sp: sp[2] - sp[1])
     for g0, g1 in gaps:
-        mid = (g0 + g1) // 2
-        owner = next((n for n, s, e in spans if s <= mid < e), "no span")
-        idle_by[owner] = idle_by.get(owner, 0.0) + (g1 - g0) / 1e9 / len(busy_ns)
+        # every piece of the gap between two edges of any span has one owner
+        cuts = sorted({g0, g1, *(t for _, s, e in spans for t in (s, e)
+                                 if g0 < t < g1)})
+        for a, b in zip(cuts, cuts[1:]):
+            owner = next((n for n, s, e in spans if s <= a and b <= e),
+                         "no span")
+            idle_ns[owner] = idle_ns.get(owner, 0) + b - a
+    n_dev = len(busy_ns)
 
     def ranked(d):
-        return [[k, v] for k, v in
+        return [[k, v / 1e9 / n_dev] for k, v in
                 sorted(d.items(), key=lambda kv: -kv[1])[:top]]
 
     return {
         "window_s": (w1 - w0) / 1e9,
-        "busy_s": sum(busy_ns) / len(busy_ns) / 1e9,
-        "devices": len(busy_ns),
+        "busy_s": sum(busy_ns) / n_dev / 1e9,
+        "devices": n_dev,
         "programs": programs,
-        "device_ops": ranked({k: v / 1e9 / len(busy_ns)
-                              for k, v in op_ns.items()}),
-        "idle_gaps": ranked(idle_by),
+        "whole_programs": whole,
+        "device_ops": ranked({
+            f"{len(r['names'])}x {kind} in {r['runs'] / n_dev:g} runs": r["ns"]
+            for kind, r in kinds.items()}),
+        "idle_gaps": ranked(idle_ns),
         "longest_gap_s": max((g1 - g0 for g0, g1 in gaps), default=0) / 1e9,
     }
 
@@ -90,10 +147,11 @@ def device_plane(rng, k: int, n_ops: int) -> dict:
     for i in range(n_ops):
         t += int(rng.choice([0, 1, 2, 7, 100, 1_000, 5_000]))
         d = int(rng.integers(1, 4_000))
-        ops.append((f"%op.{int(rng.integers(0, 12))}", t, d))
+        ops.append((NAMES[int(rng.integers(0, len(NAMES)))], t, d))
         t += d - int(rng.choice([0, 0, 0, d // 2]))
         if t > W1 + 50_000:
             break
+    # some executions straddle an edge of the window: clipped, never whole
     programs = [(f"jit_step({int(rng.integers(0, 3))})", s, 20_000)
                 for s in range(W0 - 30_000, W1 + 30_000, 45_000)]
     return {"name": f"{DEVICE_PLANE_PREFIX}{k}", "lines": [
@@ -165,7 +223,7 @@ def random_trace(seed: int):
               for k in range(int(rng.integers(1, 4)))]
     # a device that ran nothing inside the window, and a host plane
     planes.append({"name": f"{DEVICE_PLANE_PREFIX}7", "lines": [
-        {"name": OP_LINE, "events": [("%op.far", W1 + 10_000_000, 5)]}]})
+        {"name": OP_LINE, "events": [("plain", W1 + 10_000_000, 5)]}]})
     planes.append({"name": "/host:CPU", "lines": [{"name": "python", "events": [
         (trace_reduce.WINDOW_MARK, W0, W1 - W0)]}]})
     # seeds 0 and 1 hand in no spans at all: None, and an empty list
@@ -176,7 +234,7 @@ def random_trace(seed: int):
 
 
 @pytest.mark.parametrize("seed", range(16))
-def test_the_sweep_gives_the_old_loops_result_key_for_key(seed):
+def test_the_sweep_gives_the_plain_loops_result_key_for_key(seed):
     planes, spans = random_trace(seed)
     for window in (None, (W0, W1), (W0 + 123_457, W1 - 98_765)):
         # each reading of the spans is on the window it is reduced over
@@ -191,7 +249,15 @@ def test_the_sweep_gives_the_old_loops_result_key_for_key(seed):
         assert summary == want
         assert counts["spans"] == len(spans or ())
         assert counts["spans_in_window"] <= counts["spans"]
-        assert counts["gaps"] >= len(want["idle_gaps"]) > 0
+        assert len(want["idle_gaps"]) > 0
+        # shared out or not, the idle time is all there
+        assert sum(v for _, v in trace_reduce.reduce_trace(
+            planes, window, spans, top=1000)["idle_gaps"]) + got[
+                "busy_s"] == pytest.approx(got["window_s"], rel=1e-12)
+        clipped, whole = got["programs"], got["whole_programs"]
+        assert all(whole[n]["count"] <= clipped[n]["count"] for n in whole)
+    assert sum(r["count"] for r in whole.values()) < sum(
+        r["count"] for r in clipped.values())      # the narrowest window cuts
     if seed > 1:
         owners = dict(want["idle_gaps"])
         assert len(owners) > 1 or "no span" not in owners
@@ -199,10 +265,10 @@ def test_the_sweep_gives_the_old_loops_result_key_for_key(seed):
 
 def test_spans_that_touch_the_window_by_one_nanosecond_still_own_a_gap():
     """The filter keeps a span by ``start < w1 and end > w0``: one that
-    reaches one nanosecond into the window owns the gap whose middle is
-    that nanosecond, and one that stops at the window's edge owns none."""
+    reaches one nanosecond into the window owns the gap that is that
+    nanosecond, and one that stops at the window's edge owns none."""
     planes = [{"name": f"{DEVICE_PLANE_PREFIX}0", "lines": [
-        {"name": OP_LINE, "events": [("%op", W0 + 1, W1 - W0 - 2)]}]}]
+        {"name": OP_LINE, "events": [("%op.7", W0 + 1, W1 - W0 - 2)]}]}]
     spans = [("ends at the edge", W0 - 9, W0), ("first ns", W0 - 5, W0 + 1),
              ("last ns", W1 - 1, W1 + 5), ("starts at the edge", W1, W1 + 9),
              ("over all", W0 - 100, W1 + 100)]
@@ -247,4 +313,12 @@ def test_a_trace_of_a_fast_tick_reduces_under_one_ceiling():
     assert 990 <= counts["spans_in_window"] <= 1_010
     assert counts["gaps"] >= 199_999
     assert dict(summary["idle_gaps"]).keys() >= {"serving.decode_wait"}
+    # 97 serial numbers of one opcode and no shape: one kind, one row
+    (row, secs), = summary["device_ops"]
+    assert row == "97x fusion in 200000 runs"
+    assert secs == pytest.approx(summary["busy_s"])
+    # 167 ticks touch the stretch, the first and the last cut by its edges
+    step_ = "jit__paged_step(1)"
+    assert summary["programs"][step_]["count"] == 167
+    assert summary["whole_programs"][step_]["count"] == 165
     assert took < 20.0, f"{took:.1f} s: the reduction is not near-linear"
