@@ -1,0 +1,61 @@
+"""What a serving engine asks of a model family.
+
+``ContinuousGPTEngine`` holds a block-paged K/V pool and a set of jitted
+programs around ONE module call; which module, and how a token's K/V is
+shaped, is the family's to say. A configuration answers
+``config.serving_family()`` with a :class:`ServingFamily`; the engine reads
+nothing else off the configuration. ``GPTConfig`` answers with its own
+fields (so GPT-2's programs are what they were); a new family answers from
+its own module (``models/afmoe.py``).
+
+The module's contract is :class:`~sparkdl_tpu.models.gpt.GPTLMHeadModel`'s:
+``module.apply(variables, ids, cache=None | dense | paged, positions=...)``
+returns ``(logits, cache)``; a paged cache hands back this call's new
+columns ``[layers, S, L, kv_heads, head_dim]`` and the caller writes them
+into its pool.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any
+
+
+@dataclasses.dataclass(frozen=True)
+class ServingFamily:
+    module: Any
+    layers: int
+    kv_heads: int          #: heads of K and V (under the query heads)
+    head_dim: int
+    dtype: Any             #: compute dtype, and the native K/V dtype
+    #: a learned position table's length; None where positions extrapolate
+    max_positions: "int | None" = None
+    #: layers whose attention sees only the last ``window`` positions (they
+    #: gather only the table entries the window covers); the rest are full
+    window_layers: int = 0
+    window: "int | None" = None
+    #: expert layers, and (token, expert) pairs a row makes in each: the
+    #: module's cached calls then hand back ``expert_counts``
+    #: ``[expert_layers, experts]`` int32 beside their K/V
+    expert_layers: int = 0
+    experts: int = 0
+    experts_per_token: int = 0
+    #: the family has the paged path alone: the dense layout and the
+    #: sequence-parallel prefill refuse it at construction
+    paged_only: bool = False
+
+    def window_blocks(self, nb: int, block_size: int) -> int:
+        """Table entries a window layer of this family gathers in a decode
+        step where a full layer gathers ``nb``."""
+        if not self.window_layers:
+            return nb
+        return window_blocks(self.window, nb, block_size)
+
+
+def window_blocks(window: int, nb: int, block_size: int,
+                  width: int = 1) -> int:
+    """Table entries that can cover ``window`` positions behind each of
+    ``width`` new columns, wherever in a block the newest falls: what a
+    window layer gathers where a full layer gathers ``nb``."""
+    return min(nb, (window + width - 2) // block_size + 2)
+

@@ -31,6 +31,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from sparkdl_tpu.models.family import ServingFamily
 from sparkdl_tpu.parallel.expert_parallel import MoEMlpBlock
 from sparkdl_tpu.parallel.ring_attention import ring_self_attention
 from sparkdl_tpu.parallel.tensor_parallel import (
@@ -106,6 +107,17 @@ class GPTConfig:
         defaults.update(kw)
         return cls(**defaults)
 
+    def serving_family(self) -> ServingFamily:
+        """What ``ContinuousGPTEngine`` reads of this family
+        (``models/family.py``): as many K/V heads as query heads, every
+        layer full attention, no experts it counts."""
+        return ServingFamily(
+            module=GPTLMHeadModel(self), layers=self.num_layers,
+            kv_heads=self.num_heads,
+            head_dim=self.hidden_size // self.num_heads, dtype=self.dtype,
+            max_positions=(self.max_seq_len if self.positions == "learned"
+                           else None))
+
 
 def apply_rope(x: jax.Array, positions: jax.Array,
                base: float = 10000.0) -> jax.Array:
@@ -142,11 +154,14 @@ def init_cache(config: GPTConfig, batch: int, max_len: int,
     }
 
 
-def init_block_pool(config: GPTConfig, n_blocks: int,
+def init_block_pool(config, n_blocks: int,
                     block_size: int, dtype: str = "fp32") -> dict:
     """Zeroed block-paged KV pool for continuous serving
     (``serving.kv_blocks``): k/v stacked over layers,
-    ``[num_layers, n_blocks, block_size, H, D]``.
+    ``[num_layers, n_blocks, block_size, H, D]``, ``H`` the K/V heads and
+    ``D`` the head size of the configuration's family
+    (``config.serving_family()``: a GPT's query heads, fewer for a family
+    that shares K/V heads).
 
     Unlike :func:`init_cache` (one dense row per batch slot, capacity
     ``batch x max_len`` whether or not tokens exist), the pool's
@@ -174,10 +189,9 @@ def init_block_pool(config: GPTConfig, n_blocks: int,
       gather/scatter programs — compute always runs at ``config.dtype``;
       only the resident pool is compressed.
     """
-    hd = config.hidden_size // config.num_heads
-    shape = (config.num_layers, n_blocks, block_size,
-             config.num_heads, hd)
-    store = {"fp32": config.dtype, "bf16": jnp.bfloat16,
+    fam = config.serving_family()
+    shape = (fam.layers, n_blocks, block_size, fam.kv_heads, fam.head_dim)
+    store = {"fp32": fam.dtype, "bf16": jnp.bfloat16,
              "int8": jnp.int8}.get(dtype)
     if store is None:
         raise ValueError(

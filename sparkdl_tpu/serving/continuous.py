@@ -346,11 +346,7 @@ class ContinuousGPTEngine:
         import jax.numpy as jnp
         from jax import lax
 
-        from sparkdl_tpu.models.gpt import (
-            GPTLMHeadModel,
-            init_block_pool,
-            init_cache,
-        )
+        from sparkdl_tpu.models.gpt import init_block_pool, init_cache
         from sparkdl_tpu.runtime.batching import default_buckets
 
         if n_slots < 1:
@@ -407,15 +403,26 @@ class ContinuousGPTEngine:
                 "the sp prefill stages K/V in a sequence-sharded block "
                 "pool"
             )
-        if (config.positions == "learned"
-                and max_len > config.max_seq_len):
+        # THE seam to the model (models/family.py): the module, and how
+        # it shapes a token's K/V. Nothing below reads the configuration's
+        # own fields.
+        fam = config.serving_family()
+        if fam.max_positions is not None and max_len > fam.max_positions:
             raise ValueError(
                 f"max_len {max_len} exceeds the learned position table "
-                f"(max_seq_len={config.max_seq_len})"
+                f"(max_seq_len={fam.max_positions})"
+            )
+        if fam.paged_only and (kv_layout != "paged" or sp_val > 1
+                               or spec_k is not None or kv_dtype != "fp32"):
+            raise ValueError(
+                f"{type(config).__name__} is served on the paged path at "
+                "its native K/V dtype alone: kv_layout='dense', sp > 1, "
+                "spec_k and kv_dtype are not implemented for this family"
             )
         from sparkdl_tpu.serving.metrics import default_host_id
 
         self.config = config
+        self._family = fam
         self.variables = variables
         #: stable host identity for the fabric's router tier (ISSUE 14):
         #: snapshot()/capacity are keyed by it, the prefix digest names
@@ -451,7 +458,7 @@ class ContinuousGPTEngine:
         #: controller (bounded evaluation stride, not per-tick)
         self._overload_next = 0.0
         self.metrics = metrics if metrics is not None else ServingMetrics()
-        self._model = GPTLMHeadModel(config)
+        self._model = fam.module
         self._len_buckets = default_buckets(max_len, min_bucket=8)
         self._inflight: dict[int, _InFlight] = {}
         self._prefilling: dict[int, _Prefill] = {}
@@ -563,12 +570,10 @@ class ContinuousGPTEngine:
             # leave with it.
             self._kv_stored = {name: a.format.layout
                                for name, a in self._pool_kv.items()}
-            n_layers = config.num_layers
-            nh = config.num_heads
-            hd = config.hidden_size // config.num_heads
-            max_pos = (config.max_seq_len - 1
-                       if config.positions == "learned" else wp + chunk)
-            cdt = config.dtype
+            n_layers, nh, hd = fam.layers, fam.kv_heads, fam.head_dim
+            max_pos = (fam.max_positions - 1
+                       if fam.max_positions is not None else wp + chunk)
+            cdt = fam.dtype
 
             # The dtype boundary, fused into every paged program: the
             # pool is the only compressed tensor — compute (attention,
@@ -576,9 +581,27 @@ class ContinuousGPTEngine:
             # int8 carries one fp32 scale per written column
             # (models.gpt.quantize_kv), riding the block structure in
             # pool["k_scale"]/["v_scale"].
+            def _blocks_together():
+                # is the pool OBSERVED to be stored with a block's bytes
+                # together (layers, then blocks, the major axes: 4 K/V
+                # heads of 128 on the v5e, any CPU)? GPT-2 XL's 25 heads of
+                # 64 are stored block-minor there. Read when a program is
+                # traced, as _kv_stored is.
+                return all(lay.major_to_minor[:2] == (0, 1)
+                           for lay in self._kv_stored.values())
+
             def _dq_gather(pool, name, ids):
-                # pool[name][:, ids] in storage dtype -> compute dtype
-                x = pool[name][:, ids]
+                # pool[name][:, ids] in storage dtype -> compute dtype.
+                # Where blocks lie together it is ONE gather over (layer,
+                # block): the sliced form makes the compiler copy such a
+                # pool to another layout first (1.4 GB of temporaries in
+                # a one-chunk prefill at 2.7 GB).
+                if _blocks_together():
+                    layers = jnp.arange(pool[name].shape[0])[:, None]
+                    x = pool[name][layers, jnp.minimum(
+                        ids, pool[name].shape[1] - 1)[None, :]]
+                else:
+                    x = pool[name][:, ids]
                 if kv_dtype == "int8":
                     return dequantize_kv(
                         x, pool[name + "_scale"][:, ids], cdt)
@@ -682,7 +705,14 @@ class ContinuousGPTEngine:
                     off = idx % bs_kv
                     pool = _q_scatter(pool, blk, off,
                                       new["k"][:, :, 0], new["v"][:, :, 0])
-                    return (pool, idx + 1, ntok), ntok
+                    out = ntok
+                    if "expert_counts" in new:
+                        # rows each expert got, behind the step's tokens:
+                        # ONE array, so one device-to-host read a tick
+                        out = jnp.concatenate(
+                            [ntok.astype(jnp.int32),
+                             new["expert_counts"].reshape(-1)])
+                    return (pool, idx + 1, ntok), out
 
                 (pool, _, _), toks = lax.scan(
                     body, (pool, idx, tok), None, length=k
@@ -762,14 +792,41 @@ class ContinuousGPTEngine:
                 cv = cv.at[:, :, :cols].set(cache["v"])
                 return logits, ck, cv
 
+            def _q_write_blocks(pool, ids, newk, newv):
+                # _q_write where blocks lie together (_blocks_together):
+                # whole blocks one at a time and in place, as _q_scatter
+                # writes columns. The scatter of _q_write makes the compiler
+                # re-lay such a pool out and back (two copies of each of K
+                # and V an install: 16 ms and 1.4 GB of temporaries at
+                # 2.7 GB). A sentinel id rewrites what is there.
+                vals = {}
+                for name, x in (("k", newk), ("v", newv)):
+                    vals.update(_stored_as(pool, name, x))
+                live = ids < kv_blocks
+                blk = jnp.minimum(ids, kv_blocks - 1)
+
+                def body(i, pool):
+                    out = dict(pool)
+                    for name, x in vals.items():
+                        new = lax.dynamic_slice_in_dim(x, i, 1, axis=1)
+                        at = (0, blk[i]) + (0,) * (new.ndim - 2)
+                        old = lax.dynamic_slice(pool[name], at, new.shape)
+                        out[name] = lax.dynamic_update_slice(
+                            pool[name], jnp.where(live[i], new, old), at)
+                    return out
+
+                return lax.fori_loop(0, ids.shape[0], body, pool)
+
             def _installed(pool, ck, cv, ids):
                 # private prefill cache -> the slot's OWNED pool blocks
-                # (quantize-on-install rides the shared _q_write path).
+                # (quantize-on-install rides the shared _stored_as rule).
                 # ids carries the sentinel at shared-prefix positions
                 # (their content already lives in the shared blocks) and
                 # past the covered span: those writes drop.
                 kv = ck[:, 0, :w].reshape(n_layers, mb, bs_kv, nh, hd)
                 vv = cv[:, 0, :w].reshape(n_layers, mb, bs_kv, nh, hd)
+                if _blocks_together():
+                    return _q_write_blocks(pool, ids, kv, vv)
                 return _q_write(pool, (ids,), kv, vv)
 
             # Four fused chunk programs so a prefill pays the minimum
@@ -2009,10 +2066,16 @@ class ContinuousGPTEngine:
         t0 = time.perf_counter()
         # width, cols and program name the compiled shape: an xla.compile
         # under this span says which one was first seen while serving
+        fam = self._family
+        # (token, expert) pairs an expert layer computes for this chunk,
+        # pad rows and all; which experts they hit stays on the device (a
+        # chunk's span ends at its dispatch, and no read waits for it)
+        experts = ({"expert_rows": wc * fam.experts_per_token}
+                   if fam.expert_layers else {})
         with span("serving.prefill_chunk", parent=st.req.trace_ctx,
                   request_id=st.req.request_id, slot=slot,
                   start=c0, tokens=r, first=first, final=final,
-                  width=wc, cols=cols, program=program):
+                  width=wc, cols=cols, program=program, **experts):
             if first and final:
                 logits, self._pool_kv = self._chunk_one_fn(
                     self.variables, self._pool_kv,
@@ -2248,7 +2311,32 @@ class ContinuousGPTEngine:
         read = self.n_slots * nb * self._kv_bs * steps
         live = steps * sum(depths) + len(depths) * steps * (steps - 1) // 2
         self.metrics.record_kv_read(read, live)
-        return {"kv_cols_read": read, "kv_cols_live": live}
+        out = {"kv_cols_read": read, "kv_cols_live": live}
+        fam = self._family
+        if fam.window_layers:
+            # by kind of layer, summed over the layers of the kind: a
+            # window layer gathers only the entries its window covers,
+            # and of a live row's context only the window is its to read
+            wb = fam.window_blocks(nb, self._kv_bs)
+            out["kv_cols_read_window"] = (read // nb) * wb * fam.window_layers
+            out["kv_cols_read_full"] = read * (fam.layers - fam.window_layers)
+            out["kv_cols_live_window"] = sum(
+                min(d + j, fam.window) for d in depths for j in range(steps))
+        return out
+
+    def _count_experts(self, counts: np.ndarray) -> "dict[str, Any]":
+        """Span arguments from the per-expert row counts of one dispatch
+        (``[steps, expert_layers * experts]``): (token, expert) pairs a
+        layer computed (``expert_rows``), experts with at least one row
+        (``experts_hit``), both a mean over expert layers and summed over
+        steps, and the fullest expert's rows (``expert_rows_max``)."""
+        fam = self._family
+        counts = counts.reshape(-1, fam.expert_layers, fam.experts)
+        rows, hit = int(counts.sum()), int((counts > 0).sum())
+        self.metrics.record_experts(rows, hit)
+        return {"expert_rows": rows / fam.expert_layers,
+                "experts_hit": hit / fam.expert_layers,
+                "expert_rows_max": int(counts.max())}
 
     def _decode_chain_len(self, now: float) -> int:
         """Tokens to fuse into the next plain decode dispatch: the
@@ -2428,7 +2516,7 @@ class ContinuousGPTEngine:
         links = ([f.req.request_id for f in self._inflight.values()]
                  if tracing.tracing_enabled() else ())
         with span("serving.decode_step", slots=len(self._inflight),
-                  chain=k, links=links, **shape, **cols):
+                  chain=k, links=links, **shape, **cols) as step:
             # Async token readback (runtime/completion.py): the D2H copy
             # of the token ids is enqueued the moment the decode dispatch
             # is — it rides behind the compute instead of waiting for the
@@ -2465,6 +2553,11 @@ class ContinuousGPTEngine:
             toks = np.asarray(fetch.result())
             if toks.ndim == 1:  # the unchained dense step: [S] -> [1, S]
                 toks = toks[None]
+            if self._family.expert_layers:
+                # an expert family's step: rows each expert got ride
+                # behind the tokens in the same read
+                step.set_attr(**self._count_experts(toks[:, self.n_slots:]))
+                toks = toks[:, :self.n_slots]
         wall = time.perf_counter() - t0
         record_dispatch("decode", k, wall)
         self._chain_policy.record(wall, k)

@@ -96,13 +96,13 @@ def kv_bytes_per_token(config, dtype: str = "fp32") -> int:
     if dtype not in KV_DTYPES:
         raise ValueError(
             f"unknown KV dtype {dtype!r} (one of {KV_DTYPES})")
-    item = (np.dtype(config.dtype).itemsize if dtype == "fp32"
+    fam = config.serving_family()
+    item = (np.dtype(fam.dtype).itemsize if dtype == "fp32"
             else _KV_ITEMSIZE[dtype])
-    hd = config.hidden_size // config.num_heads
-    per_layer = 2 * config.num_heads * hd * item
+    per_layer = 2 * fam.kv_heads * fam.head_dim * item
     if dtype == "int8":
         per_layer += 2 * 4  # k_scale + v_scale, fp32, one per column
-    return config.num_layers * per_layer
+    return fam.layers * per_layer
 
 
 def kv_capacity_ratio(config, dtype: str) -> float:

@@ -48,6 +48,14 @@ _M_KV_COLS_LIVE = registry().counter(
     "sparkdl_serving_kv_cols_live_total",
     "of those columns, the ones that held a live row's context (the sum "
     "of the live rows' depths); the rest is bucket padding and idle slots")
+_M_EXPERT_ROWS = registry().counter(
+    "sparkdl_moe_expert_rows_total",
+    "(token, expert) pairs the held experts of a dispatch's expert layers "
+    "were given, summed over those layers: rows x experts a token")
+_M_EXPERTS_HIT = registry().counter(
+    "sparkdl_moe_experts_hit_total",
+    "held experts that were given at least one row, summed over a "
+    "dispatch's expert layers (their kernels are what it had to read)")
 
 
 def default_host_id() -> str:
@@ -117,6 +125,17 @@ class ServingMetrics:
         self.tokens = 0
         self.kv_cols_read = 0
         self.kv_cols_live = 0
+        self.expert_rows = 0
+        self.experts_hit = 0
+
+    def record_experts(self, rows: int, hit: int) -> None:
+        """One dispatch's expert layers computed ``rows`` (token, expert)
+        pairs on ``hit`` experts, both summed over those layers."""
+        with self._lock:
+            self.expert_rows += rows
+            self.experts_hit += hit
+        _M_EXPERT_ROWS.inc(rows)
+        _M_EXPERTS_HIT.inc(hit)
 
     def record_kv_read(self, read: int, live: int) -> None:
         """One paged decode dispatch gathered ``read`` K/V columns (per
@@ -174,6 +193,8 @@ class ServingMetrics:
                 "tokens": self.tokens,
                 "kv_cols_read": self.kv_cols_read,
                 "kv_cols_live": self.kv_cols_live,
+                "expert_rows": self.expert_rows,
+                "experts_hit": self.experts_hit,
                 "batch_occupancy_pct": self._occupancy.mean_step_time(),
                 "latency_s": self._latency.step_time_percentiles((50, 95, 99)),
                 "latency_mean_s": self._latency.mean_step_time(),

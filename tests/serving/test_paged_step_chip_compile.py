@@ -134,3 +134,100 @@ def test_no_view_over_all_layers_on_the_chip(compiled, which):
             or _made(text, (SLOTS * MAX_LEN // bs, bs, nh, hd)))
     stats = compiled[which].memory_analysis()
     assert stats.alias_size_in_bytes == 2 * pool.nbytes
+
+
+# -- the afmoe family at its published widths (ISSUE 29) ----------------------------
+
+@pytest.fixture(scope="module")
+def compiled_afmoe(one_chip, monkeypatch_module):
+    """The decode step, a ONE-chunk prefill (it gathers a cached prefix
+    out of the pool and installs into it) and a FINAL chunk of a 4 x 8192
+    engine over
+    two layers (one dense sliding, one full expert layer) at Trinity-Mini's
+    widths, compiled for the chip: 4 KV heads of 128 are a whole lane tile,
+    so the chip keeps the pool ROW-major (a block's bytes together), unlike
+    GPT-2 XL's 25 heads of 64 above."""
+    from sparkdl_tpu.models.afmoe import (
+        FULL,
+        SLIDING,
+        AfmoeConfig,
+        AfmoeLMHeadModel,
+    )
+    from sparkdl_tpu.parallel import moe_dropless
+
+    # the grouped product the CHIP runs (this process's backend is the CPU)
+    monkeypatch_module.setattr(moe_dropless, "auto_interpret", lambda: False)
+    cfg = AfmoeConfig(vocab_size=512, num_dense_layers=1,
+                      layer_types=(SLIDING, FULL), dtype=jnp.bfloat16)
+    variables = jax.eval_shape(
+        lambda: AfmoeLMHeadModel(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    slots, max_len = 4, 8192
+    # a pool of 16,384 blocks, as the benchmark's: a small one the compiler
+    # would move to faster memory and back
+    eng = ContinuousGPTEngine(cfg, variables, n_slots=slots, max_len=max_len,
+                              kv_blocks=16384, auto_start=False)
+    try:
+        pool = eng._pool_kv
+        eng._kv_stored = {name: _device_layout(one_chip, a)
+                          for name, a in pool.items()}
+
+        def ints(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+        mb = max_len // 16
+        private = jax.ShapeDtypeStruct(
+            (2, 1, eng._wp, 4, 128), jnp.bfloat16, sharding=one_chip)
+        return {
+            "pool": pool["k"], "stored": eng._kv_stored["k"],
+            "step": eng._paged_step_fn.lower(
+                _on(one_chip, variables), _on(one_chip, pool),
+                ints(slots, mb), ints(slots), ints(slots), 1, mb).compile(),
+            "one": eng._chunk_one_fn.lower(
+                _on(one_chip, variables), _on(one_chip, pool), ints(mb),
+                ints(), ints(1, 128), ints(mb), 128).compile(),
+            "final": eng._chunk_final_fn.lower(
+                _on(one_chip, variables), _on(one_chip, pool), private,
+                private, ints(), ints(1, 256), ints(mb), max_len).compile(),
+        }
+    finally:
+        eng.close()
+
+
+@pytest.fixture(scope="module")
+def monkeypatch_module():
+    with pytest.MonkeyPatch.context() as mp:
+        yield mp
+
+
+@pytest.mark.parametrize("which", ["step", "one", "final"])
+def test_a_pool_of_whole_lane_tiles_is_row_major_and_written_in_place(
+        compiled_afmoe, which):
+    pool = compiled_afmoe["pool"]
+    assert compiled_afmoe["stored"].major_to_minor == (0, 1, 2, 3, 4)
+    text = compiled_afmoe[which].as_text()
+    made = _made(text, pool.shape)
+    ops = {op for op, _ in made}
+    assert "dynamic-update-slice" in ops
+    # the new columns (step) and a prompt's blocks (one, final) go in where
+    # the pool lies, and a cached prefix is gathered from where it lies: no
+    # copy of the pool, no scatter over it, one layout throughout
+    assert not ops & {"copy", "copy-start", "copy-done", "scatter",
+                      "transpose"}, sorted(ops)
+    assert {order for _, order in made} == {(4, 3, 2, 1, 0)}
+    # no layer's slab is sliced out of the pool before its gather
+    assert _made(text, (1,) + pool.shape[1:]) == []
+    assert compiled_afmoe[which].memory_analysis().alias_size_in_bytes == (
+        2 * pool.nbytes)
+
+
+def test_the_expert_products_are_the_grouped_matmul_kernel(compiled_afmoe):
+    text = compiled_afmoe["step"].as_text()
+    # three projections of the one expert layer, 4 slots x 8 experts a token
+    # padded to a row tile of 128
+    calls = re.findall(r"%gmm[.\d]* = bf16\[128,(\d+)\]", text)
+    assert sorted(calls) == ["1024", "1024", "2048"]
+    # a sliding layer gathers the table entries its window covers, 129 of
+    # the 512 a full layer gathers
+    assert _made(text, (4, 129, 16, 4, 128)) and _made(text,
+                                                       (4, 512, 16, 4, 128))
