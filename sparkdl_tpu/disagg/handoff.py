@@ -8,9 +8,11 @@ quantized pool's wire economics for free:
 
 * an ``int8`` pool ships ``int8`` values plus one fp32 scale per
   written column (``models.gpt.quantize_kv``'s layout) — per token that
-  is ``2·hidden + 8`` bytes against fp32's ``8·hidden``, a
-  ``4/(1 + 4/hidden)``× reduction (3.56× at hidden=32, →4× as hidden
-  grows);
+  is ``2·width + 8`` bytes against fp32's ``8·width``, a
+  ``4/(1 + 4/width)``× reduction (``width`` the pool's stored values a
+  token, ``ServingFamily.kv_tail``: a GPT's hidden size on one merged
+  axis, padded to whole lane tiles; 3.88× at hidden=32 stored as 128,
+  →4× as it grows);
 * the decode-side install dequantizes (``q·s``, exact) and rides the
   engine's shared ``_q_write`` path, whose requantize is the exact
   round trip ``quantize_kv`` documents (absmax maps to ±127) — so a
@@ -118,7 +120,7 @@ def _dec(d: dict) -> np.ndarray:
 class KVHandoff:
     """One finished prefill, packaged for the tier crossing (see module
     docstring). ``k``/``v`` are the prompt's blocks in RAW pool storage
-    ``[num_layers, n_blocks, block_size, heads, head_dim]`` (int8/bf16/
+    ``[num_layers, n_blocks, block_size, *kv_tail]`` (int8/bf16/
     fp32 per ``kv_dtype``); ``k_scale``/``v_scale`` are the int8
     layout's per-column fp32 scales ``[num_layers, n_blocks,
     block_size]`` (None otherwise). ``first_token`` seeds decode — the

@@ -77,21 +77,11 @@ class PrefillWorker(ContinuousGPTEngine):
         _require_paged(kwargs, "PrefillWorker")
         auto_start = kwargs.pop("auto_start", True)
         super().__init__(config, variables, auto_start=False, **kwargs)
-        import jax
-
-        @jax.jit
-        def _export(pool, ids):
-            # raw-storage gather: NO dequantize — the wire ships the
-            # pool's own bytes (int8 + scales, or fp32/bf16 values), so
-            # the decode-side install's requantize round-trips exactly
-            k = pool["k"][:, ids]
-            v = pool["v"][:, ids]
-            if "k_scale" in pool:
-                return (k, v, pool["k_scale"][:, ids],
-                        pool["v_scale"][:, ids])
-            return (k, v)
-
-        self._export_fn = _export
+        # the export is the raw-storage gather a park makes: NO
+        # dequantize — the wire ships the pool's own bytes (int8 + scales,
+        # or fp32/bf16 values), so the decode-side install's requantize
+        # round-trips exactly
+        self._export_fn = self._park_fetch_fn
         self._handoffs = 0
         self._export_aborts = 0
         if auto_start:
@@ -136,7 +126,7 @@ class PrefillWorker(ContinuousGPTEngine):
             # np.asarray forces the gather to COMPLETE before the block
             # references drop below (releasing first would let an
             # eviction + realloc overwrite a block mid-copy)
-            out = [np.asarray(x)[:, :nbp] for x in out]
+            out = {name: np.asarray(x)[:, :nbp] for name, x in out.items()}
         _M_HANDOFF_SECONDS.observe(time.perf_counter() - t0)
         del self._prefilling[slot]
         self._prefix.release(blocks)
@@ -151,9 +141,8 @@ class PrefillWorker(ContinuousGPTEngine):
             prompt=st.prompt, max_new_tokens=st.max_new,
             first_token=int(first), kv_dtype=self.kv_dtype,
             block_size=self._kv_bs,
-            k=out[0], v=out[1],
-            k_scale=out[2] if len(out) == 4 else None,
-            v_scale=out[3] if len(out) == 4 else None,
+            k=out["k"], v=out["v"],
+            k_scale=out.get("k_scale"), v_scale=out.get("v_scale"),
             request_id=st.req.request_id, deadline=st.req.deadline,
             enqueued=st.req.enqueued, trace_ctx=st.req.trace_ctx,
             src_host=self.host_id,
@@ -217,7 +206,7 @@ class DecodeWorker(ContinuousGPTEngine):
             # and the sp handoff: quantized pools quantize HERE — the
             # exact requantize round trip (quantize_kv) that keeps a
             # transferred block bitwise-identical to a local prefill's
-            return _qw(pool, (inst,), kdata, vdata)
+            return _qw(pool, inst, kdata, vdata)
 
         self._install_fn = _install
         self._installs = 0
@@ -427,10 +416,12 @@ class DecodeWorker(ContinuousGPTEngine):
         k = np.asarray(h.k)
         v = np.asarray(h.v)
         if h.k_scale is not None:
+            # one scale a column, whatever trailing axes a column has
+            lead = np.shape(h.k_scale) + (1,) * (k.ndim - 3)
             k = (k.astype(np.float32)
-                 * np.asarray(h.k_scale, np.float32)[..., None, None])
+                 * np.asarray(h.k_scale, np.float32).reshape(lead))
             v = (v.astype(np.float32)
-                 * np.asarray(h.v_scale, np.float32)[..., None, None])
+                 * np.asarray(h.v_scale, np.float32).reshape(lead))
         else:
             k = k.astype(np.float32)
             v = v.astype(np.float32)

@@ -39,7 +39,12 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from sparkdl_tpu.models.family import ServingFamily, window_blocks
+from sparkdl_tpu.models.family import (
+    ServingFamily,
+    kv_per_head,
+    kv_stored,
+    window_blocks,
+)
 from sparkdl_tpu.models.gpt import apply_rope
 from sparkdl_tpu.parallel.moe_dropless import (
     dropless_experts,
@@ -199,7 +204,8 @@ def _paged_rows(pool: jax.Array, layer: int, sub: jax.Array,
     slab is sliced out of the pool first (a copy of a fifth of the pool a
     layer) and the pool keeps the layout it is stored in. A sentinel entry
     (``blocks``) clips to the layer's last block, whose columns the masks
-    hide."""
+    hide. (A pool of heads under a lane tile keeps them on one merged
+    axis, ``models/family.py``: the caller takes them apart.)"""
     x = pool[jnp.full_like(sub, layer), jnp.minimum(sub, pool.shape[1] - 1)]
     return x.astype(dtype).reshape(sub.shape[0], -1, *pool.shape[3:])
 
@@ -260,13 +266,18 @@ class AfmoeAttention(nn.Module):
             first = jnp.clip((idx + l - 1) // bs - (wb - 1), 0, nb - wb)
             sub = (table if wb == nb else jnp.take_along_axis(
                 table, first[:, None] + jnp.arange(wb)[None, :], axis=1))
-            ck = _paged_rows(cache["k"], self.layer_idx, sub, c.dtype)
-            cv = _paged_rows(cache["v"], self.layer_idx, sub, c.dtype)
+            ck = kv_per_head(
+                _paged_rows(cache["k"], self.layer_idx, sub, c.dtype), ng, hd)
+            cv = kv_per_head(
+                _paged_rows(cache["v"], self.layer_idx, sub, c.dtype), ng, hd)
             rows = jnp.arange(b)[:, None]
             cols = q_pos - (first * bs)[:, None]
-            new_entry = (k.astype(c.dtype), v.astype(c.dtype))
-            ck = ck.at[rows, cols].set(new_entry[0], mode="drop")
-            cv = cv.at[rows, cols].set(new_entry[1], mode="drop")
+            ck = ck.at[rows, cols].set(k.astype(c.dtype), mode="drop")
+            cv = cv.at[rows, cols].set(v.astype(c.dtype), mode="drop")
+            # the new columns go back as the pool stores a token
+            tail = cache["k"].shape[3:]
+            new_entry = (kv_stored(k.astype(c.dtype), tail),
+                         kv_stored(v.astype(c.dtype), tail))
             k_pos = (first * bs)[:, None] + jnp.arange(wb * bs)[None, :]
             ctx = _grouped_attention(q, ck, cv, visible(k_pos), c.dtype)
         else:
@@ -277,11 +288,18 @@ class AfmoeAttention(nn.Module):
                 raise ValueError(
                     "the afmoe family's dense cache takes a scalar idx; "
                     "per-slot decode is the paged cache's")
+            # in the cache's own trailing axes: the engine's private
+            # prefill cache keeps a token as its pool stores it
+            layer_k = cache["k"][self.layer_idx]
+            at = (0, idx) + (0,) * (layer_k.ndim - 2)
+            tail = layer_k.shape[2:]
             ck = jax.lax.dynamic_update_slice(
-                cache["k"][self.layer_idx], k.astype(c.dtype), (0, idx, 0, 0))
+                layer_k, kv_stored(k.astype(c.dtype), tail), at)
             cv = jax.lax.dynamic_update_slice(
-                cache["v"][self.layer_idx], v.astype(c.dtype), (0, idx, 0, 0))
+                cache["v"][self.layer_idx], kv_stored(v.astype(c.dtype), tail),
+                at)
             new_entry = (ck, cv)
+            ck, cv = kv_per_head(ck, ng, hd), kv_per_head(cv, ng, hd)
             width = ck.shape[1]
             kw = min(width, window + l) if sliding else width
             lo = jnp.clip(idx + l - kw, 0, width - kw)
@@ -365,8 +383,9 @@ class AfmoeLMHeadModel(nn.Module):
     a dense cache ``{"k", "v", "idx"}`` of ``init_afmoe_cache`` (scalar
     ``idx``; the updated cache back); a paged cache ``{"k", "v", "table",
     "idx"}`` over the engine's block pool (this call's new columns
-    ``[layers, S, L, kv_heads, head_dim]`` back). A cached call's cache
-    also holds ``expert_counts`` ``[expert_layers, experts_held]``.
+    ``[layers, S, L, *kv_tail]`` back, as the pool stores a token). A
+    cached call's cache also holds ``expert_counts`` ``[expert_layers,
+    experts_held]``.
     ``positions`` ([B, L]) override the rotary positions of this call's
     tokens only; masks always count from ``cache["idx"]``."""
 

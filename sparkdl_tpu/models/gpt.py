@@ -31,7 +31,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from sparkdl_tpu.models.family import ServingFamily
+from sparkdl_tpu.models.family import (
+    ServingFamily,
+    kv_per_head,
+    kv_stored,
+)
 from sparkdl_tpu.parallel.expert_parallel import MoEMlpBlock
 from sparkdl_tpu.parallel.ring_attention import ring_self_attention
 from sparkdl_tpu.parallel.tensor_parallel import (
@@ -158,10 +162,14 @@ def init_block_pool(config, n_blocks: int,
                     block_size: int, dtype: str = "fp32") -> dict:
     """Zeroed block-paged KV pool for continuous serving
     (``serving.kv_blocks``): k/v stacked over layers,
-    ``[num_layers, n_blocks, block_size, H, D]``, ``H`` the K/V heads and
-    ``D`` the head size of the configuration's family
-    (``config.serving_family()``: a GPT's query heads, fewer for a family
-    that shares K/V heads).
+    ``[num_layers, n_blocks, block_size, *kv_tail]``. The trailing axes are
+    the family's (``config.serving_family().kv_tail``): a head that fills
+    whole 128-lane tiles keeps ``(kv_heads, head_dim)``; a GPT's heads of
+    64 lie side by side on ONE axis of ``kv_heads * head_dim`` columns,
+    zero-padded to whole tiles (GPT-2 XL: 1600 -> 1664), so that the chip
+    keeps layers and blocks major and a block's ``block_size x 1664``
+    together (``{3,2,1,0:T(8,128)(2,1)}``; with ``[.., 25, 64]`` it put the
+    BLOCK axis in the lanes, PERF.md section 5).
 
     Unlike :func:`init_cache` (one dense row per batch slot, capacity
     ``batch x max_len`` whether or not tokens exist), the pool's
@@ -190,7 +198,7 @@ def init_block_pool(config, n_blocks: int,
       only the resident pool is compressed.
     """
     fam = config.serving_family()
-    shape = (fam.layers, n_blocks, block_size, fam.kv_heads, fam.head_dim)
+    shape = (fam.layers, n_blocks, block_size) + fam.kv_tail
     store = {"fp32": fam.dtype, "bf16": jnp.bfloat16,
              "int8": jnp.int8}.get(dtype)
     if store is None:
@@ -206,54 +214,126 @@ def init_block_pool(config, n_blocks: int,
     return pool
 
 
-def quantize_kv(x: jax.Array) -> "tuple[jax.Array, jax.Array]":
+def quantize_kv(x: jax.Array,
+                tail: int = 1) -> "tuple[jax.Array, jax.Array]":
     """Symmetric per-column int8 quantization of K/V columns.
 
-    ``x`` is ``[..., H, D]`` (any leading index shape); returns
-    ``(int8 values, fp32 scales[...])`` with one scale per column — the
+    ``x`` is ``[..., C]``, a token's K or V on the pool's one merged axis
+    (any leading index shape; ``tail`` trailing axes make a column where a
+    pool keeps more than one, ``[..., H, D]``); returns ``(int8 values,
+    fp32 scales[...])`` with one scale per column — the
     absmax maps to ±127, so requantize(dequantize(q, s)) == (q, s)
     exactly (the property that makes copy-on-write prefix sharing
     lossless under int8: a gathered-then-reinstalled block is
     bit-identical to its donor). Zero columns get a tiny floor scale
-    and quantize to zero.
+    and quantize to zero; the merged axis's zero pad stays zero.
     """
-    amax = jnp.max(jnp.abs(x), axis=(-2, -1))
+    axes = tuple(range(-tail, 0))
+    amax = jnp.max(jnp.abs(x), axis=axes)
     scale = (jnp.maximum(amax, 1e-30) / 127.0).astype(jnp.float32)
-    q = jnp.round(x.astype(jnp.float32) / scale[..., None, None])
+    q = jnp.round(x.astype(jnp.float32) / jnp.expand_dims(scale, axes))
     return jnp.clip(q, -127, 127).astype(jnp.int8), scale
 
 
 def dequantize_kv(q: jax.Array, scale: jax.Array,
                   dtype: Any = jnp.float32) -> jax.Array:
-    """Inverse of :func:`quantize_kv`: int8 ``[..., H, D]`` columns and
-    their per-column scales back to ``dtype``."""
-    return (q.astype(jnp.float32) * scale[..., None, None]).astype(dtype)
+    """Inverse of :func:`quantize_kv`: int8 ``[..., C]`` columns (or
+    ``[..., H, D]``: the axes the scales lack) and their per-column scales
+    back to ``dtype``."""
+    axes = tuple(range(scale.ndim - q.ndim, 0))
+    return (q.astype(jnp.float32)
+            * jnp.expand_dims(scale, axes)).astype(dtype)
 
 
 def _paged_layer_kv(cache: dict, layer: int,
                     dtype: Any) -> "tuple[jax.Array, jax.Array]":
-    """One layer's K and V of a PAGED cache as dense per-slot rows.
+    """One layer's K and V of a PAGED cache as per-slot rows, in the shape
+    they are stored in.
 
     A paged cache is ``{"k", "v"[, "k_scale", "v_scale"], "table",
-    "idx"}``: ``k``/``v`` the ``[layers, n_blocks, block_size, H, D]``
-    pool of :func:`init_block_pool` in its storage dtype, ``table`` the
-    ``[S, nb]`` live head of the block table, ``idx`` the ``[S]`` depths.
-    Gathers ``pool[layer][table]`` -> ``[S, nb*block_size, H, D]`` and
-    dequantizes that slice alone to ``dtype`` (the rule of
-    :func:`dequantize_kv`; a bf16 pool is cast). Entries past the pool
-    (the table's sentinel) clip to a block whose columns the causal mask
-    hides, exactly as the whole-pool gather did.
+    "idx"}``: ``k``/``v`` the ``[layers, n_blocks, block_size, C]`` pool of
+    :func:`init_block_pool` in its storage dtype (``C`` the merged axis of
+    heads x head size), ``table`` the ``[S, nb]`` live head of the block
+    table, ``idx`` the ``[S]`` depths. ONE gather over (layer, block),
+    ``pool[layer, table]`` -> ``[S, nb, block_size, C]``, read where the
+    pool lies (no layer's slab sliced out first), then ``[S, nb*block_size,
+    C]`` by merging major axes, which moves nothing; that slice alone is
+    dequantized to ``dtype`` (the rule of :func:`dequantize_kv`; a bf16
+    pool is cast). Entries past the pool (the table's sentinel) clip to a
+    block whose columns the causal mask hides. (A pool whose heads fill
+    whole lane tiles keeps ``[.., H, D]``; its rows are merged here.)
     """
     table = cache["table"]
+    at = (jnp.full_like(table, layer),
+          jnp.minimum(table, cache["k"].shape[1] - 1))
 
     def rows(name):
-        x = cache[name][layer][table]
+        x = cache[name][at]
         scale = cache.get(name + "_scale")
         x = (x.astype(dtype) if scale is None
-             else dequantize_kv(x, scale[layer][table], dtype))
-        return x.reshape(table.shape[0], -1, *x.shape[3:])
+             else dequantize_kv(x, scale[at], dtype))
+        return x.reshape(table.shape[0], table.shape[1] * x.shape[2], -1)
 
     return rows("k"), rows("v")
+
+
+def merged_axis_attention(q, k_old, v_old, k_new, v_new, idx,
+                          kv_mask=None):
+    """Attention of a FEW queries a row (one decode token, a verify span)
+    over K and V that keep their heads side by side on one axis, every row
+    at its own depth: the per-slot cached step of :class:`GPTAttention`.
+
+    ``q`` ``[S, L, H, D]``; ``k_old``/``v_old`` ``[S, W, C]``, the rows as
+    the cache holds them (``C >= H*D``, columns past ``H*D`` zero: the
+    pool's pad), of which row ``s`` sees the columns before ``idx[s]`` (and
+    inside ``kv_mask`` ``[S, W]``, where given); ``k_new``/``v_new``
+    ``[S, L, C]``, this call's own columns, of which query ``l`` sees
+    ``0..l``. Returns ``[S, L, H, D]``.
+
+    K is never reshaped to heads (on the chip ``[.., 25, 64]`` is a padded
+    copy of every row): the scores are ONE product over the merged axis
+    against the queries laid block-diagonally, ``Qbd[s, (l, h), h*D + d] =
+    q[s, l, h, d]`` and zero elsewhere, so the columns of other heads add
+    exact zeros; the weighted sum is ``p[s, (l, h), :] @ V[s]`` ->
+    ``[L*H, C]``, of which head ``h`` keeps its own ``D`` columns. That is
+    ``H`` times the useful products, on a step that is bound by bytes. The
+    new columns are not written into the gathered rows (a copy of every
+    row): their scores and values join the softmax and the sum beside the
+    old ones. Same precision as the einsum form: operands as given,
+    float32 scores and softmax, ``p`` cast to the operands' dtype before
+    the second product.
+    """
+    s, l, h, d = q.shape
+    c = k_old.shape[-1]
+    # own[h, c]: column c of the merged axis is head h's
+    own = jnp.arange(c)[None, :] // d == jnp.arange(h)[:, None]
+    qbd = jnp.where(own, kv_stored(q, (c,))[:, :, None], 0).reshape(
+        s, l * h, c)
+
+    def scores(k, mask):
+        x = jnp.einsum("snc,swc->snw", qbd, k,
+                       preferred_element_type=jnp.float32) / math.sqrt(d)
+        return jnp.where(mask, x.reshape(s, l, h, -1), _NEG_INF)
+
+    seen = jnp.arange(k_old.shape[1])[None, :] < idx[:, None]
+    if kv_mask is not None:
+        seen = seen & kv_mask
+    s_old = scores(k_old, seen[:, None, None, :])
+    s_new = scores(
+        k_new, (jnp.arange(l)[None, :] <= jnp.arange(l)[:, None])[
+            None, :, None, :])
+    top = jnp.maximum(s_old.max(-1), s_new.max(-1))[..., None]
+    e_old, e_new = jnp.exp(s_old - top), jnp.exp(s_new - top)
+    total = e_old.sum(-1, keepdims=True) + e_new.sum(-1, keepdims=True)
+
+    def weighted(e, v):
+        p = (e / total).astype(q.dtype).reshape(s, l * h, -1)
+        return jnp.einsum("snw,swc->snc", p, v,
+                          preferred_element_type=jnp.float32)
+
+    r = (weighted(e_old, v_old) + weighted(e_new, v_new)).reshape(s, l, h, c)
+    out = jnp.where(own, r, 0).sum(2)[..., :h * d]
+    return out.astype(q.dtype).reshape(s, l, h, d)
 
 
 class GPTAttention(nn.Module):
@@ -288,61 +368,84 @@ class GPTAttention(nn.Module):
             q = apply_rope(q, positions, c.rope_base)
             k = apply_rope(k, positions, c.rope_base)
 
-        if cache is not None:
-            # Write this call's keys/values at [idx, idx+L), then attend
-            # over the full buffer with a position mask — one code path for
-            # prefill (L>1) and decode (L=1), both jittable (idx is traced).
-            # Overflow past the buffer would silently clamp the write while
-            # the mask keeps advancing — catch it whenever idx is concrete
-            # (eager streaming drivers; generate() pre-validates its scan).
+        if cache is not None and per_slot:
+            # The per-slot step (continuous batching): every row at its own
+            # depth, few queries a row (L=1 is the classic decode step; L=k
+            # the speculative verify span and a chained step). ONE attention
+            # for both layouts (merged_axis_attention), over K and V with
+            # the heads side by side on one axis, so the paged engine and
+            # its dense oracle compare like with like: a paged cache's rows
+            # come through the block table as the pool stores them
+            # (_paged_layer_kv: never a dense all-layer view of the pool,
+            # never a reshape to heads); a dense cache's are its own
+            # buffer, heads merged. This call's columns join the softmax
+            # beside them, they are not written into the rows first.
             paged = "table" in cache
             if paged:
-                # paged cache: this layer's live blocks only, through
-                # the block table (_paged_layer_kv) — never a dense
-                # all-layer view of the pool
-                layer_k, layer_v = _paged_layer_kv(
-                    cache, self.layer_idx, c.dtype)
+                k_old, v_old = _paged_layer_kv(cache, self.layer_idx, c.dtype)
             else:
                 layer_k = cache["k"][self.layer_idx]
                 layer_v = cache["v"][self.layer_idx]
+                k_old = layer_k.reshape(b, -1, h)
+                v_old = layer_v.reshape(b, -1, h)
+            # the new columns on the rows' axis (a pool's zero pad included)
+            k_new = kv_stored(k.astype(c.dtype), k_old.shape[2:])
+            v_new = kv_stored(v.astype(c.dtype), v_old.shape[2:])
+            ctx = merged_axis_attention(q, k_old, v_old, k_new, v_new, idx,
+                                        kv_mask=attention_mask)
+            if paged:
+                # a paged cache hands back only this call's L new columns,
+                # as the pool stores them: the caller owns the pool and
+                # writes them at (block, offset) itself
+                tail = cache["k"].shape[3:]
+                new_entry = (k_new.reshape(b, l, *tail),
+                             v_new.reshape(b, l, *tail))
+            else:
+                # a dense cache hands back its updated layer: a true
+                # indexed scatter touching B x L columns at [idx[b],
+                # idx[b]+L), not a masked rewrite of the whole buffer.
+                # mode="drop" keeps the contract for rows whose columns lie
+                # past the buffer (idle/retired slots the serving engine
+                # has not reassigned yet): the write is dropped (never
+                # clamped onto column max_len-1) and the row stays
+                # garbage-but-finite — admission control owns capacity,
+                # not this kernel.
+                rows = jnp.arange(b)[:, None]
+                cols = idx[:, None] + jnp.arange(l)[None, :]
+                new_entry = (
+                    layer_k.at[rows, cols].set(k.astype(c.dtype),
+                                               mode="drop"),
+                    layer_v.at[rows, cols].set(v.astype(c.dtype),
+                                               mode="drop"))
+        elif cache is not None:
+            # Lockstep (scalar idx): write this call's keys/values at
+            # [idx, idx+L), then attend over the full buffer with a
+            # position mask — one code path for prefill (L>1) and decode
+            # (L=1), both jittable (idx is traced). Overflow past the
+            # buffer would silently clamp the write while the mask keeps
+            # advancing — catch it whenever idx is concrete (eager
+            # streaming drivers; generate() pre-validates its scan).
+            layer_k = cache["k"][self.layer_idx]
+            layer_v = cache["v"][self.layer_idx]
             max_len = layer_k.shape[1]
-            if (not per_slot and not isinstance(idx, jax.core.Tracer)
+            if (not isinstance(idx, jax.core.Tracer)
                     and int(idx) + l > max_len):
                 raise ValueError(
                     f"KV cache overflow: idx {int(idx)} + {l} new tokens > "
                     f"cache max_len {max_len}"
                 )
-            if per_slot:
-                # Per-row scatter at columns [idx[b], idx[b]+L) — a true
-                # indexed scatter touching B x L columns, not a masked
-                # rewrite of the whole buffer (L=1 is the classic decode
-                # step; L=k is the speculative verify span, every row at
-                # its own depth). mode="drop" keeps the contract for
-                # rows whose columns lie past the buffer (idle/retired
-                # slots the serving engine has not reassigned yet): the
-                # write is dropped (never clamped onto column max_len-1)
-                # and the row stays garbage-but-finite — admission
-                # control owns capacity, not this kernel.
-                rows = jnp.arange(b)[:, None]
-                cols = idx[:, None] + jnp.arange(l)[None, :]
-                ck = layer_k.at[rows, cols].set(
-                    k.astype(c.dtype), mode="drop")
-                cv = layer_v.at[rows, cols].set(
-                    v.astype(c.dtype), mode="drop")
-            else:
-                ck = jax.lax.dynamic_update_slice(
-                    layer_k, k.astype(c.dtype), (0, idx, 0, 0),
-                )
-                cv = jax.lax.dynamic_update_slice(
-                    layer_v, v.astype(c.dtype), (0, idx, 0, 0),
-                )
-            # a dense cache hands back its updated layer; a paged one
-            # only this call's L new columns — the caller owns the pool
-            # and writes them at (block, offset) itself
-            new_entry = ((k.astype(c.dtype), v.astype(c.dtype))
-                         if paged else (ck, cv))
-            if (c.attn_impl == "flash" and l == 1 and c.flash_decode
-                    and not per_slot):
+            # the cache's own trailing axes: heads and head size apart
+            # (init_cache), or side by side on one merged axis as the
+            # serving pool stores them (the engine's private prefill
+            # cache, whose rows then go into the pool as they lie)
+            at = (0, idx) + (0,) * (layer_k.ndim - 2)
+            ck = jax.lax.dynamic_update_slice(
+                layer_k, kv_stored(k.astype(c.dtype), layer_k.shape[2:]), at)
+            cv = jax.lax.dynamic_update_slice(
+                layer_v, kv_stored(v.astype(c.dtype), layer_v.shape[2:]), at)
+            new_entry = (ck, cv)
+            ck, cv = kv_per_head(ck, nh, hd), kv_per_head(cv, nh, hd)
+            if c.attn_impl == "flash" and l == 1 and c.flash_decode:
                 # opt-in single-query flash decode (see GPTConfig:
                 # dense wins at serving shapes; kernel kept for shapes
                 # where streaming the cache beats the score round-trip)
@@ -355,7 +458,7 @@ class GPTAttention(nn.Module):
                         attention_mask.astype(jnp.int32), axis=1
                     )
                 ctx = flash_decode(q, ck, cv, idx, start=start)
-            elif (c.attn_impl == "flash" and l > 1 and not per_slot
+            elif (c.attn_impl == "flash" and l > 1
                   and not isinstance(idx, jax.core.Tracer)):
                 # cached PREFILL with concrete idx (generate()'s eager
                 # prefill is always idx=0): flash over the WRITTEN prefix
@@ -373,12 +476,8 @@ class GPTAttention(nn.Module):
                     causal=True, q_offset=int(idx),
                 )
             else:
-                # prefill (L>1), non-flash decode, and every per-slot step:
-                # dense masked path. q_pos is [1, L] (lockstep) or [B, 1]
-                # (per-slot), so the causal mask is per-row exactly when
-                # rows sit at different depths.
-                max_len = ck.shape[1]
-                q_pos = jnp.reshape(idx, (-1, 1)) + jnp.arange(l)  # [1|B, L]
+                # prefill (L>1) and non-flash decode: dense masked path
+                q_pos = idx + jnp.arange(l)[None, :]  # [1, L]
                 k_pos = jnp.arange(max_len)  # [max_len]
                 mask = (k_pos[None, None, :] <= q_pos[:, :, None])[:, None]
                 if attention_mask is not None:
@@ -489,10 +588,10 @@ class GPTLMHeadModel(nn.Module):
     the building block :func:`generate` scans. A PER-SLOT cache
     (``init_cache(..., per_slot=True)``, ``idx`` [B]) decodes every row at
     its own depth with a per-row causal mask and per-row K/V scatter —
-    always the dense path; L=1 is the classic decode step and L=k scores
-    a whole speculative draft span in one pass — which is what lets
-    ``serving.continuous`` admit and retire rows mid-stream and verify
-    k drafted tokens per dispatch.
+    through :func:`merged_axis_attention`; L=1 is the classic decode step
+    and L=k scores a whole speculative draft span in one pass — which is
+    what lets ``serving.continuous`` admit and retire rows mid-stream and
+    verify k drafted tokens per dispatch.
 
     A PAGED cache (it holds a ``table`` entry: ``{"k", "v"[, "k_scale",
     "v_scale"], "table", "idx"}``, the :func:`init_block_pool` pool with
@@ -500,8 +599,9 @@ class GPTLMHeadModel(nn.Module):
     runs the same per-slot step, but every layer gathers only its own
     live blocks through the table (:func:`_paged_layer_kv`) and the
     returned ``k``/``v`` are THIS call's new columns,
-    ``[layers, S, L, H, D]`` at the compute dtype — the caller writes
-    them into its pool at (block, offset); the pool is never returned.
+    ``[layers, S, L, C]`` at the compute dtype with the heads on the
+    pool's one merged axis — the caller writes them into its pool at
+    (block, offset); the pool is never returned.
 
     ``positions``: optional [B, L] global token positions for RoPE.
     REQUIRED under ``attn_impl='ring'`` (sequence sharded on ``sp``): each

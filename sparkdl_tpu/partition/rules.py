@@ -178,7 +178,7 @@ GENERIC_RULES: "tuple[tuple[str, P], ...]" = (
 
 #: Sequence-axis placement for the paged KV BLOCK POOL (ISSUE 13):
 #: matched over an ``init_block_pool`` tree, the k/v pool arrays
-#: ``[layers, n_blocks, block_size, H, D]`` (and the int8 per-column
+#: ``[layers, n_blocks, block_size, *kv_tail]`` (and the int8 per-column
 #: scale arrays ``[layers, n_blocks, block_size]``) shard their BLOCK
 #: axis on ``sp`` — contiguous shards, so virtual block id ``b`` lives
 #: on chip ``b // (n_blocks/sp)`` (the mapping

@@ -111,7 +111,7 @@ from sparkdl_tpu.observability import tracing
 from sparkdl_tpu.observability.registry import registry
 from sparkdl_tpu.observability.tracing import span
 from sparkdl_tpu.reliability.faults import fault_point
-from sparkdl_tpu.runtime.chip import watch_compiles
+from sparkdl_tpu.runtime.chip import alike_layers_options, watch_compiles
 from sparkdl_tpu.runtime.completion import start_fetch
 from sparkdl_tpu.runtime.dispatch import (
     ChainPolicy,
@@ -479,8 +479,6 @@ class ContinuousGPTEngine:
         model = self._model
 
         if kv_layout == "paged":
-            from jax.experimental.layout import with_layout_constraint
-
             from sparkdl_tpu.models.gpt import dequantize_kv, quantize_kv
             from sparkdl_tpu.serving.kv_blocks import KVBlockPool
             from sparkdl_tpu.serving.prefix_cache import PrefixCache
@@ -560,17 +558,16 @@ class ContinuousGPTEngine:
             self._table = np.full((n_slots, mb), self._pool.sentinel,
                                   np.int32)
             self._pidx = np.zeros((n_slots,), np.int32)
-            # the layout the device keeps each pool array in (on the
-            # v5e the BLOCK axis is the minor one, not the head
-            # dimension): the decode programs' column writes are held to
-            # it, so the pool is updated where it lies. Read when a
-            # decode program is traced. TO GO with ROADMAP A8: a pool
-            # stored block-major is written in place by a plain
-            # scatter, and this, the pin and the loop in _q_scatter
-            # leave with it.
-            self._kv_stored = {name: a.format.layout
-                               for name, a in self._pool_kv.items()}
+            # A token's K or V in the pool is shaped as the family stores
+            # it (ServingFamily.kv_tail): ONE merged axis of heads x head
+            # size where a head is no whole lane tile (GPT-2's 25 x 64,
+            # padded to 1664), heads and head size apart where it is
+            # (afmoe's 4 x 128). Either way the chip keeps layers and
+            # blocks major and a block's bytes together, so every pool
+            # program below indexes (layer, block, offset) and carries the
+            # trailing axes as it finds them.
             n_layers, nh, hd = fam.layers, fam.kv_heads, fam.head_dim
+            tail = fam.kv_tail
             max_pos = (fam.max_positions - 1
                        if fam.max_positions is not None else wp + chunk)
             cdt = fam.dtype
@@ -581,75 +578,99 @@ class ContinuousGPTEngine:
             # int8 carries one fp32 scale per written column
             # (models.gpt.quantize_kv), riding the block structure in
             # pool["k_scale"]/["v_scale"].
-            def _blocks_together():
-                # is the pool OBSERVED to be stored with a block's bytes
-                # together (layers, then blocks, the major axes: 4 K/V
-                # heads of 128 on the v5e, any CPU)? GPT-2 XL's 25 heads of
-                # 64 are stored block-minor there. Read when a program is
-                # traced, as _kv_stored is.
-                return all(lay.major_to_minor[:2] == (0, 1)
-                           for lay in self._kv_stored.values())
+            def _raw_gather(pool, ids):
+                # every array's blocks ``ids`` in storage dtype,
+                # ``[layers, len(ids), block, ...]``: ONE gather over
+                # (layer, block), read where the pool lies (sliced by
+                # layer first, the compiler copies the pool to another
+                # layout: 1.4 GB of temporaries in a one-chunk prefill at
+                # 2.7 GB). A sentinel id clips to the last block.
+                at = (jnp.arange(n_layers)[:, None],
+                      jnp.minimum(ids, kv_blocks - 1)[None, :])
+                return {name: a[at] for name, a in pool.items()}
 
-            def _dq_gather(pool, name, ids):
-                # pool[name][:, ids] in storage dtype -> compute dtype.
-                # Where blocks lie together it is ONE gather over (layer,
-                # block): the sliced form makes the compiler copy such a
-                # pool to another layout first (1.4 GB of temporaries in
-                # a one-chunk prefill at 2.7 GB).
-                if _blocks_together():
-                    layers = jnp.arange(pool[name].shape[0])[:, None]
-                    x = pool[name][layers, jnp.minimum(
-                        ids, pool[name].shape[1] - 1)[None, :]]
-                else:
-                    x = pool[name][:, ids]
+            def _dq_gather(pool, ids):
+                # blocks ``ids`` of K and V -> compute dtype
+                raw = _raw_gather(pool, ids)
                 if kv_dtype == "int8":
-                    return dequantize_kv(
-                        x, pool[name + "_scale"][:, ids], cdt)
-                return x if kv_dtype == "fp32" else x.astype(cdt)
+                    return tuple(
+                        dequantize_kv(raw[name], raw[name + "_scale"], cdt)
+                        for name in ("k", "v"))
+                return raw["k"].astype(cdt), raw["v"].astype(cdt)
 
             def _stored_as(pool, name, vals):
                 # THE quantize-on-write rule, the one every pool write
                 # applies (so column writes and installs can never
-                # desynchronize): what K/V values become in the pool,
-                # by array name. int8 stores values + their per-column
-                # scales; bf16/fp32 a cast.
+                # desynchronize): what K/V values (compute dtype, the
+                # pool's trailing axes) become in the pool, by array
+                # name. int8 stores values + their per-column scales;
+                # bf16/fp32 a cast.
                 if kv_dtype == "int8":
-                    q, s = quantize_kv(vals)
+                    q, s = quantize_kv(vals, len(tail))
                     return {name: q, name + "_scale": s}
                 return {name: vals.astype(pool[name].dtype)}
 
-            def _q_write(pool, where, newk, newv):
-                # whole blocks into the pool (the prefill install, the
-                # sp and disagg handoffs): ``where`` is the advanced
-                # index after the layer axis, (ids,). Sentinel entries
-                # drop — no block corrupted.
-                ix = (slice(None),) + where
-                out = dict(pool)
-                for name, vals in (("k", newk), ("v", newv)):
-                    for key, x in _stored_as(pool, name, vals).items():
-                        out[key] = pool[key].at[ix].set(x, mode="drop")
-                return out
+            def _write_blocks(pool, ids, vals):
+                # whole blocks ``vals`` (by array name, storage dtype,
+                # ``[layers, len(ids), block, ...]``) into the DONATED
+                # pool, one at a time and in place. As ONE scatter the
+                # compiler re-lays the pool out and back (two copies of
+                # each of K and V an install: 16 ms and 1.4 GB of
+                # temporaries at 2.7 GB). A sentinel id rewrites what is
+                # there — no block corrupted.
+                live = ids < kv_blocks
+                blk = jnp.minimum(ids, kv_blocks - 1)
+
+                def body(i, pool):
+                    out = dict(pool)
+                    for name, x in vals.items():
+                        new = lax.dynamic_slice_in_dim(x, i, 1, axis=1)
+                        at = (0, blk[i]) + (0,) * (new.ndim - 2)
+                        old = lax.dynamic_slice(pool[name], at, new.shape)
+                        out[name] = lax.dynamic_update_slice(
+                            pool[name], jnp.where(live[i], new, old), at)
+                    return out
+
+                return lax.fori_loop(0, ids.shape[0], body, pool)
+
+            def _q_write(pool, ids, newk, newv):
+                # whole blocks of K and V at the compute dtype into the
+                # pool (the prefill install, the sp and disagg handoffs)
+                return _write_blocks(pool, ids, {
+                    **_stored_as(pool, "k", newk),
+                    **_stored_as(pool, "v", newv)})
 
             def _q_scatter(pool, blk, off, newk, newv):
-                # freshly written columns ([layers, *blk.shape, H, D];
+                # freshly written columns ([layers, *blk.shape, *tail];
                 # blk/off share any index shape: [S] decode, [S,k]
-                # verify) into the DONATED pool, one column at a time
-                # and in place: a loop of dynamic-update-slices that
-                # carries the pool, each HELD to the layout the pool is
-                # stored in. Left to itself the compiler re-lays the
-                # whole pool out to suit the update and back again (for
-                # ``.at[:, blk, off].set`` and for this loop alike: four
-                # passes over the pool a tick on the v5e, PERF.md
-                # section 5). A sentinel block rewrites what is there
-                # — no block corrupted. TO GO with ROADMAP A8 (the
-                # block-major pool takes ``.at[:, blk, off].set`` in
-                # 0.3 ms, PERF.md section 6): the loop, the pin and
-                # ``_kv_stored`` are a workaround for the stored layout.
+                # verify) into the DONATED pool, in place. Sentinel
+                # blocks write nothing — no block corrupted.
+                cols = {**_stored_as(pool, "k", newk),
+                        **_stored_as(pool, "v", newv)}
+                if len(tail) == 1:
+                    # the merged axis: ONE scatter a pool array, indexed
+                    # by (layer, block, offset) with a column of ``tail``
+                    # the window. (With the layer axis left a slice,
+                    # ``.at[:, blk, off]``, the window spans the layers
+                    # and the chip's compiler re-lays the whole pool out
+                    # with the layers in the sublanes and back: seen in
+                    # the compiled text, PERF.md section 6.)
+                    at = (jnp.arange(n_layers).reshape(
+                        (-1,) + (1,) * blk.ndim), blk[None], off[None])
+                    return {**pool, **{
+                        name: pool[name].at[at].set(vals, mode="drop")
+                        for name, vals in cols.items()}}
+                # a pool that keeps heads and head size apart (a head
+                # fills a lane tile) is written as PR 29 measured it: its
+                # columns go in one at a time, a loop of
+                # dynamic-update-slices that carries the pool (the sliced
+                # scatter copied that whole pool; the indexed one above
+                # compiles in place for it too but has not been measured
+                # on its cell, ROADMAP C1)
+                cols = {name: vals.reshape(
+                            (n_layers, -1) + vals.shape[1 + blk.ndim:])
+                        for name, vals in cols.items()}
                 blk, off = blk.reshape(-1), off.reshape(-1)
-                cols = {}
-                for name, vals in (("k", newk), ("v", newv)):
-                    cols.update(_stored_as(
-                        pool, name, vals.reshape(n_layers, -1, nh, hd)))
                 live = blk < kv_blocks
                 blk = jnp.minimum(blk, kv_blocks - 1)
 
@@ -660,11 +681,8 @@ class ContinuousGPTEngine:
                             vals, c, 1, axis=1)[:, :, None]
                         at = (0, blk[c], off[c]) + (0,) * (col.ndim - 3)
                         old = lax.dynamic_slice(pool[name], at, col.shape)
-                        out[name] = with_layout_constraint(
-                            lax.dynamic_update_slice(
-                                pool[name], jnp.where(live[c], col, old),
-                                at),
-                            self._kv_stored[name])
+                        out[name] = lax.dynamic_update_slice(
+                            pool[name], jnp.where(live[c], col, old), at)
                     return out
 
                 return lax.fori_loop(0, blk.shape[0], body, pool)
@@ -758,12 +776,10 @@ class ContinuousGPTEngine:
                 # an exact round trip (quantize_kv absmax maps to ±127),
                 # so a COW-shared block re-installs bit-identical to its
                 # donor.
-                kx = _dq_gather(pool, "k", ids).reshape(
-                    n_layers, 1, w, nh, hd)
-                vx = _dq_gather(pool, "v", ids).reshape(
-                    n_layers, 1, w, nh, hd)
-                pad = ((0, 0), (0, 0), (0, wp - w), (0, 0), (0, 0))
-                return jnp.pad(kx, pad), jnp.pad(vx, pad)
+                pad = ((0, 0), (0, 0), (0, wp - w)) + ((0, 0),) * len(tail)
+                return tuple(
+                    jnp.pad(x.reshape((n_layers, 1, w) + tail), pad)
+                    for x in _dq_gather(pool, ids))
 
             def _chunk_apply(variables, ck, cv, idx, ids, cols):
                 # one bounded prefill chunk, right-aligned: writes K/V
@@ -792,42 +808,15 @@ class ContinuousGPTEngine:
                 cv = cv.at[:, :, :cols].set(cache["v"])
                 return logits, ck, cv
 
-            def _q_write_blocks(pool, ids, newk, newv):
-                # _q_write where blocks lie together (_blocks_together):
-                # whole blocks one at a time and in place, as _q_scatter
-                # writes columns. The scatter of _q_write makes the compiler
-                # re-lay such a pool out and back (two copies of each of K
-                # and V an install: 16 ms and 1.4 GB of temporaries at
-                # 2.7 GB). A sentinel id rewrites what is there.
-                vals = {}
-                for name, x in (("k", newk), ("v", newv)):
-                    vals.update(_stored_as(pool, name, x))
-                live = ids < kv_blocks
-                blk = jnp.minimum(ids, kv_blocks - 1)
-
-                def body(i, pool):
-                    out = dict(pool)
-                    for name, x in vals.items():
-                        new = lax.dynamic_slice_in_dim(x, i, 1, axis=1)
-                        at = (0, blk[i]) + (0,) * (new.ndim - 2)
-                        old = lax.dynamic_slice(pool[name], at, new.shape)
-                        out[name] = lax.dynamic_update_slice(
-                            pool[name], jnp.where(live[i], new, old), at)
-                    return out
-
-                return lax.fori_loop(0, ids.shape[0], body, pool)
-
             def _installed(pool, ck, cv, ids):
                 # private prefill cache -> the slot's OWNED pool blocks
                 # (quantize-on-install rides the shared _stored_as rule).
                 # ids carries the sentinel at shared-prefix positions
                 # (their content already lives in the shared blocks) and
                 # past the covered span: those writes drop.
-                kv = ck[:, 0, :w].reshape(n_layers, mb, bs_kv, nh, hd)
-                vv = cv[:, 0, :w].reshape(n_layers, mb, bs_kv, nh, hd)
-                if _blocks_together():
-                    return _q_write_blocks(pool, ids, kv, vv)
-                return _q_write(pool, (ids,), kv, vv)
+                shape = (n_layers, mb, bs_kv) + tail
+                return _q_write(pool, ids, ck[:, 0, :w].reshape(shape),
+                                cv[:, 0, :w].reshape(shape))
 
             # Four fused chunk programs so a prefill pays the minimum
             # dispatch count (dispatch gap dominates small programs —
@@ -835,7 +824,13 @@ class ContinuousGPTEngine:
             # fuses the prefix gather, the FINAL chunk fuses the block
             # install, so a suffix that fits one chunk is ONE device
             # dispatch end to end (vs dense's prefill + scatter pair).
-            @functools.partial(jax.jit, donate_argnums=(1,),
+            # A chunk unrolls every layer: on the chip the layers share
+            # their code (alike_layers_options), or a program that
+            # installs is twenty times the size and loads as slowly.
+            chunk_jit = functools.partial(
+                jax.jit, compiler_options=alike_layers_options())
+
+            @functools.partial(chunk_jit, donate_argnums=(1,),
                                static_argnums=(6,))
             def _chunk_one(variables, pool, gids, idx, ids, inst, cols):
                 ck, cv = _gathered(pool, gids)
@@ -843,12 +838,12 @@ class ContinuousGPTEngine:
                     variables, ck, cv, idx, ids, cols)
                 return logits, _installed(pool, ck, cv, inst)
 
-            @functools.partial(jax.jit, static_argnums=(5,))
+            @functools.partial(chunk_jit, static_argnums=(5,))
             def _chunk_first(variables, pool, gids, idx, ids, cols):
                 ck, cv = _gathered(pool, gids)
                 return _chunk_apply(variables, ck, cv, idx, ids, cols)
 
-            @functools.partial(jax.jit, donate_argnums=(1, 2),
+            @functools.partial(chunk_jit, donate_argnums=(1, 2),
                                static_argnums=(5,))
             def _chunk_mid(variables, ck, cv, idx, ids, cols):
                 return _chunk_apply(variables, ck, cv, idx, ids, cols)
@@ -858,7 +853,7 @@ class ContinuousGPTEngine:
             # — jax would warn "donated buffers were not usable" on
             # every compile and free nothing earlier; they die on the
             # host right after the call regardless)
-            @functools.partial(jax.jit, donate_argnums=(1,),
+            @functools.partial(chunk_jit, donate_argnums=(1,),
                                static_argnums=(7,))
             def _chunk_final(variables, pool, ck, cv, idx, ids, inst,
                              cols):
@@ -874,22 +869,17 @@ class ContinuousGPTEngine:
                 # the quantized layout bought and what makes a resumed
                 # session bitwise-identical: unpark writes back the
                 # exact bytes decode would have read
-                out = {"k": pool["k"][:, ids], "v": pool["v"][:, ids]}
-                if kv_dtype == "int8":
-                    out["k_scale"] = pool["k_scale"][:, ids]
-                    out["v_scale"] = pool["v_scale"][:, ids]
-                return out
+                return _raw_gather(pool, ids)
 
             @functools.partial(jax.jit, donate_argnums=(0,))
             def _unpark_install(pool, ids, payload):
                 # the H2D half of a resume: whole-block raw writes
-                # into freshly allocated blocks (sentinel ids drop —
-                # same contract as every other pool write)
-                out = dict(pool)
-                for name, vals in payload.items():
-                    out[name] = pool[name].at[:, ids].set(
-                        vals.astype(pool[name].dtype), mode="drop")
-                return out
+                # into freshly allocated blocks, in place (sentinel ids
+                # write nothing — same contract as every other pool
+                # write)
+                return _write_blocks(pool, ids, {
+                    name: vals.astype(pool[name].dtype)
+                    for name, vals in payload.items()})
 
             self._paged_step_fn = _paged_step
             self._paged_verify_fn = _paged_verify
@@ -1055,10 +1045,11 @@ class ContinuousGPTEngine:
         config = self.config
         model = self._model
         bs_kv = self._kv_bs
-        n_layers, nh = config.num_layers, config.num_heads
-        hd = config.hidden_size // nh
-        max_pos = (config.max_seq_len - 1
-                   if config.positions == "learned"
+        fam = self._family
+        n_layers, nh, hd = fam.layers, fam.kv_heads, fam.head_dim
+        tail = fam.kv_tail
+        max_pos = (fam.max_positions - 1
+                   if fam.max_positions is not None
                    else self._wp + self.prefill_chunk)
         mesh = make_mesh(dp=1, sp=sp, devices=devs[:sp])
         self._sp_mesh = mesh
@@ -1087,8 +1078,8 @@ class ContinuousGPTEngine:
         # host-side arithmetic for sparkdl_sp_permute_bytes_total: each
         # chip contributes its K/V chunk shard to sp-1 peers
         self._sp_bytes_per_col = (
-            2 * n_layers * config.hidden_size
-            * np.dtype(config.dtype).itemsize * (sp - 1))
+            2 * n_layers * nh * hd
+            * np.dtype(fam.dtype).itemsize * (sp - 1))
 
         @functools.partial(
             jax.jit, donate_argnums=(1,), static_argnums=(7,),
@@ -1106,9 +1097,9 @@ class ContinuousGPTEngine:
             # targets drop: pad columns never land).
             wc = ids.shape[1]
             kbuf = sppool["k"][:, head].reshape(
-                n_layers, 1, nbh * bs_kv, nh, hd)
+                (n_layers, 1, nbh * bs_kv) + tail)
             vbuf = sppool["v"][:, head].reshape(
-                n_layers, 1, nbh * bs_kv, nh, hd)
+                (n_layers, 1, nbh * bs_kv) + tail)
             positions = jnp.minimum(
                 idx + jnp.arange(wc)[None, :], max_pos)
             cache = {"k": kbuf, "v": vbuf, "idx": idx}
@@ -1155,7 +1146,7 @@ class ContinuousGPTEngine:
             # cached prefix blocks out of the DECODE pool, dequantized
             # to the compute dtype (the same values the single-device
             # first chunk gathers into its private cache)
-            return _dq(pool, "k", gids), _dq(pool, "v", gids)
+            return _dq(pool, gids)
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def _sp_install(pool, kdata, vdata, inst):
@@ -1163,7 +1154,7 @@ class ContinuousGPTEngine:
             # the same _q_write path as the fused single-device install
             # (sentinels at shared-prefix positions drop; quantized
             # pools quantize HERE, once)
-            return _qw(pool, (inst,), kdata, vdata)
+            return _qw(pool, inst, kdata, vdata)
 
         self._sp_chunk_fn = _sp_chunk
         self._sp_seed_fn = _sp_seed

@@ -4,10 +4,11 @@ The dense continuous engine holds one ``[layers, n_slots, max_len, H, D]``
 cache, so its memory contract is ``n_slots x max_len`` worst-case columns
 whether or not tokens exist. This module is the host-side half of the
 paged layout (ROADMAP item 4, the vLLM idea): the device holds one
-``[layers, n_blocks, block_size, H, D]`` pool
-(:func:`~sparkdl_tpu.models.gpt.init_block_pool`), each serving slot maps
-its logical columns onto pool blocks through a per-slot block table, and
-THIS class owns the free list and refcounts — so
+``[layers, n_blocks, block_size, *kv_tail]`` pool
+(:func:`~sparkdl_tpu.models.gpt.init_block_pool`; the trailing axes are
+the family's, ``models/family.py``: GPT's heads on ONE merged axis), each
+serving slot maps its logical columns onto pool blocks through a per-slot
+block table, and THIS class owns the free list and refcounts — so
 
 * capacity is bounded by live tokens (``blocks_used x block_size``), not
   by ``n_slots x max_len``;
@@ -84,13 +85,17 @@ _KV_ITEMSIZE = {"bf16": 2, "int8": 1}
 
 def kv_bytes_per_token(config, dtype: str = "fp32") -> int:
     """Resident pool bytes one cached token costs under ``dtype``:
-    K + V columns across every layer, plus (int8) the two per-column
-    fp32 scales. Pure arithmetic — the number benches assert capacity
-    ratios with and operators size pools by. The ``"fp32"`` layout
-    stores at the MODEL's compute dtype (``config.dtype``, usually
+    K + V columns across every layer as the pool stores them (a merged
+    axis of heads under a lane tile is padded to whole tiles,
+    ``ServingFamily.kv_tail``: GPT-2 XL's 1600 values take 1664), plus
+    (int8) the two per-column fp32 scales. Pure arithmetic — the number
+    benches assert capacity ratios with and operators size pools by. The
+    ``"fp32"`` layout stores at the MODEL's compute dtype (``config.dtype``, usually
     float32), so a bf16-compute model honestly reports the native
     layout at 2 bytes/element — and near-zero gain from the "bf16"
     layout."""
+    import math
+
     import numpy as np
 
     if dtype not in KV_DTYPES:
@@ -99,7 +104,7 @@ def kv_bytes_per_token(config, dtype: str = "fp32") -> int:
     fam = config.serving_family()
     item = (np.dtype(fam.dtype).itemsize if dtype == "fp32"
             else _KV_ITEMSIZE[dtype])
-    per_layer = 2 * fam.kv_heads * fam.head_dim * item
+    per_layer = 2 * math.prod(fam.kv_tail) * item
     if dtype == "int8":
         per_layer += 2 * 4  # k_scale + v_scale, fp32, one per column
     return fam.layers * per_layer
@@ -398,7 +403,7 @@ class SeqShardedBlockPool(KVBlockPool):
     """A :class:`KVBlockPool` whose physical blocks live sequence-sharded
     across ``sp`` chips (ISSUE 13 / ROADMAP item 2).
 
-    The device pool array ``[layers, n_blocks, block_size, H, D]`` is
+    The device pool array ``[layers, n_blocks, block_size, *kv_tail]`` is
     placed with its block axis on the ``sp`` mesh axis (contiguous
     shards: chip ``c`` holds blocks
     ``[c * blocks_per_shard, (c+1) * blocks_per_shard)``), so a long

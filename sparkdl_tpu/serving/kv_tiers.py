@@ -118,7 +118,7 @@ class TieredKVStore:
     holds it and returns the payload.
 
     Entries are one block each: a dict of numpy arrays in storage
-    dtype (``k``/``v`` shaped ``[layers, block_size, H, D]`` plus
+    dtype (``k``/``v`` shaped ``[layers, block_size, *kv_tail]`` plus
     ``k_scale``/``v_scale`` ``[layers, block_size]`` for quantized
     pools). The disk tier serializes through the handoff raw codec so
     bf16/int8 round-trip exactly.

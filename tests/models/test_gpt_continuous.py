@@ -16,6 +16,7 @@ from sparkdl_tpu.models.gpt import (
     GPTConfig,
     GPTLMHeadModel,
     init_cache,
+    merged_axis_attention,
 )
 
 MAX_LEN = 32
@@ -178,3 +179,101 @@ def test_per_slot_overflowed_slot_drops_write():
         np.asarray(cache["k"][:, 1, -1]), before_last_col
     )
     assert int(cache["idx"][1]) == MAX_LEN + 4
+
+
+# -- the few-query attention over one merged axis (ISSUE 30) ------------------
+
+BS = 4  # a block of the pool the rows below came through
+
+
+def _einsum_attention(q, k_old, v_old, k_new, v_new, idx, kv_mask):
+    """The form the product replaced, in float64 numpy: this call's columns
+    written into the rows at ``[idx, idx+L)``, heads apart, one causal
+    softmax over the row."""
+    s, l, h, d = q.shape
+    out = np.zeros((s, l, h, d))
+    for row in range(s):
+        k = np.array(k_old[row, :, :h * d], np.float64).reshape(-1, h, d)
+        v = np.array(v_old[row, :, :h * d], np.float64).reshape(-1, h, d)
+        at = int(idx[row])
+        k[at:at + l] = np.asarray(k_new[row, :, :h * d]).reshape(l, h, d)
+        v[at:at + l] = np.asarray(v_new[row, :, :h * d]).reshape(l, h, d)
+        scores = np.einsum("qhd,khd->hqk", np.asarray(q[row], np.float64),
+                           k) / np.sqrt(d)
+        seen = np.arange(k.shape[0])[None, :] <= at + np.arange(l)[:, None]
+        if kv_mask is not None:
+            # the new columns are always real; the mask is about the rows
+            seen &= np.asarray(kv_mask[row])[None, :] | (
+                np.arange(k.shape[0])[None, :] >= at)
+        scores = np.where(seen[None], scores, -np.inf)
+        p = np.exp(scores - scores.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        out[row] = np.einsum("hqk,khd->qhd", p, v)
+    return out
+
+
+@pytest.mark.parametrize("masked", [False, True],
+                         ids=["all_columns", "left_pad_masked"])
+@pytest.mark.parametrize("merged", [32, 128],
+                         ids=["heads_fill_the_axis", "axis_zero_padded"])
+@pytest.mark.parametrize("queries", [1, 4])
+def test_product_over_the_merged_axis_is_the_einsum_attention(
+        queries, merged, masked):
+    """``merged_axis_attention`` against the einsum form, float32 against
+    float64: one query and a span of four; rows at different depths; a row
+    with nothing behind it (an idle slot's sentinel entries: every old
+    column hidden); a new column at a block's first and at its last
+    offset; the merged axis as wide as the heads and zero-padded to a lane
+    tile (the pool's storage), whose pad must change nothing."""
+    s, h, d, w = 5, 2, 16, 6 * BS
+    rng = np.random.default_rng(queries + merged)
+
+    def rows(*shape):
+        x = np.zeros(shape + (merged,), np.float32)
+        x[..., :h * d] = rng.normal(size=shape + (h * d,))
+        return jnp.asarray(x)
+
+    q = jnp.asarray(rng.normal(size=(s, queries, h, d)), jnp.float32)
+    k_old, v_old = rows(s, w), rows(s, w)
+    k_new, v_new = rows(s, queries), rows(s, queries)
+    # depths: mid-block, nothing yet, a block's first offset, its last,
+    # and deep enough that the span ends on the row's last column
+    idx = jnp.asarray([9, 0, 2 * BS, 3 * BS - 1, w - queries], jnp.int32)
+    kv_mask = None
+    if masked:
+        kv_mask = jnp.asarray(
+            np.arange(w)[None, :] >= np.asarray([3, 0, 1, 5, 2])[:, None])
+    got = merged_axis_attention(q, k_old, v_old, k_new, v_new, idx,
+                                kv_mask=kv_mask)
+    assert got.shape == (s, queries, h, d) and got.dtype == jnp.float32
+    want = _einsum_attention(q, k_old, v_old, k_new, v_new, idx, kv_mask)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-6)
+    # what lies at and past a row's depth is never read: garbage there
+    # (columns of rejected drafts, a retired slot's blocks) changes nothing
+    junk = jnp.where(np.arange(w)[None, :, None] >= np.asarray(idx)[:, None,
+                                                              None],
+                     1e4, k_old)
+    again = merged_axis_attention(q, junk, junk * 0 + v_old, k_new, v_new,
+                                  idx, kv_mask=kv_mask)
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(got))
+
+
+def test_a_shared_block_reads_the_same_for_both_rows():
+    """Two rows whose first block is ONE physical block (a copy-on-write
+    prefix) and whose queries agree get the same answer while they read
+    only that block, and differ once a row's own column joins."""
+    h, d, merged = 2, 16, 128
+    rng = np.random.default_rng(7)
+    block = rng.normal(size=(BS, merged)).astype(np.float32)
+    block[:, h * d:] = 0
+    k_old = jnp.asarray(np.stack([np.concatenate(
+        [block, rng.normal(size=(BS, merged))]) for _ in range(2)]),
+        jnp.float32).at[..., h * d:].set(0)
+    q = jnp.asarray(np.repeat(rng.normal(size=(1, 1, h, d)), 2, 0),
+                    jnp.float32)
+    new = jnp.zeros((2, 1, merged), jnp.float32).at[1, :, :h * d].set(3.0)
+    idx = jnp.asarray([BS, BS], jnp.int32)
+    out = merged_axis_attention(q, k_old, k_old, new * 0, new * 0, idx)
+    np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(out[1]))
+    out = merged_axis_attention(q, k_old, k_old, new, new, idx)
+    assert np.abs(np.asarray(out[0]) - np.asarray(out[1])).max() > 1e-3

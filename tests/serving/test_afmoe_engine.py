@@ -110,9 +110,11 @@ def test_a_decode_tick_counts_its_experts_and_its_gathers_by_layer_kind(
 def test_the_pool_is_shaped_by_the_familys_kv_heads_and_head_size(served):
     cfg = served["cfg"]
     kv = served["snap"]["kv"]
-    # K and V of 2 KV heads of 16 in float32, five layers
-    assert kv["bytes_per_token"] == 2 * 2 * 16 * 4 * 5
+    # K and V of 2 KV heads of 16 in float32, five layers: a head under a
+    # lane tile, so the 32 values lie on one merged axis stored as 128
     fam = cfg.serving_family()
+    assert fam.kv_tail == (128,)
+    assert kv["bytes_per_token"] == 2 * 128 * 4 * 5
     assert (fam.layers, fam.kv_heads, fam.head_dim) == (5, 2, 16)
     assert (fam.window_layers, fam.window, fam.expert_layers) == (4, 32, 4)
     assert fam.window_blocks(8, 16) == 3 and fam.window_blocks(2, 16) == 2

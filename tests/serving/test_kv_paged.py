@@ -15,7 +15,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel, generate
+from sparkdl_tpu.models.family import kv_per_head, kv_stored
+from sparkdl_tpu.models.gpt import (
+    GPTConfig,
+    GPTLMHeadModel,
+    generate,
+    init_block_pool,
+)
 from sparkdl_tpu.observability import tracing
 from sparkdl_tpu.observability.flight import healthz_report
 from sparkdl_tpu.observability.registry import registry
@@ -380,10 +386,10 @@ def program_arrays(fn, args):
 
 
 #: the only ways a decode program may make an array of the pool's shape:
-#: the column update in place, the loops and calls that carry it, and the
-#: pin to the layout it is stored in
-IN_PLACE = {"dynamic_update_slice", "while", "scan", "pjit", "jit",
-            "closed_call", "core_call", "layout_constraint"}
+#: the column update in place (one scatter indexed by layer, block and
+#: offset on the merged axis) and the loops and calls that carry it
+IN_PLACE = {"scatter", "while", "scan", "pjit", "jit", "closed_call",
+            "core_call"}
 
 
 @pytest.mark.parametrize("which", ["step", "verify"])
@@ -400,8 +406,8 @@ def test_decode_program_holds_no_dense_view_and_no_second_pool(bundle, which):
     try:
         fn, args = decode_program(eng, which, k=2, nb=2)
         pool_shape = eng._pool_kv["k"].shape
-        layers, _, bs, nh, hd = pool_shape
-        view = (layers, eng.n_slots, 2 * bs, nh, hd)
+        layers, _, bs, merged = pool_shape
+        view = (layers, eng.n_slots, 2 * bs, merged)
         made = program_arrays(fn, args)
         assert made, "the program traced to nothing"
         assert [m for m in made if m[1] == view] == []
@@ -422,39 +428,49 @@ def _dense_rows(pool, table, layer):
     return g.reshape(table.shape[0], -1, *g.shape[3:])
 
 
+@pytest.mark.parametrize("stored", ["merged", "per_head"])
 @pytest.mark.parametrize("width", [1, 3])
-def test_model_reads_a_paged_cache_like_the_dense_one(bundle, width):
+def test_model_reads_a_paged_cache_like_the_dense_one(bundle, width, stored):
     """``GPTLMHeadModel`` on a paged cache (pool + table) against the same
     K/V laid out as a per-slot dense cache: logits bitwise equal, and the
-    columns handed back are the ones the dense cache wrote. Rows sit at
-    different depths, one row's table is all sentinel (an idle slot) and
-    two rows share their first block (a copy-on-write prefix)."""
+    columns handed back are the ones the dense cache wrote, in the shape
+    the pool stores a token in. Rows sit at different depths, one row's
+    table is all sentinel (an idle slot) and two rows share their first
+    block (a copy-on-write prefix). The pool as ``init_block_pool`` shapes
+    it for heads of 16 (one merged axis, zero-padded to a lane tile), and
+    with heads and head size apart (what heads of 128 would keep)."""
     cfg, model, variables = bundle
     layers, bs, n_blocks = cfg.num_layers, 4, 9
     nh, hd = cfg.num_heads, cfg.hidden_size // cfg.num_heads
+    fam = cfg.serving_family()
+    tail = fam.kv_tail if stored == "merged" else (nh, hd)
+    assert init_block_pool(cfg, n_blocks, bs)["k"].shape == (
+        layers, n_blocks, bs) + fam.kv_tail == (layers, n_blocks, bs, 128)
     rng = np.random.default_rng(width)
-    pool = {n: jnp.asarray(rng.normal(size=(layers, n_blocks, bs, nh, hd)),
-                           jnp.float32) for n in ("k", "v")}
+    pool = {n: kv_stored(jnp.asarray(
+        rng.normal(size=(layers, n_blocks, bs, nh, hd)), jnp.float32), tail)
+        for n in ("k", "v")}
     sentinel = n_blocks
     table = np.asarray([[0, 1, 2], [0, 3, sentinel],
                         [sentinel, sentinel, sentinel]], np.int32)
     idx = np.asarray([9, 5, 0], np.int32)
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (3, width)), jnp.int32)
-    dense = {n: jnp.stack([jnp.asarray(_dense_rows(pool[n], table, l))
-                           for l in range(layers)]) for n in ("k", "v")}
+    dense = {n: jnp.stack([
+        kv_per_head(jnp.asarray(_dense_rows(pool[n], table, l)), nh, hd)
+        for l in range(layers)]) for n in ("k", "v")}
     want, wrote = model.apply(variables, toks,
                               cache=dict(dense, idx=jnp.asarray(idx)))
     got, new = model.apply(
         variables, toks,
         cache=dict(pool, table=jnp.asarray(table), idx=jnp.asarray(idx)))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    assert new["k"].shape == (layers, 3, width, nh, hd)
+    assert new["k"].shape == (layers, 3, width) + tail
     np.testing.assert_array_equal(np.asarray(new["idx"]), idx + width)
     for n in ("k", "v"):
         for row, at in enumerate(idx):
             np.testing.assert_array_equal(
                 np.asarray(new[n][:, row]),
-                np.asarray(wrote[n][:, row, at:at + width]))
+                np.asarray(kv_stored(wrote[n][:, row, at:at + width], tail)))
 
 
 TABLE_CASES = {
@@ -497,3 +513,35 @@ def test_tokens_through_the_table_are_bitwise_the_dense_engines(bundle, case):
         assert len(got) == n
         np.testing.assert_array_equal(
             got, want, err_msg=f"{case}: paged diverged from dense: {prompt}")
+
+
+@pytest.mark.parametrize("kw", [
+    {}, {"spec_k": 4}, {"kv_dtype": "int8"}],
+    ids=["one_token", "spec_k_4", "int8"])
+def test_heads_that_fill_lane_tiles_keep_their_own_axis(kw):
+    """A GPT with heads of 128: the stored shape follows the head size
+    alone (``models/family.py``), so its pool keeps ``[.., heads, 128]``
+    and its columns go in one at a time (the engine's loop, the afmoe
+    family's path); the model merges the gathered rows itself. Same greedy
+    tokens as ``kv_layout="dense"``; int8 (one scale a column of both
+    axes) serves its requests whole."""
+    cfg = GPTConfig.tiny(hidden_size=256, num_heads=2)
+    assert cfg.serving_family().kv_tail == (2, 128)
+    variables = GPTLMHeadModel(cfg).init(
+        jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32))
+    cases = [([5, 3, 9, 2, 7, 11], 9), ([5, 3, 9, 2, 7, 11, 1, 4], 5),
+             ([1, 2, 3, 4] * 3, 8)]
+    outs = {}
+    for layout, extra in (("paged", dict(kv_block_size=4, prefill_chunk=8,
+                                         **kw)), ("dense", {})):
+        eng = _engine(cfg, variables, kv_layout=layout, **extra)
+        if layout == "paged":
+            assert eng._pool_kv["k"].shape[2:] == (4, 2, 128)
+        futs = [eng.submit(p, n) for p, n in cases]
+        _drain(eng, futs)
+        eng.close()
+        outs[layout] = [f.result(timeout=0) for f in futs]
+    for (_, n), got, want in zip(cases, outs["paged"], outs["dense"]):
+        assert len(got) == n
+        if "kv_dtype" not in kw:
+            np.testing.assert_array_equal(got, want)

@@ -76,33 +76,45 @@ def _run(cfg, variables, cases, **kw):
 
 # -- quantization math -------------------------------------------------------
 
-def test_quantize_roundtrip_is_idempotent():
+@pytest.mark.parametrize("shape, tail", [
+    ((3, 5, 32), 1),     # a column on the pool's one merged axis
+    ((3, 5, 4, 8), 2),   # a pool that keeps heads and head size apart
+])
+def test_quantize_roundtrip_is_idempotent(shape, tail):
     """requantize(dequantize(q, s)) == (q, s) exactly: the absmax of a
     column maps to ±127, so a second trip changes nothing — the
-    property that makes COW re-installation lossless."""
+    property that makes COW re-installation lossless. A column is the
+    merged axis, or the ``tail`` trailing axes of a per-head pool."""
     rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.standard_normal((3, 5, 4, 8)), jnp.float32)
-    q, s = quantize_kv(x)
+    x = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    # a merged axis ends in the storage's zero pad, which stays zero
+    x = x.at[..., -3:].set(0) if tail == 1 else x
+    q, s = quantize_kv(x, tail)
     assert q.dtype == jnp.int8 and s.shape == (3, 5)
-    q2, s2 = quantize_kv(dequantize_kv(q, s))
+    q2, s2 = quantize_kv(dequantize_kv(q, s), tail)
     np.testing.assert_array_equal(np.asarray(q2), np.asarray(q))
     np.testing.assert_array_equal(np.asarray(s2), np.asarray(s))
+    if tail == 1:
+        np.testing.assert_array_equal(np.asarray(q[..., -3:]), 0)
     # zero columns: floor scale, zero values, no NaN
-    qz, sz = quantize_kv(jnp.zeros((2, 4, 8), jnp.float32))
+    qz, sz = quantize_kv(jnp.zeros(shape[1:], jnp.float32), tail)
     assert not np.isnan(np.asarray(sz)).any()
     np.testing.assert_array_equal(np.asarray(qz), 0)
 
 
 def test_capacity_ratio_arithmetic():
     tiny = GPTConfig.tiny()
-    assert kv_bytes_per_token(tiny, "fp32") == 2 * 2 * 32 * 4
+    # resident bytes: 2 heads of 16 lie on one merged axis, stored padded
+    # to a whole lane tile of 128 (ServingFamily.kv_tail)
+    assert tiny.serving_family().kv_tail == (128,)
+    assert kv_bytes_per_token(tiny, "fp32") == 2 * 2 * 128 * 4
     assert kv_capacity_ratio(tiny, "bf16") == 2.0
     assert kv_capacity_ratio(tiny, "int8") >= 2.0
     # the "fp32" layout stores at the MODEL dtype: a bf16-compute
     # model's native pool is already half-size, and the ratios must
     # report the honest (smaller) gain, not fp32 arithmetic
     bf = GPTConfig.tiny(dtype=jnp.bfloat16)
-    assert kv_bytes_per_token(bf, "fp32") == 2 * 2 * 32 * 2
+    assert kv_bytes_per_token(bf, "fp32") == 2 * 2 * 128 * 2
     assert kv_capacity_ratio(bf, "bf16") == 1.0
     assert 1.5 < kv_capacity_ratio(bf, "int8") < 2.0
     # a production-ish width: int8 approaches 4x
@@ -317,8 +329,8 @@ def test_compressed_pool_is_never_dequantized_whole(bundle, kv_dtype, which):
         pool = eng._pool_kv
         fn, args = decode_program(eng, which, k=2, nb=2)
         made = program_arrays(fn, args)
-        layers, _, bs, nh, hd = pool["k"].shape
-        view = (layers, eng.n_slots, 2 * bs, nh, hd)
+        layers, _, bs, merged = pool["k"].shape
+        view = (layers, eng.n_slots, 2 * bs, merged)
         stored = pool["k"].dtype
         assert stored == {"bf16": jnp.bfloat16, "int8": jnp.int8}[kv_dtype]
         whole = [m for m in made if m[1] in (pool["k"].shape, view)]

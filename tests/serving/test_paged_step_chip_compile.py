@@ -1,9 +1,12 @@
-"""The paged decode step compiled for a described TPU v5e, at GPT-2 XL's
-widths (ISSUE 27). What no CPU test can see: the chip stores the block
-pool with the BLOCK axis in the lanes (a head of 64 is half a lane tile),
-and a compiler left to itself re-lays the whole pool out to suit an
-update and back again, four passes over it a tick. Nothing runs here and
-no time is measured: the compiled text is searched for the copies.
+"""The paged decode programs compiled for a described TPU v5e, at GPT-2
+XL's widths (ISSUE 27, ISSUE 30). What no CPU test can see: how the chip
+lays the block pool out. With heads and head size apart, ``[.., 25, 64]``,
+it put the BLOCK axis in the lanes (a head of 64 is half a lane tile), and
+every gather through the table transposed a layer's slab first. Stored on
+one merged axis padded to whole tiles, ``[layers, blocks, 16, 1664]``, the
+pool lies with layers and blocks major, and the programs read and write it
+where it lies. Nothing runs here and no time is measured: the compiled text
+is searched for the layouts and the copies.
 
 One file, and the topology is described inside a fixture, so that only
 the worker that is handed this file loads the TPU's library.
@@ -18,9 +21,12 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel
-from sparkdl_tpu.serving import ContinuousGPTEngine
+from sparkdl_tpu.runtime.chip import alike_layers_options
+from sparkdl_tpu.serving import ContinuousGPTEngine, continuous
 
-LAYERS, SLOTS, MAX_LEN = 2, 8, 1024
+#: a pool of 640 blocks, so that a layer's slab ``[640, 16, ..]`` and the 8
+#: x 64 blocks a step gathers are different shapes
+LAYERS, SLOTS, MAX_LEN, BLOCKS = 2, 8, 1024, 640
 
 
 @pytest.fixture(scope="module")
@@ -39,6 +45,22 @@ def one_chip():
             raise
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def monkeypatch_module():
+    with pytest.MonkeyPatch.context() as mp:
+        yield mp
+
+
+@pytest.fixture(scope="module")
+def for_the_chip(monkeypatch_module):
+    """This process's backend is the CPU, and an engine built here asks
+    for no compile option; the programs below are compiled FOR the chip, so
+    they take the options the engine gives them there."""
+    monkeypatch_module.setattr(
+        continuous, "alike_layers_options",
+        lambda: alike_layers_options("tpu"))
 
 
 def _on(chip, tree):
@@ -62,10 +84,11 @@ def _device_layout(chip, a):
 
 
 @pytest.fixture(scope="module")
-def compiled(one_chip):
-    """_paged_step and _paged_verify of an 8 x 1024 engine over two
-    GPT-2 XL layers, compiled for the chip with the pool's layouts as the
-    chip would store them."""
+def compiled(one_chip, for_the_chip):
+    """The decode step, a chained step of 4, the verify pass of 4, a
+    ONE-chunk prefill (it gathers a cached prefix out of the pool and
+    installs into it) and a FINAL chunk of an 8 x 1024 engine over two
+    GPT-2 XL layers, compiled for the chip."""
     cfg = dataclasses.replace(
         GPTConfig.tiny(), vocab_size=512, hidden_size=1600, num_layers=LAYERS,
         num_heads=25, intermediate_size=6400, max_seq_len=MAX_LEN,
@@ -74,24 +97,32 @@ def compiled(one_chip):
         lambda: GPTLMHeadModel(cfg).init(
             jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
     eng = ContinuousGPTEngine(cfg, variables, n_slots=SLOTS, max_len=MAX_LEN,
-                              spec_k=4, auto_start=False)
+                              kv_blocks=BLOCKS, spec_k=4, auto_start=False)
     try:
         pool = eng._pool_kv
-        # the engine read the layouts of a pool on THIS host's device;
-        # the programs are traced below, for the chip's
-        eng._kv_stored = {name: _device_layout(one_chip, a)
-                          for name, a in pool.items()}
+
         def ints(*shape):
             return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
-        out = {"pool": pool["k"]}
+        mb = MAX_LEN // 16
+        # a prompt's private prefill cache keeps a token as the pool does
+        private = jax.ShapeDtypeStruct(
+            (LAYERS, 1, eng._wp) + pool["k"].shape[3:], jnp.bfloat16,
+            sharding=one_chip)
+        head = (_on(one_chip, variables), _on(one_chip, pool))
+        out = {"pool": pool["k"],
+               "stored": _device_layout(one_chip, pool["k"])}
         for name, fn, toks, k in (
                 ("step", eng._paged_step_fn, ints(SLOTS), 1),
+                ("chain", eng._paged_step_fn, ints(SLOTS), 4),
                 ("verify", eng._paged_verify_fn, ints(SLOTS, 4), 4)):
             out[name] = fn.lower(
-                _on(one_chip, variables), _on(one_chip, pool),
-                ints(SLOTS, MAX_LEN // 16), ints(SLOTS), toks, k,
-                MAX_LEN // 16).compile()
+                *head, ints(SLOTS, mb), ints(SLOTS), toks, k, mb).compile()
+        out["one"] = eng._chunk_one_fn.lower(
+            *head, ints(mb), ints(), ints(1, 128), ints(mb), 128).compile()
+        out["final"] = eng._chunk_final_fn.lower(
+            *head, private, private, ints(), ints(1, 256), ints(mb),
+            MAX_LEN).compile()
         return out
     finally:
         eng.close()
@@ -105,41 +136,108 @@ def _made(text, dims):
         r"= (\w+\[%s\]\{[^ ]*\}) ([\w\-]+)\(" % want, text)]
 
 
-@pytest.mark.parametrize("which", ["step", "verify"])
-def test_the_pool_is_updated_where_it_lies(compiled, which):
+def test_the_merged_axis_keeps_layers_and_blocks_major_on_the_chip(
+        one_chip, compiled):
+    pool = compiled["pool"]
+    # 25 heads of 64 on one axis, padded to 13 whole lane tiles
+    assert pool.shape == (LAYERS, BLOCKS, 16, 1664)
+    assert compiled["stored"].major_to_minor == (0, 1, 2, 3)
+    # what the pad is for: 1600 is 12.5 tiles, and the chip then takes the
+    # block axis for its lanes, as it did with [.., 25, 64]
+    unpadded = jax.ShapeDtypeStruct((48, 512, 16, 1600), jnp.bfloat16)
+    assert _device_layout(one_chip, unpadded).major_to_minor[-1] == 1
+
+
+@pytest.mark.parametrize(
+    "which", ["step", "chain", "verify", "one", "final"])
+def test_the_pool_is_read_and_written_where_it_lies(compiled, which):
     pool = compiled["pool"]
     text = compiled[which].as_text()
     made = _made(text, pool.shape)
     ops = {op for op, _ in made}
-    assert "dynamic-update-slice" in ops
-    # no copy of the pool, no scatter over it, no change of its axes
-    assert not ops & {"copy", "copy-start", "copy-done", "scatter",
-                      "transpose"}, sorted(ops)
-    # ...and in one layout from the argument to the result: the chip's
-    assert len({order for _, order in made}) == 1
-    assert made[0][1][0] == 1, "the block axis is no longer the minor one"
+    # the new columns as ONE scatter a pool array (step, chain, verify), a
+    # prompt's blocks as a loop of updates (one, final): in place
+    assert ops & {"scatter", "dynamic-update-slice"}, sorted(ops)
+    # no copy of the pool, no change of its axes
+    assert not ops & {"copy", "copy-start", "copy-done", "transpose"}, (
+        sorted(ops))
+    # ...in one layout from the argument to the result: the stored one
+    assert {order for _, order in made} == {(3, 2, 1, 0)}
+    # no layer's slab sliced or copied out of the pool before its gather,
+    # in either spelling of its shape
+    _, blocks, bs, merged = pool.shape
+    assert _made(text, (1, blocks, bs, merged)) == []
+    assert _made(text, (blocks, bs, merged)) == []
+    # every pool array goes out in the buffer it came in
     aliases = text.split("input_output_alias={", 1)[1].split(
         "entry_computation_layout", 1)[0]
     assert aliases.count("-alias") == 2
-
-
-@pytest.mark.parametrize("which", ["step", "verify"])
-def test_no_view_over_all_layers_on_the_chip(compiled, which):
-    pool = compiled["pool"]
-    layers, _, bs, nh, hd = pool.shape
-    text = compiled[which].as_text()
-    assert _made(text, (layers, SLOTS, MAX_LEN, nh, hd)) == []
-    # a layer's gathered rows are there, in some spelling of their shape
-    assert (_made(text, (SLOTS, MAX_LEN, nh, hd))
-            or _made(text, (SLOTS * MAX_LEN // bs, bs, nh, hd)))
     stats = compiled[which].memory_analysis()
     assert stats.alias_size_in_bytes == 2 * pool.nbytes
+    # ...and nothing of the pool's size is held beside it
+    assert stats.temp_size_in_bytes < pool.nbytes / 4
 
 
-# -- the afmoe family at its published widths (ISSUE 29) ----------------------------
+@pytest.mark.parametrize("which", ["step", "chain", "verify"])
+def test_attention_takes_the_gathered_rows_as_they_lie(compiled, which):
+    pool = compiled["pool"]
+    layers, _, bs, merged = pool.shape
+    text = compiled[which].as_text()
+    # no view over all layers, and no rows with heads and head size apart:
+    # [S, W, 25, 64] is the padded copy the parent's attention read
+    assert _made(text, (layers, SLOTS, MAX_LEN, merged)) == []
+    for rows in ((SLOTS, MAX_LEN), (SLOTS, MAX_LEN // bs, bs),
+                 (SLOTS * MAX_LEN // bs, bs)):
+        assert _made(text, rows + (25, 64)) == []
+    # a layer's gathered rows are there on the merged axis, and nothing
+    # copies or transposes them on their way into the products
+    gathered = (_made(text, (SLOTS, MAX_LEN, merged))
+                + _made(text, (SLOTS * MAX_LEN // bs, bs, merged))
+                + _made(text, (SLOTS, MAX_LEN // bs, bs, merged)))
+    assert gathered
+    assert all(order[0] == len(order) - 1 for _, order in gathered)
+    assert not {op for op, _ in gathered} & {"copy", "copy-start"}
+    # the scores and the weighted sum are products over the merged axis
+    assert re.search(r"f32\[%d,\d+,%d\]\S* convolution\(" % (
+        SLOTS, MAX_LEN), text)
+
+
+def test_the_layers_of_a_chunk_share_their_code(one_chip, for_the_chip):
+    """All 48 layers this once: a one-chunk prefill, the program a serving
+    engine holds most of, compiled as the engine compiles it on the chip
+    (``runtime.chip.alike_layers_options``). Its layers' operations are
+    compiled once and called; left to the compiler's own rule this program
+    is 116 MB of generated code (3 MB a layer), which every start then
+    loads from the cache (PERF.md section 6, PR 30)."""
+    cfg = dataclasses.replace(
+        GPTConfig.tiny(), vocab_size=512, hidden_size=1600, num_layers=48,
+        num_heads=25, intermediate_size=6400, max_seq_len=MAX_LEN,
+        positions="learned", dtype=jnp.bfloat16)
+    variables = jax.eval_shape(
+        lambda: GPTLMHeadModel(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    eng = ContinuousGPTEngine(cfg, variables, n_slots=SLOTS, max_len=MAX_LEN,
+                              auto_start=False)
+    try:
+        def ints(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+        mb = MAX_LEN // 16
+        one = eng._chunk_one_fn.lower(
+            _on(one_chip, variables), _on(one_chip, eng._pool_kv), ints(mb),
+            ints(), ints(1, 256), ints(mb), 256).compile()
+    finally:
+        eng.close()
+    stats = one.memory_analysis()
+    assert stats.generated_code_size_in_bytes < 24e6
+    # still in place: the pool goes out in the buffer it came in
+    assert stats.alias_size_in_bytes == 2 * eng._pool_kv["k"].nbytes
+
+
+# -- the afmoe family at its published widths (ISSUE 29) ----------------------
 
 @pytest.fixture(scope="module")
-def compiled_afmoe(one_chip, monkeypatch_module):
+def compiled_afmoe(one_chip, for_the_chip, monkeypatch_module):
     """The decode step, a ONE-chunk prefill (it gathers a cached prefix
     out of the pool and installs into it) and a FINAL chunk of a 4 x 8192
     engine over
@@ -169,8 +267,6 @@ def compiled_afmoe(one_chip, monkeypatch_module):
                               kv_blocks=16384, auto_start=False)
     try:
         pool = eng._pool_kv
-        eng._kv_stored = {name: _device_layout(one_chip, a)
-                          for name, a in pool.items()}
 
         def ints(*shape):
             return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
@@ -179,7 +275,8 @@ def compiled_afmoe(one_chip, monkeypatch_module):
         private = jax.ShapeDtypeStruct(
             (2, 1, eng._wp, 4, 128), jnp.bfloat16, sharding=one_chip)
         return {
-            "pool": pool["k"], "stored": eng._kv_stored["k"],
+            "pool": pool["k"],
+            "stored": _device_layout(one_chip, pool["k"]),
             "step": eng._paged_step_fn.lower(
                 _on(one_chip, variables), _on(one_chip, pool),
                 ints(slots, mb), ints(slots), ints(slots), 1, mb).compile(),
@@ -192,12 +289,6 @@ def compiled_afmoe(one_chip, monkeypatch_module):
         }
     finally:
         eng.close()
-
-
-@pytest.fixture(scope="module")
-def monkeypatch_module():
-    with pytest.MonkeyPatch.context() as mp:
-        yield mp
 
 
 @pytest.mark.parametrize("which", ["step", "one", "final"])
