@@ -7,15 +7,16 @@ the pool's RAW storage layout, so the tier crossing inherits the
 quantized pool's wire economics for free:
 
 * an ``int8`` pool ships ``int8`` values plus one fp32 scale per
-  written column (``models.gpt.quantize_kv``'s layout) — per token that
+  written column (``models.kv_pool.quantize_kv``'s layout) — per token that
   is ``2·width + 8`` bytes against fp32's ``8·width``, a
   ``4/(1 + 4/width)``× reduction (``width`` the pool's stored values a
   token, ``ServingFamily.kv_tail``: a GPT's hidden size on one merged
   axis, padded to whole lane tiles; 3.88× at hidden=32 stored as 128,
   →4× as it grows);
 * the decode-side install dequantizes (``q·s``, exact) and rides the
-  engine's shared ``_q_write`` path, whose requantize is the exact
-  round trip ``quantize_kv`` documents (absmax maps to ±127) — so a
+  engine's install (``models.kv_pool.write_kv_blocks``), whose
+  requantize is the exact round trip ``quantize_kv`` documents
+  (absmax maps to ±127) — so a
   transferred block is BITWISE-identical to one the decode host would
   have prefilled itself, and greedy tokens cannot drift across the
   split.

@@ -32,7 +32,6 @@ requeue.
 
 from __future__ import annotations
 
-import functools
 import time
 from concurrent.futures import Future
 from typing import Any
@@ -77,11 +76,6 @@ class PrefillWorker(ContinuousGPTEngine):
         _require_paged(kwargs, "PrefillWorker")
         auto_start = kwargs.pop("auto_start", True)
         super().__init__(config, variables, auto_start=False, **kwargs)
-        # the export is the raw-storage gather a park makes: NO
-        # dequantize — the wire ships the pool's own bytes (int8 + scales,
-        # or fp32/bf16 values), so the decode-side install's requantize
-        # round-trips exactly
-        self._export_fn = self._park_fetch_fn
         self._handoffs = 0
         self._export_aborts = 0
         if auto_start:
@@ -122,7 +116,11 @@ class PrefillWorker(ContinuousGPTEngine):
             wb = pow2_bucket(nbp, 1, self._mb)
             ids = np.full((wb,), self._pool.sentinel, np.int32)
             ids[:nbp] = row
-            out = self._export_fn(self._pool_kv, jnp.asarray(ids))
+            # the export is the raw-storage gather a park makes: NO
+            # dequantize — the wire ships the pool's own bytes (int8 +
+            # scales, or fp32/bf16 values), so the decode-side install's
+            # requantize round-trips exactly
+            out = self._park_fetch_fn(self._pool_kv, jnp.asarray(ids))
             # np.asarray forces the gather to COMPLETE before the block
             # references drop below (releasing first would let an
             # eviction + realloc overwrite a block mid-copy)
@@ -196,19 +194,6 @@ class DecodeWorker(ContinuousGPTEngine):
         _require_paged(kwargs, "DecodeWorker")
         auto_start = kwargs.pop("auto_start", True)
         super().__init__(config, variables, auto_start=False, **kwargs)
-        import jax
-
-        _qw = self._q_write_fn
-
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def _install(pool, kdata, vdata, inst):
-            # the same _q_write path as the fused single-device install
-            # and the sp handoff: quantized pools quantize HERE — the
-            # exact requantize round trip (quantize_kv) that keeps a
-            # transferred block bitwise-identical to a local prefill's
-            return _qw(pool, inst, kdata, vdata)
-
-        self._install_fn = _install
         self._installs = 0
         self._install_faults = 0
         if auto_start:
@@ -345,7 +330,9 @@ class DecodeWorker(ContinuousGPTEngine):
         with span("disagg.handoff_install", parent=req.trace_ctx,
                   request_id=req.request_id, slot=slot, blocks=nbp,
                   shared_blocks=n_shared):
-            self._pool_kv = self._install_fn(
+            # the engine's own install program (the sp handoff's too):
+            # quantized pools quantize there, once
+            self._pool_kv = self._install_blocks_fn(
                 self._pool_kv, kdata, vdata, jnp.asarray(inst))
         _M_HANDOFF_SECONDS.observe(time.perf_counter() - t0)
         _M_HANDOFFS.inc(stage="install")

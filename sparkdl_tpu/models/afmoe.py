@@ -39,13 +39,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from sparkdl_tpu.models.family import (
-    ServingFamily,
-    kv_per_head,
-    kv_stored,
-    window_blocks,
-)
+from sparkdl_tpu.models.family import ServingFamily, window_blocks
 from sparkdl_tpu.models.gpt import apply_rope
+from sparkdl_tpu.models.kv_pool import kv_per_head, kv_stored, layer_rows
 from sparkdl_tpu.parallel.moe_dropless import (
     dropless_experts,
     route_sigmoid_topk,
@@ -196,20 +192,6 @@ def _grouped_attention(q, k, v, mask, dtype):
     return jnp.einsum("bgrlk,bkgd->blgrd", p, v).reshape(b, l, h * d)
 
 
-def _paged_rows(pool: jax.Array, layer: int, sub: jax.Array,
-                dtype: Any) -> jax.Array:
-    """One layer's K (or V) of the block pool ``[layers, blocks, block, G,
-    D]`` as rows ``[S, entries * block, G, D]`` through the table entries
-    ``sub`` ``[S, entries]``: ONE gather over (layer, block), so no layer's
-    slab is sliced out of the pool first (a copy of a fifth of the pool a
-    layer) and the pool keeps the layout it is stored in. A sentinel entry
-    (``blocks``) clips to the layer's last block, whose columns the masks
-    hide. (A pool of heads under a lane tile keeps them on one merged
-    axis, ``models/family.py``: the caller takes them apart.)"""
-    x = pool[jnp.full_like(sub, layer), jnp.minimum(sub, pool.shape[1] - 1)]
-    return x.astype(dtype).reshape(sub.shape[0], -1, *pool.shape[3:])
-
-
 class AfmoeAttention(nn.Module):
     config: AfmoeConfig
     layer_idx: int
@@ -266,10 +248,8 @@ class AfmoeAttention(nn.Module):
             first = jnp.clip((idx + l - 1) // bs - (wb - 1), 0, nb - wb)
             sub = (table if wb == nb else jnp.take_along_axis(
                 table, first[:, None] + jnp.arange(wb)[None, :], axis=1))
-            ck = kv_per_head(
-                _paged_rows(cache["k"], self.layer_idx, sub, c.dtype), ng, hd)
-            cv = kv_per_head(
-                _paged_rows(cache["v"], self.layer_idx, sub, c.dtype), ng, hd)
+            ck, cv = (kv_per_head(x, ng, hd) for x in layer_rows(
+                cache, self.layer_idx, sub, c.dtype))
             rows = jnp.arange(b)[:, None]
             cols = q_pos - (first * bs)[:, None]
             ck = ck.at[rows, cols].set(k.astype(c.dtype), mode="drop")

@@ -21,9 +21,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
-#: lanes of a TPU vector tile: a minor axis that is no multiple of it is
-#: padded to one by the chip, or loses the minor place to an axis that is
-LANE_TILE = 128
+from sparkdl_tpu.models import kv_pool
 
 
 @dataclasses.dataclass(frozen=True)
@@ -52,9 +50,10 @@ class ServingFamily:
     @property
     def kv_tail(self) -> "tuple[int, ...]":
         """The trailing axes of a token's K (or V) in the block pool
-        ``[layers, blocks, block_size, *kv_tail]``: :func:`kv_tail` of this
-        family's heads."""
-        return kv_tail(self.kv_heads, self.head_dim)
+        ``[layers, blocks, block_size, *kv_tail]``:
+        :func:`~sparkdl_tpu.models.kv_pool.kv_tail` of this family's
+        heads."""
+        return kv_pool.kv_tail(self.kv_heads, self.head_dim)
 
     def window_blocks(self, nb: int, block_size: int) -> int:
         """Table entries a window layer of this family gathers in a decode
@@ -62,40 +61,6 @@ class ServingFamily:
         if not self.window_layers:
             return nb
         return window_blocks(self.window, nb, block_size)
-
-
-def kv_tail(kv_heads: int, head_dim: int) -> "tuple[int, ...]":
-    """The trailing axes of a token's K (or V) in a block pool, chosen from
-    the head size alone. A head that fills whole lane tiles keeps its own
-    axis, ``(kv_heads, head_dim)``. One that does not (GPT-2's 64) would
-    leave the chip no minor axis that tiles, and the chip then puts the
-    BLOCK axis in the lanes (PERF.md section 5): its heads are stored side
-    by side on ONE axis, ``(kv_heads * head_dim,)`` with zero columns up to
-    the next whole tile (1600 -> 1664: unpadded, the chip still takes the
-    block axis). A family's module takes the pool in either shape."""
-    if head_dim % LANE_TILE == 0:
-        return (kv_heads, head_dim)
-    return (-(-kv_heads * head_dim // LANE_TILE) * LANE_TILE,)
-
-
-def kv_stored(x, tail: "tuple[int, ...]"):
-    """K or V ``[..., kv_heads, head_dim]`` in a pool's trailing shape
-    ``[..., *tail]`` (storage only: the pad of a merged axis is zeros)."""
-    if len(tail) == 2:
-        return x
-    import jax.numpy as jnp
-
-    x = x.reshape(*x.shape[:-2], -1)
-    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, tail[0] - x.shape[-1])])
-
-
-def kv_per_head(x, kv_heads: int, head_dim: int):
-    """The inverse of :func:`kv_stored`: ``[..., kv_heads, head_dim]`` of
-    what a pool stores, whichever trailing shape it keeps."""
-    if x.shape[-2:] == (kv_heads, head_dim):
-        return x
-    return x[..., :kv_heads * head_dim].reshape(
-        *x.shape[:-1], kv_heads, head_dim)
 
 
 def window_blocks(window: int, nb: int, block_size: int,

@@ -31,11 +31,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from sparkdl_tpu.models.family import (
-    ServingFamily,
-    kv_per_head,
-    kv_stored,
-)
+from sparkdl_tpu.models.family import ServingFamily
+from sparkdl_tpu.models.kv_pool import kv_per_head, kv_stored, layer_rows
 from sparkdl_tpu.parallel.expert_parallel import MoEMlpBlock
 from sparkdl_tpu.parallel.ring_attention import ring_self_attention
 from sparkdl_tpu.parallel.tensor_parallel import (
@@ -158,125 +155,6 @@ def init_cache(config: GPTConfig, batch: int, max_len: int,
     }
 
 
-def init_block_pool(config, n_blocks: int,
-                    block_size: int, dtype: str = "fp32") -> dict:
-    """Zeroed block-paged KV pool for continuous serving
-    (``serving.kv_blocks``): k/v stacked over layers,
-    ``[num_layers, n_blocks, block_size, *kv_tail]``. The trailing axes are
-    the family's (``config.serving_family().kv_tail``): a head that fills
-    whole 128-lane tiles keeps ``(kv_heads, head_dim)``; a GPT's heads of
-    64 lie side by side on ONE axis of ``kv_heads * head_dim`` columns,
-    zero-padded to whole tiles (GPT-2 XL: 1600 -> 1664), so that the chip
-    keeps layers and blocks major and a block's ``block_size x 1664``
-    together (``{3,2,1,0:T(8,128)(2,1)}``; with ``[.., 25, 64]`` it put the
-    BLOCK axis in the lanes, PERF.md section 5).
-
-    Unlike :func:`init_cache` (one dense row per batch slot, capacity
-    ``batch x max_len`` whether or not tokens exist), the pool's
-    capacity is ``n_blocks x block_size`` TOKENS shared by every slot: a
-    slot maps its logical columns onto pool blocks through a block
-    table, the cached step reads K/V through that table one layer at a
-    time (a paged cache, :class:`GPTLMHeadModel`: the pool itself is
-    never gathered whole nor copied, only the new columns are written
-    into it), and the same physical block can back the shared prompt
-    prefix of many slots (``serving.prefix_cache``). Bookkeeping (free list,
-    refcounts, tables) is host-side and lives in
-    :class:`~sparkdl_tpu.serving.kv_blocks.KVBlockPool`.
-
-    ``dtype`` picks the STORAGE layout (``serving.kv_blocks.KV_DTYPES``):
-
-    - ``"fp32"`` — store at the model's compute dtype (``config.dtype``),
-      the exact layout; gather/scatter are plain copies.
-    - ``"bf16"`` — store bfloat16, dequantize to the compute dtype on
-      gather: half the pool bytes per token.
-    - ``"int8"`` — store int8 with one fp32 scale per written COLUMN
-      (``k_scale``/``v_scale``, ``[num_layers, n_blocks, block_size]``,
-      riding the block structure): ~4x fewer pool bytes per token. The
-      quantize/dequantize math is :func:`quantize_kv` /
-      :func:`dequantize_kv`, fused by the serving engine into its paged
-      gather/scatter programs — compute always runs at ``config.dtype``;
-      only the resident pool is compressed.
-    """
-    fam = config.serving_family()
-    shape = (fam.layers, n_blocks, block_size) + fam.kv_tail
-    store = {"fp32": fam.dtype, "bf16": jnp.bfloat16,
-             "int8": jnp.int8}.get(dtype)
-    if store is None:
-        raise ValueError(
-            f"unknown KV pool dtype {dtype!r} (fp32 | bf16 | int8)")
-    pool = {
-        "k": jnp.zeros(shape, store),
-        "v": jnp.zeros(shape, store),
-    }
-    if dtype == "int8":
-        pool["k_scale"] = jnp.zeros(shape[:3], jnp.float32)
-        pool["v_scale"] = jnp.zeros(shape[:3], jnp.float32)
-    return pool
-
-
-def quantize_kv(x: jax.Array,
-                tail: int = 1) -> "tuple[jax.Array, jax.Array]":
-    """Symmetric per-column int8 quantization of K/V columns.
-
-    ``x`` is ``[..., C]``, a token's K or V on the pool's one merged axis
-    (any leading index shape; ``tail`` trailing axes make a column where a
-    pool keeps more than one, ``[..., H, D]``); returns ``(int8 values,
-    fp32 scales[...])`` with one scale per column — the
-    absmax maps to ±127, so requantize(dequantize(q, s)) == (q, s)
-    exactly (the property that makes copy-on-write prefix sharing
-    lossless under int8: a gathered-then-reinstalled block is
-    bit-identical to its donor). Zero columns get a tiny floor scale
-    and quantize to zero; the merged axis's zero pad stays zero.
-    """
-    axes = tuple(range(-tail, 0))
-    amax = jnp.max(jnp.abs(x), axis=axes)
-    scale = (jnp.maximum(amax, 1e-30) / 127.0).astype(jnp.float32)
-    q = jnp.round(x.astype(jnp.float32) / jnp.expand_dims(scale, axes))
-    return jnp.clip(q, -127, 127).astype(jnp.int8), scale
-
-
-def dequantize_kv(q: jax.Array, scale: jax.Array,
-                  dtype: Any = jnp.float32) -> jax.Array:
-    """Inverse of :func:`quantize_kv`: int8 ``[..., C]`` columns (or
-    ``[..., H, D]``: the axes the scales lack) and their per-column scales
-    back to ``dtype``."""
-    axes = tuple(range(scale.ndim - q.ndim, 0))
-    return (q.astype(jnp.float32)
-            * jnp.expand_dims(scale, axes)).astype(dtype)
-
-
-def _paged_layer_kv(cache: dict, layer: int,
-                    dtype: Any) -> "tuple[jax.Array, jax.Array]":
-    """One layer's K and V of a PAGED cache as per-slot rows, in the shape
-    they are stored in.
-
-    A paged cache is ``{"k", "v"[, "k_scale", "v_scale"], "table",
-    "idx"}``: ``k``/``v`` the ``[layers, n_blocks, block_size, C]`` pool of
-    :func:`init_block_pool` in its storage dtype (``C`` the merged axis of
-    heads x head size), ``table`` the ``[S, nb]`` live head of the block
-    table, ``idx`` the ``[S]`` depths. ONE gather over (layer, block),
-    ``pool[layer, table]`` -> ``[S, nb, block_size, C]``, read where the
-    pool lies (no layer's slab sliced out first), then ``[S, nb*block_size,
-    C]`` by merging major axes, which moves nothing; that slice alone is
-    dequantized to ``dtype`` (the rule of :func:`dequantize_kv`; a bf16
-    pool is cast). Entries past the pool (the table's sentinel) clip to a
-    block whose columns the causal mask hides. (A pool whose heads fill
-    whole lane tiles keeps ``[.., H, D]``; its rows are merged here.)
-    """
-    table = cache["table"]
-    at = (jnp.full_like(table, layer),
-          jnp.minimum(table, cache["k"].shape[1] - 1))
-
-    def rows(name):
-        x = cache[name][at]
-        scale = cache.get(name + "_scale")
-        x = (x.astype(dtype) if scale is None
-             else dequantize_kv(x, scale[at], dtype))
-        return x.reshape(table.shape[0], table.shape[1] * x.shape[2], -1)
-
-    return rows("k"), rows("v")
-
-
 def merged_axis_attention(q, k_old, v_old, k_new, v_new, idx,
                           kv_mask=None):
     """Attention of a FEW queries a row (one decode token, a verify span)
@@ -376,13 +254,19 @@ class GPTAttention(nn.Module):
             # the heads side by side on one axis, so the paged engine and
             # its dense oracle compare like with like: a paged cache's rows
             # come through the block table as the pool stores them
-            # (_paged_layer_kv: never a dense all-layer view of the pool,
-            # never a reshape to heads); a dense cache's are its own
-            # buffer, heads merged. This call's columns join the softmax
-            # beside them, they are not written into the rows first.
+            # (kv_pool.layer_rows: never a dense all-layer view of the
+            # pool, never a reshape to heads; a pool whose heads fill
+            # whole lane tiles keeps ``[.., H, D]`` and its rows are merged
+            # here, a reshape that moves nothing); a dense cache's are its
+            # own buffer, heads merged. This call's columns join the
+            # softmax beside them, they are not written into the rows
+            # first.
             paged = "table" in cache
             if paged:
-                k_old, v_old = _paged_layer_kv(cache, self.layer_idx, c.dtype)
+                k_old, v_old = (
+                    x.reshape(b, x.shape[1], -1) if x.ndim > 3 else x
+                    for x in layer_rows(cache, self.layer_idx,
+                                        cache["table"], c.dtype))
             else:
                 layer_k = cache["k"][self.layer_idx]
                 layer_v = cache["v"][self.layer_idx]
@@ -594,10 +478,12 @@ class GPTLMHeadModel(nn.Module):
     verify k drafted tokens per dispatch.
 
     A PAGED cache (it holds a ``table`` entry: ``{"k", "v"[, "k_scale",
-    "v_scale"], "table", "idx"}``, the :func:`init_block_pool` pool with
+    "v_scale"], "table", "idx"}``, the pool of
+    :func:`~sparkdl_tpu.models.kv_pool.init_block_pool` with
     the ``[S, nb]`` live head of the block table and per-slot ``idx``)
     runs the same per-slot step, but every layer gathers only its own
-    live blocks through the table (:func:`_paged_layer_kv`) and the
+    live blocks through the table
+    (:func:`~sparkdl_tpu.models.kv_pool.layer_rows`) and the
     returned ``k``/``v`` are THIS call's new columns,
     ``[layers, S, L, C]`` at the compute dtype with the heads on the
     pool's one merged axis — the caller writes them into its pool at

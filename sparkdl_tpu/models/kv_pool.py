@@ -1,0 +1,300 @@
+"""How a token's K and V lie in the device pool: the ONE module that knows.
+
+A block pool is a ``dict`` of arrays by name, ``k`` and ``v``
+``[layers, blocks, block_size, *tail]`` in a storage dtype and, where that
+dtype is int8, ``k_scale`` / ``v_scale`` ``[layers, blocks, block_size]``
+(one fp32 scale per written column). This module owns every decision about
+that format: the trailing axes (:func:`kv_tail`), the storage dtypes and
+their scales (:data:`KV_DTYPES`, :func:`quantize_kv`), the sentinel block id
+(``blocks``, one past the last) that clips on a read and drops on a write,
+and the forms of a read through a table, a block write and a column write
+that the chip's compiler answers IN PLACE, not with a copy of the pool.
+
+Both model families (``models/gpt.py``, ``models/afmoe.py``) read a layer's
+rows through :func:`layer_rows`; the serving engine's programs
+(``serving/paged_programs.py``) read and write whole blocks and columns
+through the functions below it. Pure functions of a pool and indices: what
+the engine's closures once took from their enclosing scope is read off the
+pool itself (``layers`` and ``blocks`` are ``pool["k"].shape[:2]``, the tail
+is ``pool["k"].shape[3:]``, an int8 pool is the one that has ``k_scale``).
+Nothing here imports the rest of the package; the host's bookkeeping (free
+list, refcounts, tables) is :mod:`~sparkdl_tpu.serving.kv_blocks`'s.
+"""
+
+from __future__ import annotations
+
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+#: lanes of a TPU vector tile: a minor axis that is no multiple of it is
+#: padded to one by the chip, or loses the minor place to an axis that is
+LANE_TILE = 128
+
+#: Supported pool storage layouts: "fp32" stores at the model's compute
+#: dtype (exact, the default), "bf16"/"int8" compress the resident pool
+#: (compute still runs at the model dtype; see :func:`quantize_kv`).
+KV_DTYPES = ("fp32", "bf16", "int8")
+
+
+# -- the trailing axes --------------------------------------------------------
+
+def kv_tail(kv_heads: int, head_dim: int) -> "tuple[int, ...]":
+    """The trailing axes of a token's K (or V) in a block pool, chosen from
+    the head size alone. A head that fills whole lane tiles keeps its own
+    axis, ``(kv_heads, head_dim)``. One that does not (GPT-2's 64) would
+    leave the chip no minor axis that tiles, and the chip then puts the
+    BLOCK axis in the lanes (PERF.md section 5): its heads are stored side
+    by side on ONE axis, ``(kv_heads * head_dim,)`` with zero columns up to
+    the next whole tile (1600 -> 1664: unpadded, the chip still takes the
+    block axis). A family's module takes the pool in either shape."""
+    if head_dim % LANE_TILE == 0:
+        return (kv_heads, head_dim)
+    return (-(-kv_heads * head_dim // LANE_TILE) * LANE_TILE,)
+
+
+def kv_stored(x, tail: "tuple[int, ...]"):
+    """K or V ``[..., kv_heads, head_dim]`` in a pool's trailing shape
+    ``[..., *tail]`` (storage only: the pad of a merged axis is zeros)."""
+    if len(tail) == 2:
+        return x
+    x = x.reshape(*x.shape[:-2], -1)
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, tail[0] - x.shape[-1])])
+
+
+def kv_per_head(x, kv_heads: int, head_dim: int):
+    """The inverse of :func:`kv_stored`: ``[..., kv_heads, head_dim]`` of
+    what a pool stores, whichever trailing shape it keeps."""
+    if x.shape[-2:] == (kv_heads, head_dim):
+        return x
+    return x[..., :kv_heads * head_dim].reshape(
+        *x.shape[:-1], kv_heads, head_dim)
+
+
+# -- the pool, and its storage dtypes -----------------------------------------
+
+def init_block_pool(config, n_blocks: int,
+                    block_size: int, dtype: str = "fp32") -> dict:
+    """Zeroed block-paged KV pool for continuous serving
+    (``serving.kv_blocks``): k/v stacked over layers,
+    ``[num_layers, n_blocks, block_size, *kv_tail]``, the trailing axes
+    :func:`kv_tail` of the family's heads (``config.serving_family()``), so
+    that the chip keeps layers and blocks major and a block's bytes
+    together (GPT-2 XL: ``{3,2,1,0:T(8,128)(2,1)}``).
+
+    Unlike ``models.gpt.init_cache`` (one dense row per batch slot), the
+    pool's capacity is ``n_blocks x block_size`` TOKENS shared by every
+    slot through a block table; which blocks are free, shared or cached is
+    :class:`~sparkdl_tpu.serving.kv_blocks.KVBlockPool`'s to say.
+
+    ``dtype`` picks the STORAGE layout (:data:`KV_DTYPES`):
+
+    - ``"fp32"`` — store at the model's compute dtype (``config.dtype``),
+      the exact layout; gather/scatter are plain copies.
+    - ``"bf16"`` — store bfloat16, dequantize to the compute dtype on
+      gather: half the pool bytes per token.
+    - ``"int8"`` — store int8 with one fp32 scale per written COLUMN
+      (``k_scale``/``v_scale``, ``[num_layers, n_blocks, block_size]``,
+      riding the block structure): ~4x fewer pool bytes per token, by the
+      rule of :func:`quantize_kv` / :func:`dequantize_kv`. Compute always
+      runs at ``config.dtype``; only the resident pool is compressed.
+    """
+    fam = config.serving_family()
+    shape = (fam.layers, n_blocks, block_size) + fam.kv_tail
+    store = {"fp32": fam.dtype, "bf16": jnp.bfloat16,
+             "int8": jnp.int8}.get(dtype)
+    if store is None:
+        raise ValueError(
+            f"unknown KV pool dtype {dtype!r} ({' | '.join(KV_DTYPES)})")
+    pool = {
+        "k": jnp.zeros(shape, store),
+        "v": jnp.zeros(shape, store),
+    }
+    if dtype == "int8":
+        pool["k_scale"] = jnp.zeros(shape[:3], jnp.float32)
+        pool["v_scale"] = jnp.zeros(shape[:3], jnp.float32)
+    return pool
+
+
+def quantize_kv(x: jax.Array,
+                tail: int = 1) -> "tuple[jax.Array, jax.Array]":
+    """Symmetric per-column int8 quantization of K/V columns.
+
+    ``x`` is ``[..., C]``, a token's K or V on the pool's one merged axis
+    (any leading index shape; ``tail`` trailing axes make a column where a
+    pool keeps more than one, ``[..., H, D]``); returns ``(int8 values,
+    fp32 scales[...])`` with one scale per column — the absmax maps to
+    ±127, so requantize(dequantize(q, s)) == (q, s) exactly (the property
+    that makes copy-on-write prefix sharing lossless under int8: a
+    gathered-then-reinstalled block is bit-identical to its donor). Zero
+    columns get a tiny floor scale and quantize to zero; the merged axis's
+    zero pad stays zero.
+    """
+    axes = tuple(range(-tail, 0))
+    amax = jnp.max(jnp.abs(x), axis=axes)
+    scale = (jnp.maximum(amax, 1e-30) / 127.0).astype(jnp.float32)
+    q = jnp.round(x.astype(jnp.float32) / jnp.expand_dims(scale, axes))
+    return jnp.clip(q, -127, 127).astype(jnp.int8), scale
+
+
+def dequantize_kv(q: jax.Array, scale: jax.Array,
+                  dtype: Any = jnp.float32) -> jax.Array:
+    """Inverse of :func:`quantize_kv`: int8 ``[..., C]`` columns (or
+    ``[..., H, D]``: the axes the scales lack) and their per-column scales
+    back to ``dtype``."""
+    axes = tuple(range(scale.ndim - q.ndim, 0))
+    return (q.astype(jnp.float32)
+            * jnp.expand_dims(scale, axes)).astype(dtype)
+
+
+def stored_as(pool: dict, name: str, vals: jax.Array) -> dict:
+    """THE quantize-on-write rule, the one every pool write applies (so
+    column writes and installs can never desynchronize): what K/V values
+    (compute dtype, the pool's trailing axes) become in the pool, by array
+    name. int8 stores values + their per-column scales; bf16/fp32 a cast."""
+    if name + "_scale" in pool:
+        q, s = quantize_kv(vals, pool[name].ndim - 3)
+        return {name: q, name + "_scale": s}
+    return {name: vals.astype(pool[name].dtype)}
+
+
+# -- the one read through a table ---------------------------------------------
+
+def layer_rows(cache: dict, layer: int, table: jax.Array,
+               dtype: Any) -> "tuple[jax.Array, jax.Array]":
+    """One layer's K and V of a pool as per-slot rows ``[S, entries *
+    block_size, *tail]``, in the shape they are stored in, through the
+    table entries ``table`` ``[S, entries]`` (the live head of the block
+    table, or a window layer's sub-table).
+
+    ``cache`` holds the pool's arrays by name (a family's paged cache is
+    the pool plus ``table`` and ``idx``). ONE gather over (layer, block),
+    ``pool[layer, table]`` -> ``[S, entries, block_size, *tail]``, read
+    where the pool lies: no layer's slab is sliced out first (a copy of
+    that share of the pool a layer, and the pool would lose the layout it
+    is stored in), then rows by merging major axes, which moves nothing;
+    that slice alone is dequantized to ``dtype`` (the rule of
+    :func:`dequantize_kv`; a bf16 pool is cast). Entries past the pool (the
+    table's sentinel) clip to the layer's last block, whose columns the
+    caller's masks hide.
+    """
+    at = (jnp.full_like(table, layer),
+          jnp.minimum(table, cache["k"].shape[1] - 1))
+
+    def rows(name):
+        x = cache[name][at]
+        scale = cache.get(name + "_scale")
+        x = (x.astype(dtype) if scale is None
+             else dequantize_kv(x, scale[at], dtype))
+        return x.reshape(table.shape[0], table.shape[1] * x.shape[2],
+                         *x.shape[3:])
+
+    return rows("k"), rows("v")
+
+
+# -- whole blocks, read and written -------------------------------------------
+
+def gather_blocks(pool: dict, ids: jax.Array) -> dict:
+    """Every array's blocks ``ids`` in storage dtype, ``[layers, len(ids),
+    block, ...]``: ONE gather over (layer, block), read where the pool lies
+    (sliced by layer first, the compiler copies the pool to another layout:
+    1.4 GB of temporaries in a one-chunk prefill at 2.7 GB). A sentinel id
+    clips to the last block."""
+    layers, blocks = pool["k"].shape[:2]
+    at = (jnp.arange(layers)[:, None],
+          jnp.minimum(ids, blocks - 1)[None, :])
+    return {name: a[at] for name, a in pool.items()}
+
+
+def gather_blocks_as(pool: dict, ids: jax.Array,
+                     dtype: Any) -> "tuple[jax.Array, jax.Array]":
+    """Blocks ``ids`` of K and V -> the compute dtype ``dtype``."""
+    raw = gather_blocks(pool, ids)
+    if "k_scale" in pool:
+        return tuple(
+            dequantize_kv(raw[name], raw[name + "_scale"], dtype)
+            for name in ("k", "v"))
+    return raw["k"].astype(dtype), raw["v"].astype(dtype)
+
+
+def write_blocks(pool: dict, ids: jax.Array, vals: dict) -> dict:
+    """Whole blocks ``vals`` (by array name, storage dtype, ``[layers,
+    len(ids), block, ...]``) into the DONATED pool, one at a time and in
+    place. As ONE scatter the compiler re-lays the pool out and back (two
+    copies of each of K and V an install: 16 ms and 1.4 GB of temporaries
+    at 2.7 GB). A sentinel id rewrites what is there — no block
+    corrupted."""
+    blocks = pool["k"].shape[1]
+    live = ids < blocks
+    blk = jnp.minimum(ids, blocks - 1)
+
+    def body(i, pool):
+        out = dict(pool)
+        for name, x in vals.items():
+            new = lax.dynamic_slice_in_dim(x, i, 1, axis=1)
+            at = (0, blk[i]) + (0,) * (new.ndim - 2)
+            old = lax.dynamic_slice(pool[name], at, new.shape)
+            out[name] = lax.dynamic_update_slice(
+                pool[name], jnp.where(live[i], new, old), at)
+        return out
+
+    return lax.fori_loop(0, ids.shape[0], body, pool)
+
+
+def write_kv_blocks(pool: dict, ids: jax.Array, newk: jax.Array,
+                    newv: jax.Array) -> dict:
+    """Whole blocks of K and V at the compute dtype into the pool (the
+    prefill install, the sp and disagg handoffs)."""
+    return write_blocks(pool, ids, {
+        **stored_as(pool, "k", newk),
+        **stored_as(pool, "v", newv)})
+
+
+# -- columns, written ---------------------------------------------------------
+
+def scatter_columns(pool: dict, blk: jax.Array, off: jax.Array,
+                    newk: jax.Array, newv: jax.Array) -> dict:
+    """Freshly written columns (``[layers, *blk.shape, *tail]``; blk/off
+    share any index shape: ``[S]`` decode, ``[S, k]`` verify) into the
+    DONATED pool, in place. Sentinel blocks write nothing — no block
+    corrupted."""
+    layers, blocks = pool["k"].shape[:2]
+    cols = {**stored_as(pool, "k", newk),
+            **stored_as(pool, "v", newv)}
+    if pool["k"].ndim == 4:
+        # the merged axis: ONE scatter a pool array, indexed by (layer, block,
+        # offset) with a column of ``tail`` the window. (With the layer axis
+        # left a slice, ``.at[:, blk, off]``, the window spans the layers and
+        # the chip's compiler re-lays the whole pool out with the layers in the
+        # sublanes and back: seen in the compiled text, PERF.md section 6.)
+        at = (jnp.arange(layers).reshape(
+            (-1,) + (1,) * blk.ndim), blk[None], off[None])
+        return {**pool, **{
+            name: pool[name].at[at].set(vals, mode="drop")
+            for name, vals in cols.items()}}
+    # a pool that keeps heads and head size apart (a head fills a lane tile) is
+    # written as PR 29 measured it: its columns go in one at a time, a loop of
+    # dynamic-update-slices that carries the pool (the sliced scatter copied
+    # that whole pool; the indexed one above compiles in place for it too but
+    # has not been measured on its cell, ROADMAP A12)
+    cols = {name: vals.reshape(
+                (layers, -1) + vals.shape[1 + blk.ndim:])
+            for name, vals in cols.items()}
+    blk, off = blk.reshape(-1), off.reshape(-1)
+    live = blk < blocks
+    blk = jnp.minimum(blk, blocks - 1)
+
+    def body(c, pool):
+        out = dict(pool)
+        for name, vals in cols.items():
+            col = lax.dynamic_slice_in_dim(
+                vals, c, 1, axis=1)[:, :, None]
+            at = (0, blk[c], off[c]) + (0,) * (col.ndim - 3)
+            old = lax.dynamic_slice(pool[name], at, col.shape)
+            out[name] = lax.dynamic_update_slice(
+                pool[name], jnp.where(live[c], col, old), at)
+        return out
+
+    return lax.fori_loop(0, blk.shape[0], body, pool)

@@ -177,7 +177,7 @@ GENERIC_RULES: "tuple[tuple[str, P], ...]" = (
 )
 
 #: Sequence-axis placement for the paged KV BLOCK POOL (ISSUE 13):
-#: matched over an ``init_block_pool`` tree, the k/v pool arrays
+#: matched over a ``models.kv_pool.init_block_pool`` tree, the k/v arrays
 #: ``[layers, n_blocks, block_size, *kv_tail]`` (and the int8 per-column
 #: scale arrays ``[layers, n_blocks, block_size]``) shard their BLOCK
 #: axis on ``sp`` — contiguous shards, so virtual block id ``b`` lives

@@ -20,29 +20,35 @@ batching is a scheduling decision, never a quality decision.
 Decode is greedy (temperature 0), the deterministic serving default;
 sampled decode stays on the lockstep ``DeepTextGenerator`` path.
 
-KV layouts (``kv_layout=``, ROADMAP item 4):
+Three parts, one to a module, the arrows pointing one way:
 
-- ``"paged"`` (default) — block-paged KV pool
-  (:mod:`~sparkdl_tpu.serving.kv_blocks`): each slot maps its columns
-  onto refcounted ``block_size``-token blocks through a block table.
-  The jitted decode step hands the model the pool and the table's live
-  head as a PAGED cache (models/gpt.py): each layer gathers only its
-  own live blocks, one layer at a time, and the step writes the new
-  column of every row into the donated pool in place, in the layout
-  the device stores it in — no dense view over all layers, no copy of
-  the pool on any tick — so persistent KV memory is bounded by
-  allocated tokens, not ``n_slots x max_len``. Admission
-  against an exhausted pool DEFERS (re-queues in order) instead of
-  erroring. Prompts are prefilled right-aligned in bounded CHUNKS
-  (``prefill_chunk`` tokens per engine tick, interleaved with decode
-  ticks — a long prompt no longer freezes in-flight decode latency),
-  and a radix prefix cache
-  (:mod:`~sparkdl_tpu.serving.prefix_cache`) lets a request reuse the
-  cached K/V of its longest shared prompt prefix and prefill only the
-  suffix (partial tail blocks shared copy-on-write). Greedy tokens
-  stay oracle-identical on every path (tests/serving/test_kv_paged.py).
-- ``"dense"`` — the original one-dense-buffer-per-slot layout, kept as
-  the parity oracle and fallback.
+- the POOL'S DEVICE FORMAT, :mod:`sparkdl_tpu.models.kv_pool`: how a
+  token's K and V lie in the block pool (trailing axes, storage dtype and
+  scales, the sentinel id) and the reads and writes of it that the chip
+  compiles in place;
+- the DEVICE PROGRAMS, :mod:`sparkdl_tpu.serving.paged_programs`: the
+  decode step, the verify pass, the four chunk programs, the tier and
+  handoff copies, as named functions of (sizes, module, arrays);
+- the SCHEDULER, this module: queue, slots, host block tables
+  (:mod:`~sparkdl_tpu.serving.kv_blocks`), prefix cache, admission and
+  retirement. ``__init__`` binds each program to a ``jax.jit`` handle and
+  defines none.
+
+KV layouts (``kv_layout=``): ``"paged"`` (default) maps each slot's columns
+onto refcounted ``block_size``-token blocks through a block table; the
+decode step hands the model the pool and the table's live head as a PAGED
+cache, so persistent KV memory is bounded by allocated tokens, not
+``n_slots x max_len``. Admission against an exhausted pool DEFERS
+(re-queues in order) instead of erroring. Prompts are prefilled
+right-aligned in bounded CHUNKS (``prefill_chunk`` tokens per engine tick,
+interleaved with decode ticks — a long prompt no longer freezes in-flight
+decode latency), and a radix prefix cache
+(:mod:`~sparkdl_tpu.serving.prefix_cache`) lets a request reuse the cached
+K/V of its longest shared prompt prefix and prefill only the suffix
+(partial tail blocks shared copy-on-write). Greedy tokens stay
+oracle-identical on every path (tests/serving/test_kv_paged.py).
+``"dense"`` is the original one-dense-buffer-per-slot layout, kept as the
+parity suites' reference (ROADMAP names the condition under which it goes).
 
 Speculative multi-token decoding (``spec_k=``, ROADMAP item 3): a
 draft source (:mod:`~sparkdl_tpu.serving.spec_decode` — radix-trie
@@ -65,15 +71,11 @@ state to fall back to — it propagates like any decode-dispatch error
 (the engine loop fails every pending Future loudly rather than serving
 from a consumed cache).
 
-Quantized KV blocks (``kv_dtype=``): the paged pool can store
-``"bf16"`` or ``"int8"`` (one fp32 scale per written column) instead
-of the compute dtype — quantize-on-write / dequantize-on-gather are
-fused into the paged programs (decode dequantizes one layer's gathered
-slice at a time, never the pool), so pool
-capacity (and deferred-admission pressure) improves 2-4x
-(:func:`~sparkdl_tpu.serving.kv_blocks.kv_capacity_ratio`) while
-compute still runs at the model dtype; bench_serving's dense-vs-paged
-parity harness measures the quality trade.
+Quantized KV blocks (``kv_dtype=``): the paged pool can store ``"bf16"``
+or ``"int8"`` (one fp32 scale per written column) instead of the compute
+dtype, 2-4x the capacity
+(:func:`~sparkdl_tpu.serving.kv_blocks.kv_capacity_ratio`); the rule is
+``kv_pool``'s and compute still runs at the model dtype.
 
 Sequence-parallel prefill (``sp=``, ROADMAP item 2): with ``sp=N``
 the chunked prefill becomes SPATIAL — each chunk dispatches across N
@@ -89,9 +91,8 @@ greedy tokens stay bitwise across sp∈{1,2} on every decode mode. An
 injected collective fault (``sp.permute``/``sp.gather``) re-queues the
 victim request instead of failing it (:class:`SpCollectiveError` in
 the flight ring). README "Long-context serving" has the sizing
-arithmetic; PERF.md the measured trade (sp=2 prefill 2.26x at 3072
-prompt tokens on the CPU harness — and a measured LOSS below ~1k
-tokens, where the per-chunk fixed costs beat the query split).
+arithmetic; nothing across chips has been measured on the chip (PERF.md
+section 7).
 """
 
 from __future__ import annotations
@@ -103,14 +104,23 @@ import time
 from concurrent.futures import Future
 from typing import Any, Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
+from sparkdl_tpu.models.gpt import init_cache
+from sparkdl_tpu.models.kv_pool import init_block_pool
 from sparkdl_tpu.observability import flight as flight_mod
 from sparkdl_tpu.observability import slo as slo_mod
 from sparkdl_tpu.observability import tracing
 from sparkdl_tpu.observability.registry import registry
 from sparkdl_tpu.observability.tracing import span
 from sparkdl_tpu.reliability.faults import fault_point
+from sparkdl_tpu.runtime.batching import (
+    default_buckets,
+    pick_bucket,
+    pow2_bucket,
+)
 from sparkdl_tpu.runtime.chip import alike_layers_options, watch_compiles
 from sparkdl_tpu.runtime.completion import start_fetch
 from sparkdl_tpu.runtime.dispatch import (
@@ -118,14 +128,32 @@ from sparkdl_tpu.runtime.dispatch import (
     SpecPolicy,
     record_dispatch,
 )
+from sparkdl_tpu.serving import kv_tiers as kv_tiers_mod
+from sparkdl_tpu.serving import paged_programs as programs
 from sparkdl_tpu.serving import tenancy
-from sparkdl_tpu.serving.metrics import ServingMetrics
+from sparkdl_tpu.serving.kv_blocks import (
+    KVBlockPool,
+    SeqShardedBlockPool,
+    kv_bytes_per_token,
+    kv_capacity_ratio,
+)
+from sparkdl_tpu.serving.metrics import (
+    EngineObservability,
+    ServingMetrics,
+    default_host_id,
+)
+from sparkdl_tpu.serving.paged_programs import bound
+from sparkdl_tpu.serving.prefix_cache import PrefixCache
 from sparkdl_tpu.serving.queue import (
     DeadlineExceededError,
     EngineClosedError,
     Request,
     RequestQueue,
     record_request_failure,
+)
+from sparkdl_tpu.serving.spec_decode import (
+    default_draft_source,
+    greedy_accept,
 )
 
 
@@ -288,35 +316,20 @@ class ContinuousGPTEngine:
     single-step tests; the default runs the loop on a daemon thread.
 
     ``chain_tokens`` fuses up to k decode steps into ONE device dispatch
-    (``lax.scan`` over the donated cache — runtime/dispatch.py): a
-    decode step is tiny next to the per-dispatch gap, so the unchained
-    loop pays a full dispatch *per generated token*. Chaining trades
-    admission/retirement granularity (checks run every k tokens, not
-    every token) for k-fold dispatch amortization; k is re-bounded every
-    tick by the smallest remaining token budget in flight (the earliest
-    possible retirement — nothing is decoded past it) and by the
-    tightest in-flight deadline over the measured per-token time, so
-    p99 latency does not regress. Greedy tokens are identical at any k.
-    None = auto-calibrate from the dispatch gap; 1 (default) = one
-    token per dispatch, the exact pre-chaining tick semantics.
+    (``lax.scan`` over the donated cache — runtime/dispatch.py),
+    trading admission/retirement granularity (checks run every k tokens)
+    for k-fold dispatch amortization; k is re-bounded every tick by the
+    remaining budgets and deadlines in flight (:meth:`_bounded_tokens`).
+    Greedy tokens are identical at any k. None = auto-calibrate from the
+    dispatch gap; 1 (default) = one token per dispatch.
 
-    ``sp`` (paged layout; pin via ``SPARKDL_TPU_SP``) spreads each
-    prefill chunk across that many chips and stages the prompt's K/V
-    in a sequence-sharded pool (``sp_kv_blocks`` sizes it; default =
-    the decode pool rounded up to divide ``sp``). Power of two, at
-    most the visible device count. Decode is untouched: one handoff
-    gather per admission. None/1 (default) = off.
-
-    ``spec_k`` (paged layout) turns on speculative decoding: up to
-    ``spec_k - 1`` draft tokens per slot (from ``draft_source``,
-    default radix-trie + n-gram — :mod:`serving.spec_decode`) are
-    verified by one L=k target-model dispatch; accepted tokens are
-    bitwise-identical to plain decode, and the verify width shrinks
-    under the same budget/deadline caps as ``chain_tokens`` plus the
-    measured acceptance rate. None (default) = off. ``kv_dtype``
-    ("fp32" | "bf16" | "int8") picks the paged pool's storage layout;
-    quantize/dequantize are fused into the paged programs and compute
-    stays at the model dtype.
+    The module docstring has the mechanisms of the rest: ``sp`` (paged
+    layout; pin via ``SPARKDL_TPU_SP``; a power of two, at most the
+    visible device count; ``sp_kv_blocks`` sizes the staging pool,
+    default = the decode pool rounded up to divide ``sp``; None/1 =
+    off), ``spec_k`` (paged layout; up to ``spec_k - 1`` drafts a slot
+    from ``draft_source``, default radix-trie + n-gram; None = off) and
+    ``kv_dtype`` ("fp32" | "bf16" | "int8", the paged pool's storage).
     """
 
     @_with_init_span
@@ -342,13 +355,6 @@ class ContinuousGPTEngine:
                  tenants: "tenancy.TenantRegistry | None" = None,
                  host_id: "str | None" = None,
                  auto_start: bool = True):
-        import jax
-        import jax.numpy as jnp
-        from jax import lax
-
-        from sparkdl_tpu.models.gpt import init_block_pool, init_cache
-        from sparkdl_tpu.runtime.batching import default_buckets
-
         if n_slots < 1:
             raise ValueError(f"n_slots must be >= 1, got {n_slots}")
         if chain_tokens is not None and chain_tokens < 1:
@@ -419,7 +425,6 @@ class ContinuousGPTEngine:
                 "its native K/V dtype alone: kv_layout='dense', sp > 1, "
                 "spec_k and kv_dtype are not implemented for this family"
             )
-        from sparkdl_tpu.serving.metrics import default_host_id
 
         self.config = config
         self._family = fam
@@ -477,15 +482,7 @@ class ContinuousGPTEngine:
         self._thread: threading.Thread | None = None
 
         model = self._model
-
         if kv_layout == "paged":
-            from sparkdl_tpu.models.gpt import dequantize_kv, quantize_kv
-            from sparkdl_tpu.serving.kv_blocks import KVBlockPool
-            from sparkdl_tpu.serving.prefix_cache import PrefixCache
-            from sparkdl_tpu.serving.spec_decode import (
-                default_draft_source,
-            )
-
             if kv_block_size < 1:
                 raise ValueError(
                     f"kv_block_size must be >= 1, got {kv_block_size}")
@@ -538,12 +535,10 @@ class ContinuousGPTEngine:
             #: it; the sp staging branch points it at _sp_pool)
             self._defer_pool = self._pool
             if host_kv_blocks is not None:
-                from sparkdl_tpu.serving.kv_tiers import TieredKVStore
-
                 # disk overflow may only drop trie LEAVES — dropping
                 # an interior parked node would orphan its (parked)
                 # descendants' payloads
-                self._kv_tiers = TieredKVStore(
+                self._kv_tiers = kv_tiers_mod.TieredKVStore(
                     host_kv_blocks, disk_kv_blocks or 0,
                     spill_dir=kv_spill_dir,
                     is_droppable=lambda node: not node.children)
@@ -551,6 +546,9 @@ class ContinuousGPTEngine:
                                        tiers=self._kv_tiers)
             self._draft = (draft_source if draft_source is not None
                            else default_draft_source(self._prefix))
+            # the device pool, shaped and stored as models/kv_pool.py says:
+            # the only compressed tensor (the programs below compute, and
+            # keep their private prefill caches, at the model dtype)
             self._pool_kv = init_block_pool(config, kv_blocks, bs_kv,
                                             dtype=kv_dtype)
             # block tables: one row per slot, sentinel (= kv_blocks)
@@ -558,427 +556,64 @@ class ContinuousGPTEngine:
             self._table = np.full((n_slots, mb), self._pool.sentinel,
                                   np.int32)
             self._pidx = np.zeros((n_slots,), np.int32)
-            # A token's K or V in the pool is shaped as the family stores
-            # it (ServingFamily.kv_tail): ONE merged axis of heads x head
-            # size where a head is no whole lane tile (GPT-2's 25 x 64,
-            # padded to 1664), heads and head size apart where it is
-            # (afmoe's 4 x 128). Either way the chip keeps layers and
-            # blocks major and a block's bytes together, so every pool
-            # program below indexes (layer, block, offset) and carries the
-            # trailing axes as it finds them.
-            n_layers, nh, hd = fam.layers, fam.kv_heads, fam.head_dim
-            tail = fam.kv_tail
-            max_pos = (fam.max_positions - 1
-                       if fam.max_positions is not None else wp + chunk)
-            cdt = fam.dtype
-
-            # The dtype boundary, fused into every paged program: the
-            # pool is the only compressed tensor — compute (attention,
-            # private prefill caches) always runs at the model dtype.
-            # int8 carries one fp32 scale per written column
-            # (models.gpt.quantize_kv), riding the block structure in
-            # pool["k_scale"]/["v_scale"].
-            def _raw_gather(pool, ids):
-                # every array's blocks ``ids`` in storage dtype,
-                # ``[layers, len(ids), block, ...]``: ONE gather over
-                # (layer, block), read where the pool lies (sliced by
-                # layer first, the compiler copies the pool to another
-                # layout: 1.4 GB of temporaries in a one-chunk prefill at
-                # 2.7 GB). A sentinel id clips to the last block.
-                at = (jnp.arange(n_layers)[:, None],
-                      jnp.minimum(ids, kv_blocks - 1)[None, :])
-                return {name: a[at] for name, a in pool.items()}
-
-            def _dq_gather(pool, ids):
-                # blocks ``ids`` of K and V -> compute dtype
-                raw = _raw_gather(pool, ids)
-                if kv_dtype == "int8":
-                    return tuple(
-                        dequantize_kv(raw[name], raw[name + "_scale"], cdt)
-                        for name in ("k", "v"))
-                return raw["k"].astype(cdt), raw["v"].astype(cdt)
-
-            def _stored_as(pool, name, vals):
-                # THE quantize-on-write rule, the one every pool write
-                # applies (so column writes and installs can never
-                # desynchronize): what K/V values (compute dtype, the
-                # pool's trailing axes) become in the pool, by array
-                # name. int8 stores values + their per-column scales;
-                # bf16/fp32 a cast.
-                if kv_dtype == "int8":
-                    q, s = quantize_kv(vals, len(tail))
-                    return {name: q, name + "_scale": s}
-                return {name: vals.astype(pool[name].dtype)}
-
-            def _write_blocks(pool, ids, vals):
-                # whole blocks ``vals`` (by array name, storage dtype,
-                # ``[layers, len(ids), block, ...]``) into the DONATED
-                # pool, one at a time and in place. As ONE scatter the
-                # compiler re-lays the pool out and back (two copies of
-                # each of K and V an install: 16 ms and 1.4 GB of
-                # temporaries at 2.7 GB). A sentinel id rewrites what is
-                # there — no block corrupted.
-                live = ids < kv_blocks
-                blk = jnp.minimum(ids, kv_blocks - 1)
-
-                def body(i, pool):
-                    out = dict(pool)
-                    for name, x in vals.items():
-                        new = lax.dynamic_slice_in_dim(x, i, 1, axis=1)
-                        at = (0, blk[i]) + (0,) * (new.ndim - 2)
-                        old = lax.dynamic_slice(pool[name], at, new.shape)
-                        out[name] = lax.dynamic_update_slice(
-                            pool[name], jnp.where(live[i], new, old), at)
-                    return out
-
-                return lax.fori_loop(0, ids.shape[0], body, pool)
-
-            def _q_write(pool, ids, newk, newv):
-                # whole blocks of K and V at the compute dtype into the
-                # pool (the prefill install, the sp and disagg handoffs)
-                return _write_blocks(pool, ids, {
-                    **_stored_as(pool, "k", newk),
-                    **_stored_as(pool, "v", newv)})
-
-            def _q_scatter(pool, blk, off, newk, newv):
-                # freshly written columns ([layers, *blk.shape, *tail];
-                # blk/off share any index shape: [S] decode, [S,k]
-                # verify) into the DONATED pool, in place. Sentinel
-                # blocks write nothing — no block corrupted.
-                cols = {**_stored_as(pool, "k", newk),
-                        **_stored_as(pool, "v", newv)}
-                if len(tail) == 1:
-                    # the merged axis: ONE scatter a pool array, indexed
-                    # by (layer, block, offset) with a column of ``tail``
-                    # the window. (With the layer axis left a slice,
-                    # ``.at[:, blk, off]``, the window spans the layers
-                    # and the chip's compiler re-lays the whole pool out
-                    # with the layers in the sublanes and back: seen in
-                    # the compiled text, PERF.md section 6.)
-                    at = (jnp.arange(n_layers).reshape(
-                        (-1,) + (1,) * blk.ndim), blk[None], off[None])
-                    return {**pool, **{
-                        name: pool[name].at[at].set(vals, mode="drop")
-                        for name, vals in cols.items()}}
-                # a pool that keeps heads and head size apart (a head
-                # fills a lane tile) is written as PR 29 measured it: its
-                # columns go in one at a time, a loop of
-                # dynamic-update-slices that carries the pool (the sliced
-                # scatter copied that whole pool; the indexed one above
-                # compiles in place for it too but has not been measured
-                # on its cell, ROADMAP C1)
-                cols = {name: vals.reshape(
-                            (n_layers, -1) + vals.shape[1 + blk.ndim:])
-                        for name, vals in cols.items()}
-                blk, off = blk.reshape(-1), off.reshape(-1)
-                live = blk < kv_blocks
-                blk = jnp.minimum(blk, kv_blocks - 1)
-
-                def body(c, pool):
-                    out = dict(pool)
-                    for name, vals in cols.items():
-                        col = lax.dynamic_slice_in_dim(
-                            vals, c, 1, axis=1)[:, :, None]
-                        at = (0, blk[c], off[c]) + (0,) * (col.ndim - 3)
-                        old = lax.dynamic_slice(pool[name], at, col.shape)
-                        out[name] = lax.dynamic_update_slice(
-                            pool[name], jnp.where(live[c], col, old), at)
-                    return out
-
-                return lax.fori_loop(0, blk.shape[0], body, pool)
-
-            @functools.partial(jax.jit, donate_argnums=(1,),
-                               static_argnums=(5, 6))
-            def _paged_step(variables, pool, table, idx, tok, k, nb):
-                # k tokens for every slot THROUGH the block table: the
-                # model takes the pool itself as a paged cache (a
-                # ``table`` entry, models/gpt.py), so each layer gathers
-                # only its own live blocks into an [S, nb*bs] slice —
-                # same math over the same width as the dense layout, so
-                # greedy tokens stay bitwise-identical — and hands back
-                # the one new column per row, which is the ONLY write to
-                # the donated pool (in place, at (block, offset)): no
-                # all-layer dense view, no copy of the pool. ``nb``
-                # (static, bucketed) is the block count covering the
-                # DEEPEST live row through this chain — the gather and
-                # attention touch only the live head of the table, often
-                # FEWER columns than the dense layout's fixed max_len
-                # (masked-width invariance keeps tokens bitwise). Rows
-                # are right-aligned (no left pad: column i holds real
-                # token i), so the causal mask alone masks garbage
-                # columns and positions need no start offset. Sentinel
-                # table entries clip on gather (masked garbage) and write
-                # nothing (_q_scatter: no block corrupted).
-                sub = table[:, :nb]
-
-                def body(carry, _):
-                    pool, idx, tok = carry
-                    logits, new = model.apply(
-                        variables, tok[:, None],
-                        cache=dict(pool, table=sub, idx=idx),
-                    )
-                    ntok = jnp.argmax(logits[:, -1], axis=-1)
-                    rows = jnp.arange(n_slots)
-                    blk = table[rows, idx // bs_kv]
-                    off = idx % bs_kv
-                    pool = _q_scatter(pool, blk, off,
-                                      new["k"][:, :, 0], new["v"][:, :, 0])
-                    out = ntok
-                    if "expert_counts" in new:
-                        # rows each expert got, behind the step's tokens:
-                        # ONE array, so one device-to-host read a tick
-                        out = jnp.concatenate(
-                            [ntok.astype(jnp.int32),
-                             new["expert_counts"].reshape(-1)])
-                    return (pool, idx + 1, ntok), out
-
-                (pool, _, _), toks = lax.scan(
-                    body, (pool, idx, tok), None, length=k
-                )
-                return toks, pool
-
-            @functools.partial(jax.jit, donate_argnums=(1,),
-                               static_argnums=(5, 6))
-            def _paged_verify(variables, pool, table, idx, toks, k, nb):
-                # Speculative verify: score a k-token span for every
-                # slot in ONE dispatch. Column 0 of ``toks`` is each
-                # slot's current last token, columns 1.. its proposed
-                # drafts; the L=k per-slot step (models/gpt.py) writes
-                # all k columns at [idx[s], idx[s]+k) and the per-row
-                # causal mask conditions position j on the real context
-                # plus drafts [:j] — exactly the logits greedy
-                # acceptance needs, through the same paged cache as
-                # _paged_step so greedy tokens stay bitwise. Columns of
-                # REJECTED drafts scatter back as garbage PAST the
-                # accepted frontier (the host advances pidx only over
-                # accepted inputs): they sit causally masked until the
-                # next dispatch's own writes overwrite them — the same
-                # garbage-but-finite contract as retired-slot columns.
-                logits, new = model.apply(
-                    variables, toks,
-                    cache=dict(pool, table=table[:, :nb], idx=idx),
-                )
-                out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                rows = jnp.arange(n_slots)[:, None]
-                pos = idx[:, None] + jnp.arange(k)[None, :]
-                blk = table[rows, pos // bs_kv]
-                off = pos % bs_kv
-                return out, _q_scatter(pool, blk, off, new["k"], new["v"])
-
-            def _gathered(pool, ids):
-                # cached-prefix blocks -> the head of a private prefill
-                # cache (the copy that makes partial-block sharing
-                # copy-on-write: the sharer re-installs into blocks it
-                # owns, the donor block is never written). Sentinel ids
-                # clip to garbage the chunked prefill masks/overwrites.
-                # Quantized pools dequantize here: the private cache is
-                # compute-dtype, and the final install requantizes —
-                # an exact round trip (quantize_kv absmax maps to ±127),
-                # so a COW-shared block re-installs bit-identical to its
-                # donor.
-                pad = ((0, 0), (0, 0), (0, wp - w)) + ((0, 0),) * len(tail)
-                return tuple(
-                    jnp.pad(x.reshape((n_layers, 1, w) + tail), pad)
-                    for x in _dq_gather(pool, ids))
-
-            def _chunk_apply(variables, ck, cv, idx, ids, cols):
-                # one bounded prefill chunk, right-aligned: writes K/V
-                # at columns [idx, idx+width) of the private cache,
-                # where width = ids.shape[1] is the POWER-OF-2 BUCKET of
-                # this chunk's real token count (same compile-reuse
-                # trick as the dense path's prompt buckets: a 24-token
-                # suffix pays a 32-wide program, not a chunk-cap-wide
-                # one). ``cols`` (static, bucketed >= idx+width) bounds
-                # the attention to the LIVE head of the buffer — every
-                # column past it is causally masked garbage anyway, so
-                # slicing changes nothing but the wasted FLOPs. The tail
-                # of the chunk is zero-padded on the right; pad queries
-                # produce garbage columns PAST every real position, so
-                # the causal mask hides them until real writes overwrite
-                # them — no attention_mask needed (vs the dense path's
-                # left-pad masking).
-                positions = jnp.minimum(
-                    idx + jnp.arange(ids.shape[1])[None, :], max_pos)
-                cache = {"k": ck[:, :, :cols], "v": cv[:, :, :cols],
-                         "idx": idx}
-                logits, cache = model.apply(
-                    variables, ids, cache=cache, positions=positions,
-                )
-                ck = ck.at[:, :, :cols].set(cache["k"])
-                cv = cv.at[:, :, :cols].set(cache["v"])
-                return logits, ck, cv
-
-            def _installed(pool, ck, cv, ids):
-                # private prefill cache -> the slot's OWNED pool blocks
-                # (quantize-on-install rides the shared _stored_as rule).
-                # ids carries the sentinel at shared-prefix positions
-                # (their content already lives in the shared blocks) and
-                # past the covered span: those writes drop.
-                shape = (n_layers, mb, bs_kv) + tail
-                return _q_write(pool, ids, ck[:, 0, :w].reshape(shape),
-                                cv[:, 0, :w].reshape(shape))
-
-            # Four fused chunk programs so a prefill pays the minimum
-            # dispatch count (dispatch gap dominates small programs —
-            # the ISSUE 3 lesson applied to admission): the FIRST chunk
-            # fuses the prefix gather, the FINAL chunk fuses the block
-            # install, so a suffix that fits one chunk is ONE device
-            # dispatch end to end (vs dense's prefill + scatter pair).
-            # A chunk unrolls every layer: on the chip the layers share
-            # their code (alike_layers_options), or a program that
-            # installs is twenty times the size and loads as slowly.
-            chunk_jit = functools.partial(
-                jax.jit, compiler_options=alike_layers_options())
-
-            @functools.partial(chunk_jit, donate_argnums=(1,),
-                               static_argnums=(6,))
-            def _chunk_one(variables, pool, gids, idx, ids, inst, cols):
-                ck, cv = _gathered(pool, gids)
-                logits, ck, cv = _chunk_apply(
-                    variables, ck, cv, idx, ids, cols)
-                return logits, _installed(pool, ck, cv, inst)
-
-            @functools.partial(chunk_jit, static_argnums=(5,))
-            def _chunk_first(variables, pool, gids, idx, ids, cols):
-                ck, cv = _gathered(pool, gids)
-                return _chunk_apply(variables, ck, cv, idx, ids, cols)
-
-            @functools.partial(chunk_jit, donate_argnums=(1, 2),
-                               static_argnums=(5,))
-            def _chunk_mid(variables, ck, cv, idx, ids, cols):
-                return _chunk_apply(variables, ck, cv, idx, ids, cols)
-
-            # (ck/cv are deliberately NOT donated here or in _chunk_one:
-            # no output shares their shape, so donation could not alias
-            # — jax would warn "donated buffers were not usable" on
-            # every compile and free nothing earlier; they die on the
-            # host right after the call regardless)
-            @functools.partial(chunk_jit, donate_argnums=(1,),
-                               static_argnums=(7,))
-            def _chunk_final(variables, pool, ck, cv, idx, ids, inst,
-                             cols):
-                logits, ck, cv = _chunk_apply(
-                    variables, ck, cv, idx, ids, cols)
-                return logits, _installed(pool, ck, cv, inst)
-
-            @jax.jit
-            def _park_fetch(pool, ids):
-                # the D2H half of a park: the given blocks' RAW
-                # storage-dtype bytes (int8 codes + their scales, no
-                # dequantize) — raw is both the 4x cheaper transfer
-                # the quantized layout bought and what makes a resumed
-                # session bitwise-identical: unpark writes back the
-                # exact bytes decode would have read
-                return _raw_gather(pool, ids)
-
-            @functools.partial(jax.jit, donate_argnums=(0,))
-            def _unpark_install(pool, ids, payload):
-                # the H2D half of a resume: whole-block raw writes
-                # into freshly allocated blocks, in place (sentinel ids
-                # write nothing — same contract as every other pool
-                # write)
-                return _write_blocks(pool, ids, {
-                    name: vals.astype(pool[name].dtype)
-                    for name, vals in payload.items()})
-
-            self._paged_step_fn = _paged_step
-            self._paged_verify_fn = _paged_verify
-            self._chunk_one_fn = _chunk_one
-            self._chunk_first_fn = _chunk_first
-            self._chunk_mid_fn = _chunk_mid
-            self._chunk_final_fn = _chunk_final
-            self._park_fetch_fn = _park_fetch
-            self._unpark_install_fn = _unpark_install
-            # the sp handoff/prefix programs reuse the dtype boundary
-            self._dq_gather_fn = _dq_gather
-            self._q_write_fn = _q_write
+            # what the programs close over (serving/paged_programs.py):
+            # derived here, set by nobody
+            sizes = self._sizes = programs.PagedSizes(
+                n_slots=n_slots, block_size=bs_kv, mb=mb, w=w, wp=wp,
+                max_pos=(fam.max_positions - 1
+                         if fam.max_positions is not None else wp + chunk),
+                dtype=fam.dtype)
+            # One binding a program, under the function's own name (what
+            # the device trace shows and the benchmark reads). The jit
+            # calls stay in THIS file: sparkdl-lint's donation-safety rule
+            # learns the donating handles from them and checks that every
+            # call site below rebinds self._pool_kv.
+            self._paged_step_fn = jax.jit(
+                bound(programs._paged_step, sizes, model),
+                donate_argnums=(1,), static_argnums=(5, 6))
+            self._paged_verify_fn = jax.jit(
+                bound(programs._paged_verify, sizes, model),
+                donate_argnums=(1,), static_argnums=(5, 6))
+            alike = alike_layers_options()
+            self._chunk_one_fn = jax.jit(
+                bound(programs._chunk_one, sizes, model),
+                donate_argnums=(1,), static_argnums=(6,),
+                compiler_options=alike)
+            self._chunk_first_fn = jax.jit(
+                bound(programs._chunk_first, sizes, model),
+                static_argnums=(5,), compiler_options=alike)
+            self._chunk_mid_fn = jax.jit(
+                bound(programs._chunk_mid, sizes, model),
+                donate_argnums=(1, 2), static_argnums=(5,),
+                compiler_options=alike)
+            self._chunk_final_fn = jax.jit(
+                bound(programs._chunk_final, sizes, model),
+                donate_argnums=(1,), static_argnums=(7,),
+                compiler_options=alike)
+            self._park_fetch_fn = jax.jit(programs._park_fetch)
+            self._unpark_install_fn = jax.jit(
+                programs._unpark_install, donate_argnums=(0,))
+            self._install_blocks_fn = jax.jit(
+                programs._install_blocks, donate_argnums=(0,))
             if sp_val > 1:
                 self._init_sp(sp_val, sp_kv_blocks)
         else:
             self._cache = init_cache(
                 config, n_slots, max_len, per_slot=True)
             self._start = np.zeros((n_slots,), np.int32)
-
-        @jax.jit
-        def _prefill(variables, ids, mask):
-            # batch-1 left-padded prefill in a fresh scalar-idx cache of
-            # the SHARED buffer width, so columns line up at scatter time.
-            # jit's shape cache gives one compile per prompt-length bucket.
-            lp = ids.shape[1]
-            cache = init_cache(config, 1, max_len)
-            positions = jnp.clip(jnp.cumsum(mask, axis=1) - 1, 0)
-            key_valid = jnp.concatenate(
-                [mask.astype(bool),
-                 jnp.ones((1, max_len - lp), bool)], axis=1,
-            )
-            logits, cache = model.apply(
-                variables, ids, cache=cache, positions=positions,
-                attention_mask=key_valid,
-            )
-            return jnp.argmax(logits[:, -1], axis=-1), cache
-
-        # donate the cache through scatter and step: the engine always
-        # discards the old version, and without donation every token
-        # would materialize a second full [layers, S, max_len, H, D]
-        # buffer (2x HBM peak + a copy per token at serving sizes)
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def _scatter(cache, row, slot):
-            # install a prefilled row into slot (traced index: one compile)
-            return {
-                "k": jax.lax.dynamic_update_slice_in_dim(
-                    cache["k"], row["k"], slot, axis=1),
-                "v": jax.lax.dynamic_update_slice_in_dim(
-                    cache["v"], row["v"], slot, axis=1),
-                "idx": cache["idx"].at[slot].set(
-                    row["idx"].astype(jnp.int32)),
-            }
-
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        def _step(variables, cache, tok, start):
-            # one token for every slot; the per-slot cache gives each row
-            # its own causal depth, `start` masks its left-pad columns,
-            # and RoPE/learned positions count real tokens only
-            positions = (cache["idx"] - start)[:, None]
-            key_valid = jnp.arange(max_len)[None, :] >= start[:, None]
-            logits, cache = model.apply(
-                variables, tok[:, None], cache=cache, positions=positions,
-                attention_mask=key_valid,
-            )
-            return jnp.argmax(logits[:, -1], axis=-1), cache
-
-        @functools.partial(jax.jit, donate_argnums=(1,),
-                           static_argnums=(3,))
-        def _step_chain(variables, cache, tok, k, start):
-            # k tokens per dispatch: scan the single-step body carrying
-            # (cache, tok) — each step's argmax feeds the next, exactly
-            # the unchained sequence, amortizing the dispatch gap k-fold.
-            # The carried cache IS the iteration dependence (no CSE
-            # collapse possible) and rides the donated input buffer.
-            def body(carry, _):
-                cache, tok = carry
-                positions = (cache["idx"] - start)[:, None]
-                key_valid = (jnp.arange(max_len)[None, :]
-                             >= start[:, None])
-                logits, cache = model.apply(
-                    variables, tok[:, None], cache=cache,
-                    positions=positions, attention_mask=key_valid,
-                )
-                tok = jnp.argmax(logits[:, -1], axis=-1)
-                return (cache, tok), tok
-
-            (cache, _), toks = lax.scan(
-                body, (cache, tok), None, length=k
-            )
-            return toks, cache
-
-        self._prefill_fn = _prefill
-        self._scatter_fn = _scatter
-        self._step_fn = _step
-        self._step_chain_fn = _step_chain
+            # the dense reference's programs
+            self._prefill_fn = jax.jit(
+                bound(programs._prefill, max_len, model))
+            self._scatter_fn = jax.jit(
+                programs._scatter, donate_argnums=(0,))
+            self._step_fn = jax.jit(
+                bound(programs._step, max_len, model), donate_argnums=(1,))
+            self._step_chain_fn = jax.jit(
+                bound(programs._step_chain, max_len, model),
+                donate_argnums=(1,), static_argnums=(3,))
         # process-wide registrations go LAST: a constructor failure above
         # (bad config, cache init OOM) must not leak a tracker/provider
         # bound to a half-built engine
-        from sparkdl_tpu.serving.metrics import EngineObservability
 
         self._obs = EngineObservability(
             "continuous", self._flight_context, slo=slo, n_slots=n_slots)
@@ -988,41 +623,28 @@ class ContinuousGPTEngine:
 
     # -- sequence-parallel prefill (ISSUE 13 / ROADMAP item 2) ---------------
     def _init_sp(self, sp: int, sp_kv_blocks: "int | None") -> None:
-        """Spatial prefill chunks: a dp=1 mesh over the first ``sp``
-        local devices, a sequence-sharded STAGING pool (block axis on
-        the ``sp`` mesh axis, placed through the partitioner's
-        ``KV_POOL_RULES``), and explicit-sharding chunk programs whose
-        QUERIES are sharded over ``sp`` — each chip embeds and projects
-        its contiguous token shard, GSPMD all-gathers the chunk's K/V
-        for the causal attention (the all-gather schedule of
-        ``models.gpt.sp_prefill``; the ring rotation is the large-sp /
-        on-chip variant), so one tick's chunk runs across ``sp`` chips
-        instead of one. The staging pool holds the accumulating prompt
-        K/V between ticks (sharded — a long context never has to fit
-        one chip); decode stays on the untouched single-device paged
-        path, fed by ONE gather at the prefill→decode handoff
-        (``sp.gather`` fault site).
+        """Spatial prefill chunks (the module docstring has the design):
+        a dp=1 mesh over the first ``sp`` local devices, a sequence-sharded
+        STAGING pool (block axis on the ``sp`` mesh axis, placed through
+        the partitioner's ``KV_POOL_RULES``), and explicit-sharding chunk
+        programs whose QUERIES are sharded over ``sp`` (the all-gather
+        schedule of ``models.gpt.sp_prefill``; the ring rotation is the
+        large-sp / on-chip variant).
 
         Staging stores the COMPUTE dtype even under quantized decode
         pools: chunks then attend over exact K/V (bitwise-identical to
         the sp=1 private-cache path) and the handoff install quantizes
         ONCE — exactly where the single-device install does.
         """
-        import functools
-
-        import jax
-        import jax.numpy as jnp
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
-        from sparkdl_tpu.models.gpt import init_block_pool
         from sparkdl_tpu.partition.mesh_factory import make_mesh
         from sparkdl_tpu.partition.rules import (
             KV_POOL_RULES,
             match_partition_rules,
             sequence_activation_spec,
         )
-        from sparkdl_tpu.serving.kv_blocks import SeqShardedBlockPool
 
         if sp & (sp - 1):
             raise ValueError(
@@ -1042,15 +664,13 @@ class ContinuousGPTEngine:
         # tokens than one program can carry).
         self._chunk_cap = max(sp, (self._chunk_cap // sp) * sp)
         self.prefill_chunk = min(self.prefill_chunk, self._chunk_cap)
-        config = self.config
-        model = self._model
         bs_kv = self._kv_bs
         fam = self._family
-        n_layers, nh, hd = fam.layers, fam.kv_heads, fam.head_dim
-        tail = fam.kv_tail
-        max_pos = (fam.max_positions - 1
-                   if fam.max_positions is not None
-                   else self._wp + self.prefill_chunk)
+        # a staged chunk's pad tail is clamped under the per-tick budget
+        # as it stands AFTER the clamp above
+        sizes = dataclasses.replace(self._sizes, max_pos=(
+            fam.max_positions - 1 if fam.max_positions is not None
+            else self._wp + self.prefill_chunk))
         mesh = make_mesh(dp=1, sp=sp, devices=devs[:sp])
         self._sp_mesh = mesh
         n_sp = (sp_kv_blocks if sp_kv_blocks is not None
@@ -1064,7 +684,7 @@ class ContinuousGPTEngine:
         # wp = w + chunk_cap against
         self._mb_sp = -(-(self._w + self._chunk_cap) // bs_kv)
         self._sp_pool = SeqShardedBlockPool(n_sp, bs_kv, sp)
-        sp_tree = init_block_pool(config, n_sp, bs_kv)
+        sp_tree = init_block_pool(self.config, n_sp, bs_kv)
         specs = match_partition_rules(KV_POOL_RULES, sp_tree)
         pool_sh = jax.tree_util.tree_map(
             lambda s: NamedSharding(mesh, s), specs)
@@ -1078,89 +698,24 @@ class ContinuousGPTEngine:
         # host-side arithmetic for sparkdl_sp_permute_bytes_total: each
         # chip contributes its K/V chunk shard to sp-1 peers
         self._sp_bytes_per_col = (
-            2 * n_layers * nh * hd
+            2 * fam.layers * fam.kv_heads * fam.head_dim
             * np.dtype(fam.dtype).itemsize * (sp - 1))
-
-        @functools.partial(
-            jax.jit, donate_argnums=(1,), static_argnums=(7,),
+        # the staged world's programs (serving/paged_programs.py), with
+        # where each argument lives on the sp mesh; the handoff's install
+        # into the decode pool is the engine's _install_blocks_fn
+        self._sp_chunk_fn = jax.jit(
+            bound(programs._sp_chunk, sizes, self._model),
+            donate_argnums=(1,), static_argnums=(7,),
             in_shardings=(rep, pool_sh, rep, rep, ids_sh, rep, rep),
             out_shardings=(logits_sh, pool_sh))
-        def _sp_chunk(variables, sppool, head, idx, ids, sblk, soff,
-                      nbh):
-            # One SPATIAL prefill chunk: gather the staged head
-            # (sentinels clip to causally-masked garbage), write this
-            # chunk's K/V into it through the model's cached path —
-            # queries sharded over sp, K all-gathered by GSPMD for the
-            # dense masked softmax, so logits are bitwise-identical to
-            # the single-device chunk — then scatter the freshly
-            # written columns back to their staged blocks (sentinel
-            # targets drop: pad columns never land).
-            wc = ids.shape[1]
-            kbuf = sppool["k"][:, head].reshape(
-                (n_layers, 1, nbh * bs_kv) + tail)
-            vbuf = sppool["v"][:, head].reshape(
-                (n_layers, 1, nbh * bs_kv) + tail)
-            positions = jnp.minimum(
-                idx + jnp.arange(wc)[None, :], max_pos)
-            cache = {"k": kbuf, "v": vbuf, "idx": idx}
-            logits, cache = model.apply(
-                variables, ids, cache=cache, positions=positions)
-            newk = jax.lax.dynamic_slice_in_dim(
-                cache["k"][:, 0], idx, wc, axis=1)
-            newv = jax.lax.dynamic_slice_in_dim(
-                cache["v"][:, 0], idx, wc, axis=1)
-            ix = (slice(None), sblk, soff)
-            out = dict(sppool)
-            out["k"] = sppool["k"].at[ix].set(newk, mode="drop")
-            out["v"] = sppool["v"].at[ix].set(newv, mode="drop")
-            return logits, out
-
-        @functools.partial(
-            jax.jit, donate_argnums=(0,),
-            in_shardings=(pool_sh, rep, rep, rep),
-            out_shardings=pool_sh)
-        def _sp_seed(sppool, kdata, vdata, ids):
-            # cached-prefix K/V -> the staged blocks backing the hit
-            # span (the prefix gather, sharded along the same axis):
-            # whole-block writes, sentinel targets drop
-            out = dict(sppool)
-            out["k"] = sppool["k"].at[:, ids].set(kdata, mode="drop")
-            out["v"] = sppool["v"].at[:, ids].set(vdata, mode="drop")
-            return out
-
-        @functools.partial(
-            jax.jit,
+        self._sp_seed_fn = jax.jit(
+            programs._sp_seed, donate_argnums=(0,),
+            in_shardings=(pool_sh, rep, rep, rep), out_shardings=pool_sh)
+        self._sp_gather_fn = jax.jit(
+            programs._sp_gather,
             in_shardings=(pool_sh, rep), out_shardings=(rep, rep))
-        def _sp_gather(sppool, ids):
-            # prefill->decode handoff: the request's staged blocks,
-            # gathered ONCE across the sp shards (replicated out; the
-            # host hop to the single-device decode pool is the
-            # documented boundary between the two device worlds)
-            return sppool["k"][:, ids], sppool["v"][:, ids]
-
-        _dq = self._dq_gather_fn
-        _qw = self._q_write_fn
-
-        @jax.jit
-        def _sp_prefix_fetch(pool, gids):
-            # cached prefix blocks out of the DECODE pool, dequantized
-            # to the compute dtype (the same values the single-device
-            # first chunk gathers into its private cache)
-            return _dq(pool, gids)
-
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def _sp_install(pool, kdata, vdata, inst):
-            # the handoff install into the decode pool's owned blocks:
-            # the same _q_write path as the fused single-device install
-            # (sentinels at shared-prefix positions drop; quantized
-            # pools quantize HERE, once)
-            return _qw(pool, inst, kdata, vdata)
-
-        self._sp_chunk_fn = _sp_chunk
-        self._sp_seed_fn = _sp_seed
-        self._sp_gather_fn = _sp_gather
-        self._sp_prefix_fetch_fn = _sp_prefix_fetch
-        self._sp_install_fn = _sp_install
+        self._sp_prefix_fetch_fn = jax.jit(
+            bound(programs._sp_prefix_fetch, sizes))
 
     # -- submission ----------------------------------------------------------
     def submit(self, prompt_ids, max_new_tokens: int, *,
@@ -1175,8 +730,6 @@ class ContinuousGPTEngine:
         bitwise-compatible single-user path. See
         :meth:`RequestQueue.submit` for the typed admission rejects
         (``TenantThrottledError``/``BrownoutShedError``)."""
-        from sparkdl_tpu.runtime.batching import pick_bucket
-
         prompt = np.asarray(prompt_ids, np.int32)
         if prompt.ndim != 1 or prompt.size == 0:
             raise ValueError(
@@ -1609,10 +1162,6 @@ class ContinuousGPTEngine:
         return True
 
     def _admit_dense(self, slot: int, req: Request) -> None:
-        import jax.numpy as jnp
-
-        from sparkdl_tpu.runtime.batching import pick_bucket
-
         gen: GenRequest = req.payload
         lp = pick_bucket(len(gen.prompt), self._len_buckets)
         t0 = time.perf_counter()
@@ -1646,8 +1195,6 @@ class ContinuousGPTEngine:
         worst-case remaining blocks up front (so decode can never hit
         mid-stream exhaustion), and queue the suffix for chunked
         prefill. False = pool exhausted right now (defer)."""
-        import jax.numpy as jnp
-
         gen: GenRequest = req.payload
         prompt = np.asarray(gen.prompt, np.int32)
         plen = len(prompt)
@@ -1767,8 +1314,6 @@ class ContinuousGPTEngine:
         """Copy the matched prefix span (full blocks + COW partial
         tail) from the decode pool into the staged blocks backing it —
         one dequantizing fetch, one sharded seed scatter."""
-        import jax.numpy as jnp
-
         seed = np.full((self._mb,), self._sp_pool.sentinel, np.int32)
         seed[:n_hit_blocks] = sp_blocks[:n_hit_blocks]
         kd, vd = self._sp_prefix_fetch_fn(
@@ -1803,11 +1348,6 @@ class ContinuousGPTEngine:
         torn park (injected ``kv.park`` fault or transfer failure):
         the caller falls back to plain eviction — the session simply
         re-prefills next turn, nothing is lost."""
-        import jax.numpy as jnp
-
-        from sparkdl_tpu.runtime.completion import start_fetch
-        from sparkdl_tpu.serving import kv_tiers as kv_tiers_mod
-
         t0 = time.monotonic()
         try:
             fault_point("kv.park")
@@ -1832,10 +1372,6 @@ class ContinuousGPTEngine:
         block. False = corrupt unpark (injected ``kv.unpark`` fault):
         the caller prunes the parked subtree and the suffix
         re-prefills — the request still completes."""
-        import jax.numpy as jnp
-
-        from sparkdl_tpu.serving import kv_tiers as kv_tiers_mod
-
         t0 = time.monotonic()
         try:
             fault_point("kv.unpark")
@@ -1901,7 +1437,6 @@ class ContinuousGPTEngine:
         if self.kv_layout != "paged" or self._kv_tiers is None:
             return None
         from sparkdl_tpu.disagg.handoff import _enc
-        from sparkdl_tpu.serving import kv_tiers as kv_tiers_mod
 
         t0 = time.monotonic()
         sessions: "list[dict]" = []
@@ -1968,7 +1503,6 @@ class ContinuousGPTEngine:
                 or not bundle):
             return 0
         from sparkdl_tpu.disagg.handoff import _dec
-        from sparkdl_tpu.serving import kv_tiers as kv_tiers_mod
 
         if int(bundle.get("block_size") or 0) != self._kv_bs:
             return 0
@@ -2030,15 +1564,12 @@ class ContinuousGPTEngine:
 
     def _prefill_chunk_step(self, slot: int, st: _Prefill,
                             r: int) -> None:
-        import jax.numpy as jnp
-
         if st.sp_blocks is not None:
             self._sp_chunk_step(slot, st, r)
             return
         c0 = st.pos
         first = st.ck is None
         final = c0 + r == len(st.prompt)
-        from sparkdl_tpu.runtime.batching import pow2_bucket
 
         # chunk-program width: power-of-2 bucket of the real token
         # count (capped by the budget) — compile reuse without paying
@@ -2145,10 +1676,6 @@ class ContinuousGPTEngine:
         feed the ChainPolicy: its calibrated dispatch gap is measured
         on single-device programs, and a collective-bearing dispatch
         would skew the auto-K the decode loop calibrates from."""
-        import jax.numpy as jnp
-
-        from sparkdl_tpu.runtime.batching import pow2_bucket
-
         try:
             # the injectable stand-in for a failed collective hop
             # (ring permute / all-gather): fires BEFORE the dispatch so
@@ -2208,8 +1735,6 @@ class ContinuousGPTEngine:
         owned blocks — after this the per-token loop is EXACTLY the
         single-device paged path. Returns False when the ``sp.gather``
         fault site fired (request re-queued, nothing lost)."""
-        import jax.numpy as jnp
-
         try:
             fault_point("sp.gather")
         except Exception as e:
@@ -2224,7 +1749,7 @@ class ContinuousGPTEngine:
             # host hop: the staged world is mesh-committed, the decode
             # pool single-device — one bounded copy per ADMISSION, not
             # per token
-            self._pool_kv = self._sp_install_fn(
+            self._pool_kv = self._install_blocks_fn(
                 self._pool_kv, np.asarray(kd), np.asarray(vd),
                 jnp.asarray(st.install_ids))
         self._sp_handoffs += 1
@@ -2367,12 +1892,6 @@ class ContinuousGPTEngine:
         below 2, no proposer had a draft, or the ``spec.verify`` fault
         site fired (the chaos contract: a failed verify falls back to
         plain decode, zero lost requests)."""
-        import jax
-        import jax.numpy as jnp
-
-        from sparkdl_tpu.runtime.batching import pow2_bucket
-        from sparkdl_tpu.serving.spec_decode import greedy_accept
-
         k = self._spec_width(time.monotonic())
         if k < 2:
             return False
@@ -2479,9 +1998,6 @@ class ContinuousGPTEngine:
         return True
 
     def _decode_step(self) -> None:
-        import jax
-        import jax.numpy as jnp
-
         if (self.spec_k is not None and self.kv_layout == "paged"
                 and self._spec_step()):
             return
@@ -2489,8 +2005,6 @@ class ContinuousGPTEngine:
         paged = self.kv_layout == "paged"
         shape, cols = {}, {}
         if paged:
-            from sparkdl_tpu.runtime.batching import pow2_bucket
-
             # static gather width: blocks covering the deepest live
             # row through this whole chain (idx advances k), bucketed
             # to a power of two for compile reuse, capped at the
@@ -2708,11 +2222,6 @@ class ContinuousGPTEngine:
         return out
 
     def _kv_snapshot(self) -> "dict[str, Any] | None":
-        from sparkdl_tpu.serving.kv_blocks import (
-            kv_bytes_per_token,
-            kv_capacity_ratio,
-        )
-
         if self.kv_layout != "paged":
             return None
         return {

@@ -5,9 +5,9 @@ cache, so its memory contract is ``n_slots x max_len`` worst-case columns
 whether or not tokens exist. This module is the host-side half of the
 paged layout (ROADMAP item 4, the vLLM idea): the device holds one
 ``[layers, n_blocks, block_size, *kv_tail]`` pool
-(:func:`~sparkdl_tpu.models.gpt.init_block_pool`; the trailing axes are
-the family's, ``models/family.py``: GPT's heads on ONE merged axis), each
-serving slot maps its logical columns onto pool blocks through a per-slot
+(:func:`~sparkdl_tpu.models.kv_pool.init_block_pool`; that module owns the
+device format, the trailing axes among it: GPT's heads on ONE merged axis),
+each serving slot maps its logical columns onto pool blocks through a per-slot
 block table, and THIS class owns the free list and refcounts — so
 
 * capacity is bounded by live tokens (``blocks_used x block_size``), not
@@ -36,10 +36,10 @@ need ever recorded by :meth:`~KVBlockPool.record_deferral`, so parked
 capacity can never starve the largest request the pool has seen.
 
 Quantized layouts (ROADMAP item 3): the pool's DEVICE storage
-(:func:`~sparkdl_tpu.models.gpt.init_block_pool`) can hold blocks in
+(:func:`~sparkdl_tpu.models.kv_pool.init_block_pool`) can hold blocks in
 ``bf16`` or ``int8`` (one fp32 scale per written column) instead of the
-compute dtype — :data:`KV_DTYPES`. This class stays dtype-agnostic
-bookkeeping; it records the layout for observability
+compute dtype — :data:`~sparkdl_tpu.models.kv_pool.KV_DTYPES`. This class
+stays dtype-agnostic bookkeeping; it records the layout for observability
 (``sparkdl_kv_pool_dtype{dtype=...}`` counts live pools per layout) and
 :func:`kv_bytes_per_token` / :func:`kv_capacity_ratio` give the sizing
 arithmetic benches and admission math share: int8 fits 2-4x the live
@@ -52,6 +52,7 @@ from __future__ import annotations
 import collections
 from typing import Iterable, Optional
 
+from sparkdl_tpu.models.kv_pool import KV_DTYPES
 from sparkdl_tpu.observability.registry import GaugeShare, registry
 
 _M_TOTAL = registry().gauge(
@@ -74,11 +75,6 @@ _M_SP_IMBALANCE = registry().gauge(
     "sparkdl_sp_shard_imbalance",
     "sequence-sharded pool imbalance: (max - min) used blocks across "
     "sp shards / blocks per shard (0 = perfectly balanced)")
-
-#: Supported pool storage layouts: "fp32" stores at the model's compute
-#: dtype (exact, the default), "bf16"/"int8" compress the resident pool
-#: (compute still runs at the model dtype; see models.gpt.quantize_kv).
-KV_DTYPES = ("fp32", "bf16", "int8")
 
 _KV_ITEMSIZE = {"bf16": 2, "int8": 1}
 

@@ -1,0 +1,373 @@
+"""The device programs of :class:`~sparkdl_tpu.serving.continuous.
+ContinuousGPTEngine`, as named functions.
+
+Each is a function of (:class:`PagedSizes`, the family's module, its
+arrays): the engine binds the first two (:func:`bound`) and hands the rest
+to ``jax.jit`` under the function's own name, so a program can be lowered,
+compiled for a described chip or timed from ``(sizes, module, arrays)``
+with no engine, queue or thread around it. How K and V lie in the pool is
+not decided here: every read and write of it goes through
+:mod:`sparkdl_tpu.models.kv_pool`.
+
+NAMES ARE PART OF THE CONTRACT. The benchmark finds programs in a device
+trace by name: ``paged_step`` (``decode_device_ms``, both roofline shares,
+``expert_device_ms``) and ``_chunk_`` (``prefill_device_share``). A
+function renamed here reads as null there with every CPU test green;
+``tests/serving/test_paged_programs.py`` holds the names.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import inspect
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from sparkdl_tpu.models import kv_pool
+
+
+@dataclasses.dataclass(frozen=True)
+class PagedSizes:
+    """What the engine derives from ``n_slots``, ``max_len``,
+    ``kv_block_size``, ``prefill_chunk`` and the family, and its programs
+    close over. No option: nobody sets it."""
+
+    n_slots: int
+    block_size: int
+    mb: int        #: table width, blocks per sequence
+    w: int         #: gathered virtual-cache width, ``mb * block_size``
+    wp: int        #: a private prefill cache's width: ``w`` + one chunk
+    max_pos: int   #: the last position a chunk's pad tail may claim
+    dtype: Any     #: compute dtype (the pool may store another)
+
+
+def bound(fn, *head):
+    """``fn`` with its leading arguments fixed, STILL under ``fn``'s name:
+    ``jax.jit`` names a program after the function it is given, and a bare
+    ``functools.partial`` has none (``jit__unknown`` on the device). The
+    signature is what is left to pass (no ``__wrapped__``: jit would read
+    the unbound one off it), so the compiled parameters keep their names."""
+    def program(*args):
+        return fn(*head, *args)
+
+    program.__name__ = fn.__name__
+    program.__qualname__ = fn.__qualname__
+    left = list(inspect.signature(fn).parameters.values())[len(head):]
+    program.__signature__ = inspect.Signature(left)
+    return program
+
+
+# -- the tick -----------------------------------------------------------------
+
+def _paged_step(sizes, model, variables, pool, table, idx, tok, k, nb):
+    # k tokens for every slot THROUGH the block table: the model takes the pool
+    # itself as a paged cache (a ``table`` entry, models/gpt.py), so each layer
+    # gathers only its own live blocks into an [S, nb*bs] slice — same math
+    # over the same width as the dense layout, so greedy tokens stay
+    # bitwise-identical — and hands back the one new column per row, which is
+    # the ONLY write to the donated pool (in place, at (block, offset)): no
+    # all-layer dense view, no copy of the pool. ``nb`` (static, bucketed) is
+    # the block count covering the DEEPEST live row through this chain — the
+    # gather and attention touch only the live head of the table, often FEWER
+    # columns than the dense layout's fixed max_len (masked-width invariance
+    # keeps tokens bitwise). Rows are right-aligned (no left pad: column i
+    # holds real token i), so the causal mask alone masks garbage columns and
+    # positions need no start offset. Sentinel table entries clip on gather
+    # (masked garbage) and write nothing (kv_pool.scatter_columns: no block
+    # corrupted).
+    sub = table[:, :nb]
+
+    def body(carry, _):
+        pool, idx, tok = carry
+        logits, new = model.apply(
+            variables, tok[:, None],
+            cache=dict(pool, table=sub, idx=idx),
+        )
+        ntok = jnp.argmax(logits[:, -1], axis=-1)
+        rows = jnp.arange(sizes.n_slots)
+        blk = table[rows, idx // sizes.block_size]
+        off = idx % sizes.block_size
+        pool = kv_pool.scatter_columns(
+            pool, blk, off, new["k"][:, :, 0], new["v"][:, :, 0])
+        out = ntok
+        if "expert_counts" in new:
+            # rows each expert got, behind the step's tokens: ONE array, so one
+            # device-to-host read a tick
+            out = jnp.concatenate(
+                [ntok.astype(jnp.int32),
+                 new["expert_counts"].reshape(-1)])
+        return (pool, idx + 1, ntok), out
+
+    (pool, _, _), toks = lax.scan(
+        body, (pool, idx, tok), None, length=k
+    )
+    return toks, pool
+
+
+def _paged_verify(sizes, model, variables, pool, table, idx, toks, k, nb):
+    # Speculative verify: score a k-token span for every slot in ONE dispatch.
+    # Column 0 of ``toks`` is each slot's current last token, columns 1.. its
+    # proposed drafts; the L=k per-slot step (models/gpt.py) writes all k
+    # columns at [idx[s], idx[s]+k) and the per-row causal mask conditions
+    # position j on the real context plus drafts [:j] — exactly the logits
+    # greedy acceptance needs, through the same paged cache as _paged_step so
+    # greedy tokens stay bitwise. Columns of REJECTED drafts scatter back as
+    # garbage PAST the accepted frontier (the host advances pidx only over
+    # accepted inputs): they sit causally masked until the next dispatch's own
+    # writes overwrite them — the same garbage-but-finite contract as
+    # retired-slot columns.
+    logits, new = model.apply(
+        variables, toks,
+        cache=dict(pool, table=table[:, :nb], idx=idx),
+    )
+    out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    rows = jnp.arange(sizes.n_slots)[:, None]
+    pos = idx[:, None] + jnp.arange(k)[None, :]
+    blk = table[rows, pos // sizes.block_size]
+    off = pos % sizes.block_size
+    return out, kv_pool.scatter_columns(pool, blk, off, new["k"], new["v"])
+
+
+# -- chunked prefill ----------------------------------------------------------
+
+def _gathered(sizes, pool, ids):
+    # cached-prefix blocks -> the head of a private prefill cache (the copy
+    # that makes partial-block sharing copy-on-write: the sharer re-installs
+    # into blocks it owns, the donor block is never written). Sentinel ids clip
+    # to garbage the chunked prefill masks/overwrites. Quantized pools
+    # dequantize here: the private cache is compute-dtype, and the final
+    # install requantizes — an exact round trip (quantize_kv absmax maps to
+    # ±127), so a COW-shared block re-installs bit-identical to its donor.
+    layers, tail = pool["k"].shape[0], pool["k"].shape[3:]
+    pad = (((0, 0), (0, 0), (0, sizes.wp - sizes.w))
+           + ((0, 0),) * len(tail))
+    return tuple(
+        jnp.pad(x.reshape((layers, 1, sizes.w) + tail), pad)
+        for x in kv_pool.gather_blocks_as(pool, ids, sizes.dtype))
+
+
+def _chunk_apply(sizes, model, variables, ck, cv, idx, ids, cols):
+    # one bounded prefill chunk, right-aligned: writes K/V at columns [idx,
+    # idx+width) of the private cache, where width = ids.shape[1] is the
+    # POWER-OF-2 BUCKET of this chunk's real token count (same compile-reuse
+    # trick as the dense path's prompt buckets: a 24-token suffix pays a
+    # 32-wide program, not a chunk-cap-wide one). ``cols`` (static, bucketed >=
+    # idx+width) bounds the attention to the LIVE head of the buffer — every
+    # column past it is causally masked garbage anyway, so slicing changes
+    # nothing but the wasted FLOPs. The tail of the chunk is zero-padded on the
+    # right; pad queries produce garbage columns PAST every real position, so
+    # the causal mask hides them until real writes overwrite them — no
+    # attention_mask needed (vs the dense path's left-pad masking).
+    positions = jnp.minimum(
+        idx + jnp.arange(ids.shape[1])[None, :], sizes.max_pos)
+    cache = {"k": ck[:, :, :cols], "v": cv[:, :, :cols],
+             "idx": idx}
+    logits, cache = model.apply(
+        variables, ids, cache=cache, positions=positions,
+    )
+    ck = ck.at[:, :, :cols].set(cache["k"])
+    cv = cv.at[:, :, :cols].set(cache["v"])
+    return logits, ck, cv
+
+
+def _installed(sizes, pool, ck, cv, ids):
+    # private prefill cache -> the slot's OWNED pool blocks
+    # (quantize-on-install rides the shared kv_pool.stored_as rule). ids
+    # carries the sentinel at shared-prefix positions (their content already
+    # lives in the shared blocks) and past the covered span: those writes drop.
+    shape = ((pool["k"].shape[0], sizes.mb, sizes.block_size)
+             + pool["k"].shape[3:])
+    return kv_pool.write_kv_blocks(
+        pool, ids, ck[:, 0, :sizes.w].reshape(shape),
+        cv[:, 0, :sizes.w].reshape(shape))
+
+
+# Four fused chunk programs so a prefill pays the minimum dispatch count
+# (dispatch gap dominates small programs — the ISSUE 3 lesson applied to
+# admission): the FIRST chunk fuses the prefix gather, the FINAL chunk fuses
+# the block install, so a suffix that fits one chunk is ONE device dispatch end
+# to end (vs dense's prefill + scatter pair). A chunk unrolls every layer: on
+# the chip the layers share their code (the engine compiles the four with
+# runtime.chip.alike_layers_options), or a program that installs is twenty
+# times the size and loads as slowly.
+
+def _chunk_one(sizes, model, variables, pool, gids, idx, ids, inst, cols):
+    logits, ck, cv = _chunk_first(
+        sizes, model, variables, pool, gids, idx, ids, cols)
+    return logits, _installed(sizes, pool, ck, cv, inst)
+
+
+def _chunk_first(sizes, model, variables, pool, gids, idx, ids, cols):
+    ck, cv = _gathered(sizes, pool, gids)
+    return _chunk_apply(sizes, model, variables, ck, cv, idx, ids, cols)
+
+
+def _chunk_mid(sizes, model, variables, ck, cv, idx, ids, cols):
+    return _chunk_apply(sizes, model, variables, ck, cv, idx, ids, cols)
+
+
+# (ck/cv are deliberately NOT donated here or in _chunk_one: no output shares
+# their shape, so donation could not alias — jax would warn "donated buffers
+# were not usable" on every compile and free nothing earlier; they die on the
+# host right after the call regardless)
+def _chunk_final(sizes, model, variables, pool, ck, cv, idx, ids, inst,
+                 cols):
+    logits, ck, cv = _chunk_apply(
+        sizes, model, variables, ck, cv, idx, ids, cols)
+    return logits, _installed(sizes, pool, ck, cv, inst)
+
+
+# -- whole blocks across a boundary: tiers, handoffs --------------------------
+
+def _park_fetch(pool, ids):
+    # the D2H half of a park: the given blocks' RAW storage-dtype bytes (int8
+    # codes + their scales, no dequantize) — raw is both the 4x cheaper
+    # transfer the quantized layout bought and what makes a resumed session
+    # bitwise-identical: unpark writes back the exact bytes decode would have
+    # read. A prefill tier's export (disagg/workers.py) is this same gather.
+    return kv_pool.gather_blocks(pool, ids)
+
+
+def _unpark_install(pool, ids, payload):
+    # the H2D half of a resume: whole-block raw writes into freshly allocated
+    # blocks, in place (sentinel ids write nothing — same contract as every
+    # other pool write)
+    return kv_pool.write_blocks(pool, ids, {
+        name: vals.astype(pool[name].dtype)
+        for name, vals in payload.items()})
+
+
+def _install_blocks(pool, kdata, vdata, inst):
+    # K and V that crossed a boundary at the compute dtype (the
+    # sequence-parallel handoff, a decode tier's adopted prefill) into the
+    # decode pool's owned blocks: the same kv_pool.write_kv_blocks path as the
+    # fused single-device install (sentinels at shared-prefix positions drop;
+    # quantized pools quantize HERE, once: the exact requantize round trip,
+    # quantize_kv, that keeps a transferred block bitwise-identical to a local
+    # prefill's)
+    return kv_pool.write_kv_blocks(pool, inst, kdata, vdata)
+
+
+# -- sequence-parallel prefill (the engine jits these with shardings) ---------
+
+def _sp_chunk(sizes, model, variables, sppool, head, idx, ids, sblk, soff,
+              nbh):
+    # One SPATIAL prefill chunk: gather the staged head (sentinels clip to
+    # causally-masked garbage), write this chunk's K/V into it through the
+    # model's cached path — queries sharded over sp, K all-gathered by GSPMD
+    # for the dense masked softmax, so logits are bitwise-identical to the
+    # single-device chunk — then scatter the freshly written columns back to
+    # their staged blocks (sentinel targets drop: pad columns never land).
+    wc = ids.shape[1]
+    layers, tail = sppool["k"].shape[0], sppool["k"].shape[3:]
+    kbuf = sppool["k"][:, head].reshape(
+        (layers, 1, nbh * sizes.block_size) + tail)
+    vbuf = sppool["v"][:, head].reshape(
+        (layers, 1, nbh * sizes.block_size) + tail)
+    positions = jnp.minimum(
+        idx + jnp.arange(wc)[None, :], sizes.max_pos)
+    cache = {"k": kbuf, "v": vbuf, "idx": idx}
+    logits, cache = model.apply(
+        variables, ids, cache=cache, positions=positions)
+    newk = jax.lax.dynamic_slice_in_dim(
+        cache["k"][:, 0], idx, wc, axis=1)
+    newv = jax.lax.dynamic_slice_in_dim(
+        cache["v"][:, 0], idx, wc, axis=1)
+    ix = (slice(None), sblk, soff)
+    out = dict(sppool)
+    out["k"] = sppool["k"].at[ix].set(newk, mode="drop")
+    out["v"] = sppool["v"].at[ix].set(newv, mode="drop")
+    return logits, out
+
+
+def _sp_seed(sppool, kdata, vdata, ids):
+    # cached-prefix K/V -> the staged blocks backing the hit span (the prefix
+    # gather, sharded along the same axis): whole-block writes, sentinel
+    # targets drop
+    out = dict(sppool)
+    out["k"] = sppool["k"].at[:, ids].set(kdata, mode="drop")
+    out["v"] = sppool["v"].at[:, ids].set(vdata, mode="drop")
+    return out
+
+
+def _sp_gather(sppool, ids):
+    # prefill->decode handoff: the request's staged blocks, gathered ONCE
+    # across the sp shards (replicated out; the host hop to the single-device
+    # decode pool is the documented boundary between the two device worlds)
+    return sppool["k"][:, ids], sppool["v"][:, ids]
+
+
+def _sp_prefix_fetch(sizes, pool, gids):
+    # cached prefix blocks out of the DECODE pool, dequantized to the compute
+    # dtype (the same values the single-device first chunk gathers into its
+    # private cache)
+    return kv_pool.gather_blocks_as(pool, gids, sizes.dtype)
+
+
+# -- the dense reference (kv_layout="dense") ----------------------------------
+
+def _prefill(max_len, model, variables, ids, mask):
+    # batch-1 left-padded prefill in a fresh scalar-idx cache of the SHARED
+    # buffer width, so columns line up at scatter time. jit's shape cache gives
+    # one compile per prompt-length bucket.
+    from sparkdl_tpu.models.gpt import init_cache
+
+    lp = ids.shape[1]
+    cache = init_cache(model.config, 1, max_len)
+    positions = jnp.clip(jnp.cumsum(mask, axis=1) - 1, 0)
+    key_valid = jnp.concatenate(
+        [mask.astype(bool),
+         jnp.ones((1, max_len - lp), bool)], axis=1,
+    )
+    logits, cache = model.apply(
+        variables, ids, cache=cache, positions=positions,
+        attention_mask=key_valid,
+    )
+    return jnp.argmax(logits[:, -1], axis=-1), cache
+
+
+# the engine donates the cache through scatter and step: it always discards the
+# old version, and without donation every token would materialize a second full
+# [layers, S, max_len, H, D] buffer (2x HBM peak + a copy per token at serving
+# sizes)
+def _scatter(cache, row, slot):
+    # install a prefilled row into slot (traced index: one compile)
+    return {
+        "k": jax.lax.dynamic_update_slice_in_dim(
+            cache["k"], row["k"], slot, axis=1),
+        "v": jax.lax.dynamic_update_slice_in_dim(
+            cache["v"], row["v"], slot, axis=1),
+        "idx": cache["idx"].at[slot].set(
+            row["idx"].astype(jnp.int32)),
+    }
+
+
+def _step(max_len, model, variables, cache, tok, start):
+    # one token for every slot; the per-slot cache gives each row its own
+    # causal depth, `start` masks its left-pad columns, and RoPE/learned
+    # positions count real tokens only
+    positions = (cache["idx"] - start)[:, None]
+    key_valid = jnp.arange(max_len)[None, :] >= start[:, None]
+    logits, cache = model.apply(
+        variables, tok[:, None], cache=cache, positions=positions,
+        attention_mask=key_valid,
+    )
+    return jnp.argmax(logits[:, -1], axis=-1), cache
+
+
+def _step_chain(max_len, model, variables, cache, tok, k, start):
+    # k tokens per dispatch: scan the single-step body carrying (cache, tok) —
+    # each step's argmax feeds the next, exactly the unchained sequence,
+    # amortizing the dispatch gap k-fold. The carried cache IS the iteration
+    # dependence (no CSE collapse possible) and rides the donated input buffer.
+    def body(carry, _):
+        tok, cache = _step(max_len, model, variables, *carry, start)
+        return (cache, tok), tok
+
+    (cache, _), toks = lax.scan(
+        body, (cache, tok), None, length=k
+    )
+    return toks, cache

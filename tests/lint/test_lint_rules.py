@@ -69,6 +69,16 @@ class TestDonationSafety:
     def test_negative_rebind_idioms(self):
         assert run_rule(DonationSafetyRule(), load("donation_ok.py")) == []
 
+    def test_a_bound_module_function_jitted_on_self_is_seen(self):
+        """The engine's binding form since ISSUE 31, ``self._x_fn =
+        jax.jit(bound(f, a, b), donate_argnums=(1,))``: the handle
+        donates, so the one call that does not rebind the pool is the one
+        finding; the rebinding call and the undonating handle are clean."""
+        found = run_rule(DonationSafetyRule(), load("donation_bound.py"))
+        assert [f.line for f in found] == [26], found
+        assert "'self._pool_kv'" in found[0].message
+        assert "self._step_fn" in found[0].message
+
     def test_rebind_inside_compound_statements_is_clean(self):
         """The documented same-statement rebind idiom must stay clean
         inside if/for/try suites — the call is judged at ITS statement,

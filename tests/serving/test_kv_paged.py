@@ -15,12 +15,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sparkdl_tpu.models.family import kv_per_head, kv_stored
-from sparkdl_tpu.models.gpt import (
-    GPTConfig,
-    GPTLMHeadModel,
-    generate,
+from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel, generate
+from sparkdl_tpu.models.kv_pool import (
     init_block_pool,
+    kv_per_head,
+    kv_stored,
 )
 from sparkdl_tpu.observability import tracing
 from sparkdl_tpu.observability.flight import healthz_report

@@ -15,13 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sparkdl_tpu.models.gpt import (
-    GPTConfig,
-    GPTLMHeadModel,
-    dequantize_kv,
-    generate,
-    quantize_kv,
-)
+from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel, generate
+from sparkdl_tpu.models.kv_pool import dequantize_kv, quantize_kv
 from sparkdl_tpu.observability.flight import healthz_report
 from sparkdl_tpu.observability.registry import registry
 from sparkdl_tpu.reliability.faults import inject
