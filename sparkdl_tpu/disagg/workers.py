@@ -342,11 +342,9 @@ class DecodeWorker(ContinuousGPTEngine):
         row[n_shared:nb_total] = owned
         self._table[slot] = row
         self._prefix.register(toks, [int(b) for b in row[:nbp]])
-        self._pidx[slot] = plen
-        self._last_tok[slot] = int(h.first_token)
         fl = _InFlight(req, [int(h.first_token)], h.max_new_tokens,
                        blocks=shared + owned, prompt=prompt)
-        self._inflight[slot] = fl
+        self._join_decode(slot, fl, plen)
         self._pool.reset_deferral_streak()
         # latency attribution (ISSUE 17): this is the single place all
         # five request phases publish from — the prefill tier shipped
@@ -380,18 +378,17 @@ class DecodeWorker(ContinuousGPTEngine):
             host=self.host_id, blocks=nbp, shared_blocks=n_shared,
             src_host=h.src_host)
         if self._is_done(fl):  # max_new_tokens=1, or instant eos
-            self._complete(slot)
+            self._complete(fl)
         return True
 
-    def _complete(self, slot: int) -> None:
+    def _complete(self, flight: _InFlight) -> None:
         # close the (compute, decode) phase for adopted handoffs: the
         # admit stamp rides the _InFlight (dies with it — failure-safe)
-        fl = self._inflight.get(slot)
-        t_adm = getattr(fl, "_phase_admit_start", None)
+        t_adm = getattr(flight, "_phase_admit_start", None)
         if t_adm is not None:
             observe_phase("compute", "decode",
                           time.monotonic() - t_adm)
-        super()._complete(slot)
+        super()._complete(flight)
 
     def _wire_to_compute(self, h: KVHandoff):
         """Wire storage → install-ready fp32 block data, padded to the

@@ -34,6 +34,29 @@ Three parts, one to a module, the arrows pointing one way:
   retirement. ``__init__`` binds each program to a ``jax.jit`` handle and
   defines none.
 
+A TICK OF THE PAGED LOOP, in order (``tick``): a deadline that has expired
+fails its row; free slots admit from the queue; the prefilling rows'
+chunks are dispatched; step n+1 is LAUNCHED for every live row with budget
+left, taking each row's token from step n's output where it still lies on
+the device; a prompt whose last chunk was dispatched above is waited for
+and its first token read (the row joins the decode batch with the host's
+word for its token, and rides step n+2); and only then are step n's ids
+waited for, read and retired. So the loop runs one step ahead of the host:
+from one launch to the next, whatever the host does (the waits, the
+reads, retiring, admitting, the chunk's dispatch, the launch itself) has a
+decode step queued or running under it. The cursor ``_pidx`` moves at the
+launch, by one a token whatever the token is. A row that ends by its
+budget is known by count: it rides no step past it, and gives its slot
+back when its last step is launched (its blocks when its ids are read). A
+row that ends by ``eos_id`` is found one step late and rides one step
+more, whose token is dropped and whose one column falls in a block the row
+still owned at the launch (admission reserves prompt + budget). Whatever
+touches a live row's blocks or ``produced`` outside that order (an
+expiring deadline, a preemption, ``close``, ``begin_drain``,
+``park_cold``, ``export_parked_sessions``, ``snapshot``) first collects
+the step in flight (``_collect``). The dense layout and a speculative
+engine (``spec_k``) launch, read and retire in one tick, as ever.
+
 KV layouts (``kv_layout=``): ``"paged"`` (default) maps each slot's columns
 onto refcounted ``block_size``-token blocks through a block table; the
 decode step hands the model the pool and the table's live head as a PAGED
@@ -97,6 +120,7 @@ section 7).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import threading
@@ -170,6 +194,14 @@ _M_SP_PERMUTE_BYTES = registry().counter(
     "estimated K/V bytes moved between sp chips by prefill collectives "
     "(2 x layers x chunk_width x hidden x itemsize x (sp-1) per "
     "dispatch)")
+
+
+_M_DECODE_AHEAD = registry().counter(
+    "sparkdl_serving_decode_ahead_total",
+    "paged decode steps launched, by whether the step before was still "
+    "unread on the device (ahead=1: the host's part of a tick ran under "
+    "it) or not (ahead=0: a row-set's first step, or the loop was "
+    "settled in between)", labels=("ahead",))
 
 
 class SpCollectiveError(RuntimeError):
@@ -254,6 +286,33 @@ class _InFlight:
     #: prompt ids (paged layout): the draft proposer's context is
     #: prompt + produced — ids only, never device state
     prompt: "np.ndarray | None" = None
+    #: the slot the row decodes in; None once it has given it back, which
+    #: a row that ends by its budget does when its last step is LAUNCHED
+    slot: "int | None" = None
+    #: tokens a launched step is making for this row that the host has not
+    #: read yet (paged layout): they count against the budget, and while
+    #: there are any the row's newest token is on the device alone
+    unread: int = 0
+
+    @property
+    def left(self) -> int:
+        """Budget left once the tokens already launched are counted."""
+        return self.max_new - len(self.produced) - self.unread
+
+
+@dataclasses.dataclass
+class _StepOut:
+    """One paged decode step that was launched and whose ids the host has
+    not read: what it takes to read them, retire its rows and say, in
+    ``serving.decode_step``, what the step was."""
+
+    toks: Any  # on the device: [k, n_slots (+ expert counts)]
+    fetch: Any  # the FetchTicket of their copy to the host
+    rows: "list[tuple[int, _InFlight]]"
+    t0: float  # time.monotonic() at the start of the launch
+    #: what ``serving.decode_step`` says of it: ``chain`` (its tokens a
+    #: row), ``slots``, ``links``, ``nb``, what it gathers
+    attrs: "dict[str, Any]"
 
 
 @dataclasses.dataclass
@@ -468,6 +527,15 @@ class ContinuousGPTEngine:
         self._inflight: dict[int, _InFlight] = {}
         self._prefilling: dict[int, _Prefill] = {}
         self._last_tok = np.zeros((n_slots,), np.int32)
+        #: the paged decode steps launched and not yet read, oldest first
+        #: (the loop keeps ONE ahead of its own reads; two for the moment
+        #: between a launch and the read before it), and when the last
+        #: one was read
+        self._steps_out: "collections.deque[_StepOut]" = collections.deque()
+        self._collected_at = 0.0
+        #: (slot, prefill, its first token on the device) of the prompts
+        #: whose last chunk this tick dispatched; read later in the tick
+        self._firsts: "list[tuple[int, _Prefill, Any]]" = []
         self._prefill_seconds = 0.0
         self._prefill_chunks = 0
         self._deferrals = 0
@@ -556,6 +624,10 @@ class ContinuousGPTEngine:
             self._table = np.full((n_slots, mb), self._pool.sentinel,
                                   np.int32)
             self._pidx = np.zeros((n_slots,), np.int32)
+            # every slot's newest token as the last step left it ON THE
+            # DEVICE: the next step's input for the rows whose ids the host
+            # has not read (device_put: jnp.zeros would compile a program)
+            self._dev_tok = jax.device_put(np.zeros((n_slots,), np.int32))
             # what the programs close over (serving/paged_programs.py):
             # derived here, set by nobody
             sizes = self._sizes = programs.PagedSizes(
@@ -570,7 +642,7 @@ class ContinuousGPTEngine:
             # call site below rebinds self._pool_kv.
             self._paged_step_fn = jax.jit(
                 bound(programs._paged_step, sizes, model),
-                donate_argnums=(1,), static_argnums=(5, 6))
+                donate_argnums=(1,), static_argnums=(6, 7))
             self._paged_verify_fn = jax.jit(
                 bound(programs._paged_verify, sizes, model),
                 donate_argnums=(1,), static_argnums=(5, 6))
@@ -844,6 +916,7 @@ class ContinuousGPTEngine:
         instantly. Idempotent-ish: a second call returns []."""
         self.queue.close()
         reqs = self.queue.extract_pending()
+        self._settle()
         flight_mod.record_event(
             "engine.drain_begin", engine=getattr(self._obs, "name", None),
             host=self.host_id, extracted=len(reqs),
@@ -933,10 +1006,11 @@ class ContinuousGPTEngine:
     # -- one scheduling quantum ---------------------------------------------
     def tick(self) -> bool:
         """Admit into free slots, advance chunked prefills by at most
-        ``prefill_chunk`` tokens, advance every live row one token,
-        retire finished rows. Returns True if any work happened (False =
-        idle tick). Thread-safe; the background loop is just
-        ``while True: tick()``."""
+        ``prefill_chunk`` tokens, launch every live row's next token,
+        then read and retire the step launched a tick ago (the module
+        docstring has the order and why). Returns True if any work
+        happened (False = idle tick). Thread-safe; the background loop
+        is just ``while True: tick()``."""
         # one span a WORKING tick, in a trace of its own that links its
         # riders; an idle engine ticks 200 times a second and leaves none
         with self._lock, span("serving.tick") as tick_span:
@@ -956,13 +1030,19 @@ class ContinuousGPTEngine:
                             and s not in self._prefilling]
             if free:
                 wait = (0.0 if self._inflight or self._prefilling
-                        else self.idle_wait_s)
+                        or self._steps_out else self.idle_wait_s)
                 reqs = self.queue.take(len(free), wait)
                 deferred = False
                 for i, req in enumerate(reqs):
                     slot = free.pop(0)
                     try:
                         placed = self._admit_traced(slot, req)
+                        if not placed and self._steps_out:
+                            # the rows whose last ids are still out hold
+                            # blocks that a loop which had read them
+                            # would have freed by now: read them, ask again
+                            self._collect()
+                            placed = self._admit_traced(slot, req)
                     except Exception as e:
                         # take() already moved this Future to RUNNING, so
                         # nobody else can resolve it: a failed admission
@@ -1004,7 +1084,9 @@ class ContinuousGPTEngine:
             if self._prefilling:
                 self._prefill_tick()
                 did_work = True
-            if self._inflight:
+            if self._inflight or self._steps_out or self._firsts:
+                # (no live row but a step still out: the last tokens of
+                # a drain, or the step a late eos rode)
                 self._decode_step()
                 did_work = True
             if not (did_work or admitted):
@@ -1092,6 +1174,7 @@ class ContinuousGPTEngine:
         # the same teardown discipline as _sp_abort: drop the prefill
         # record, release staging + every pool reference, THEN requeue
         # — on the fault path too, so the victim is never lost
+        self._collect()
         del self._prefilling[slot]
         self._release_sp_staging(st)
         self._prefix.release(st.all_blocks())
@@ -1184,10 +1267,10 @@ class ContinuousGPTEngine:
         self.metrics.record_tokens(1, phase="prefill")
         self._start[slot] = lp - len(gen.prompt)
         self._last_tok[slot] = first
-        flight = _InFlight(req, [first], gen.max_new_tokens)
+        flight = _InFlight(req, [first], gen.max_new_tokens, slot=slot)
         self._inflight[slot] = flight
         if self._is_done(flight):  # max_new_tokens=1, or instant eos
-            self._complete(slot)
+            self._complete(flight)
 
     # -- paged admission + chunked prefill -----------------------------------
     def _admit_paged(self, slot: int, req: Request) -> bool:
@@ -1411,6 +1494,7 @@ class ContinuousGPTEngine:
                 "park_cold needs a host tier: construct the engine "
                 "with host_kv_blocks")
         with self._lock:
+            self._collect()
             n = (max_blocks if max_blocks is not None
                  else self._prefix.cached_blocks)
             freed = self._prefix.demote(
@@ -1441,6 +1525,7 @@ class ContinuousGPTEngine:
         t0 = time.monotonic()
         sessions: "list[dict]" = []
         with self._lock:
+            self._collect()
             paths = self._prefix.parked_leaf_paths()
             if max_sessions is not None:
                 paths = paths[:int(max_sessions)]
@@ -1626,11 +1711,23 @@ class ContinuousGPTEngine:
         _M_PREFILL_CHUNKS.inc()
         if final:
             # the chunk's last REAL column seeds decode (argmax on
-            # device: the same op the oracle's generate uses)
-            with self._first_token_span(st.req, slot):
-                tok = int(jnp.argmax(logits[0, r - 1]))
-            self._finish_prefill(slot, st, tok)
+            # device: the same op the oracle's generate uses), queued
+            # right behind the chunk. It is READ after this tick's decode
+            # step is launched (_read_first_tokens): waiting for it here
+            # would leave the device with nothing queued behind the chunk
+            self._firsts.append((slot, st, jnp.argmax(logits[0, r - 1])))
         self._prefill_seconds += time.perf_counter() - t0
+
+    def _read_first_tokens(self) -> None:
+        """Wait for the last chunks this tick dispatched and read each
+        prompt's first token: its row joins the decode batch with it."""
+        firsts, self._firsts = self._firsts, []
+        for slot, st, first in firsts:
+            t0 = time.perf_counter()
+            with self._first_token_span(st.req, slot):
+                tok = int(first)
+            self._finish_prefill(slot, st, tok)
+            self._prefill_seconds += time.perf_counter() - t0
 
     def _first_token_span(self, req: Request, slot: int):
         """Around the blocking read that ends a prefill: the host waits
@@ -1655,16 +1752,26 @@ class ContinuousGPTEngine:
             tuple(int(t) for t in st.prompt),
             [int(b) for b in row[:n_prompt_blocks]],
         )
-        self._pidx[slot] = plen
-        self._last_tok[slot] = first
         self.metrics.record_tokens(1, phase="prefill")
         del self._prefilling[slot]
         flight = _InFlight(st.req, [first], st.max_new,
                            blocks=st.shared + st.owned,
                            prompt=st.prompt)
-        self._inflight[slot] = flight
+        self._join_decode(slot, flight, plen)
         if self._is_done(flight):  # max_new_tokens=1, or instant eos
-            self._complete(slot)
+            self._complete(flight)
+
+    def _join_decode(self, slot: int, flight: _InFlight,
+                     depth: int) -> None:
+        """A row joins the decode batch (paged layout) with ``depth``
+        columns of K/V in its blocks and a first token the HOST knows,
+        ``flight.produced[-1]``: a prefill's, a handoff's, a resume's. No
+        launched step made that token, so the next step takes the host's
+        word for this row and not the device's (``_launch_step``)."""
+        self._pidx[slot] = depth
+        self._last_tok[slot] = flight.produced[-1]
+        flight.slot = slot
+        self._inflight[slot] = flight
 
     # -- sequence-parallel chunk dispatch + handoff ---------------------------
     def _sp_chunk_step(self, slot: int, st: _Prefill, r: int) -> None:
@@ -1778,17 +1885,25 @@ class ContinuousGPTEngine:
             self._sp_pool.release(self._sp_pool.deref(st.sp_blocks))
             st.sp_blocks = None
 
-    def _release_slot(self, slot: int,
-                      blocks: "list[int] | None") -> None:
-        """Return a retiring slot's table to sentinel and drop its block
-        references (registered prompt blocks stay cached for prefix
-        reuse; the rest free)."""
-        if self.kv_layout != "paged":
+    def _vacate(self, flight: _InFlight) -> None:
+        """Give a decoding row's slot back, if it still has one: the slot
+        is free for admission and its table row empty. The row's blocks
+        are another matter (:meth:`_free`)."""
+        slot, flight.slot = flight.slot, None
+        if slot is None:
             return
-        self._table[slot] = self._pool.sentinel
-        self._pidx[slot] = 0
-        if blocks:
-            self._prefix.release(blocks)
+        del self._inflight[slot]
+        if self.kv_layout == "paged":
+            self._table[slot] = self._pool.sentinel
+            self._pidx[slot] = 0
+
+    def _free(self, flight: _InFlight) -> None:
+        """A decoding row ends, whichever way: its slot back, if it still
+        has one, and its block references dropped (registered prompt
+        blocks stay cached for prefix reuse; the rest free)."""
+        self._vacate(flight)
+        if flight.blocks:
+            self._prefix.release(flight.blocks)
 
     def _bounded_tokens(self, now: float, cap: int) -> int:
         """Clamp a per-dispatch token count to (a) the smallest
@@ -1800,30 +1915,30 @@ class ContinuousGPTEngine:
         survived. Shared by the chained decode AND the speculative
         verify width — budget/deadline semantics cannot drift between
         the two."""
-        cap = min(cap, *(
-            f.max_new - len(f.produced) for f in self._inflight.values()
-        ))
+        going = [f for _, f in self._going_on()]
+        cap = min(cap, *(f.left for f in going))
         tok_s = self._chain_policy.program_s
         if tok_s:
-            for f in self._inflight.values():
+            for f in going:
                 if f.req.deadline is not None:
                     headroom = (f.req.deadline - now) / (2.0 * tok_s)
                     cap = min(cap, int(headroom))
-        elif any(f.req.deadline is not None
-                 for f in self._inflight.values()):
+        elif any(f.req.deadline is not None for f in going):
             # no per-token estimate yet and a deadline is in flight: the
             # first dispatch doubles as the measurement probe at k=1 so
             # a request can never expire inside an unmeasured chain
             return 1
         return cap
 
-    def _count_kv_read(self, nb: int, steps: int = 1) -> "dict[str, int]":
+    def _count_kv_read(self, nb: int, slots: "list[int]",
+                       steps: int = 1) -> "dict[str, int]":
         """Count what a paged dispatch gathers through the block table,
         per layer, and hand it back as span arguments: every slot's
         ``nb`` blocks at each of the dispatch's ``steps`` model passes
-        (``kv_cols_read``), and how much of that is a live row's context,
-        which deepens by one a pass (``kv_cols_live``)."""
-        depths = [int(self._pidx[s]) for s in self._inflight]
+        (``kv_cols_read``), and how much of that is the context of a row
+        that rides it (``slots``), which deepens by one a pass
+        (``kv_cols_live``)."""
+        depths = [int(self._pidx[s]) for s in slots]
         read = self.n_slots * nb * self._kv_bs * steps
         live = steps * sum(depths) + len(depths) * steps * (steps - 1) // 2
         self.metrics.record_kv_read(read, live)
@@ -1939,7 +2054,7 @@ class ContinuousGPTEngine:
                  if tracing.tracing_enabled() else ())
         # the span runs on over the acceptance loop, so that it can say
         # how many tokens the verify made (its ``tokens``)
-        cols = self._count_kv_read(nb)  # one pass, k wide
+        cols = self._count_kv_read(nb, list(self._inflight))  # one pass, k wide
         with span("serving.spec_verify", slots=len(self._inflight),
                   k=k, links=links, **cols) as verify:
             out, self._pool_kv = self._paged_verify_fn(
@@ -1979,7 +2094,7 @@ class ContinuousGPTEngine:
                     self._pidx[slot] += 1
                     tokens += 1
                     if self._is_done(flight):
-                        self._complete(slot)
+                        self._complete(flight)
                         break
             verify.set_attr(tokens=tokens)
         self._spec_tokens += tokens
@@ -1998,48 +2113,162 @@ class ContinuousGPTEngine:
         return True
 
     def _decode_step(self) -> None:
-        if (self.spec_k is not None and self.kv_layout == "paged"
-                and self._spec_step()):
+        """Advance the live rows. The paged loop keeps ONE step ahead of
+        its own reads: step n+1 is launched from step n's tokens while
+        they are still on the device, and only then are step n's ids
+        waited for, read and retired, so everything the host does between
+        two launches runs under a step. Two programs on other branches
+        stay synchronous: the dense reference (its ``_step_fn``), and a
+        speculative engine, whose verify needs the accepted count on the
+        host before its width is chosen."""
+        if self.kv_layout != "paged":
+            self._dense_step()
+            return
+        if self.spec_k is not None:
+            self._read_first_tokens()
+            if self._inflight and not self._spec_step():
+                self._launch_step(False)
+                self._collect()
+            return
+        ahead = bool(self._steps_out)
+        self._launch_step(ahead)
+        # with the step launched: the host waits for a prompt's last
+        # chunk, which lies behind step n on the device, with step n+1
+        # queued behind the chunk. The rows that join here ride step n+2
+        self._read_first_tokens()
+        if ahead:
+            self._collect_step()
+        if not self._steps_out:
+            # nothing is running under the host (no row went on above):
+            # what has just joined goes at once
+            self._launch_step(False)
+
+    def _going_on(self) -> "list[tuple[int, _InFlight]]":
+        """The live rows that have budget left once the tokens of the step
+        in flight are counted: a row that ends by its budget is known by
+        count before any read, and rides no further step."""
+        return [(s, f) for s, f in self._inflight.items() if f.left > 0]
+
+    def _launch_step(self, ahead: bool) -> None:
+        """Launch one paged step for the rows that go on, if any does.
+        ``ahead``: the step before is still unread (its rows' newest
+        tokens are on the device alone)."""
+        rows = self._going_on()
+        if not rows:
             return
         k = self._decode_chain_len(time.monotonic())
-        paged = self.kv_layout == "paged"
-        shape, cols = {}, {}
-        if paged:
-            # static gather width: blocks covering the deepest live
-            # row through this whole chain (idx advances k), bucketed
-            # to a power of two for compile reuse, capped at the
-            # table width. It decides what the tick costs, so it rides
-            # on the spans.
-            need = max((self._pidx[s] for s in self._inflight),
-                       default=0) + k
-            nb = pow2_bucket(-(-need // self._kv_bs), 1, self._mb)
-            shape["nb"] = nb
-            cols = self._count_kv_read(nb, k)
-        t0 = time.perf_counter()
+        # static gather width: blocks covering the deepest live row
+        # through this whole chain (idx advances k), bucketed to a power
+        # of two for compile reuse, capped at the table width. It decides
+        # what the tick costs, so it rides on the spans.
+        slots = [s for s, _ in rows]
+        need = max(int(self._pidx[s]) for s in slots) + k
+        nb = pow2_bucket(-(-need // self._kv_bs), 1, self._mb)
         # decode ticks are batch-level: their spans link every rider's
         # request id so each request's trace pulls in its decode steps
+        links = ([f.req.request_id for _, f in rows]
+                 if tracing.tracing_enabled() else ())
+        attrs = dict(slots=len(rows), chain=k, links=links, nb=nb,
+                     **self._count_kv_read(nb, slots, k))
+        # the host's word for a row's token, -1 where the step in flight
+        # is making it (the program then takes its own, _dev_tok)
+        tok = self._last_tok.copy()
+        for slot, f in rows:
+            if f.unread:
+                tok[slot] = -1
+        t0 = time.monotonic()
+        # table and cursor go over as COPIES: the host writes both again
+        # (a row joins, a row retires, the cursor below) before the step
+        # that was handed them has run
+        with span("serving.decode_dispatch", k=k, nb=nb, ahead=int(ahead)):
+            toks, self._dev_tok, self._pool_kv = self._paged_step_fn(
+                self.variables, self._pool_kv,
+                jnp.asarray(self._table.copy()),
+                jnp.asarray(self._pidx.copy()),
+                jnp.asarray(tok), self._dev_tok, k, nb,
+            )
+        # Async token readback (runtime/completion.py): the D2H copy of
+        # the ids is enqueued the moment the step is, and rides behind it
+        fetch = start_fetch(toks, path="decode")
+        _M_DECODE_AHEAD.inc(ahead=str(int(ahead)))
+        self._steps_out.append(_StepOut(toks, fetch, rows, t0, attrs))
+        for slot, f in rows:
+            # one column written per decoded token, whatever the token
+            # is: the cursor moves at the launch, so that the next launch
+            # needs nothing of this step's result
+            f.unread += k
+            self._pidx[slot] += k
+            if f.left == 0:
+                # its last tokens are on their way, which the host knows
+                # by count: the slot is free for the next admission as
+                # early as in a loop that had read them by now. The
+                # blocks stay the row's until it has all its tokens.
+                self._vacate(f)
+
+    def _collect_step(self) -> None:
+        """Wait for the oldest unread step's ids, read them, retire its
+        rows. ``serving.decode_step`` is recorded HERE: from the step's
+        launch to the instant its ids reached the host. The step leaves
+        ``_steps_out`` once it is read: one the device lost stays there,
+        for ``_fail_inflight`` to fail its rows."""
+        step = self._steps_out[0]
+        # block_until_ready splits compute from collection so
+        # sparkdl_fetch_wait_seconds{path="decode"} meters ONLY the
+        # residual copy wait, not the decode program itself
+        with span("serving.decode_wait"):
+            jax.block_until_ready(step.toks)
+        # sparkdl-lint: disable=blocking-in-hot-loop -- block_until_ready above completed the step; only the already-enqueued D2H copy remains
+        toks = np.asarray(step.fetch.result())
+        now = time.monotonic()
+        self._steps_out.popleft()
+        attrs, k = step.attrs, step.attrs["chain"]
+        if self._family.expert_layers:
+            # an expert family's step: rows each expert got ride behind
+            # the tokens in the same read
+            attrs.update(self._count_experts(toks[:, self.n_slots:]))
+            toks = toks[:, :self.n_slots]
+        tracing.record_span("serving.decode_step", step.t0, now,
+                            parent=tracing.current_context(), **attrs)
+        for _, flight in step.rows:
+            flight.unread -= k
+        # what one step costs the loop: the interval since the step
+        # before was read, or since this one's launch where nothing was
+        # out then. A launch-to-read wall of a step launched AHEAD would
+        # hold the rest of the step before it too.
+        wall = now - max(step.t0, self._collected_at)
+        self._collected_at = now
+        record_dispatch("decode", k, wall)
+        self._chain_policy.record(wall, k)
+        self.metrics.record_batch(len(step.rows), self.n_slots)
+        self._retire(toks, k, step.rows, attrs["links"])
+
+    def _collect(self) -> None:
+        """Read every step that is out and retire its rows: after it the
+        host's view of every row (``produced``, blocks, Futures) is what a
+        synchronous loop's would be. Whatever touches that view outside
+        the decode loop's own order calls this first, under the engine
+        lock. (Device order needs no such care: the pool is threaded
+        through every program.)"""
+        while self._steps_out:
+            self._collect_step()
+
+    def _settle(self) -> None:
+        """:meth:`_collect` for a caller that does not hold the engine
+        lock."""
+        with self._lock:
+            self._collect()
+
+    def _dense_step(self) -> None:
+        """The dense reference's step: launched, waited for, read and
+        retired in one tick."""
+        k = self._decode_chain_len(time.monotonic())
+        t0 = time.perf_counter()
         links = ([f.req.request_id for f in self._inflight.values()]
                  if tracing.tracing_enabled() else ())
         with span("serving.decode_step", slots=len(self._inflight),
-                  chain=k, links=links, **shape, **cols) as step:
-            # Async token readback (runtime/completion.py): the D2H copy
-            # of the token ids is enqueued the moment the decode dispatch
-            # is — it rides behind the compute instead of waiting for the
-            # host to come back with a blocking np.asarray after the
-            # program retires.
-            # block_until_ready splits compute from collection so
-            # sparkdl_fetch_wait_seconds{path="decode"} meters ONLY the
-            # residual copy wait, not the decode program itself. The two
-            # child spans split the same way: the host's own work to
-            # launch the program, then its wait for the device.
-            with span("serving.decode_dispatch", k=k, **shape):
-                if paged:
-                    toks, self._pool_kv = self._paged_step_fn(
-                        self.variables, self._pool_kv,
-                        jnp.asarray(self._table), jnp.asarray(self._pidx),
-                        jnp.asarray(self._last_tok), k, nb,
-                    )
-                elif k == 1:
+                  chain=k, links=links):
+            with span("serving.decode_dispatch", k=k):
+                if k == 1:
                     toks, self._cache = self._step_fn(
                         self.variables, self._cache,
                         jnp.asarray(self._last_tok),
@@ -2056,39 +2285,39 @@ class ContinuousGPTEngine:
                 jax.block_until_ready(toks)
             # sparkdl-lint: disable=blocking-in-hot-loop -- block_until_ready above completed the dispatch; only the already-enqueued D2H copy remains
             toks = np.asarray(fetch.result())
-            if toks.ndim == 1:  # the unchained dense step: [S] -> [1, S]
+            if toks.ndim == 1:  # the unchained step: [S] -> [1, S]
                 toks = toks[None]
-            if self._family.expert_layers:
-                # an expert family's step: rows each expert got ride
-                # behind the tokens in the same read
-                step.set_attr(**self._count_experts(toks[:, self.n_slots:]))
-                toks = toks[:, :self.n_slots]
         wall = time.perf_counter() - t0
         record_dispatch("decode", k, wall)
         self._chain_policy.record(wall, k)
         self.metrics.record_batch(len(self._inflight), self.n_slots)
-        # what the tick made: rows times k, less what an eos or a spent
-        # budget dropped mid-chain
+        self._retire(toks, k, list(self._inflight.items()), links)
+
+    def _retire(self, toks: np.ndarray, k: int,
+                rows: "list[tuple[int, _InFlight]]", links) -> None:
+        """Hand a read step's ``k`` tokens to the ``rows`` that rode it.
+        What the step made: rows times k, less what an eos dropped
+        mid-chain, and less the rows that ended while it was out."""
         with span("serving.retire", links=links) as retire:
             tokens = completed = 0
             for j in range(k):
-                live = [s for s in self._inflight]
+                # a row found at its eos one step late rode this step
+                # too: its Future is resolved by now, and what the step
+                # made for it is dropped — rows are independent, so it
+                # influenced nobody, and its one column fell in a block
+                # the row still owned at the launch
+                live = [(s, f) for s, f in rows if not f.req.future.done()]
                 if not live:
                     break
-                for slot in live:
-                    flight = self._inflight[slot]
+                for slot, flight in live:
                     flight.produced.append(int(toks[j, slot]))
-                    self._last_tok[slot] = toks[j, slot]
+                    if flight.slot is not None:
+                        self._last_tok[slot] = toks[j, slot]
                     tokens += 1
-                    if paged:
-                        # one column written per decoded token: keep the
-                        # host block-table cursor in lockstep
-                        self._pidx[slot] += 1
                     if self._is_done(flight):
-                        # eos (or budget) mid-chain: any later tokens the
-                        # chain decoded for this row are simply dropped —
-                        # rows are independent, so they influenced nobody
-                        self._complete(slot)
+                        # eos mid-chain: any later tokens the chain
+                        # decoded for this row are dropped the same way
+                        self._complete(flight)
                         completed += 1
             retire.set_attr(tokens=tokens, completed=completed)
         self.metrics.record_tokens(tokens, phase="decode")
@@ -2109,11 +2338,12 @@ class ContinuousGPTEngine:
                 **({"error": type(error).__name__} if error else {}),
             )
 
-    def _register_session(self, slot: int, flight: _InFlight) -> None:
+    def _register_session(self, flight: _InFlight) -> None:
         """Index the finished turn's whole sequence — prompt plus
-        produced tokens minus the last (columns ``[0, pidx)`` hold
-        exactly the KV of ``prompt + produced[:-1]``, the _pidx
-        invariant) — so the session's NEXT turn, whose prompt embeds
+        produced tokens minus the last (the row's first columns hold
+        exactly the KV of ``prompt + produced[:-1]``, in its blocks in
+        table order; one more column may follow, written by the step a
+        late eos rode) — so the session's NEXT turn, whose prompt embeds
         this turn verbatim, parks and resumes instead of
         re-prefilling. Tiered engines only: without a park tier the
         extra registrations would just bloat the LRU."""
@@ -2122,14 +2352,14 @@ class ContinuousGPTEngine:
         if not seq:
             return
         nb = -(-len(seq) // self._kv_bs)
-        row = self._table[slot]
-        self._prefix.register(seq, [int(b) for b in row[:nb]])
+        self._prefix.register(seq, [int(b) for b in flight.blocks[:nb]])
 
-    def _complete(self, slot: int) -> None:
-        flight = self._inflight.pop(slot)
+    def _complete(self, flight: _InFlight) -> None:
+        """The row has all its tokens: it holds nothing any more, and its
+        Future resolves."""
         if self._kv_tiers is not None:
-            self._register_session(slot, flight)
-        self._release_slot(slot, flight.blocks)
+            self._register_session(flight)
+        self._free(flight)
         now = time.monotonic()
         self._record_request_span(
             flight.req, now, ok=True, tokens=len(flight.produced))
@@ -2160,23 +2390,26 @@ class ContinuousGPTEngine:
             reg.note_outcome(req.tenant, now - req.enqueued, ok=False)
 
     def _expire_inflight(self, now: float) -> None:
-        for slot in list(self._inflight):
-            flight = self._inflight[slot]
-            if flight.req.expired(now):
-                self._inflight.pop(slot)
-                self._release_slot(slot, flight.blocks)
-                self._fail_request(
-                    flight.req,
-                    DeadlineExceededError(
-                        "deadline exceeded mid-decode "
-                        f"({len(flight.produced)}/{flight.max_new} "
-                        "tokens)"),
-                    tokens=len(flight.produced))
+        late = [f for f in self._inflight.values() if f.req.expired(now)]
+        if late:
+            # the tokens they are owed first: a row may have finished
+            self._collect()
+        for flight in late:
+            if flight.req.future.done():
+                continue
+            self._free(flight)
+            self._fail_request(
+                flight.req,
+                DeadlineExceededError(
+                    "deadline exceeded mid-decode "
+                    f"({len(flight.produced)}/{flight.max_new} "
+                    "tokens)"),
+                tokens=len(flight.produced))
         for slot in list(self._prefilling):
             st = self._prefilling[slot]
             if st.req.expired(now):
                 self._prefilling.pop(slot)
-                self._release_slot(slot, st.all_blocks())
+                self._prefix.release(st.all_blocks())
                 self._release_sp_staging(st)
                 self._fail_request(
                     st.req,
@@ -2186,14 +2419,27 @@ class ContinuousGPTEngine:
                     tokens=0)
 
     def _fail_inflight(self, exc: Exception) -> None:
-        for slot in list(self._inflight):
-            flight = self._inflight.pop(slot)
-            self._release_slot(slot, flight.blocks)
+        try:
+            self._collect()
+        except Exception as e:
+            # the step died with the device (a crashed loop lands here):
+            # its rows fail below with the rest, none is left waiting
+            flight_mod.record_event(
+                "engine.step_lost", error=type(e).__name__,
+                engine=getattr(self._obs, "name", None))
+        # every row still owed tokens: those in a slot, and those that
+        # gave theirs back when a step that is now lost was launched
+        owed = list(self._inflight.values()) + [
+            f for step in self._steps_out for _, f in step.rows
+            if f.slot is None and not f.req.future.done()]
+        self._steps_out.clear()
+        for flight in owed:
+            self._free(flight)
             self._fail_request(flight.req, exc,
                                tokens=len(flight.produced))
         for slot in list(self._prefilling):
             st = self._prefilling.pop(slot)
-            self._release_slot(slot, st.all_blocks())
+            self._prefix.release(st.all_blocks())
             self._release_sp_staging(st)
             self._fail_request(st.req, exc, tokens=0)
 
@@ -2360,6 +2606,12 @@ class ContinuousGPTEngine:
         }
 
     def snapshot(self) -> dict[str, Any]:
+        # between ticks this reads what a synchronous loop would show;
+        # mid-tick it reads a live engine as it always did, and does not
+        # wait for the lock (the caller may be a Future's callback on the
+        # engine's own thread, inside the tick)
+        if not self._lock.locked():
+            self._settle()
         out = self.metrics.snapshot(self.queue)
         out["host_id"] = self.host_id
         out["capacity"] = self.capacity()

@@ -62,7 +62,7 @@ def bound(fn, *head):
 
 # -- the tick -----------------------------------------------------------------
 
-def _paged_step(sizes, model, variables, pool, table, idx, tok, k, nb):
+def _paged_step(sizes, model, variables, pool, table, idx, tok, prev, k, nb):
     # k tokens for every slot THROUGH the block table: the model takes the pool
     # itself as a paged cache (a ``table`` entry, models/gpt.py), so each layer
     # gathers only its own live blocks into an [S, nb*bs] slice — same math
@@ -78,7 +78,15 @@ def _paged_step(sizes, model, variables, pool, table, idx, tok, k, nb):
     # positions need no start offset. Sentinel table entries clip on gather
     # (masked garbage) and write nothing (kv_pool.scatter_columns: no block
     # corrupted).
+    #
+    # A row's input token is the HOST's where it has one (``tok >= 0``: the row
+    # joined since the last step, from a prefill, a handoff or a resume, or the
+    # last step's ids have been read) and otherwise the one the step before
+    # this made, ``prev``, which is that step's second output and has never
+    # left the device: the engine launches a step before it has read the last
+    # one's ids. Every step hands its last tokens on the same way.
     sub = table[:, :nb]
+    tok = jnp.where(tok >= 0, tok, prev)
 
     def body(carry, _):
         pool, idx, tok = carry
@@ -86,7 +94,7 @@ def _paged_step(sizes, model, variables, pool, table, idx, tok, k, nb):
             variables, tok[:, None],
             cache=dict(pool, table=sub, idx=idx),
         )
-        ntok = jnp.argmax(logits[:, -1], axis=-1)
+        ntok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         rows = jnp.arange(sizes.n_slots)
         blk = table[rows, idx // sizes.block_size]
         off = idx % sizes.block_size
@@ -97,14 +105,13 @@ def _paged_step(sizes, model, variables, pool, table, idx, tok, k, nb):
             # rows each expert got, behind the step's tokens: ONE array, so one
             # device-to-host read a tick
             out = jnp.concatenate(
-                [ntok.astype(jnp.int32),
-                 new["expert_counts"].reshape(-1)])
+                [ntok, new["expert_counts"].reshape(-1)])
         return (pool, idx + 1, ntok), out
 
-    (pool, _, _), toks = lax.scan(
+    (pool, _, last), toks = lax.scan(
         body, (pool, idx, tok), None, length=k
     )
-    return toks, pool
+    return toks, last, pool
 
 
 def _paged_verify(sizes, model, variables, pool, table, idx, toks, k, nb):

@@ -86,6 +86,11 @@ def test_budget_bound_never_delays_retirement(bundle):
     before = dispatch_count("decode")
     fut = eng.submit([5, 3, 9, 2, 7], 3)
     eng.tick()  # admit + one chained decode dispatch of exactly k=2
+    # its last tokens by count: the slot is free, the Future not yet done
+    (step,) = eng._steps_out
+    assert step.attrs["chain"] == 2 and eng.active_slots == 0
+    assert not fut.done()
+    eng.tick()  # nothing left to launch: its ids are read, the row retires
     assert fut.done()
     assert dispatch_count("decode") - before == 1
     np.testing.assert_array_equal(
@@ -119,7 +124,10 @@ def test_cold_first_dispatch_with_deadline_probes_at_k1(bundle):
     fut = eng.submit([3, 4], 9, timeout_s=30.0)
     eng.tick()
     flight = next(iter(eng._inflight.values()))
-    assert len(flight.produced) == 2  # prefill token + ONE probed token
+    # prefill token, and ONE probed token on its way
+    assert (len(flight.produced), flight.unread) == (1, 1)
+    eng.tick()  # still unmeasured when the next is launched: one again
+    assert (len(flight.produced), flight.unread) == (2, 1)
     assert not fut.done()
     eng.close(drain=False)
 
@@ -138,9 +146,52 @@ def test_tight_deadline_bounds_chain_len(bundle):
     eng.tick()  # admission + first decode dispatch
     flight = next(iter(eng._inflight.values()), None)
     if flight is not None:  # not already expired on a slow host
-        # prefill produced 1; a bounded dispatch adds exactly 1 token
-        assert len(flight.produced) == 2
+        # prefill produced 1; a bounded dispatch launches exactly 1 token
+        assert (len(flight.produced), flight.unread) == (1, 1)
     eng.close(drain=False)
+
+
+def test_the_chain_policy_is_fed_the_pace_of_the_loop_ahead(bundle,
+                                                             monkeypatch):
+    """The paged loop reads a step a tick after it launched it, so a
+    launch-to-read wall holds two steps. What ``ChainPolicy.record`` and
+    ``record_dispatch`` get is the interval between two successive reads,
+    one step's worth, so ``chain_tokens=None`` still sizes its chains by
+    what a step costs: here a step is made to cost 50 ms (a sleep after
+    each launch, the host going on underneath)."""
+    from sparkdl_tpu.serving import continuous
+
+    cfg, model, variables = bundle
+    step_s = 0.05
+    eng = _engine(cfg, variables, chain_tokens=1)
+    launch = eng._launch_step
+    walls = []
+
+    def slow_launch(ahead):
+        launch(ahead)
+        time.sleep(step_s)
+
+    def record(path, k, wall_s=None):
+        walls.append((path, k, wall_s))
+
+    monkeypatch.setattr(eng, "_launch_step", slow_launch)
+    monkeypatch.setattr(continuous, "record_dispatch", record)
+    try:
+        fut = eng.submit([5, 3, 9, 2, 7], 10)
+        while not fut.done():
+            eng.tick()
+    finally:
+        eng.close()
+    assert [(p, k) for p, k, _ in walls] == [("decode", 1)] * 9
+    # the first step was launched with nothing out: launch to read, which
+    # is its own tick's sleep and the next tick's
+    assert walls[0][2] >= 2 * step_s
+    for _, _, wall in walls[1:]:
+        assert step_s <= wall < 2 * step_s, walls
+    est = eng._chain_policy.program_s
+    assert 0.7 * step_s < est < 2 * step_s
+    np.testing.assert_array_equal(
+        fut.result(timeout=0), _oracle(model, variables, [5, 3, 9, 2, 7], 10))
 
 
 def test_threaded_engine_with_chaining(bundle):

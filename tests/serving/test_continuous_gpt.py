@@ -62,7 +62,10 @@ def test_join_leave_oracle_manual_ticks(bundle):
         ([2, 2, 2], 4),
     ]
     futs = [eng.submit(p, n) for p, n in cases[:2]]
-    eng.tick()              # admit A+B, first shared step (2 tokens each)
+    # the paged loop reads a step's ids a tick after it launched the step
+    eng.tick()              # admit A+B, first tokens; step 1 launched
+    eng.tick()              # step 2 launched; step 1 read (2 tokens each)
+    assert not futs[1].done()
     eng.tick()              # 3rd tokens: B (max_new=3) leaves, A decodes on
     assert futs[1].done() and not futs[0].done()
     futs.append(eng.submit(*cases[2]))

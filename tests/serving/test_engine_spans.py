@@ -22,8 +22,10 @@ from sparkdl_tpu.serving import ContinuousGPTEngine
 #: (prompt tokens, max new tokens): two slots, so requests queue, overlap,
 #: prefill in several chunks (chunk 8) and retire at different ticks
 REQUESTS = ((5, 4), (20, 6), (9, 1), (3, 7), (17, 3))
+#: what lies inside ONE tick (a paged ``serving.decode_step`` does not: it
+#: runs from its launch in one tick to the read of its ids in the next)
 PHASES = ("serving.admit", "serving.prefill_chunk", "serving.first_token",
-          "serving.decode_step", "serving.retire")
+          "serving.decode_dispatch", "serving.decode_wait", "serving.retire")
 
 
 @pytest.fixture(scope="module")
@@ -126,19 +128,42 @@ class TestTickTree:
             for a, b in zip(mine, mine[1:]):
                 assert _end(a) <= b["ts"], (a["name"], b["name"])
 
-    def test_decode_step_splits_into_dispatch_and_wait(self, served):
-        steps = {e["args"]["span_id"]: e
-                 for e in served["spans"]("serving.decode_step")}
-        kids = served["spans"]("serving.decode_dispatch", "serving.decode_wait")
-        assert len(kids) == 2 * len(steps)
-        for k in kids:
-            step = steps[k["args"]["parent_id"]]
-            assert step["ts"] <= k["ts"] and _end(k) <= _end(step)
-        for step in steps.values():
-            assert step["args"]["nb"] >= 1 and step["args"]["chain"] == 1
-        for d in served["spans"]("serving.decode_dispatch"):
-            assert d["args"]["nb"] == steps[d["args"]["parent_id"]][
-                "args"]["nb"] and d["args"]["k"] == 1
+    def test_decode_step_runs_from_its_dispatch_to_its_wait(self, served):
+        """Every step has one launch and one wait, in the order the steps
+        were launched: the step starts with its ``decode_dispatch`` and
+        ends when the ``decode_wait`` for ITS ids and their read are over,
+        which is a tick later wherever the loop ran ahead."""
+        def by_start(*names):
+            return sorted(served["spans"](*names), key=lambda e: e["ts"])
+
+        steps = by_start("serving.decode_step")
+        launches = by_start("serving.decode_dispatch")
+        waits = by_start("serving.decode_wait")
+        assert steps and len(launches) == len(waits) == len(steps)
+        ticks = by_start("serving.tick")
+
+        def tick_of(e):
+            (i,) = [i for i, t in enumerate(ticks)
+                    if t["ts"] <= e["ts"] and _end(e) <= _end(t)]
+            return i
+
+        for step, launch, wait in zip(steps, launches, waits):
+            assert step["ts"] <= launch["ts"] <= _end(launch) <= wait["ts"]
+            assert _end(wait) <= _end(step)
+            assert step["args"]["nb"] == launch["args"]["nb"] >= 1
+            assert step["args"]["chain"] == launch["args"]["k"] == 1
+            assert _end(step) <= _end(ticks[tick_of(wait)])
+        # a step launched with the one before it unread: the launch comes
+        # first, in the tick that then waits for the earlier step's ids
+        n_ahead = 0
+        for n in range(len(steps) - 1):
+            if launches[n + 1]["args"]["ahead"]:
+                n_ahead += 1
+                assert launches[n + 1]["ts"] < waits[n]["ts"]
+                assert tick_of(launches[n + 1]) == tick_of(waits[n])
+            else:
+                assert _end(waits[n]) <= launches[n + 1]["ts"]
+        assert n_ahead and not launches[0]["args"]["ahead"]
 
     def test_retire_and_decode_hang_under_their_tick(self, served):
         ticks = {e["args"]["span_id"]: e for e in served["spans"]("serving.tick")}
@@ -381,12 +406,18 @@ def test_a_profiler_capture_holds_the_engines_spans(bundle, traced,
             for ev in line.events:
                 if ev.name.startswith("serving."):
                     host.setdefault(ev.name, []).append(dict(ev.stats))
-    assert {"serving.tick", "serving.decode_step", "serving.decode_dispatch",
+    assert {"serving.tick", "serving.decode_dispatch",
             "serving.decode_wait", "serving.retire", "serving.admit",
             "serving.prefill_chunk", "serving.first_token"} <= set(host)
-    assert len(host["serving.decode_step"]) == 2
-    assert all(s["chain"] == 1 and s["nb"] >= 1
-               for s in host["serving.decode_step"])
+    # the LIVE spans are mirrored. A paged ``serving.decode_step`` is
+    # recorded when its ids are read, a tick after it began, so the capture
+    # holds the two live spans that bracket it: its launch and its wait
+    assert "serving.decode_step" not in host
+    assert len(host["serving.decode_dispatch"]) == 2
+    assert all(s["k"] == 1 and s["nb"] >= 1
+               for s in host["serving.decode_dispatch"])
+    assert [s["ahead"] for s in host["serving.decode_dispatch"]] == [0, 1]
+    assert len(host["serving.decode_wait"]) == 2
     assert host["serving.prefill_chunk"][0]["program"] == "chunk_one"
 
 
@@ -419,15 +450,16 @@ def test_kv_cols_read_and_live_equal_a_hand_count(bundle, traced):
     finally:
         eng.close()
     assert [len(o) for o in outs] == [3, 4, 2]
-    # tick 1 admits (5, 3) and (9, 4) and decodes both: depths 5 and 9
-    # under 3 blocks, bucketed to 4; tick 2: 6 and 10, after which the
-    # first is done; tick 3 admits (2, 2) beside the second at 11; its one
-    # decode step ends it, and the second has ended too
+    # tick 1 admits (5, 3) and (9, 4) and launches both: depths 5 and 9
+    # under 3 blocks, bucketed to 4; tick 2: 6 and 10, which is the first
+    # one's last by count, so its slot is free; tick 3 admits (2, 2), whose
+    # first token is read after the second's last step, at 11, is
+    # launched; tick 4 launches its one step alone, under one block
     want = _hand_count(2, 4, [([5, 9], 4, 1), ([6, 10], 4, 1),
-                              ([11, 2], 4, 1)])
+                              ([11], 4, 1), ([2], 1, 1)])
     steps = sorted(_spans("serving.decode_step"), key=lambda e: e["ts"])
     assert [(e["args"]["kv_cols_read"], e["args"]["kv_cols_live"])
-            for e in steps] == [(32, 14), (32, 16), (32, 13)]
+            for e in steps] == [(32, 14), (32, 16), (32, 11), (8, 2)]
     assert (sum(e["args"]["kv_cols_read"] for e in steps),
             sum(e["args"]["kv_cols_live"] for e in steps)) == want
     assert (snap["kv_cols_read"], snap["kv_cols_live"]) == want

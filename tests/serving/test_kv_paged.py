@@ -371,15 +371,18 @@ def decode_program(eng, which, k=2, nb=2):
     """``_paged_step`` or ``_paged_verify`` of ``eng`` with the arguments
     a tick would hand it (k tokens a row, ``nb`` blocks of the table)."""
     fn = {"step": eng._paged_step_fn, "verify": eng._paged_verify_fn}[which]
-    tok = (jnp.asarray(eng._last_tok) if which == "step"
-           else jnp.zeros((eng.n_slots, k), jnp.int32))
+    # the step takes each row's token from the host or, where the host has
+    # none yet, from the step before (its second output, ``_dev_tok``)
+    tok = ((jnp.asarray(eng._last_tok), eng._dev_tok) if which == "step"
+           else (jnp.zeros((eng.n_slots, k), jnp.int32),))
     return fn, (eng.variables, eng._pool_kv, jnp.asarray(eng._table),
-                jnp.asarray(eng._pidx), tok, k, nb)
+                jnp.asarray(eng._pidx), *tok, k, nb)
 
 
 def program_arrays(fn, args):
     """(primitive, shape, dtype) of everything the traced program makes."""
-    closed = jax.make_jaxpr(fn, static_argnums=(5, 6))(*args)
+    n = len(args)
+    closed = jax.make_jaxpr(fn, static_argnums=(n - 2, n - 1))(*args)
     return [(eqn.primitive.name, tuple(v.aval.shape), v.aval.dtype)
             for eqn in walk_jaxpr(closed.jaxpr) for v in eqn.outvars]
 

@@ -40,7 +40,8 @@ def lowered():
             (cfg.num_layers, 1, eng._wp) + pool["k"].shape[3:], cfg.dtype)
         return {
             "_paged_step": eng._paged_step_fn.lower(
-                v, pool, _ints(SLOTS, mb), _ints(SLOTS), _ints(SLOTS), 1, mb),
+                v, pool, _ints(SLOTS, mb), _ints(SLOTS), _ints(SLOTS),
+                _ints(SLOTS), 1, mb),
             "_chunk_one": eng._chunk_one_fn.lower(
                 v, pool, _ints(mb), _ints(), _ints(1, 8), _ints(mb), 8),
             "_chunk_first": eng._chunk_first_fn.lower(
@@ -88,11 +89,13 @@ def test_a_program_lowers_from_sizes_module_and_arrays_alone():
         max_pos=mb * bs + 16, dtype=cfg.dtype)
     pool = jax.eval_shape(lambda: kv_pool.init_block_pool(cfg, 4, bs))
     step = jax.jit(programs.bound(programs._paged_step, sizes, model),
-                   donate_argnums=(1,), static_argnums=(5, 6))
+                   donate_argnums=(1,), static_argnums=(6, 7))
     low = step.lower(variables, pool, _ints(SLOTS, mb), _ints(SLOTS),
-                     _ints(SLOTS), 1, mb)
+                     _ints(SLOTS), _ints(SLOTS), 1, mb)
     assert low.as_text().startswith("module @jit__paged_step ")
-    toks, out = low.out_info
-    assert toks.shape == (1, SLOTS)
+    # the ids for the host, and every slot's last one again for the step
+    # that is launched before the host has read them
+    toks, last, out = low.out_info
+    assert toks.shape == (1, SLOTS) and last.shape == (SLOTS,)
     assert {n: a.shape for n, a in out.items()} == {
         n: a.shape for n, a in pool.items()}

@@ -112,12 +112,13 @@ def compiled(one_chip, for_the_chip):
         head = (_on(one_chip, variables), _on(one_chip, pool))
         out = {"pool": pool["k"],
                "stored": _device_layout(one_chip, pool["k"])}
+        # (a step's tokens: the host's, and the step before's on the device)
         for name, fn, toks, k in (
-                ("step", eng._paged_step_fn, ints(SLOTS), 1),
-                ("chain", eng._paged_step_fn, ints(SLOTS), 4),
-                ("verify", eng._paged_verify_fn, ints(SLOTS, 4), 4)):
+                ("step", eng._paged_step_fn, (ints(SLOTS), ints(SLOTS)), 1),
+                ("chain", eng._paged_step_fn, (ints(SLOTS), ints(SLOTS)), 4),
+                ("verify", eng._paged_verify_fn, (ints(SLOTS, 4),), 4)):
             out[name] = fn.lower(
-                *head, ints(SLOTS, mb), ints(SLOTS), toks, k, mb).compile()
+                *head, ints(SLOTS, mb), ints(SLOTS), *toks, k, mb).compile()
         out["one"] = eng._chunk_one_fn.lower(
             *head, ints(mb), ints(), ints(1, 128), ints(mb), 128).compile()
         out["final"] = eng._chunk_final_fn.lower(
@@ -279,7 +280,8 @@ def compiled_afmoe(one_chip, for_the_chip, monkeypatch_module):
             "stored": _device_layout(one_chip, pool["k"]),
             "step": eng._paged_step_fn.lower(
                 _on(one_chip, variables), _on(one_chip, pool),
-                ints(slots, mb), ints(slots), ints(slots), 1, mb).compile(),
+                ints(slots, mb), ints(slots), ints(slots), ints(slots), 1,
+                mb).compile(),
             "one": eng._chunk_one_fn.lower(
                 _on(one_chip, variables), _on(one_chip, pool), ints(mb),
                 ints(), ints(1, 128), ints(mb), 128).compile(),
