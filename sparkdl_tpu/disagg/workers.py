@@ -57,6 +57,18 @@ from sparkdl_tpu.disagg.handoff import (
 __all__ = ["DecodeWorker", "PrefillWorker"]
 
 
+def _refuse_state(engine: ContinuousGPTEngine, what: str) -> None:
+    """A handoff is K/V blocks. A family with state layers keeps, beside
+    them, a recurrent state a slot that no block holds, and a sequence
+    cannot go on without it: refused, by name, until a payload carries it."""
+    fam = engine._family
+    if fam.state_layers:
+        raise NotImplementedError(
+            f"{what} is not implemented for {type(engine.config).__name__}"
+            f": {fam.state_layers} of its layers keep a recurrent state a "
+            "slot, which a handoff of K/V blocks does not carry")
+
+
 def _require_paged(kwargs: dict, who: str) -> None:
     if kwargs.get("kv_layout", "paged") != "paged":
         raise ValueError(
@@ -80,6 +92,10 @@ class PrefillWorker(ContinuousGPTEngine):
         self._export_aborts = 0
         if auto_start:
             self.start()
+
+    def submit(self, prompt_ids, max_new_tokens: int, **kwargs) -> Future:
+        _refuse_state(self, "exporting a prefill to a decode tier")
+        return super().submit(prompt_ids, max_new_tokens, **kwargs)
 
     def _admission_budget_tokens(self, max_new_tokens: int) -> int:
         # prompt blocks only: the decode tier owns the generation span
@@ -213,6 +229,7 @@ class DecodeWorker(ContinuousGPTEngine):
         enters via ``queue.adopt`` — already accepted upstream, so the
         depth limit never re-rejects it."""
         h = handoff
+        _refuse_state(self, "adopting another tier's prefill")
         if int(h.block_size) != self._kv_bs:
             raise ValueError(
                 f"handoff block_size {h.block_size} != decode tier "

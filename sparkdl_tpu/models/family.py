@@ -6,20 +6,26 @@ shaped, is the family's to say. A configuration answers
 ``config.serving_family()`` with a :class:`ServingFamily`; the engine reads
 nothing else off the configuration. ``GPTConfig`` answers with its own
 fields (so GPT-2's programs are what they were); a new family answers from
-its own module (``models/afmoe.py``).
+its own module (``models/afmoe.py``, ``models/olmo_hybrid.py``).
 
 The module's contract is :class:`~sparkdl_tpu.models.gpt.GPTLMHeadModel`'s:
 ``module.apply(variables, ids, cache=None | dense | paged, positions=...)``
 returns ``(logits, cache)``; a paged cache hands back this call's new
 columns ``[layers, S, L, *kv_tail]``, in the shape the pool stores a token's
 K or V in (:attr:`ServingFamily.kv_tail`), and the caller writes them into
-its pool.
+its pool. ``layers`` there are the layers that KEEP K/V
+(:attr:`ServingFamily.pool_layers`): a family may keep, in some layers, a
+recurrent state a SLOT instead (:attr:`ServingFamily.state_layers`), which
+the pool holds by slot beside its blocks and the module hands back whole.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
+
+import numpy as np
 
 from sparkdl_tpu.models import kv_pool
 
@@ -46,6 +52,28 @@ class ServingFamily:
     #: the family has the paged path alone: the dense layout and the
     #: sequence-parallel prefill refuse it at construction
     paged_only: bool = False
+    #: layers that keep K/V a token: the leading axis of the pool's ``k``
+    #: and ``v`` (None: every layer does)
+    kv_layers: "int | None" = None
+    #: layers that keep a recurrent state a SLOT, whatever the context's
+    #: length, and the arrays each keeps, ``(name, shape a slot, dtype)``:
+    #: the pool holds ``name`` as ``[state_layers, n_slots, *shape]``. No
+    #: block holds any of it, so what moves blocks alone (the prefix cache,
+    #: a park, a handoff) does not carry a sequence of such a family
+    state_layers: int = 0
+    state_arrays: "tuple[tuple[str, tuple[int, ...], Any], ...]" = ()
+
+    @property
+    def pool_layers(self) -> int:
+        """The leading axis of the pool's ``k`` and ``v``."""
+        return self.layers if self.kv_layers is None else self.kv_layers
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Bytes of recurrent state one slot holds, all state layers."""
+        return self.state_layers * sum(
+            math.prod(shape) * np.dtype(dtype).itemsize
+            for _, shape, dtype in self.state_arrays)
 
     @property
     def kv_tail(self) -> "tuple[int, ...]":

@@ -10,8 +10,18 @@ their scales (:data:`KV_DTYPES`, :func:`quantize_kv`), the sentinel block id
 and the forms of a read through a table, a block write and a column write
 that the chip's compiler answers IN PLACE, not with a copy of the pool.
 
-Both model families (``models/gpt.py``, ``models/afmoe.py``) read a layer's
-rows through :func:`layer_rows`; the serving engine's programs
+A family with recurrent layers (``models/olmo_hybrid.py``) keeps, beside its
+blocks, arrays indexed by SLOT (``[state_layers, n_slots, ...]``, as
+``ServingFamily.state_arrays`` names and shapes them): they ride the same
+donated dict through every program, and the rule is that what reads or
+writes BLOCKS leaves them alone. :func:`block_arrays` is the pool without
+them; the reads below gather from it, the write loops carry it alone and
+hand the slot arrays back as they came. ``layers`` of ``k`` and ``v`` are
+the layers that keep K/V, which for such a family are not all of them.
+
+Every model family (``models/gpt.py``, ``models/afmoe.py``,
+``models/olmo_hybrid.py``) reads a layer's rows through
+:func:`layer_rows`; the serving engine's programs
 (``serving/paged_programs.py``) read and write whole blocks and columns
 through the functions below it. Pure functions of a pool and indices: what
 the engine's closures once took from their enclosing scope is read off the
@@ -32,6 +42,8 @@ from jax import lax
 #: lanes of a TPU vector tile: a minor axis that is no multiple of it is
 #: padded to one by the chip, or loses the minor place to an axis that is
 LANE_TILE = 128
+#: sublanes of one: the axis before the minor one is stored in rows of it
+SUBLANE_TILE = 8
 
 #: Supported pool storage layouts: "fp32" stores at the model's compute
 #: dtype (exact, the default), "bf16"/"int8" compress the resident pool
@@ -43,14 +55,22 @@ KV_DTYPES = ("fp32", "bf16", "int8")
 
 def kv_tail(kv_heads: int, head_dim: int) -> "tuple[int, ...]":
     """The trailing axes of a token's K (or V) in a block pool, chosen from
-    the head size alone. A head that fills whole lane tiles keeps its own
-    axis, ``(kv_heads, head_dim)``. One that does not (GPT-2's 64) would
-    leave the chip no minor axis that tiles, and the chip then puts the
-    BLOCK axis in the lanes (PERF.md section 5): its heads are stored side
-    by side on ONE axis, ``(kv_heads * head_dim,)`` with zero columns up to
-    the next whole tile (1600 -> 1664: unpadded, the chip still takes the
-    block axis). A family's module takes the pool in either shape."""
-    if head_dim % LANE_TILE == 0:
+    the heads alone. Heads keep their own axis, ``(kv_heads, head_dim)``,
+    where the chip then keeps the pool row-major: a head that fills whole
+    lane tiles, and a count of heads that fills whole sublane tiles (a
+    multiple of 8) or is one of the small tiles the chip has (2, 4). Any
+    other (GPT-2's head of 64; 30 heads of 128, which the chip would store
+    with the block's 16 tokens in the sublanes and the heads outside them,
+    and copy the whole pool to the other order and back around every
+    program that writes a column) would leave the chip no minor axes that
+    tile as stored: its heads are stored side by side on ONE axis,
+    ``(kv_heads * head_dim,)`` with zero columns up to the next whole tile
+    (1600 -> 1664: unpadded, the chip takes the block axis for its lanes).
+    A family's module takes the pool in either shape.
+    ``tests/serving/test_paged_step_chip_compile.py`` reads both rules off
+    a described v5e."""
+    if head_dim % LANE_TILE == 0 and (kv_heads % SUBLANE_TILE == 0
+                                      or kv_heads in (2, 4)):
         return (kv_heads, head_dim)
     return (-(-kv_heads * head_dim // LANE_TILE) * LANE_TILE,)
 
@@ -76,10 +96,11 @@ def kv_per_head(x, kv_heads: int, head_dim: int):
 # -- the pool, and its storage dtypes -----------------------------------------
 
 def init_block_pool(config, n_blocks: int,
-                    block_size: int, dtype: str = "fp32") -> dict:
+                    block_size: int, dtype: str = "fp32",
+                    n_slots: "int | None" = None) -> dict:
     """Zeroed block-paged KV pool for continuous serving
-    (``serving.kv_blocks``): k/v stacked over layers,
-    ``[num_layers, n_blocks, block_size, *kv_tail]``, the trailing axes
+    (``serving.kv_blocks``): k/v stacked over the layers that keep K/V,
+    ``[pool_layers, n_blocks, block_size, *kv_tail]``, the trailing axes
     :func:`kv_tail` of the family's heads (``config.serving_family()``), so
     that the chip keeps layers and blocks major and a block's bytes
     together (GPT-2 XL: ``{3,2,1,0:T(8,128)(2,1)}``).
@@ -100,9 +121,13 @@ def init_block_pool(config, n_blocks: int,
       riding the block structure): ~4x fewer pool bytes per token, by the
       rule of :func:`quantize_kv` / :func:`dequantize_kv`. Compute always
       runs at ``config.dtype``; only the resident pool is compressed.
+
+    A family with state layers also gets its arrays by slot, ``[state_layers,
+    n_slots, *shape]`` each in its own dtype, zeroed (``n_slots`` is then
+    required): never compressed, never indexed by block.
     """
     fam = config.serving_family()
-    shape = (fam.layers, n_blocks, block_size) + fam.kv_tail
+    shape = (fam.pool_layers, n_blocks, block_size) + fam.kv_tail
     store = {"fp32": fam.dtype, "bf16": jnp.bfloat16,
              "int8": jnp.int8}.get(dtype)
     if store is None:
@@ -115,7 +140,39 @@ def init_block_pool(config, n_blocks: int,
     if dtype == "int8":
         pool["k_scale"] = jnp.zeros(shape[:3], jnp.float32)
         pool["v_scale"] = jnp.zeros(shape[:3], jnp.float32)
+    if fam.state_layers:
+        if n_slots is None:
+            raise ValueError(
+                "a family with state layers keeps its state by slot: "
+                "init_block_pool needs n_slots")
+        for name, tail, store in fam.state_arrays:
+            pool[name] = jnp.zeros((fam.state_layers, n_slots) + tail, store)
     return pool
+
+
+_BLOCK_ARRAYS = ("k", "v", "k_scale", "v_scale")
+
+
+def block_arrays(pool: dict) -> dict:
+    """The pool's arrays that are indexed by BLOCK (``k``, ``v`` and their
+    scales): all of it but a recurrent family's arrays by slot, which have
+    another second axis and which no block-wise read or write touches."""
+    return {name: a for name, a in pool.items() if name in _BLOCK_ARRAYS}
+
+
+def slot_arrays(pool: dict) -> dict:
+    """The pool's arrays indexed by SLOT: a recurrent family's state."""
+    return {name: a for name, a in pool.items() if name not in _BLOCK_ARRAYS}
+
+
+def install_slot(pool: dict, slot: jax.Array, rows: dict) -> dict:
+    """One sequence's state ``rows`` (by array name, ``[state_layers, 1,
+    ...]``: a prefill's running state at its last real token) into row
+    ``slot`` of the DONATED pool's arrays by slot, in place."""
+    return {**pool, **{
+        name: lax.dynamic_update_slice_in_dim(
+            pool[name], vals.astype(pool[name].dtype), slot, axis=1)
+        for name, vals in rows.items()}}
 
 
 def quantize_kv(x: jax.Array,
@@ -205,7 +262,7 @@ def gather_blocks(pool: dict, ids: jax.Array) -> dict:
     layers, blocks = pool["k"].shape[:2]
     at = (jnp.arange(layers)[:, None],
           jnp.minimum(ids, blocks - 1)[None, :])
-    return {name: a[at] for name, a in pool.items()}
+    return {name: a[at] for name, a in block_arrays(pool).items()}
 
 
 def gather_blocks_as(pool: dict, ids: jax.Array,
@@ -240,7 +297,8 @@ def write_blocks(pool: dict, ids: jax.Array, vals: dict) -> dict:
                 pool[name], jnp.where(live[i], new, old), at)
         return out
 
-    return lax.fori_loop(0, ids.shape[0], body, pool)
+    return {**lax.fori_loop(0, ids.shape[0], body, block_arrays(pool)),
+            **slot_arrays(pool)}
 
 
 def write_kv_blocks(pool: dict, ids: jax.Array, newk: jax.Array,
@@ -297,4 +355,5 @@ def scatter_columns(pool: dict, blk: jax.Array, off: jax.Array,
                 pool[name], jnp.where(live[c], col, old), at)
         return out
 
-    return lax.fori_loop(0, blk.shape[0], body, pool)
+    return {**lax.fori_loop(0, blk.shape[0], body, block_arrays(pool)),
+            **slot_arrays(pool)}

@@ -137,7 +137,7 @@ from sparkdl_tpu.models.kv_pool import init_block_pool
 from sparkdl_tpu.observability import flight as flight_mod
 from sparkdl_tpu.observability import slo as slo_mod
 from sparkdl_tpu.observability import tracing
-from sparkdl_tpu.observability.registry import registry
+from sparkdl_tpu.observability.registry import GaugeShare, registry
 from sparkdl_tpu.observability.tracing import span
 from sparkdl_tpu.reliability.faults import fault_point
 from sparkdl_tpu.runtime.batching import (
@@ -167,7 +167,7 @@ from sparkdl_tpu.serving.metrics import (
     default_host_id,
 )
 from sparkdl_tpu.serving.paged_programs import bound
-from sparkdl_tpu.serving.prefix_cache import PrefixCache
+from sparkdl_tpu.serving.prefix_cache import PrefixCache, PrefixMatch
 from sparkdl_tpu.serving.queue import (
     DeadlineExceededError,
     EngineClosedError,
@@ -195,6 +195,15 @@ _M_SP_PERMUTE_BYTES = registry().counter(
     "(2 x layers x chunk_width x hidden x itemsize x (sp-1) per "
     "dispatch)")
 
+
+_M_STATE_BYTES = registry().gauge(
+    "sparkdl_linear_state_bytes",
+    "device bytes of recurrent state held by slot (the linear-attention "
+    "layers' states and convolution tails), all engines")
+_M_SCAN_TOKENS = registry().counter(
+    "sparkdl_linear_scan_tokens_total",
+    "real prompt tokens taken through the chunkwise recurrence of a "
+    "family with state layers")
 
 _M_DECODE_AHEAD = registry().counter(
     "sparkdl_serving_decode_ahead_total",
@@ -338,6 +347,9 @@ class _Prefill:
     cow_block: "int | None" = None
     ck: Any = None  # None until the first (gather-fused) chunk ran
     cv: Any = None
+    #: a family with state layers: ``[the running state by array name]``
+    #: after the chunks so far, beside ``ck``/``cv`` (empty for any other)
+    rec: "list[Any]" = dataclasses.field(default_factory=list)
     chunks: int = 0
     #: sequence-parallel staging blocks (sp > 1): the prompt's K/V
     #: accumulate in these SeqShardedBlockPool blocks — sharded across
@@ -484,6 +496,14 @@ class ContinuousGPTEngine:
                 "its native K/V dtype alone: kv_layout='dense', sp > 1, "
                 "spec_k and kv_dtype are not implemented for this family"
             )
+        if fam.state_layers and host_kv_blocks is not None:
+            raise ValueError(
+                f"{type(config).__name__} keeps a recurrent state a slot in "
+                f"{fam.state_layers} of its layers, which no K/V block "
+                "holds: a parked block could not resume its sequence, so "
+                "tiered KV (host_kv_blocks, park_cold, a parked turn's "
+                "resume) is not implemented for this family"
+            )
 
         self.config = config
         self._family = fam
@@ -539,6 +559,9 @@ class ContinuousGPTEngine:
         self._prefill_seconds = 0.0
         self._prefill_chunks = 0
         self._deferrals = 0
+        #: prompt tokens of prefix matches that a family with state layers
+        #: could not honour (_admit_paged)
+        self._prefix_passed_up = 0
         #: host/disk tier store for parked cold sessions (ROADMAP
         #: item 1); None = flat single-tier cache (the default)
         self._kv_tiers = None
@@ -618,7 +641,7 @@ class ContinuousGPTEngine:
             # the only compressed tensor (the programs below compute, and
             # keep their private prefill caches, at the model dtype)
             self._pool_kv = init_block_pool(config, kv_blocks, bs_kv,
-                                            dtype=kv_dtype)
+                                            dtype=kv_dtype, n_slots=n_slots)
             # block tables: one row per slot, sentinel (= kv_blocks)
             # marks empty entries — gather clips it, scatter drops it
             self._table = np.full((n_slots, mb), self._pool.sentinel,
@@ -656,8 +679,9 @@ class ContinuousGPTEngine:
                 static_argnums=(5,), compiler_options=alike)
             self._chunk_mid_fn = jax.jit(
                 bound(programs._chunk_mid, sizes, model),
-                donate_argnums=(1, 2), static_argnums=(5,),
-                compiler_options=alike)
+                # (a family with state layers: its running state too)
+                donate_argnums=(1, 2) + ((7,) if fam.state_layers else ()),
+                static_argnums=(5,), compiler_options=alike)
             self._chunk_final_fn = jax.jit(
                 bound(programs._chunk_final, sizes, model),
                 donate_argnums=(1,), static_argnums=(7,),
@@ -667,6 +691,8 @@ class ContinuousGPTEngine:
                 programs._unpark_install, donate_argnums=(0,))
             self._install_blocks_fn = jax.jit(
                 programs._install_blocks, donate_argnums=(0,))
+            self._g_state = GaugeShare(_M_STATE_BYTES)
+            self._g_state.set(n_slots * fam.state_bytes_per_slot)
             if sp_val > 1:
                 self._init_sp(sp_val, sp_kv_blocks)
         else:
@@ -770,7 +796,7 @@ class ContinuousGPTEngine:
         # host-side arithmetic for sparkdl_sp_permute_bytes_total: each
         # chip contributes its K/V chunk shard to sp-1 peers
         self._sp_bytes_per_col = (
-            2 * fam.layers * fam.kv_heads * fam.head_dim
+            2 * fam.pool_layers * fam.kv_heads * fam.head_dim
             * np.dtype(fam.dtype).itemsize * (sp - 1))
         # the staged world's programs (serving/paged_programs.py), with
         # where each argument lives on the sp mesh; the handoff's install
@@ -899,6 +925,7 @@ class ContinuousGPTEngine:
         self._obs.close(drain=drain)
         if self.kv_layout == "paged":
             self._pool.close()
+            self._g_state.set(0)
             if self.sp > 1:
                 self._sp_pool.close()
             if self._kv_tiers is not None:
@@ -1224,6 +1251,7 @@ class ContinuousGPTEngine:
         with span("serving.admit", parent=req.trace_ctx,
                   request_id=req.request_id, slot=slot,
                   prompt_len=len(req.payload.prompt)) as sp:
+            passed_up = self._prefix_passed_up
             placed = self._admit(slot, req)
             if sp.context is not None:
                 st = self._prefilling.get(slot) if placed else None
@@ -1231,6 +1259,9 @@ class ContinuousGPTEngine:
                 sp.set_attr(
                     deferred=not placed,
                     cached_tokens=st.hit if st is not None else 0,
+                    **({"prefix_passed_up":
+                        self._prefix_passed_up - passed_up}
+                       if self._family.state_layers else {}),
                     blocks=(len(st.all_blocks()) if st is not None
                             else len(flight.blocks or ())
                             if flight is not None else 0))
@@ -1304,11 +1335,20 @@ class ContinuousGPTEngine:
         # the last prompt token must always prefill — the cache holds
         # K/V, not the logits that seed decode
         m = self._prefix.match(toks[:-1])
+        passed_up = 0
         if restored:
             self._prefix.release(restored)
         matched = (m.full_blocks
                    + ([m.partial_block] if m.partial_block is not None
                       else []))
+        if self._family.state_layers and m.hit_tokens:
+            # the matched blocks hold K/V up to the boundary and nothing of
+            # the state AT it, which every state layer would need to go on
+            # from there: the match is passed up and the prompt prefilled
+            # whole (ROADMAP B4: state snapshots at block boundaries)
+            self._prefix.release(matched)
+            passed_up = m.hit_tokens
+            m, matched = PrefixMatch([], None, 0, 0), []
         try:
             owned = self._alloc_blocks(nb_total - len(m.full_blocks))
         except Exception as e:
@@ -1376,6 +1416,7 @@ class ContinuousGPTEngine:
                     self._prefix.release([cow])
                     cow = None
         self._prefix.record_lookup(m.hit_tokens, plen - m.hit_tokens)
+        self._prefix_passed_up += passed_up  # (counted once: it is placed)
         if m.hit_tokens:
             flight_mod.record_event(
                 "kv.prefix_hit", request_id=req.request_id,
@@ -1679,27 +1720,40 @@ class ContinuousGPTEngine:
         # chunk's span ends at its dispatch, and no read waits for it)
         experts = ({"expert_rows": wc * fam.experts_per_token}
                    if fam.expert_layers else {})
+        # a family with state layers: the chunk's real token count reaches
+        # the program (a recurrence has no causal mask to hide the pad
+        # behind), the running state rides beside ck/cv, and the last chunk
+        # installs it into the slot's row
+        state = bool(fam.state_layers)
+        n = (jnp.asarray(r, jnp.int32),) if state else ()
+        row = (jnp.asarray(slot, jnp.int32),) if state else ()
+        scan = ({"scan_tokens": r, "pad_tokens": wc - r} if state else {})
         with span("serving.prefill_chunk", parent=st.req.trace_ctx,
                   request_id=st.req.request_id, slot=slot,
                   start=c0, tokens=r, first=first, final=final,
-                  width=wc, cols=cols, program=program, **experts):
+                  width=wc, cols=cols, program=program, **experts, **scan):
             if first and final:
                 logits, self._pool_kv = self._chunk_one_fn(
                     self.variables, self._pool_kv,
                     jnp.asarray(st.gather_ids), idx, ids,
-                    jnp.asarray(st.install_ids), cols)
+                    jnp.asarray(st.install_ids), cols, *n, *row)
             elif first:
-                logits, st.ck, st.cv = self._chunk_first_fn(
+                logits, st.ck, st.cv, *st.rec = self._chunk_first_fn(
                     self.variables, self._pool_kv,
-                    jnp.asarray(st.gather_ids), idx, ids, cols)
+                    jnp.asarray(st.gather_ids), idx, ids, cols, *n)
             elif final:
                 logits, self._pool_kv = self._chunk_final_fn(
                     self.variables, self._pool_kv, st.ck, st.cv,
-                    idx, ids, jnp.asarray(st.install_ids), cols)
+                    idx, ids, jnp.asarray(st.install_ids), cols,
+                    *n, *st.rec, *row)
                 st.ck = st.cv = None
+                st.rec = []
             else:
-                logits, st.ck, st.cv = self._chunk_mid_fn(
-                    self.variables, st.ck, st.cv, idx, ids, cols)
+                logits, st.ck, st.cv, *st.rec = self._chunk_mid_fn(
+                    self.variables, st.ck, st.cv, idx, ids, cols,
+                    *n, *st.rec)
+        if state:
+            _M_SCAN_TOKENS.inc(r)
         if first and st.cow_block is not None:
             # the gather is dispatched: the COW copy is sequenced before
             # any later overwrite of the source block — drop the hold
@@ -1944,13 +1998,22 @@ class ContinuousGPTEngine:
         self.metrics.record_kv_read(read, live)
         out = {"kv_cols_read": read, "kv_cols_live": live}
         fam = self._family
+        if fam.state_layers:
+            # the rows whose state the dispatch advances, once a pass, and
+            # what each reads and writes of it: its state in every state
+            # layer, in and out. (K/V is gathered in ``pool_layers`` of the
+            # family's layers alone: the readers multiply by that.)
+            out["state_rows"] = len(slots) * steps
+            out["state_bytes"] = (2 * len(slots) * steps
+                                  * fam.state_bytes_per_slot)
         if fam.window_layers:
             # by kind of layer, summed over the layers of the kind: a
             # window layer gathers only the entries its window covers,
             # and of a live row's context only the window is its to read
             wb = fam.window_blocks(nb, self._kv_bs)
             out["kv_cols_read_window"] = (read // nb) * wb * fam.window_layers
-            out["kv_cols_read_full"] = read * (fam.layers - fam.window_layers)
+            out["kv_cols_read_full"] = read * (fam.pool_layers
+                                               - fam.window_layers)
             out["kv_cols_live_window"] = sum(
                 min(d + j, fam.window) for d in depths for j in range(steps))
         return out
@@ -2491,6 +2554,11 @@ class ContinuousGPTEngine:
             "dtype": self.kv_dtype,
             "bytes_per_token": kv_bytes_per_token(
                 self.config, self.kv_dtype),
+            # what a family with state layers holds beside the pool: by
+            # slot, whatever the contexts' lengths (0 for any other)
+            "state_bytes_per_slot": self._family.state_bytes_per_slot,
+            "state_bytes": self.n_slots * self._family.state_bytes_per_slot,
+            "prefix_passed_up": self._prefix_passed_up,
             "capacity_ratio_vs_fp32": round(kv_capacity_ratio(
                 self.config, self.kv_dtype), 4),
             **({"sp": {
@@ -2593,6 +2661,9 @@ class ContinuousGPTEngine:
                            - len(self._prefilling)),
             "kv_blocks_free": self._pool.free_count if paged else None,
             "kv_blocks_total": self._pool.n_blocks if paged else None,
+            "kv_bytes_per_token": (kv_bytes_per_token(
+                self.config, self.kv_dtype) if paged else None),
+            "state_bytes": self.n_slots * self._family.state_bytes_per_slot,
             "kv_blocks_cold": cold,
             "kv_parked_blocks": parked,
             "kv_parked_sessions": sessions,

@@ -81,7 +81,10 @@ _KV_ITEMSIZE = {"bf16": 2, "int8": 1}
 
 def kv_bytes_per_token(config, dtype: str = "fp32") -> int:
     """Resident pool bytes one cached token costs under ``dtype``:
-    K + V columns across every layer as the pool stores them (a merged
+    K + V columns across every layer that KEEPS K/V
+    (``ServingFamily.pool_layers``: a recurrent family's state layers hold
+    nothing a token, and ``ServingFamily.state_bytes_per_slot`` a slot),
+    as the pool stores them (a merged
     axis of heads under a lane tile is padded to whole tiles,
     ``ServingFamily.kv_tail``: GPT-2 XL's 1600 values take 1664), plus
     (int8) the two per-column fp32 scales. Pure arithmetic — the number
@@ -103,7 +106,7 @@ def kv_bytes_per_token(config, dtype: str = "fp32") -> int:
     per_layer = 2 * math.prod(fam.kv_tail) * item
     if dtype == "int8":
         per_layer += 2 * 4  # k_scale + v_scale, fp32, one per column
-    return fam.layers * per_layer
+    return fam.pool_layers * per_layer
 
 
 def kv_capacity_ratio(config, dtype: str) -> float:

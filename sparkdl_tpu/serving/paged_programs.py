@@ -79,6 +79,14 @@ def _paged_step(sizes, model, variables, pool, table, idx, tok, prev, k, nb):
     # (masked garbage) and write nothing (kv_pool.scatter_columns: no block
     # corrupted).
     #
+    # A family with state layers (the pool then holds arrays by slot) has no
+    # sentinel to drop a write: the rows that are LIVE in this step are the
+    # ones whose table row holds a block, and the module moves the state of
+    # no other row. A free slot, and a slot whose prompt's last chunk is
+    # queued ahead of this step but whose row joins only the next one (the
+    # table this step was handed is the one of its launch), keep their state
+    # bit for bit. The state rides the donated pool through the chain.
+    #
     # A row's input token is the HOST's where it has one (``tok >= 0``: the row
     # joined since the last step, from a prefill, a handoff or a resume, or the
     # last step's ids have been read) and otherwise the one the step before
@@ -87,12 +95,14 @@ def _paged_step(sizes, model, variables, pool, table, idx, tok, prev, k, nb):
     # one's ids. Every step hands its last tokens on the same way.
     sub = table[:, :nb]
     tok = jnp.where(tok >= 0, tok, prev)
+    recurrent = kv_pool.slot_arrays(pool)
+    live = ({"live": table[:, 0] < pool["k"].shape[1]} if recurrent else {})
 
     def body(carry, _):
         pool, idx, tok = carry
         logits, new = model.apply(
             variables, tok[:, None],
-            cache=dict(pool, table=sub, idx=idx),
+            cache=dict(pool, table=sub, idx=idx, **live),
         )
         ntok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         rows = jnp.arange(sizes.n_slots)
@@ -100,6 +110,7 @@ def _paged_step(sizes, model, variables, pool, table, idx, tok, prev, k, nb):
         off = idx % sizes.block_size
         pool = kv_pool.scatter_columns(
             pool, blk, off, new["k"][:, :, 0], new["v"][:, :, 0])
+        pool.update({name: new[name] for name in recurrent})
         out = ntok
         if "expert_counts" in new:
             # rows each expert got, behind the step's tokens: ONE array, so one
@@ -156,7 +167,8 @@ def _gathered(sizes, pool, ids):
         for x in kv_pool.gather_blocks_as(pool, ids, sizes.dtype))
 
 
-def _chunk_apply(sizes, model, variables, ck, cv, idx, ids, cols):
+def _chunk_apply(sizes, model, variables, ck, cv, idx, ids, cols, n=None,
+                 rec=None):
     # one bounded prefill chunk, right-aligned: writes K/V at columns [idx,
     # idx+width) of the private cache, where width = ids.shape[1] is the
     # POWER-OF-2 BUCKET of this chunk's real token count (same compile-reuse
@@ -168,28 +180,52 @@ def _chunk_apply(sizes, model, variables, ck, cv, idx, ids, cols):
     # right; pad queries produce garbage columns PAST every real position, so
     # the causal mask hides them until real writes overwrite them — no
     # attention_mask needed (vs the dense path's left-pad masking).
+    #
+    # A recurrence has no causal mask to hide a pad: a family with state
+    # layers is handed ``n``, the chunk's count of REAL tokens, and ``rec``,
+    # the running state of this prompt by array name (``[state_layers, 1,
+    # ...]``); its module lets no token past ``n`` move the state and hands
+    # the state at token ``n`` back, which is this function's fourth result.
     positions = jnp.minimum(
         idx + jnp.arange(ids.shape[1])[None, :], sizes.max_pos)
     cache = {"k": ck[:, :, :cols], "v": cv[:, :, :cols],
-             "idx": idx}
+             "idx": idx, **({} if n is None else dict(rec, n=n))}
     logits, cache = model.apply(
         variables, ids, cache=cache, positions=positions,
     )
     ck = ck.at[:, :, :cols].set(cache["k"])
     cv = cv.at[:, :, :cols].set(cache["v"])
-    return logits, ck, cv
+    if n is None:
+        return logits, ck, cv
+    return logits, ck, cv, {name: cache[name] for name in rec}
 
 
-def _installed(sizes, pool, ck, cv, ids):
+def _fresh(sizes, pool):
+    # a prompt's private cache and running state at token 0 for a family with
+    # state layers: no cached prefix is ever gathered for it (its blocks do
+    # not hold the state at the boundary), so the cache starts as zeros and
+    # not as a gather of sentinels, and the state as the sequence's start
+    shape = ((pool["k"].shape[0], 1, sizes.wp) + pool["k"].shape[3:])
+    ck = jnp.zeros(shape, sizes.dtype)
+    rec = {name: jnp.zeros((a.shape[0], 1) + a.shape[2:], a.dtype)
+           for name, a in kv_pool.slot_arrays(pool).items()}
+    return ck, ck, rec
+
+
+def _installed(sizes, pool, ck, cv, ids, slot=None, rec=None):
     # private prefill cache -> the slot's OWNED pool blocks
     # (quantize-on-install rides the shared kv_pool.stored_as rule). ids
     # carries the sentinel at shared-prefix positions (their content already
     # lives in the shared blocks) and past the covered span: those writes drop.
+    # A family with state layers: the prompt's state at its last token ``rec``
+    # -> row ``slot`` of the pool's arrays by slot, whole (whatever the row
+    # held, of the sequence before it, is gone).
     shape = ((pool["k"].shape[0], sizes.mb, sizes.block_size)
              + pool["k"].shape[3:])
-    return kv_pool.write_kv_blocks(
+    pool = kv_pool.write_kv_blocks(
         pool, ids, ck[:, 0, :sizes.w].reshape(shape),
         cv[:, 0, :sizes.w].reshape(shape))
+    return pool if rec is None else kv_pool.install_slot(pool, slot, rec)
 
 
 # Four fused chunk programs so a prefill pays the minimum dispatch count
@@ -200,20 +236,34 @@ def _installed(sizes, pool, ck, cv, ids):
 # the chip the layers share their code (the engine compiles the four with
 # runtime.chip.alike_layers_options), or a program that installs is twenty
 # times the size and loads as slowly.
+#
+# A family with state layers passes each program two things more, after
+# ``cols``: ``n``, the chunk's real token count, and (mid, final) ``rec``, the
+# prompt's running state, or (one, final) ``slot``, the row of the pool's
+# arrays by slot that the last chunk installs the state into. The programs of
+# the other families are called without them and are what they were.
 
-def _chunk_one(sizes, model, variables, pool, gids, idx, ids, inst, cols):
-    logits, ck, cv = _chunk_first(
-        sizes, model, variables, pool, gids, idx, ids, cols)
-    return logits, _installed(sizes, pool, ck, cv, inst)
+def _chunk_one(sizes, model, variables, pool, gids, idx, ids, inst, cols,
+               n=None, slot=None):
+    logits, ck, cv, *last = _chunk_first(
+        sizes, model, variables, pool, gids, idx, ids, cols, n)
+    return logits, _installed(sizes, pool, ck, cv, inst, slot, *last)
 
 
-def _chunk_first(sizes, model, variables, pool, gids, idx, ids, cols):
+def _chunk_first(sizes, model, variables, pool, gids, idx, ids, cols,
+                 n=None):
+    if n is not None:
+        ck, cv, rec = _fresh(sizes, pool)
+        return _chunk_apply(sizes, model, variables, ck, cv, idx, ids, cols,
+                            n, rec)
     ck, cv = _gathered(sizes, pool, gids)
     return _chunk_apply(sizes, model, variables, ck, cv, idx, ids, cols)
 
 
-def _chunk_mid(sizes, model, variables, ck, cv, idx, ids, cols):
-    return _chunk_apply(sizes, model, variables, ck, cv, idx, ids, cols)
+def _chunk_mid(sizes, model, variables, ck, cv, idx, ids, cols, n=None,
+               rec=None):
+    return _chunk_apply(sizes, model, variables, ck, cv, idx, ids, cols, n,
+                        rec)
 
 
 # (ck/cv are deliberately NOT donated here or in _chunk_one: no output shares
@@ -221,10 +271,10 @@ def _chunk_mid(sizes, model, variables, ck, cv, idx, ids, cols):
 # were not usable" on every compile and free nothing earlier; they die on the
 # host right after the call regardless)
 def _chunk_final(sizes, model, variables, pool, ck, cv, idx, ids, inst,
-                 cols):
-    logits, ck, cv = _chunk_apply(
-        sizes, model, variables, ck, cv, idx, ids, cols)
-    return logits, _installed(sizes, pool, ck, cv, inst)
+                 cols, n=None, rec=None, slot=None):
+    logits, ck, cv, *last = _chunk_apply(
+        sizes, model, variables, ck, cv, idx, ids, cols, n, rec)
+    return logits, _installed(sizes, pool, ck, cv, inst, slot, *last)
 
 
 # -- whole blocks across a boundary: tiers, handoffs --------------------------

@@ -324,3 +324,119 @@ def test_the_expert_products_are_the_grouped_matmul_kernel(compiled_afmoe):
     # the 512 a full layer gathers
     assert _made(text, (4, 129, 16, 4, 128)) and _made(text,
                                                        (4, 512, 16, 4, 128))
+
+
+# -- the olmo_hybrid family at its published widths (ISSUE 34) ------------------
+
+@pytest.fixture(scope="module")
+def compiled_hybrid(one_chip, for_the_chip):
+    """The decode step, a MID chunk (the running state
+    goes in and comes out) and a FINAL chunk (it installs K/V into the
+    slot's blocks and the state into the slot's row) of a 16 x 8192 engine
+    over one linear-attention and one full-attention layer at
+    Olmo-Hybrid-7B's widths, compiled for the chip."""
+    from sparkdl_tpu.models.olmo_hybrid import (
+        FULL,
+        LINEAR,
+        OlmoHybridConfig,
+        OlmoHybridLMHeadModel,
+    )
+
+    cfg = OlmoHybridConfig(vocab_size=512, layer_types=(LINEAR, FULL),
+                           dtype=jnp.bfloat16)
+    variables = jax.eval_shape(
+        lambda: OlmoHybridLMHeadModel(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    slots, max_len = 16, 8192
+    eng = ContinuousGPTEngine(cfg, variables, n_slots=slots, max_len=max_len,
+                              auto_start=False)
+    try:
+        pool = eng._pool_kv
+
+        def ints(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+        mb = max_len // 16
+        private = jax.ShapeDtypeStruct(
+            (1, 1, eng._wp, 3840), jnp.bfloat16, sharding=one_chip)
+        rec = {name: jax.ShapeDtypeStruct(
+            (1, 1) + a.shape[2:], a.dtype, sharding=one_chip)
+            for name, a in pool.items() if name in ("state", "conv")}
+        head = (_on(one_chip, variables), _on(one_chip, pool))
+        step = (ints(slots, mb), ints(slots), ints(slots), ints(slots))
+        return {
+            "pool": pool,
+            "stored": {name: _device_layout(one_chip, a)
+                       for name, a in pool.items()},
+            "step": eng._paged_step_fn.lower(*head, *step, 1, mb).compile(),
+            "mid": eng._chunk_mid_fn.lower(
+                head[0], private, private, ints(), ints(1, 256), 4096,
+                ints(), rec).compile(),
+            "final": eng._chunk_final_fn.lower(
+                *head, private, private, ints(), ints(1, 256), ints(mb),
+                max_len, ints(), rec, ints()).compile(),
+        }
+    finally:
+        eng.close()
+
+
+def test_thirty_heads_of_128_are_stored_on_one_axis_and_why(one_chip,
+                                                            compiled_hybrid):
+    """30 heads of 128 kept apart, ``[.., 16, 30, 128]``, the chip stores
+    with the block's 16 tokens in the sublanes and the heads outside them
+    (30 is no whole count of sublane tiles), and a program that writes a
+    column copies the whole pool to the other order and back. Side by side
+    on one axis the pool is row-major, as GPT-2 XL's; a count of heads that
+    is 2, 4 or a multiple of 8 keeps its own axis row-major (afmoe's 4)."""
+    pool = compiled_hybrid["pool"]
+    assert pool["k"].shape == (1, 8192, 16, 3840)
+    assert compiled_hybrid["stored"]["k"].major_to_minor == (0, 1, 2, 3)
+    apart = jax.ShapeDtypeStruct((2, 8192, 16, 30, 128), jnp.bfloat16)
+    assert _device_layout(one_chip, apart).major_to_minor == (0, 1, 3, 2, 4)
+    for heads in (2, 4, 8, 16, 32):
+        kept = jax.ShapeDtypeStruct((2, 1024, 16, heads, 128), jnp.bfloat16)
+        assert _device_layout(one_chip, kept).major_to_minor == (
+            0, 1, 2, 3, 4), heads
+    # the state by slot lies as it is indexed (its 192 values a row are
+    # padded to two lane tiles), the tails with the slots in the sublanes
+    assert pool["state"].shape == (1, 16, 30, 96, 192)
+    assert compiled_hybrid["stored"]["state"].major_to_minor == (
+        0, 1, 2, 3, 4)
+    assert pool["conv"].shape == (1, 16, 3, 11520)
+
+
+@pytest.mark.parametrize("which", ["step", "final"])
+def test_neither_the_pool_nor_the_state_is_copied(compiled_hybrid, which):
+    pool = compiled_hybrid["pool"]
+    text = compiled_hybrid[which].as_text()
+    for name in ("k", "v", "state"):
+        made = _made(text, pool[name].shape)
+        ops = {op for op, _ in made}
+        # (a pool of ONE layer that keeps K/V is written through a view
+        # without the leading axis: what must not be there is a copy)
+        assert not ops & {"copy", "copy-start", "copy-done", "transpose"}, (
+            name, sorted(ops))
+        # one layout from the argument to the result: the stored one
+        assert {order for _, order in made} == {
+            tuple(range(pool[name].ndim))[::-1]}, name
+    # every pool array, K, V, state and tails, goes out in the buffer it
+    # came in (the state's 192 columns are stored as 256)
+    stats = compiled_hybrid[which].memory_analysis()
+    padded_state = pool["state"].nbytes // 192 * 256
+    assert stats.alias_size_in_bytes == (
+        2 * pool["k"].nbytes + padded_state + pool["conv"].nbytes)
+    # what is held beside them is a layer's gathered rows, not a pool
+    assert stats.temp_size_in_bytes < 1.1 * pool["k"].nbytes
+
+
+def test_a_mid_chunk_hands_the_running_state_on_in_its_own_buffers(
+        compiled_hybrid):
+    stats = compiled_hybrid["mid"].memory_analysis()
+    private = 1 * 8448 * 3840 * 2
+    state = 30 * 96 * 256 * 4      # as the chip pads it
+    assert stats.alias_size_in_bytes >= 2 * private + state
+    text = compiled_hybrid["mid"].as_text()
+    # the triangular systems are solved by the chip's blocked inverse and a
+    # product at full precision, one a linear layer
+    assert text.count("InvertDiagBlocksLowerTriangular") >= 1
+    assert "operand_precision={highest,highest}" in text
