@@ -55,6 +55,11 @@ class ServingFamily:
     #: layers that keep K/V a token: the leading axis of the pool's ``k``
     #: and ``v`` (None: every layer does)
     kv_layers: "int | None" = None
+    #: the module's one-token step reads the old K/V where the pool keeps
+    #: it, each row's own blocks and no more (``ops/paged_decode.py``, by
+    #: its rule ``reads_in_place``), where it would gather every row's
+    #: ``nb`` blocks: what the engine's count of a step's reads follows
+    decode_reads_in_place: bool = False
     #: layers that keep a recurrent state a SLOT, whatever the context's
     #: length, and the arrays each keeps, ``(name, shape a slot, dtype)``:
     #: the pool holds ``name`` as ``[state_layers, n_slots, *shape]``. No

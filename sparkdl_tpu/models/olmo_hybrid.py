@@ -70,7 +70,13 @@ from sparkdl_tpu.models.afmoe import (
 )
 from sparkdl_tpu.models.family import ServingFamily
 from sparkdl_tpu.models.gpt import merged_axis_attention
-from sparkdl_tpu.models.kv_pool import kv_per_head, kv_stored, layer_rows
+from sparkdl_tpu.models.kv_pool import (
+    kv_per_head,
+    kv_stored,
+    kv_tail,
+    layer_rows,
+)
+from sparkdl_tpu.ops import paged_decode
 
 LINEAR, FULL = "linear_attention", "full_attention"
 #: tokens a sub-chunk of the chunkwise recurrence: one triangular system of
@@ -147,6 +153,9 @@ class OlmoHybridConfig:
             kv_heads=self.num_heads, head_dim=self.head_dim,
             dtype=self.dtype, max_positions=None, paged_only=True,
             kv_layers=self.layers_of(FULL),
+            decode_reads_in_place=paged_decode.reads_in_place(
+                kv_tail(self.num_heads, self.head_dim), self.num_heads,
+                self.head_dim),
             state_layers=self.layers_of(LINEAR),
             state_arrays=(
                 ("state", (h, self.linear_key_head_dim,
@@ -415,20 +424,31 @@ class OlmoHybridAttention(nn.Module):
             ctx = _grouped_attention(
                 q, k, v, visible(jnp.arange(l)[None, :]), c.dtype)
         elif "table" in cache:
-            # one query a row, every row at its own depth: the rows come
-            # through the table as the pool stores them and are never
-            # reshaped to heads nor written into (models/gpt.py,
-            # merged_axis_attention: this call's column joins the softmax
-            # beside them)
-            k_old, v_old = (
-                a.reshape(b, a.shape[1], -1) if a.ndim > 3 else a
-                for a in layer_rows(cache, self.kv_index, cache["table"],
-                                    c.dtype))
-            k_new = kv_stored(k.astype(c.dtype), k_old.shape[2:])
-            v_new = kv_stored(v.astype(c.dtype), v_old.shape[2:])
-            ctx = merged_axis_attention(
-                q, k_old, v_old, k_new, v_new, idx).reshape(b, l, nh * hd)
+            # one query a row, every row at its own depth. This call's
+            # column joins the softmax beside the old ones and is written
+            # into nothing here (the step's one column write is the
+            # engine's)
             tail = cache["k"].shape[3:]
+            merged = (math.prod(tail),)
+            k_new = kv_stored(k.astype(c.dtype), merged)
+            v_new = kv_stored(v.astype(c.dtype), merged)
+            if paged_decode.reads_in_place(tail, nh, hd):
+                # heads of whole lane tiles on one unpadded axis: the old
+                # columns are read where the pool keeps them, each row's
+                # own blocks and no more (ops/paged_decode.py)
+                ctx = paged_decode.paged_decode_attention(
+                    q, cache["k"], cache["v"], self.kv_index,
+                    cache["table"], idx, k_new, v_new)
+            else:
+                # the rows come through the table as the pool stores them
+                # and are never reshaped to heads (models/gpt.py,
+                # merged_axis_attention)
+                k_old, v_old = (
+                    a.reshape(b, a.shape[1], -1) if a.ndim > 3 else a
+                    for a in layer_rows(cache, self.kv_index,
+                                        cache["table"], c.dtype))
+                ctx = merged_axis_attention(q, k_old, v_old, k_new, v_new, idx)
+            ctx = ctx.reshape(b, l, nh * hd)
             new_entry = (k_new.reshape(b, l, *tail),
                          v_new.reshape(b, l, *tail))
         else:

@@ -1,0 +1,279 @@
+"""Pallas TPU paged decode: one query a row over K and V read where the
+block pool stores them, through the block table, each row to its own depth.
+
+The per-slot cached step gathers every row's ``nb`` blocks out of the pool
+(``kv_pool.layer_rows``), writes them out as rows and reads them again in
+the products (``gpt.merged_axis_attention``): every row pays for the
+deepest row's power-of-two depth, twice. This kernel leaves the pool in
+device memory and copies, for row ``s``, the ``ceil(depth[s] / block)``
+blocks its table names and no more, a GROUP of blocks a step of its loop
+(each block its own copy, the blocks being scattered; the next group's
+copies run under this group's products, the next row's first group under
+this row's last), and keeps a running maximum, sum and accumulator over
+the groups. Nothing is materialised but a row's ``[heads, head_dim]``
+result.
+
+Which pools it takes is :func:`reads_in_place`'s to say, a rule on shapes
+alone: a token's heads side by side on ONE unpadded axis, each head a whole
+count of lane tiles, so that a block ``pool[layer, b]`` is one contiguous
+piece of bytes and a head's columns are whole tiles of it. A group's scores
+and weighted sum are TWO products over that merged axis, against the query
+laid block-diagonally (row ``h`` holds head ``h``'s query in head ``h``'s
+columns and zeros elsewhere, so other heads add exact zeros), of which head
+``h`` keeps its own columns at the row's end. The matrix unit's time is the
+loading of K's and V's tiles, the same whether one row streams against a
+tile or thirty-two; one small product a head reads the same milliseconds on
+the chip (both forms run at the speed of the copies alone, PERF.md section
+6, PR 35) and is sixty products and sixty single-row stores a group to
+trace and lower, at every start of every program that holds the kernel.
+
+Same precisions as ``merged_axis_attention``: operands as stored, float32
+scores, maximum, sum and accumulator, the weights cast to the operands'
+dtype before the product with V. This call's own column is NOT the
+kernel's: it hands back ``(acc, max, sum)`` of the old columns and
+:func:`paged_decode_attention` joins the new one to them.
+
+Inference-only: no custom VJP (decode never backprops).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+from sparkdl_tpu.ops._pallas import auto_interpret
+from sparkdl_tpu.ops._pallas import smem as _smem
+from sparkdl_tpu.ops._pallas import vmem as _vmem
+
+_NEG_INF = -1e30
+#: lanes of a TPU vector tile (``kv_pool.LANE_TILE``; this module imports
+#: nothing of the models)
+_LANE_TILE = 128
+#: K (and as much V) a step of the kernel's loop moves: a block's copy is a
+#: few tenths of a microsecond of bandwidth and a step's overhead as much,
+#: so a step moves a group of blocks of about this many bytes
+_GROUP_BYTES = 2 << 20
+
+
+def reads_in_place(tail: "tuple[int, ...]", kv_heads: int,
+                   head_dim: int) -> bool:
+    """Whether the paged decode attention reads a pool of this trailing
+    shape in place (THE rule, asked by the module that calls the kernel and
+    by the engine's count of what a step reads): the heads side by side on
+    one axis with no pad, each a whole count of lane tiles. Olmo-Hybrid's
+    30 x 128 is; GPT-2 XL's 25 x 64 padded to 1664 and a per-head pool
+    ``(4, 128)`` are not, and keep ``layer_rows`` and their own attention."""
+    return (tuple(tail) == (kv_heads * head_dim,)
+            and head_dim % _LANE_TILE == 0)
+
+
+def _group_blocks(nb: int, block_bytes: int) -> int:
+    """Blocks a step of the loop copies: the power of two whose bytes come
+    nearest under :data:`_GROUP_BYTES`, and no more than the table holds."""
+    g = max(1, _GROUP_BYTES // block_bytes)
+    return min(1 << (g.bit_length() - 1), nb)
+
+
+def _kernel(table_ref, depth_ref, qbd_ref, k_hbm, v_hbm,
+            o_ref, m_ref, l_ref,
+            kbuf, vbuf, sems, first, acc_scr, m_scr, l_scr, *,
+            layer: int, group: int, bs: int, heads: int, head_dim: int):
+    from jax.experimental.pallas import tpu as pltpu
+
+    s = pl.program_id(0)
+    rows = pl.num_programs(0)
+    span = group * bs                     # tokens a group holds
+
+    def blocks_of(row):
+        return pl.cdiv(depth_ref[row], bs)
+
+    def copies(row, grp, slot, act):
+        """``start`` or ``wait`` for the copies of group ``grp`` of ``row``
+        into buffer ``slot``: the blocks of the group that lie inside the
+        row's depth, each from where the table says the pool keeps it."""
+        n = jnp.minimum(blocks_of(row) - grp * group, group)
+
+        def one(j, _):
+            blk = table_ref[row, grp * group + j]
+            at = pl.ds(pl.multiple_of(j * bs, bs), bs)
+            for hbm, buf, sem in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                getattr(pltpu.make_async_copy(
+                    hbm.at[layer, blk], buf.at[slot, at],
+                    sems.at[sem, slot]), act)()
+            return _
+
+        jax.lax.fori_loop(0, n, one, 0)
+
+    @pl.when(s == 0)
+    def _first_row():
+        # what a partial group leaves of a buffer is multiplied by weights
+        # of exactly zero: it must be finite, so V's starts as zeros (later
+        # it holds blocks of the pool, which are)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        first[0] = 0      # the buffer this row's first group goes into
+        first[1] = 0      # whether the row before started its copies
+
+    groups = pl.cdiv(blocks_of(s), group)
+    nxt = jnp.minimum(s + 1, rows - 1)
+    hand_on = (s + 1 < rows) & (depth_ref[nxt] > 0)
+    slot0, primed = first[0], first[1]
+
+    @pl.when((groups > 0) & (primed == 0))
+    def _cold():
+        copies(s, 0, slot0, "start")
+
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    depth = depth_ref[s]
+
+    def step(g, _):
+        slot = (slot0 + g) % 2
+
+        last = g + 1 == groups
+
+        @pl.when(jnp.logical_not(last) | hand_on)
+        def _ahead():
+            # under this group's products: the row's next group, or after
+            # its last the first group of the row that follows
+            copies(jnp.where(last, nxt, s), jnp.where(last, 0, g + 1),
+                   1 - slot, "start")
+
+        copies(s, g, slot, "wait")
+        x = jax.lax.dot_general(
+            qbd_ref[0], kbuf[slot], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) / math.sqrt(head_dim)
+        pos = g * span + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        x = jnp.where(pos < depth, x, _NEG_INF)
+        m_prev = m_scr[...]
+        m_next = jnp.maximum(m_prev, x.max(axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_next)
+        p = jnp.exp(x - m_next)
+        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
+        m_scr[...] = m_next
+        acc_scr[...] = acc_scr[...] * corr + jnp.dot(
+            p.astype(vbuf.dtype), vbuf[slot],
+            preferred_element_type=jnp.float32)
+        return _
+
+    jax.lax.fori_loop(0, groups, step, 0)
+
+    first[0] = (slot0 + groups) % 2
+    first[1] = ((groups > 0) & hand_on).astype(jnp.int32)
+    # a head keeps its own columns of its row of the merged accumulator
+    for h in range(heads):
+        o_ref[0, h:h + 1, :] = acc_scr[
+            h:h + 1, h * head_dim:(h + 1) * head_dim]
+    m_ref[0] = m_scr[:heads]
+    l_ref[0] = l_scr[:heads]
+
+
+def paged_decode_partial(q, k_pool, v_pool, layer: int, table, depth):
+    """Attention of one query a row over the OLD columns of a paged pool.
+
+    ``q`` ``[S, H, D]``; ``k_pool``/``v_pool`` the pool's arrays WHOLE,
+    ``[layers, blocks, block, H*D]`` (:func:`reads_in_place`), of which the
+    kernel reads layer ``layer`` (static); ``table`` ``[S, nb]`` int32, each
+    row's blocks in order, every entry inside the pool; ``depth`` ``[S]``
+    int32, the columns row ``s`` sees: it fetches ``ceil(depth[s] / block)``
+    blocks and a row of depth 0 none. Returns float32 ``(acc [S, H, D], m
+    [S, H, 1], l [S, H, 1])``: the unnormalised weighted sum of V, the
+    running maximum of the scores and the sum of the weights under it
+    (``acc = 0``, ``m = -1e30``, ``l = 0`` for a row of depth 0).
+    """
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, heads, head_dim = q.shape
+    bs, width = k_pool.shape[2:]
+    if not reads_in_place(k_pool.shape[3:], heads, head_dim):
+        raise ValueError(
+            f"the paged decode kernel reads a pool [.., {heads} x "
+            f"{head_dim}] of whole lane tiles, not {k_pool.shape}")
+    if k_pool.dtype != q.dtype or v_pool.dtype != q.dtype:
+        raise ValueError(
+            f"the paged decode kernel takes K and V as the query's dtype "
+            f"{q.dtype} with no scales, not {k_pool.dtype}")
+    group = _group_blocks(
+        table.shape[1], bs * width * k_pool.dtype.itemsize)
+    # the query laid block-diagonally on the merged axis, on whole sublane
+    # tiles of rows: qbd[s, h, h*D + d] = q[s, h, d]
+    hp = -(-heads // 16) * 16
+    own = (jnp.arange(width)[None, :] // head_dim
+           == jnp.arange(hp)[:, None])
+    qbd = jnp.where(own, q.reshape(rows, 1, width), 0)
+    buffers = 2 * 2 * group * bs * width * k_pool.dtype.itemsize
+    out = pl.pallas_call(
+        functools.partial(_kernel, layer=layer, group=group, bs=bs,
+                          heads=heads, head_dim=head_dim),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(rows,),
+            in_specs=[
+                pl.BlockSpec((1, hp, width), lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, heads, head_dim), lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec((1, heads, 1), lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec((1, heads, 1), lambda s, *_: (s, 0, 0)),
+            ],
+            scratch_shapes=[
+                _vmem((2, group * bs, width), k_pool.dtype),
+                _vmem((2, group * bs, width), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                _smem((2,), jnp.int32),
+                _vmem((hp, width), jnp.float32),
+                _vmem((hp, 1), jnp.float32),
+                _vmem((hp, 1), jnp.float32),
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct((rows, heads, head_dim), jnp.float32),
+            jax.ShapeDtypeStruct((rows, heads, 1), jnp.float32),
+            jax.ShapeDtypeStruct((rows, heads, 1), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=buffers + (16 << 20)),
+        interpret=auto_interpret(),
+        name="paged_decode",
+    )(table.astype(jnp.int32), depth.astype(jnp.int32), qbd, k_pool, v_pool)
+    return tuple(out)
+
+
+def paged_decode_attention(q, k_pool, v_pool, layer: int, table, idx,
+                           k_new, v_new):
+    """The per-slot cached step's attention over a pool that
+    :func:`reads_in_place`: what ``merged_axis_attention`` over
+    ``layer_rows`` computes, with the old columns read in the pool.
+
+    ``q`` ``[S, 1, H, D]``, one query a row; the pool, ``layer``, and
+    ``table`` ``[S, nb]`` as a family's paged cache holds them (a sentinel
+    entry marks a row that holds no block, which reads nothing); ``idx``
+    ``[S]``, the old columns each row sees; ``k_new``/``v_new``
+    ``[S, 1, H*D]``, this call's own column, which joins the softmax beside
+    the old ones and is written nowhere. Returns ``[S, 1, H, D]``.
+    """
+    rows, width, heads, head_dim = q.shape
+    if width != 1:
+        raise ValueError(
+            f"the paged decode kernel takes one query a row, not {width}")
+    blocks = k_pool.shape[1]
+    depth = jnp.where(table[:, 0] < blocks, idx, 0)
+    acc, m, l = paged_decode_partial(
+        q[:, 0], k_pool, v_pool, layer, jnp.minimum(table, blocks - 1),
+        depth)
+    k_new, v_new = (x.reshape(rows, heads, head_dim) for x in (k_new, v_new))
+    x_new = jnp.einsum(
+        "shd,shd->sh", q[:, 0], k_new,
+        preferred_element_type=jnp.float32)[..., None] / math.sqrt(head_dim)
+    top = jnp.maximum(m, x_new)
+    e_old, e_new = jnp.exp(m - top), jnp.exp(x_new - top)
+    total = l * e_old + e_new
+    p_new = (e_new / total).astype(q.dtype).astype(jnp.float32)
+    out = acc * (e_old / total) + p_new * v_new.astype(jnp.float32)
+    return out.astype(q.dtype)[:, None]

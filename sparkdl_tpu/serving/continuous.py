@@ -1986,14 +1986,21 @@ class ContinuousGPTEngine:
 
     def _count_kv_read(self, nb: int, slots: "list[int]",
                        steps: int = 1) -> "dict[str, int]":
-        """Count what a paged dispatch gathers through the block table,
+        """Count what a paged dispatch reads through the block table,
         per layer, and hand it back as span arguments: every slot's
         ``nb`` blocks at each of the dispatch's ``steps`` model passes
         (``kv_cols_read``), and how much of that is the context of a row
         that rides it (``slots``), which deepens by one a pass
-        (``kv_cols_live``)."""
+        (``kv_cols_live``). A family whose step reads the pool in place
+        (``decode_reads_in_place``) fetches, for each riding row, the
+        whole blocks its depth reaches and nothing for any other slot."""
         depths = [int(self._pidx[s]) for s in slots]
-        read = self.n_slots * nb * self._kv_bs * steps
+        bs = self._kv_bs
+        if self._family.decode_reads_in_place:
+            read = sum(-(-(d + j) // bs) * bs
+                       for d in depths for j in range(steps))
+        else:
+            read = self.n_slots * nb * bs * steps
         live = steps * sum(depths) + len(depths) * steps * (steps - 1) // 2
         self.metrics.record_kv_read(read, live)
         out = {"kv_cols_read": read, "kv_cols_live": live}

@@ -41,9 +41,10 @@ _M_TOKENS_BY_PHASE = {phase: _M_TOKENS.labels(phase=phase)
                       for phase in ("prefill", "decode")}
 _M_KV_COLS_READ = registry().counter(
     "sparkdl_serving_kv_cols_read_total",
-    "K/V columns the paged decode programs gathered through the block "
+    "K/V columns the paged decode programs read through the block "
     "table, per layer: slots x blocks under the deepest live row x block "
-    "size, for every step of a dispatch")
+    "size, for every step of a dispatch (a family whose step reads the "
+    "pool in place: each riding row's depth in whole blocks)")
 _M_KV_COLS_LIVE = registry().counter(
     "sparkdl_serving_kv_cols_live_total",
     "of those columns, the ones that held a live row's context (the sum "

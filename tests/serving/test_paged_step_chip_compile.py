@@ -329,7 +329,7 @@ def test_the_expert_products_are_the_grouped_matmul_kernel(compiled_afmoe):
 # -- the olmo_hybrid family at its published widths (ISSUE 34) ------------------
 
 @pytest.fixture(scope="module")
-def compiled_hybrid(one_chip, for_the_chip):
+def compiled_hybrid(one_chip, for_the_chip, monkeypatch_module):
     """The decode step, a MID chunk (the running state
     goes in and comes out) and a FINAL chunk (it installs K/V into the
     slot's blocks and the state into the slot's row) of a 16 x 8192 engine
@@ -341,7 +341,10 @@ def compiled_hybrid(one_chip, for_the_chip):
         OlmoHybridConfig,
         OlmoHybridLMHeadModel,
     )
+    from sparkdl_tpu.ops import paged_decode
 
+    # the paged attention the CHIP runs (this process's backend is the CPU)
+    monkeypatch_module.setattr(paged_decode, "auto_interpret", lambda: False)
     cfg = OlmoHybridConfig(vocab_size=512, layer_types=(LINEAR, FULL),
                            dtype=jnp.bfloat16)
     variables = jax.eval_shape(
@@ -427,6 +430,55 @@ def test_neither_the_pool_nor_the_state_is_copied(compiled_hybrid, which):
         2 * pool["k"].nbytes + padded_state + pool["conv"].nbytes)
     # what is held beside them is a layer's gathered rows, not a pool
     assert stats.temp_size_in_bytes < 1.1 * pool["k"].nbytes
+
+
+def test_the_hybrid_step_reads_k_and_v_in_the_pool_through_the_kernel(
+        compiled_hybrid):
+    """Thirty heads of 128 on one unpadded axis meet the rule
+    (``ops/paged_decode.reads_in_place``): the full layer's one-token
+    attention is the paged kernel (ISSUE 35), which takes the pool's own
+    buffers, and no row is gathered out of them."""
+    pool = compiled_hybrid["pool"]
+    text = compiled_hybrid["step"].as_text()
+    # one call a full layer, handed the step's K and V parameters as they
+    # are (no copy, no slice, no other layout in between)
+    calls = re.findall(
+        r"%paged_decode[.\d]* = \(f32\[16,30,128\]\S*, f32\[16,30,1\]\S*, "
+        r"f32\[16,30,1\]\S*\) custom-call\(([^)]*)\), "
+        r"custom_call_target=\"tpu_custom_call\"", text)
+    assert len(calls) == 1
+    operands = [a.strip() for a in calls[0].split(",")]
+    for name in operands[-2:]:
+        assert re.search(
+            r"%s = bf16\[1,8192,16,3840\]\{3,2,1,0:T\(8,128\)\(2,1\)\} "
+            r"parameter\(" % re.escape(name), text), name
+    # what the parent gathered, 16 rows x 512 blocks a layer and 1.0 GB a
+    # gather, is not made in any spelling (in this pool of ONE layer the
+    # flat spelling is the pool's own view, which only the column write
+    # makes), and nothing of that size is held beside the pool
+    assert _made(text, (16, 8192, 3840)) == []
+    assert _made(text, (16, 512, 16, 3840)) == []
+    assert {op for op, _ in _made(text, (8192, 16, 3840))} <= {
+        "parameter", "bitcast", "scatter", "fusion"}
+    stats = compiled_hybrid["step"].memory_analysis()
+    assert stats.temp_size_in_bytes < pool["k"].nbytes / 16
+
+
+@pytest.mark.parametrize("family, rows", [
+    ("compiled", (SLOTS, MAX_LEN // 16, 16, 1664)),
+    ("compiled_afmoe", (4, 512, 16, 4, 128)),
+])
+def test_a_family_the_rule_leaves_alone_keeps_its_gathers(request, family,
+                                                          rows):
+    """GPT-2 XL (heads of 64 on a padded axis) and Trinity (a per-head
+    pool) do not meet the rule: their steps hold no paged kernel and
+    gather their rows through the table as they did."""
+    text = request.getfixturevalue(family)["step"].as_text()
+    # (by the instruction's name: the text also lists the files of its
+    # stack frames, a test file of that name among them)
+    assert not re.search(r"%paged_decode[.\d]* = ", text)
+    flat = (rows[0] * rows[1],) + rows[2:]
+    assert _made(text, rows) or _made(text, flat)
 
 
 def test_a_mid_chunk_hands_the_running_state_on_in_its_own_buffers(
