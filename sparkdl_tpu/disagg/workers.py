@@ -59,14 +59,16 @@ __all__ = ["DecodeWorker", "PrefillWorker"]
 
 def _refuse_state(engine: ContinuousGPTEngine, what: str) -> None:
     """A handoff is K/V blocks. A family with state layers keeps, beside
-    them, a recurrent state a slot that no block holds, and a sequence
-    cannot go on without it: refused, by name, until a payload carries it."""
+    them, arrays by slot that no block holds (a recurrent state; a window
+    layer's ring of its last columns), and a sequence cannot go on without
+    them: refused, by name, until a payload carries them."""
     fam = engine._family
     if fam.state_layers:
         raise NotImplementedError(
             f"{what} is not implemented for {type(engine.config).__name__}"
-            f": {fam.state_layers} of its layers keep a recurrent state a "
-            "slot, which a handoff of K/V blocks does not carry")
+            f": {fam.state_layers} of its layers keep arrays by slot (a "
+            "recurrent state, or a window's last columns), which a handoff "
+            "of K/V blocks does not carry")
 
 
 def _require_paged(kwargs: dict, who: str) -> None:

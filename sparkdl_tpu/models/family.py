@@ -6,17 +6,23 @@ shaped, is the family's to say. A configuration answers
 ``config.serving_family()`` with a :class:`ServingFamily`; the engine reads
 nothing else off the configuration. ``GPTConfig`` answers with its own
 fields (so GPT-2's programs are what they were); a new family answers from
-its own module (``models/afmoe.py``, ``models/olmo_hybrid.py``).
+its own module (``models/afmoe.py``, ``models/olmo_hybrid.py``,
+``models/mimo_v2_flash.py``).
 
 The module's contract is :class:`~sparkdl_tpu.models.gpt.GPTLMHeadModel`'s:
 ``module.apply(variables, ids, cache=None | dense | paged, positions=...)``
 returns ``(logits, cache)``; a paged cache hands back this call's new
 columns ``[layers, S, L, *kv_tail]``, in the shape the pool stores a token's
-K or V in (:attr:`ServingFamily.kv_tail`), and the caller writes them into
-its pool. ``layers`` there are the layers that KEEP K/V
-(:attr:`ServingFamily.pool_layers`): a family may keep, in some layers, a
-recurrent state a SLOT instead (:attr:`ServingFamily.state_layers`), which
-the pool holds by slot beside its blocks and the module hands back whole.
+K or V in (:attr:`ServingFamily.kv_tail` for K, :attr:`ServingFamily.v_tail`
+for V: a value head may be narrower than a key head, and then the two differ
+in their trailing shape), and the caller writes them into its pool.
+``layers`` there are the layers that KEEP K/V a token
+(:attr:`ServingFamily.pool_layers`): a family may keep, in some layers,
+arrays by SLOT instead (:attr:`ServingFamily.state_layers`), whose size does
+not grow with the context: a recurrent state (``models/olmo_hybrid.py``), or
+the last ``window`` columns of a window layer's K and V kept as a ring
+(``models/mimo_v2_flash.py``, :attr:`ServingFamily.ring_columns`). The pool
+holds them by slot beside its blocks and the module hands them back whole.
 """
 
 from __future__ import annotations
@@ -35,7 +41,8 @@ class ServingFamily:
     module: Any
     layers: int
     kv_heads: int          #: heads of K and V (under the query heads)
-    head_dim: int
+    head_dim: int          #: a key head's size, and a value head's unless
+    #: ``v_head_dim`` says another
     dtype: Any             #: compute dtype, and the native K/V dtype
     #: a learned position table's length; None where positions extrapolate
     max_positions: "int | None" = None
@@ -67,6 +74,14 @@ class ServingFamily:
     #: a park, a handoff) does not carry a sequence of such a family
     state_layers: int = 0
     state_arrays: "tuple[tuple[str, tuple[int, ...], Any], ...]" = ()
+    #: a value head's size where it is not the key's (None: ``head_dim``)
+    v_head_dim: "int | None" = None
+    #: the arrays by slot are RINGS of this many columns: each state layer
+    #: is a window layer that keeps its last ``ring_columns`` positions' K
+    #: and V a slot, written at ``position % ring_columns``, and no block
+    #: (0: the arrays by slot are no ring). Not ``window_layers``: those
+    #: keep every block and gather the entries their window covers
+    ring_columns: int = 0
 
     @property
     def pool_layers(self) -> int:
@@ -82,11 +97,18 @@ class ServingFamily:
 
     @property
     def kv_tail(self) -> "tuple[int, ...]":
-        """The trailing axes of a token's K (or V) in the block pool
-        ``[layers, blocks, block_size, *kv_tail]``:
-        :func:`~sparkdl_tpu.models.kv_pool.kv_tail` of this family's
-        heads."""
-        return kv_pool.kv_tail(self.kv_heads, self.head_dim)
+        """The trailing axes of a token's K (and, where a value head is a
+        key head's size, V) in the block pool ``[layers, blocks,
+        block_size, *kv_tail]``: :func:`~sparkdl_tpu.models.kv_pool.kv_tails`
+        of this family's heads."""
+        return kv_pool.kv_tails(self.kv_heads, self.head_dim,
+                                self.v_head_dim)[0]
+
+    @property
+    def v_tail(self) -> "tuple[int, ...]":
+        """The trailing axes of a token's V in the block pool."""
+        return kv_pool.kv_tails(self.kv_heads, self.head_dim,
+                                self.v_head_dim)[1]
 
     def window_blocks(self, nb: int, block_size: int) -> int:
         """Table entries a window layer of this family gathers in a decode

@@ -3,16 +3,22 @@
 A block pool is a ``dict`` of arrays by name, ``k`` and ``v``
 ``[layers, blocks, block_size, *tail]`` in a storage dtype and, where that
 dtype is int8, ``k_scale`` / ``v_scale`` ``[layers, blocks, block_size]``
-(one fp32 scale per written column). This module owns every decision about
-that format: the trailing axes (:func:`kv_tail`), the storage dtypes and
+(one fp32 scale per written column). K and V may DIFFER in their tail (a
+family whose value heads are narrower than its key heads,
+:func:`kv_tails`): every read and write below takes each array's tail off
+that array, never V's off ``pool["k"]``. This module owns every decision
+about that format: the trailing axes (:func:`kv_tail`), the storage dtypes and
 their scales (:data:`KV_DTYPES`, :func:`quantize_kv`), the sentinel block id
 (``blocks``, one past the last) that clips on a read and drops on a write,
 and the forms of a read through a table, a block write and a column write
 that the chip's compiler answers IN PLACE, not with a copy of the pool.
 
-A family with recurrent layers (``models/olmo_hybrid.py``) keeps, beside its
-blocks, arrays indexed by SLOT (``[state_layers, n_slots, ...]``, as
-``ServingFamily.state_arrays`` names and shapes them): they ride the same
+A family may keep, beside its blocks, arrays indexed by SLOT
+(``[state_layers, n_slots, ...]``, as ``ServingFamily.state_arrays`` names
+and shapes them), which hold either a recurrent state
+(``models/olmo_hybrid.py``: linear-attention layers) or a window layer's
+last ``window`` columns of K and V as a ring (``models/mimo_v2_flash.py``):
+what a layer keeps whatever the context's length. They ride the same
 donated dict through every program, and the rule is that what reads or
 writes BLOCKS leaves them alone. :func:`block_arrays` is the pool without
 them; the reads below gather from it, the write loops carry it alone and
@@ -20,13 +26,14 @@ hand the slot arrays back as they came. ``layers`` of ``k`` and ``v`` are
 the layers that keep K/V, which for such a family are not all of them.
 
 Every model family (``models/gpt.py``, ``models/afmoe.py``,
-``models/olmo_hybrid.py``) reads a layer's rows through
-:func:`layer_rows`; the serving engine's programs
+``models/olmo_hybrid.py``, ``models/mimo_v2_flash.py``) reads a layer's
+rows through :func:`layer_rows`; the serving engine's programs
 (``serving/paged_programs.py``) read and write whole blocks and columns
 through the functions below it. Pure functions of a pool and indices: what
 the engine's closures once took from their enclosing scope is read off the
-pool itself (``layers`` and ``blocks`` are ``pool["k"].shape[:2]``, the tail
-is ``pool["k"].shape[3:]``, an int8 pool is the one that has ``k_scale``).
+pool itself (``layers`` and ``blocks`` are ``pool["k"].shape[:2]``, an
+array's tail is its own ``shape[3:]``, an int8 pool is the one that has
+``k_scale``).
 Nothing here imports the rest of the package; the host's bookkeeping (free
 list, refcounts, tables) is :mod:`~sparkdl_tpu.serving.kv_blocks`'s.
 """
@@ -72,7 +79,33 @@ def kv_tail(kv_heads: int, head_dim: int) -> "tuple[int, ...]":
     if head_dim % LANE_TILE == 0 and (kv_heads % SUBLANE_TILE == 0
                                       or kv_heads in (2, 4)):
         return (kv_heads, head_dim)
+    return _merged_tail(kv_heads, head_dim)
+
+
+def _merged_tail(kv_heads: int, head_dim: int) -> "tuple[int]":
+    """Heads side by side on ONE axis, padded to whole lane tiles."""
     return (-(-kv_heads * head_dim // LANE_TILE) * LANE_TILE,)
+
+
+def kv_tails(kv_heads: int, head_dim: int, v_head_dim: "int | None" = None
+             ) -> "tuple[tuple[int, ...], tuple[int, ...]]":
+    """``(K's tail, V's tail)`` of a family whose value heads are
+    ``v_head_dim`` wide (None: as wide as its key heads, and both tails are
+    :func:`kv_tail`'s). Each is :func:`kv_tail` of its own head size,
+    unless the two would then differ in their NUMBER of axes (4 key heads
+    of 192 go on one axis of 768, 4 value heads of 128 could keep two): V
+    then goes on one merged axis too (512: whole lane tiles, no pad), so
+    that one form of every write and of the products over the rows serves
+    both arrays of a pool. The described v5e keeps both row-major and
+    writes both in place (``tests/serving/test_paged_step_chip_compile.py``)."""
+    k_tail = kv_tail(kv_heads, head_dim)
+    if v_head_dim is None or v_head_dim == head_dim:
+        return k_tail, k_tail
+    v_tail = kv_tail(kv_heads, v_head_dim)
+    if len(v_tail) != len(k_tail):
+        return (_merged_tail(kv_heads, head_dim),
+                _merged_tail(kv_heads, v_head_dim))
+    return k_tail, v_tail
 
 
 def kv_stored(x, tail: "tuple[int, ...]"):
@@ -100,8 +133,9 @@ def init_block_pool(config, n_blocks: int,
                     n_slots: "int | None" = None) -> dict:
     """Zeroed block-paged KV pool for continuous serving
     (``serving.kv_blocks``): k/v stacked over the layers that keep K/V,
-    ``[pool_layers, n_blocks, block_size, *kv_tail]``, the trailing axes
-    :func:`kv_tail` of the family's heads (``config.serving_family()``), so
+    ``[pool_layers, n_blocks, block_size, *tail]``, the trailing axes
+    :func:`kv_tails` of the family's heads (``config.serving_family()``; V's
+    are its own where a value head is not a key head's size), so
     that the chip keeps layers and blocks major and a block's bytes
     together (GPT-2 XL: ``{3,2,1,0:T(8,128)(2,1)}``).
 
@@ -128,6 +162,7 @@ def init_block_pool(config, n_blocks: int,
     """
     fam = config.serving_family()
     shape = (fam.pool_layers, n_blocks, block_size) + fam.kv_tail
+    v_shape = shape[:3] + fam.v_tail
     store = {"fp32": fam.dtype, "bf16": jnp.bfloat16,
              "int8": jnp.int8}.get(dtype)
     if store is None:
@@ -135,7 +170,7 @@ def init_block_pool(config, n_blocks: int,
             f"unknown KV pool dtype {dtype!r} ({' | '.join(KV_DTYPES)})")
     pool = {
         "k": jnp.zeros(shape, store),
-        "v": jnp.zeros(shape, store),
+        "v": jnp.zeros(v_shape, store),
     }
     if dtype == "int8":
         pool["k_scale"] = jnp.zeros(shape[:3], jnp.float32)
@@ -161,7 +196,8 @@ def block_arrays(pool: dict) -> dict:
 
 
 def slot_arrays(pool: dict) -> dict:
-    """The pool's arrays indexed by SLOT: a recurrent family's state."""
+    """The pool's arrays indexed by SLOT: a recurrent state, or a window
+    layer's ring of columns."""
     return {name: a for name, a in pool.items() if name not in _BLOCK_ARRAYS}
 
 
@@ -321,7 +357,7 @@ def scatter_columns(pool: dict, blk: jax.Array, off: jax.Array,
     layers, blocks = pool["k"].shape[:2]
     cols = {**stored_as(pool, "k", newk),
             **stored_as(pool, "v", newv)}
-    if pool["k"].ndim == 4:
+    if all(pool[name].ndim == 4 for name in ("k", "v")):
         # the merged axis: ONE scatter a pool array, indexed by (layer, block,
         # offset) with a column of ``tail`` the window. (With the layer axis
         # left a slice, ``.at[:, blk, off]``, the window spans the layers and

@@ -200,6 +200,11 @@ _M_STATE_BYTES = registry().gauge(
     "sparkdl_linear_state_bytes",
     "device bytes of recurrent state held by slot (the linear-attention "
     "layers' states and convolution tails), all engines")
+_M_RING_BYTES = registry().gauge(
+    "sparkdl_window_ring_bytes",
+    "device bytes of window layers' rings held by slot (the last "
+    "``window`` columns of K and V a slot a window layer, whatever the "
+    "contexts' lengths), all engines")
 _M_SCAN_TOKENS = registry().counter(
     "sparkdl_linear_scan_tokens_total",
     "real prompt tokens taken through the chunkwise recurrence of a "
@@ -498,8 +503,9 @@ class ContinuousGPTEngine:
             )
         if fam.state_layers and host_kv_blocks is not None:
             raise ValueError(
-                f"{type(config).__name__} keeps a recurrent state a slot in "
-                f"{fam.state_layers} of its layers, which no K/V block "
+                f"{type(config).__name__} keeps arrays by slot (a recurrent "
+                f"state, or a window's last columns) in {fam.state_layers} "
+                "of its layers, which no K/V block "
                 "holds: a parked block could not resume its sequence, so "
                 "tiered KV (host_kv_blocks, park_cold, a parked turn's "
                 "resume) is not implemented for this family"
@@ -691,7 +697,10 @@ class ContinuousGPTEngine:
                 programs._unpark_install, donate_argnums=(0,))
             self._install_blocks_fn = jax.jit(
                 programs._install_blocks, donate_argnums=(0,))
-            self._g_state = GaugeShare(_M_STATE_BYTES)
+            # what the family holds by slot: rings of a window's columns,
+            # or a recurrent state
+            self._g_state = GaugeShare(
+                _M_RING_BYTES if fam.ring_columns else _M_STATE_BYTES)
             self._g_state.set(n_slots * fam.state_bytes_per_slot)
             if sp_val > 1:
                 self._init_sp(sp_val, sp_kv_blocks)
@@ -2013,6 +2022,14 @@ class ContinuousGPTEngine:
             out["state_rows"] = len(slots) * steps
             out["state_bytes"] = (2 * len(slots) * steps
                                   * fam.state_bytes_per_slot)
+        if fam.ring_columns:
+            # a state layer that is a window layer: per layer, the ring
+            # columns a pass reads (every slot's ring, whole) and how many
+            # of them hold a riding row's context inside its window
+            out["win_cols_read"] = self.n_slots * fam.ring_columns * steps
+            out["win_cols_live"] = sum(
+                min(d + j, fam.ring_columns)
+                for d in depths for j in range(steps))
         if fam.window_layers:
             # by kind of layer, summed over the layers of the kind: a
             # window layer gathers only the entries its window covers,
@@ -2030,14 +2047,20 @@ class ContinuousGPTEngine:
         (``[steps, expert_layers * experts]``): (token, expert) pairs a
         layer computed (``expert_rows``), experts with at least one row
         (``experts_hit``), both a mean over expert layers and summed over
-        steps, and the fullest expert's rows (``expert_rows_max``)."""
+        steps, and the fullest expert's rows (``expert_rows_max``): all
+        three over the experts this chip HOLDS. ``expert_pairs`` is what
+        the router made, every row's ``experts_per_token`` a layer: where
+        the chip holds a share of the experts, ``expert_rows`` over it is
+        the share that was routed here."""
         fam = self._family
         counts = counts.reshape(-1, fam.expert_layers, fam.experts)
         rows, hit = int(counts.sum()), int((counts > 0).sum())
         self.metrics.record_experts(rows, hit)
         return {"expert_rows": rows / fam.expert_layers,
                 "experts_hit": hit / fam.expert_layers,
-                "expert_rows_max": int(counts.max())}
+                "expert_rows_max": int(counts.max()),
+                "expert_pairs": (counts.shape[0] * self.n_slots
+                                 * fam.experts_per_token)}
 
     def _decode_chain_len(self, now: float) -> int:
         """Tokens to fuse into the next plain decode dispatch: the

@@ -103,7 +103,7 @@ def kv_bytes_per_token(config, dtype: str = "fp32") -> int:
     fam = config.serving_family()
     item = (np.dtype(fam.dtype).itemsize if dtype == "fp32"
             else _KV_ITEMSIZE[dtype])
-    per_layer = 2 * math.prod(fam.kv_tail) * item
+    per_layer = (math.prod(fam.kv_tail) + math.prod(fam.v_tail)) * item
     if dtype == "int8":
         per_layer += 2 * 4  # k_scale + v_scale, fp32, one per column
     return fam.pool_layers * per_layer

@@ -159,12 +159,16 @@ def _gathered(sizes, pool, ids):
     # dequantize here: the private cache is compute-dtype, and the final
     # install requantizes — an exact round trip (quantize_kv absmax maps to
     # ±127), so a COW-shared block re-installs bit-identical to its donor.
-    layers, tail = pool["k"].shape[0], pool["k"].shape[3:]
-    pad = (((0, 0), (0, 0), (0, sizes.wp - sizes.w))
-           + ((0, 0),) * len(tail))
+    layers = pool["k"].shape[0]
+
+    def private(x):
+        tail = x.shape[3:]
+        pad = (((0, 0), (0, 0), (0, sizes.wp - sizes.w))
+               + ((0, 0),) * len(tail))
+        return jnp.pad(x.reshape((layers, 1, sizes.w) + tail), pad)
+
     return tuple(
-        jnp.pad(x.reshape((layers, 1, sizes.w) + tail), pad)
-        for x in kv_pool.gather_blocks_as(pool, ids, sizes.dtype))
+        private(x) for x in kv_pool.gather_blocks_as(pool, ids, sizes.dtype))
 
 
 def _chunk_apply(sizes, model, variables, ck, cv, idx, ids, cols, n=None,
@@ -205,11 +209,15 @@ def _fresh(sizes, pool):
     # state layers: no cached prefix is ever gathered for it (its blocks do
     # not hold the state at the boundary), so the cache starts as zeros and
     # not as a gather of sentinels, and the state as the sequence's start
-    shape = ((pool["k"].shape[0], 1, sizes.wp) + pool["k"].shape[3:])
-    ck = jnp.zeros(shape, sizes.dtype)
+    head = (pool["k"].shape[0], 1, sizes.wp)
+    ck = jnp.zeros(head + pool["k"].shape[3:], sizes.dtype)
+    # (ONE array for both where K and V are shaped alike: Olmo's programs
+    # then lower to the text they lowered to)
+    cv = (ck if pool["v"].shape[3:] == pool["k"].shape[3:]
+          else jnp.zeros(head + pool["v"].shape[3:], sizes.dtype))
     rec = {name: jnp.zeros((a.shape[0], 1) + a.shape[2:], a.dtype)
            for name, a in kv_pool.slot_arrays(pool).items()}
-    return ck, ck, rec
+    return ck, cv, rec
 
 
 def _installed(sizes, pool, ck, cv, ids, slot=None, rec=None):
@@ -218,13 +226,13 @@ def _installed(sizes, pool, ck, cv, ids, slot=None, rec=None):
     # carries the sentinel at shared-prefix positions (their content already
     # lives in the shared blocks) and past the covered span: those writes drop.
     # A family with state layers: the prompt's state at its last token ``rec``
-    # -> row ``slot`` of the pool's arrays by slot, whole (whatever the row
-    # held, of the sequence before it, is gone).
-    shape = ((pool["k"].shape[0], sizes.mb, sizes.block_size)
-             + pool["k"].shape[3:])
+    # (a recurrent state; a window layer's ring of its last columns) -> row
+    # ``slot`` of the pool's arrays by slot, whole (whatever the row held, of
+    # the sequence before it, is gone).
+    head = (pool["k"].shape[0], sizes.mb, sizes.block_size)
     pool = kv_pool.write_kv_blocks(
-        pool, ids, ck[:, 0, :sizes.w].reshape(shape),
-        cv[:, 0, :sizes.w].reshape(shape))
+        pool, ids, ck[:, 0, :sizes.w].reshape(head + pool["k"].shape[3:]),
+        cv[:, 0, :sizes.w].reshape(head + pool["v"].shape[3:]))
     return pool if rec is None else kv_pool.install_slot(pool, slot, rec)
 
 

@@ -492,3 +492,148 @@ def test_a_mid_chunk_hands_the_running_state_on_in_its_own_buffers(
     # product at full precision, one a linear layer
     assert text.count("InvertDiagBlocksLowerTriangular") >= 1
     assert "operand_precision={highest,highest}" in text
+
+
+# -- the mimo_v2_flash family at its published widths (ISSUE 36) ------------------
+
+@pytest.fixture(scope="module")
+def compiled_mimo(one_chip, for_the_chip, monkeypatch_module):
+    """The decode step (gathers at 512 blocks a row), a MID chunk (the rings
+    go in and come out) and a FINAL chunk (it installs K/V into the slot's
+    blocks and the rings into the slot's row) of a 32 x 16384 engine over a
+    full dense layer, a window expert layer and a full expert layer at
+    MiMo-V2-Flash's widths, 16 of 256 experts held, compiled for the chip:
+    keys of 192 over values of 128, K on one axis of 768 and V on one of
+    512, the window layer's last 128 columns a slot in a ring."""
+    from sparkdl_tpu.models.mimo_v2_flash import (
+        FULL,
+        WINDOW,
+        MimoV2FlashConfig,
+        MimoV2FlashLMHeadModel,
+    )
+    from sparkdl_tpu.parallel import moe_dropless
+
+    # the grouped product the CHIP runs (this process's backend is the CPU)
+    monkeypatch_module.setattr(moe_dropless, "auto_interpret", lambda: False)
+    cfg = MimoV2FlashConfig(
+        vocab_size=512, hybrid_layer_pattern=(FULL, WINDOW, FULL),
+        moe_layer_freq=(0, 1, 1), experts_held=16, dtype=jnp.bfloat16)
+    variables = jax.eval_shape(
+        lambda: MimoV2FlashLMHeadModel(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    slots, max_len = 32, 16384
+    eng = ContinuousGPTEngine(cfg, variables, n_slots=slots, max_len=max_len,
+                              auto_start=False)
+    try:
+        pool = eng._pool_kv
+
+        def ints(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+        mb = max_len // 16
+        private = {
+            name: jax.ShapeDtypeStruct(
+                (2, 1, eng._wp) + pool[name].shape[3:], jnp.bfloat16,
+                sharding=one_chip) for name in ("k", "v")}
+        rec = {name: jax.ShapeDtypeStruct(
+            (1, 1) + a.shape[2:], a.dtype, sharding=one_chip)
+            for name, a in pool.items() if name in ("win_k", "win_v")}
+        head = (_on(one_chip, variables), _on(one_chip, pool))
+        step = (ints(slots, mb), ints(slots), ints(slots), ints(slots))
+        return {
+            "pool": pool,
+            "stored": {name: _device_layout(one_chip, a)
+                       for name, a in pool.items()},
+            "step": eng._paged_step_fn.lower(*head, *step, 1, 512).compile(),
+            "mid": eng._chunk_mid_fn.lower(
+                head[0], private["k"], private["v"], ints(), ints(1, 256),
+                8192, ints(), rec).compile(),
+            "final": eng._chunk_final_fn.lower(
+                *head, private["k"], private["v"], ints(), ints(1, 256),
+                ints(mb), 8192, ints(), rec, ints()).compile(),
+        }
+    finally:
+        eng.close()
+
+
+def test_keys_of_192_and_values_of_128_each_lie_on_one_axis_row_major(
+        one_chip, compiled_mimo):
+    """4 K heads of 192 go on one axis of 768 (six lane tiles, no pad); V's
+    4 heads of 128 join them on one of 512 (``kv_pool.kv_tails``): both
+    pools are row-major, and so are the rings, a slot's 128 columns of 8
+    heads together."""
+    pool = compiled_mimo["pool"]
+    assert pool["k"].shape == (2, 32768, 16, 768)
+    assert pool["v"].shape == (2, 32768, 16, 512)
+    assert pool["win_k"].shape == (1, 32, 128, 1536)
+    assert pool["win_v"].shape == (1, 32, 128, 1024)
+    for name in pool:
+        assert compiled_mimo["stored"][name].major_to_minor == (0, 1, 2, 3)
+    # a head of 192 kept apart would be padded to two lane tiles a head
+    apart = jax.ShapeDtypeStruct((2, 1024, 16, 4, 192), jnp.bfloat16)
+    text = jax.jit(lambda x: x).lower(_on(one_chip, apart)).compile().as_text()
+    assert "T(4,128)" in text or "T(8,128)" in text
+
+
+@pytest.mark.parametrize("which", ["step", "final"])
+def test_neither_the_unequal_pool_nor_the_rings_are_copied_around_a_write(
+        compiled_mimo, which):
+    pool = compiled_mimo["pool"]
+    text = compiled_mimo[which].as_text()
+    for name in ("k", "v"):
+        made = _made(text, pool[name].shape)
+        ops = {op for op, _ in made}
+        assert ops & {"scatter", "dynamic-update-slice"}, (name, sorted(ops))
+        assert not ops & {"copy", "copy-start", "copy-done", "transpose"}, (
+            name, sorted(ops))
+        assert {order for _, order in made} == {(3, 2, 1, 0)}, name
+    for name in ("win_k", "win_v"):
+        # a ring is written where it lies (a scatter of one column a row in
+        # the step, a slot's row in the last chunk); the chip may FETCH it
+        # into faster memory to read it (``copy-start`` to ``S(1)``), which
+        # is no copy around the write: the result is the argument's buffer
+        made = _made(text, pool[name].shape) + _made(text,
+                                                     pool[name].shape[1:])
+        ops = {op for op, _ in made}
+        assert not ops & {"copy", "transpose"}, (name, sorted(ops))
+        assert {order[0] for _, order in made} == {len(order) - 1
+                                                   for _, order in made}
+    # every pool array, K, V and both rings, goes out in the buffer it came in
+    stats = compiled_mimo[which].memory_analysis()
+    assert stats.alias_size_in_bytes == sum(a.nbytes for a in pool.values())
+    # what is held beside them is a full layer's gathered rows (step: 32
+    # rows x 8,192 columns of K and of V, 0.67 GB) or a chunk's scores
+    # (final: 64 heads x 256 queries x 8,192 keys in float32, 0.54 GB, and
+    # their exponentials), not a pool
+    assert stats.temp_size_in_bytes < 0.6 * pool["k"].nbytes
+
+
+def test_the_mimo_step_gathers_its_rows_as_stored_and_runs_the_grouped_kernel(
+        compiled_mimo):
+    text = compiled_mimo["step"].as_text()
+    # the full layers' rows come through the table on their merged axes and
+    # are never split into heads of 192 (a padded copy of every row)
+    for tail in (768, 512):
+        gathered = (_made(text, (32, 8192, tail))
+                    + _made(text, (32, 512, 16, tail))
+                    + _made(text, (32 * 512, 16, tail)))
+        assert gathered, tail
+        assert not {op for op, _ in gathered} & {"copy", "copy-start"}, tail
+    for apart in ((32, 8192, 4, 192), (32, 8192, 4, 128),
+                  (32, 128, 8, 192), (32, 128, 8, 128)):
+        assert _made(text, apart) == [], apart
+    # ``paged_decode.reads_in_place`` is false for a head of 192: no kernel
+    assert not re.search(r"%paged_decode[.\d]* = ", text)
+    # three products an expert layer over the 16 HELD experts: 32 rows x 8
+    # pairs padded to row tiles of 128, whichever experts the pairs went to
+    calls = re.findall(r"%gmm[.\d]* = bf16\[256,(\d+)\]", text)
+    assert sorted(calls) == ["2048", "2048", "2048", "2048", "4096", "4096"]
+    assert re.search(r"bf16\[16,4096,2048\]\S* parameter\(", text)
+
+
+def test_a_mimo_mid_chunk_hands_the_rings_on_in_their_own_buffers(
+        compiled_mimo):
+    stats = compiled_mimo["mid"].memory_analysis()
+    private = 2 * (16384 + 256) * (768 + 512) * 2
+    rings = 128 * (1536 + 1024) * 2
+    assert stats.alias_size_in_bytes >= private + rings
