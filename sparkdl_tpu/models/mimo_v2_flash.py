@@ -71,7 +71,13 @@ import jax.numpy as jnp
 
 from sparkdl_tpu.models.afmoe import AfmoeSwiGLU, _gain, _kernel, rms_norm
 from sparkdl_tpu.models.family import ServingFamily
-from sparkdl_tpu.models.kv_pool import kv_per_head, kv_stored, layer_rows
+from sparkdl_tpu.models.kv_pool import (
+    kv_per_head,
+    kv_stored,
+    kv_tails,
+    layer_rows,
+)
+from sparkdl_tpu.ops import paged_decode
 from sparkdl_tpu.parallel.moe_dropless import (
     dropless_experts,
     route_sigmoid_topk,
@@ -188,6 +194,9 @@ class MimoV2FlashConfig:
             expert_layers=sum(self.moe_layer_freq), experts=self.held,
             experts_per_token=self.num_experts_per_tok, paged_only=True,
             kv_layers=self.layers_of(FULL),
+            decode_reads_in_place=paged_decode.reads_in_place(
+                *kv_tails(self.num_kv_heads, self.head_dim, self.v_head_dim),
+                self.num_kv_heads, self.head_dim, self.v_head_dim),
             state_layers=self.layers_of(WINDOW), ring_columns=w,
             state_arrays=(("win_k", (w,) + ring_k, self.dtype),
                           ("win_v", (w,) + ring_v, self.dtype)))
@@ -425,12 +434,24 @@ class MimoAttention(nn.Module):
         if "table" in cache:
             # one query a row, every row at its own depth; this call's
             # column joins the softmax beside the old ones
+            k_new, v_new = merged(k[:, 0]), merged(v[:, 0])
             if window_layer:
                 # the slot's ring: every slot but the one this column will
                 # overwrite holds a position inside the window
-                k_old, v_old = cache["win_k"][self.at], cache["win_v"][self.at]
                 pos = ring_positions(idx, w)                     # [B, w]
-                seen = (pos >= 0) & (pos > (idx - w)[:, None])
+                ctx = merged_sink_attention(
+                    q[:, 0], cache["win_k"][self.at], cache["win_v"][self.at],
+                    k_new, v_new, (pos >= 0) & (pos > (idx - w)[:, None]),
+                    sink, ng)[:, None]
+            elif paged_decode.reads_in_place(
+                    cache["k"].shape[3:], cache["v"].shape[3:], ng, dk, dv):
+                # K and V each on one unpadded axis of whole lane tiles: the
+                # old columns are read where the pool keeps them, each row's
+                # own blocks and no more (ops/paged_decode.py; a full layer
+                # has no sink)
+                ctx = paged_decode.paged_decode_attention(
+                    q, cache["k"], cache["v"], self.at, cache["table"], idx,
+                    k_new[:, None], v_new[:, None])
             else:
                 # the rows come through the table as the pool stores them
                 k_old, v_old = (
@@ -438,10 +459,9 @@ class MimoAttention(nn.Module):
                     for a in layer_rows(cache, self.at, cache["table"],
                                         c.dtype))
                 seen = jnp.arange(k_old.shape[1])[None, :] < idx[:, None]
-            k_new, v_new = merged(k[:, 0]), merged(v[:, 0])
-            ctx = merged_sink_attention(
-                q[:, 0], k_old[..., :ng * dk], v_old[..., :ng * dv],
-                k_new, v_new, seen, sink, ng)[:, None]
+                ctx = merged_sink_attention(
+                    q[:, 0], k_old[..., :ng * dk], v_old[..., :ng * dv],
+                    k_new, v_new, seen, None, ng)[:, None]
             if window_layer:
                 # the one column a row written at position % window; a row
                 # that is not live writes nothing (an index past the ring

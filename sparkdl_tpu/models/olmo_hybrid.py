@@ -148,14 +148,14 @@ class OlmoHybridConfig:
 
     def serving_family(self) -> ServingFamily:
         h = self.linear_num_heads
+        tail = kv_tail(self.num_heads, self.head_dim)
         return ServingFamily(
             module=OlmoHybridLMHeadModel(self), layers=self.num_layers,
             kv_heads=self.num_heads, head_dim=self.head_dim,
             dtype=self.dtype, max_positions=None, paged_only=True,
             kv_layers=self.layers_of(FULL),
             decode_reads_in_place=paged_decode.reads_in_place(
-                kv_tail(self.num_heads, self.head_dim), self.num_heads,
-                self.head_dim),
+                tail, tail, self.num_heads, self.head_dim),
             state_layers=self.layers_of(LINEAR),
             state_arrays=(
                 ("state", (h, self.linear_key_head_dim,
@@ -432,7 +432,8 @@ class OlmoHybridAttention(nn.Module):
             merged = (math.prod(tail),)
             k_new = kv_stored(k.astype(c.dtype), merged)
             v_new = kv_stored(v.astype(c.dtype), merged)
-            if paged_decode.reads_in_place(tail, nh, hd):
+            if paged_decode.reads_in_place(tail, cache["v"].shape[3:], nh,
+                                           hd):
                 # heads of whole lane tiles on one unpadded axis: the old
                 # columns are read where the pool keeps them, each row's
                 # own blocks and no more (ops/paged_decode.py)

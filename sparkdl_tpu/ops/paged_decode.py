@@ -10,22 +10,32 @@ blocks its table names and no more, a GROUP of blocks a step of its loop
 (each block its own copy, the blocks being scattered; the next group's
 copies run under this group's products, the next row's first group under
 this row's last), and keeps a running maximum, sum and accumulator over
-the groups. Nothing is materialised but a row's ``[heads, head_dim]``
+the groups. Nothing is materialised but a row's ``[heads, v_head_dim]``
 result.
 
 Which pools it takes is :func:`reads_in_place`'s to say, a rule on shapes
-alone: a token's heads side by side on ONE unpadded axis, each head a whole
-count of lane tiles, so that a block ``pool[layer, b]`` is one contiguous
-piece of bytes and a head's columns are whole tiles of it. A group's scores
-and weighted sum are TWO products over that merged axis, against the query
-laid block-diagonally (row ``h`` holds head ``h``'s query in head ``h``'s
-columns and zeros elsewhere, so other heads add exact zeros), of which head
-``h`` keeps its own columns at the row's end. The matrix unit's time is the
-loading of K's and V's tiles, the same whether one row streams against a
-tile or thirty-two; one small product a head reads the same milliseconds on
-the chip (both forms run at the speed of the copies alone, PERF.md section
-6, PR 35) and is sixty products and sixty single-row stores a group to
-trace and lower, at every start of every program that holds the kernel.
+alone, asked with K's and V's trailing shapes: each array keeps a token's
+heads side by side on ONE unpadded axis whose width is a whole count of lane
+tiles, so that a block ``pool[layer, b]`` is one contiguous piece of bytes,
+and a VALUE head is a whole count of lane tiles (a head keeps its own ``Dv``
+columns of a row of the result: whole tiles of it). K and V need not be
+alike: a key head may be wider than a value head (MiMo-V2-Flash: keys of
+192 on an axis of 768 over values of 128 on one of 512), a key head need
+not be whole tiles, and a GROUP of query heads may share each K/V head (64
+over 4). A group's scores and weighted sum are TWO products over the merged
+axes, against the query laid block-diagonally OUTSIDE the kernel (row ``h``
+holds head ``h``'s query in the columns of its own K/V head ``g(h) = h //
+(H // G)`` and zeros elsewhere, so other heads add exact zeros; a boundary
+at 192 is a mask in ``jnp`` and never a slice of a tile in here), of which
+head ``h`` keeps K/V head ``g(h)``'s columns at the row's end. With as many
+K/V heads as query heads and one head size (Olmo-Hybrid's 30 x 128) that
+is a diagonal of single rows: the special case, not a second path. The
+matrix unit's time is the loading of K's and V's tiles, the same whether
+one row streams against a tile or sixty-four; one small product a head
+reads the same milliseconds on the chip (both forms run at the speed of
+the copies alone, PERF.md section 6, PR 35) and is sixty products and sixty
+single-row stores a group to trace and lower, at every start of every
+program that holds the kernel.
 
 Same precisions as ``merged_axis_attention``: operands as stored, float32
 scores, maximum, sum and accumulator, the weights cast to the operands'
@@ -53,27 +63,43 @@ _NEG_INF = -1e30
 #: lanes of a TPU vector tile (``kv_pool.LANE_TILE``; this module imports
 #: nothing of the models)
 _LANE_TILE = 128
-#: K (and as much V) a step of the kernel's loop moves: a block's copy is a
-#: few tenths of a microsecond of bandwidth and a step's overhead as much,
-#: so a step moves a group of blocks of about this many bytes
+#: what a step of the kernel's loop moves of the WIDER of K and V (and of
+#: the other, as many tokens): a block's copy is a few tenths of a
+#: microsecond of bandwidth and a step's overhead as much, so a step moves
+#: a group of blocks of about this many bytes. Two buffers of each array
+#: are then 8 MiB at most, which with a group's scores fits the 16 MiB of
+#: faster memory a kernel gets when it asks for none: the kernel asks for
+#: none, because a call that NAMES its limit keeps the chip's compiler from
+#: holding anything else there across it (MiMo-V2-Flash's step: each
+#: layer's 96 MiB query kernel in the other order, 0.31 ms a layer out of
+#: and into device memory where it is 0.15 held there; PERF.md section 6,
+#: PR 37)
 _GROUP_BYTES = 2 << 20
 
 
-def reads_in_place(tail: "tuple[int, ...]", kv_heads: int,
-                   head_dim: int) -> bool:
-    """Whether the paged decode attention reads a pool of this trailing
-    shape in place (THE rule, asked by the module that calls the kernel and
-    by the engine's count of what a step reads): the heads side by side on
-    one axis with no pad, each a whole count of lane tiles. Olmo-Hybrid's
-    30 x 128 is; GPT-2 XL's 25 x 64 padded to 1664 and a per-head pool
-    ``(4, 128)`` are not, and keep ``layer_rows`` and their own attention."""
-    return (tuple(tail) == (kv_heads * head_dim,)
-            and head_dim % _LANE_TILE == 0)
+def reads_in_place(k_tail: "tuple[int, ...]", v_tail: "tuple[int, ...]",
+                   kv_heads: int, head_dim: int,
+                   v_head_dim: "int | None" = None) -> bool:
+    """Whether the paged decode attention reads a pool whose K and V have
+    these trailing shapes in place (THE rule, asked by the module that calls
+    the kernel and by the engine's count of what a step reads): each array's
+    ``kv_heads`` heads side by side on one axis with no pad, that axis a
+    whole count of lane tiles, and a value head a whole count of lane tiles.
+    Olmo-Hybrid's 30 x 128 is, and MiMo-V2-Flash's 4 x 192 over 4 x 128;
+    GPT-2 XL's 25 x 64 padded to 1664, a per-head pool ``(4, 128)`` and a
+    value head of 64 are not, and keep ``layer_rows`` and their own
+    attention. How many query heads share a K/V head is not the rule's."""
+    v_head_dim = head_dim if v_head_dim is None else v_head_dim
+    return (tuple(k_tail) == (kv_heads * head_dim,)
+            and tuple(v_tail) == (kv_heads * v_head_dim,)
+            and k_tail[0] % _LANE_TILE == 0
+            and v_head_dim % _LANE_TILE == 0)
 
 
 def _group_blocks(nb: int, block_bytes: int) -> int:
-    """Blocks a step of the loop copies: the power of two whose bytes come
-    nearest under :data:`_GROUP_BYTES`, and no more than the table holds."""
+    """Blocks a step of the loop copies: the power of two whose bytes (of
+    the wider of K and V) come nearest under :data:`_GROUP_BYTES`, and no
+    more than the table holds."""
     g = max(1, _GROUP_BYTES // block_bytes)
     return min(1 << (g.bit_length() - 1), nb)
 
@@ -81,7 +107,8 @@ def _group_blocks(nb: int, block_bytes: int) -> int:
 def _kernel(table_ref, depth_ref, qbd_ref, k_hbm, v_hbm,
             o_ref, m_ref, l_ref,
             kbuf, vbuf, sems, first, acc_scr, m_scr, l_scr, *,
-            layer: int, group: int, bs: int, heads: int, head_dim: int):
+            layer: int, group: int, bs: int, heads: int, kv_heads: int,
+            head_dim: int, v_head_dim: int):
     from jax.experimental.pallas import tpu as pltpu
 
     s = pl.program_id(0)
@@ -164,99 +191,124 @@ def _kernel(table_ref, depth_ref, qbd_ref, k_hbm, v_hbm,
 
     first[0] = (slot0 + groups) % 2
     first[1] = ((groups > 0) & hand_on).astype(jnp.int32)
-    # a head keeps its own columns of its row of the merged accumulator
-    for h in range(heads):
-        o_ref[0, h:h + 1, :] = acc_scr[
-            h:h + 1, h * head_dim:(h + 1) * head_dim]
+    # the query heads of K/V head g keep g's columns of their rows of the
+    # merged accumulator: the diagonal blocks, side by side
+    share = heads // kv_heads
+    for g in range(kv_heads):
+        cols = slice(g * v_head_dim, (g + 1) * v_head_dim)
+        o_ref[0, :, cols] = acc_scr[g * share:(g + 1) * share, cols]
     m_ref[0] = m_scr[:heads]
     l_ref[0] = l_scr[:heads]
+
+
+def _block_diagonal(q, kv_heads: int, rows: int):
+    """The query laid block-diagonally on K's merged axis: ``out[s, h, g(h)
+    * Dk + d] = q[s, h, d]`` for head ``h``'s own K/V head ``g(h) = h // (H
+    // kv_heads)`` and zero elsewhere, on ``rows >= H`` rows (the rest are
+    zeros). ``q`` ``[S, H, Dk]`` -> ``[S, rows, kv_heads * Dk]``."""
+    _, heads, head_dim = q.shape
+    q = jnp.pad(q, ((0, 0), (0, rows - heads), (0, 0)))
+    own = (jnp.arange(kv_heads * head_dim)[None, :] // head_dim
+           == (jnp.arange(rows) // (heads // kv_heads))[:, None])
+    return jnp.where(own, jnp.tile(q, (1, 1, kv_heads)), 0)
 
 
 def paged_decode_partial(q, k_pool, v_pool, layer: int, table, depth):
     """Attention of one query a row over the OLD columns of a paged pool.
 
-    ``q`` ``[S, H, D]``; ``k_pool``/``v_pool`` the pool's arrays WHOLE,
-    ``[layers, blocks, block, H*D]`` (:func:`reads_in_place`), of which the
-    kernel reads layer ``layer`` (static); ``table`` ``[S, nb]`` int32, each
-    row's blocks in order, every entry inside the pool; ``depth`` ``[S]``
-    int32, the columns row ``s`` sees: it fetches ``ceil(depth[s] / block)``
-    blocks and a row of depth 0 none. Returns float32 ``(acc [S, H, D], m
-    [S, H, 1], l [S, H, 1])``: the unnormalised weighted sum of V, the
-    running maximum of the scores and the sum of the weights under it
-    (``acc = 0``, ``m = -1e30``, ``l = 0`` for a row of depth 0).
+    ``q`` ``[S, H, Dk]``; ``k_pool`` ``[layers, blocks, block, G*Dk]`` and
+    ``v_pool`` ``[layers, blocks, block, G*Dv]``, the pool's arrays WHOLE
+    (:func:`reads_in_place`; ``G`` divides ``H``, query heads ``g * H/G ..``
+    share K/V head ``g``), of which the kernel reads layer ``layer``
+    (static); ``table`` ``[S, nb]`` int32, each row's blocks in order, every
+    entry inside the pool; ``depth`` ``[S]`` int32, the columns row ``s``
+    sees: it fetches ``ceil(depth[s] / block)`` blocks and a row of depth 0
+    none. Returns float32 ``(acc [S, G, H/G, Dv], m [S, H, 1], l [S, H,
+    1])``: the unnormalised weighted sum of V, the running maximum of the
+    scores and the sum of the weights under it (``acc = 0``, ``m = -1e30``,
+    ``l = 0`` for a row of depth 0).
     """
     from jax.experimental.pallas import tpu as pltpu
 
     rows, heads, head_dim = q.shape
-    bs, width = k_pool.shape[2:]
-    if not reads_in_place(k_pool.shape[3:], heads, head_dim):
+    bs, k_width = k_pool.shape[2:]
+    v_width = v_pool.shape[-1]
+    kv_heads = k_width // head_dim
+    v_head_dim = v_width // max(kv_heads, 1)
+    if (kv_heads < 1 or heads % kv_heads or not reads_in_place(
+            k_pool.shape[3:], v_pool.shape[3:], kv_heads, head_dim,
+            v_head_dim)):
         raise ValueError(
-            f"the paged decode kernel reads a pool [.., {heads} x "
-            f"{head_dim}] of whole lane tiles, not {k_pool.shape}")
+            f"the paged decode kernel reads K and V of whole lane tiles, "
+            f"heads of {head_dim} side by side under {heads} query heads, "
+            f"not {k_pool.shape} and {v_pool.shape}")
     if k_pool.dtype != q.dtype or v_pool.dtype != q.dtype:
         raise ValueError(
             f"the paged decode kernel takes K and V as the query's dtype "
-            f"{q.dtype} with no scales, not {k_pool.dtype}")
+            f"{q.dtype} with no scales, not {k_pool.dtype} and "
+            f"{v_pool.dtype}")
     group = _group_blocks(
-        table.shape[1], bs * width * k_pool.dtype.itemsize)
-    # the query laid block-diagonally on the merged axis, on whole sublane
-    # tiles of rows: qbd[s, h, h*D + d] = q[s, h, d]
-    hp = -(-heads // 16) * 16
-    own = (jnp.arange(width)[None, :] // head_dim
-           == jnp.arange(hp)[:, None])
-    qbd = jnp.where(own, q.reshape(rows, 1, width), 0)
-    buffers = 2 * 2 * group * bs * width * k_pool.dtype.itemsize
-    out = pl.pallas_call(
+        table.shape[1],
+        bs * max(k_width, v_width) * k_pool.dtype.itemsize)
+    share = heads // kv_heads
+    hp = -(-heads // 16) * 16       # whole sublane tiles of rows
+    qbd = _block_diagonal(q, kv_heads, hp)
+    acc, m, l = pl.pallas_call(
         functools.partial(_kernel, layer=layer, group=group, bs=bs,
-                          heads=heads, head_dim=head_dim),
+                          heads=heads, kv_heads=kv_heads, head_dim=head_dim,
+                          v_head_dim=v_head_dim),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(rows,),
             in_specs=[
-                pl.BlockSpec((1, hp, width), lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec((1, hp, k_width), lambda s, *_: (s, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=[
-                pl.BlockSpec((1, heads, head_dim), lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec((1, share, v_width), lambda s, *_: (s, 0, 0)),
                 pl.BlockSpec((1, heads, 1), lambda s, *_: (s, 0, 0)),
                 pl.BlockSpec((1, heads, 1), lambda s, *_: (s, 0, 0)),
             ],
             scratch_shapes=[
-                _vmem((2, group * bs, width), k_pool.dtype),
-                _vmem((2, group * bs, width), v_pool.dtype),
+                _vmem((2, group * bs, k_width), k_pool.dtype),
+                _vmem((2, group * bs, v_width), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 _smem((2,), jnp.int32),
-                _vmem((hp, width), jnp.float32),
+                _vmem((hp, v_width), jnp.float32),
                 _vmem((hp, 1), jnp.float32),
                 _vmem((hp, 1), jnp.float32),
             ]),
         out_shape=[
-            jax.ShapeDtypeStruct((rows, heads, head_dim), jnp.float32),
+            # head g * H/G + i's columns are [s, i, g*Dv : (g+1)*Dv]
+            jax.ShapeDtypeStruct((rows, share, v_width), jnp.float32),
             jax.ShapeDtypeStruct((rows, heads, 1), jnp.float32),
             jax.ShapeDtypeStruct((rows, heads, 1), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=buffers + (16 << 20)),
+            dimension_semantics=("arbitrary",)),
         interpret=auto_interpret(),
         name="paged_decode",
     )(table.astype(jnp.int32), depth.astype(jnp.int32), qbd, k_pool, v_pool)
-    return tuple(out)
+    acc = acc.reshape(rows, share, kv_heads, v_head_dim).swapaxes(1, 2)
+    return acc, m, l
 
 
 def paged_decode_attention(q, k_pool, v_pool, layer: int, table, idx,
                            k_new, v_new):
     """The per-slot cached step's attention over a pool that
-    :func:`reads_in_place`: what ``merged_axis_attention`` over
-    ``layer_rows`` computes, with the old columns read in the pool.
+    :func:`reads_in_place`: what ``merged_axis_attention`` (or, for grouped
+    heads of unequal size, ``mimo_v2_flash.merged_sink_attention`` with no
+    sink) over ``layer_rows`` computes, with the old columns read in the
+    pool.
 
-    ``q`` ``[S, 1, H, D]``, one query a row; the pool, ``layer``, and
+    ``q`` ``[S, 1, H, Dk]``, one query a row; the pool, ``layer``, and
     ``table`` ``[S, nb]`` as a family's paged cache holds them (a sentinel
     entry marks a row that holds no block, which reads nothing); ``idx``
-    ``[S]``, the old columns each row sees; ``k_new``/``v_new``
-    ``[S, 1, H*D]``, this call's own column, which joins the softmax beside
-    the old ones and is written nowhere. Returns ``[S, 1, H, D]``.
+    ``[S]``, the old columns each row sees; ``k_new`` ``[S, 1, G*Dk]`` and
+    ``v_new`` ``[S, 1, G*Dv]``, this call's own column, which joins the
+    softmax beside the old ones and is written nowhere. Returns ``[S, 1,
+    H*Dv]``, the heads side by side as the output projection takes them.
     """
     rows, width, heads, head_dim = q.shape
     if width != 1:
@@ -267,13 +319,18 @@ def paged_decode_attention(q, k_pool, v_pool, layer: int, table, idx,
     acc, m, l = paged_decode_partial(
         q[:, 0], k_pool, v_pool, layer, jnp.minimum(table, blocks - 1),
         depth)
-    k_new, v_new = (x.reshape(rows, heads, head_dim) for x in (k_new, v_new))
+    kv_heads = acc.shape[1]
+    # by K/V head and the query heads that share it: [S, G, H/G, ..]
+    by_group = (rows, kv_heads, heads // kv_heads, -1)
+    m, l = m.reshape(by_group), l.reshape(by_group)
     x_new = jnp.einsum(
-        "shd,shd->sh", q[:, 0], k_new,
+        "sgrd,sgd->sgr", q.reshape(by_group),
+        k_new.reshape(rows, kv_heads, head_dim),
         preferred_element_type=jnp.float32)[..., None] / math.sqrt(head_dim)
     top = jnp.maximum(m, x_new)
     e_old, e_new = jnp.exp(m - top), jnp.exp(x_new - top)
     total = l * e_old + e_new
     p_new = (e_new / total).astype(q.dtype).astype(jnp.float32)
-    out = acc * (e_old / total) + p_new * v_new.astype(jnp.float32)
-    return out.astype(q.dtype)[:, None]
+    v_new = v_new.reshape(rows, kv_heads, 1, -1).astype(jnp.float32)
+    out = acc * (e_old / total) + p_new * v_new
+    return out.astype(q.dtype).reshape(rows, 1, -1)

@@ -3,37 +3,58 @@ under the Pallas interpreter, beside what it takes the place of on the same
 pool, table and ``idx``: ``kv_pool.layer_rows`` + ``merged_axis_attention``.
 Small shapes with heads of 128 on one unpadded axis, float32, groups of TWO
 blocks (the kernel derives a group from its shapes and a byte count: the
-count is made small here, so that a table of eight blocks is four groups)."""
+count is made small here, so that a table of eight blocks is four groups).
+Since ISSUE 37 a GROUP of query heads may share each K/V head and a key head
+may differ from a value head in size: those shapes beside a plain softmax a
+head (:func:`_plain`), which knows nothing of merged axes."""
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from sparkdl_tpu.models.gpt import merged_axis_attention
-from sparkdl_tpu.models.kv_pool import kv_tail, layer_rows
+from sparkdl_tpu.models.kv_pool import kv_tail, kv_tails, layer_rows
 from sparkdl_tpu.ops import paged_decode
 
 ROWS, HEADS, HEAD, BS, BLOCKS, LAYERS = 4, 2, 128, 16, 48, 2
 GROUP = 2  # blocks: a group's edge every 32 columns
 
 
+#: (query heads, K/V heads, key head, value head)
+EQUAL = (HEADS, HEADS, HEAD, HEAD)
+SHAPES = [
+    pytest.param(EQUAL, id="as many K/V heads as query heads, one size"),
+    pytest.param((32, 2, 192, 128),
+                 id="MiMo's: 16 query heads a K/V head, keys of 192 over "
+                    "values of 128"),
+    pytest.param((8, 4, 64, 256),
+                 id="2 a K/V head, a key head of half a lane tile under "
+                    "values of two"),
+]
+
+
+def _small_groups(monkeypatch, kv_heads=HEADS, head=HEAD):
+    monkeypatch.setattr(paged_decode, "_GROUP_BYTES",
+                        GROUP * BS * kv_heads * head * 4)
+
+
 @pytest.fixture
 def small_groups(monkeypatch):
-    monkeypatch.setattr(paged_decode, "_GROUP_BYTES",
-                        GROUP * BS * HEADS * HEAD * 4)
+    _small_groups(monkeypatch)
 
 
-def _case(depths, nb, free=(), dtype=jnp.float32, seed=0):
+def _case(depths, nb, free=(), dtype=jnp.float32, seed=0, shape=EQUAL):
     """A pool of random K and V, one query and one new column a row, and a
     table that scatters each live row's blocks over the pool; rows in
     ``free`` hold the sentinel throughout (and whatever ``idx`` says)."""
+    heads, kv_heads, dk, dv = shape
     rng = np.random.default_rng(seed)
 
     def normal(*shape):
         return jnp.asarray(rng.standard_normal(shape), dtype)
 
-    pool = {"k": normal(LAYERS, BLOCKS, BS, HEADS * HEAD),
-            "v": normal(LAYERS, BLOCKS, BS, HEADS * HEAD)}
+    pool = {"k": normal(LAYERS, BLOCKS, BS, kv_heads * dk),
+            "v": normal(LAYERS, BLOCKS, BS, kv_heads * dv)}
     table = np.full((ROWS, nb), BLOCKS, np.int32)
     perm, at = rng.permutation(BLOCKS), 0
     for s, d in enumerate(depths):
@@ -41,8 +62,8 @@ def _case(depths, nb, free=(), dtype=jnp.float32, seed=0):
         table[s, :n] = perm[at:at + n]
         at += n
     return (pool, jnp.asarray(table), jnp.asarray(depths, jnp.int32),
-            normal(ROWS, 1, HEADS, HEAD), normal(ROWS, 1, HEADS * HEAD),
-            normal(ROWS, 1, HEADS * HEAD))
+            normal(ROWS, 1, heads, dk), normal(ROWS, 1, kv_heads * dk),
+            normal(ROWS, 1, kv_heads * dv))
 
 
 def _both(layer, pool, table, idx, q, k_new, v_new):
@@ -50,7 +71,35 @@ def _both(layer, pool, table, idx, q, k_new, v_new):
     want = merged_axis_attention(q, k_old, v_old, k_new, v_new, idx)
     got = paged_decode.paged_decode_attention(
         q, pool["k"], pool["v"], layer, table, idx, k_new, v_new)
-    return np.asarray(want, np.float32), np.asarray(got, np.float32)
+    return (np.asarray(want, np.float32),
+            np.asarray(got, np.float32).reshape(want.shape))
+
+
+def _plain(layer, pool, table, idx, q, k_new, v_new):
+    """Softmax attention a row and a head in float64 numpy: the row's blocks
+    through its table to its depth, then this call's own column; query head
+    ``h`` reads K/V head ``h // (H / G)``. A free row (the sentinel first)
+    sees its own column alone. ``[S, H, Dv]``."""
+    rows, _, heads, dk = q.shape
+    kv_heads = pool["k"].shape[-1] // dk
+    k, v, q, k_new, v_new = (
+        np.asarray(a, np.float64)
+        for a in (pool["k"][layer], pool["v"][layer], q, k_new, v_new))
+    table, idx = np.asarray(table), np.asarray(idx)
+    out = np.zeros((rows, heads, v.shape[-1] // kv_heads))
+    for s in range(rows):
+        depth = 0 if table[s, 0] >= BLOCKS else int(idx[s])
+        blocks = table[s, :-(-depth // BS)]
+        ks = np.concatenate([k[blocks].reshape(-1, k.shape[-1])[:depth],
+                             k_new[s]]).reshape(depth + 1, kv_heads, dk)
+        vs = np.concatenate([v[blocks].reshape(-1, v.shape[-1])[:depth],
+                             v_new[s]]).reshape(depth + 1, kv_heads, -1)
+        for h in range(heads):
+            g = h // (heads // kv_heads)
+            x = ks[:, g] @ q[s, 0, h] / np.sqrt(dk)
+            p = np.exp(x - x.max())
+            out[s, h] = (p / p.sum()) @ vs[:, g]
+    return out
 
 
 @pytest.mark.parametrize("depths, nb, layer", [
@@ -94,6 +143,54 @@ def test_stored_bfloat16_keeps_the_merged_axis_attentions_precisions(
     np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
 
 
+@pytest.mark.parametrize("depths, free", [
+    pytest.param((127, 3, 64, 17), (), id="rows of very unequal depth"),
+    pytest.param((0, 5, 0, 100), (),
+                 id="rows at depth 0 and one that ends inside a block"),
+    pytest.param((31, 32, 33, 64), (),
+                 id="depths that end on and inside a group"),
+    pytest.param((40, 37, 100, 90), (1, 3),
+                 id="two rows hold the sentinel throughout"),
+])
+@pytest.mark.parametrize("shape", SHAPES)
+def test_grouped_query_heads_over_keys_and_values_of_unequal_width(
+        monkeypatch, shape, depths, free):
+    """The kernel against a plain softmax a head: each of a K/V head's
+    query heads keeps that head's columns of its own row and no other's."""
+    heads, kv_heads, dk, dv = shape
+    _small_groups(monkeypatch, kv_heads, dk)
+    case = _case(depths, 8, free=free, shape=shape)
+    pool, table, idx, q, k_new, v_new = case
+    got = paged_decode.paged_decode_attention(
+        q, pool["k"], pool["v"], 1, table, idx, k_new, v_new)
+    assert got.shape == (ROWS, 1, heads * dv)
+    np.testing.assert_allclose(
+        np.asarray(got).reshape(ROWS, heads, dv), _plain(1, *case),
+        rtol=1e-5, atol=1e-5)
+
+
+def test_the_grouped_kernel_is_the_gather_and_mimos_merged_attention(
+        monkeypatch):
+    """At MiMo-V2-Flash's head sizes in bfloat16, beside what its full
+    layers ran before: ``layer_rows`` + ``merged_sink_attention`` with no
+    sink. Both cast the weights to bfloat16 before the product with V."""
+    from sparkdl_tpu.models.mimo_v2_flash import merged_sink_attention
+
+    shape = (32, 2, 192, 128)
+    _small_groups(monkeypatch, 2, 192)
+    pool, table, idx, q, k_new, v_new = _case(
+        (127, 3, 64, 17), 8, dtype=jnp.bfloat16, shape=shape)
+    k_old, v_old = layer_rows(pool, 1, table, q.dtype)
+    seen = jnp.arange(k_old.shape[1])[None, :] < idx[:, None]
+    want = merged_sink_attention(q[:, 0], k_old, v_old, k_new[:, 0],
+                                 v_new[:, 0], seen, None, 2)
+    got = paged_decode.paged_decode_attention(
+        q, pool["k"], pool["v"], 1, table, idx, k_new, v_new)
+    np.testing.assert_allclose(np.asarray(got[:, 0], np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=2e-2, atol=2e-2)
+
+
 def test_more_than_one_query_a_row_raises():
     pool, table, idx, q, k_new, v_new = _case((5, 6, 7, 8), 2)
     wide = jnp.concatenate([q, q], axis=1)
@@ -116,6 +213,25 @@ def test_a_pool_with_another_dtype_or_shape_raises():
             0, table, idx, k_new, v_new)
 
 
+@pytest.mark.parametrize("which", ["k", "v"])
+def test_k_or_v_of_another_dtype_than_the_querys_raises(which):
+    """Unequal dtypes are refused an array at a time: the kernel multiplies
+    operands as stored and carries no scales."""
+    pool, table, idx, q, k_new, v_new = _case((5, 6, 7, 8), 2)
+    pool = dict(pool, **{which: pool[which].astype(jnp.bfloat16)})
+    with pytest.raises(ValueError, match="no scales"):
+        paged_decode.paged_decode_attention(
+            q, pool["k"], pool["v"], 0, table, idx, k_new, v_new)
+
+
+def test_query_heads_that_no_count_of_kv_heads_divides_raise():
+    pool, table, idx, q, k_new, v_new = _case(
+        (5, 6, 7, 8), 2, shape=(3, 2, 128, 128))
+    with pytest.raises(ValueError, match="under 3 query heads"):
+        paged_decode.paged_decode_attention(
+            q, pool["k"], pool["v"], 0, table, idx, k_new, v_new)
+
+
 @pytest.mark.parametrize("heads, head, taken", [
     pytest.param(30, 128, True, id="Olmo-Hybrid: 30 x 128 on one axis"),
     pytest.param(3, 256, True, id="heads of two lane tiles"),
@@ -125,8 +241,45 @@ def test_a_pool_with_another_dtype_or_shape_raises():
     pytest.param(4, 16, False, id="a tiny configuration"),
 ])
 def test_the_rule_by_which_a_pool_is_read_in_place(heads, head, taken):
+    tail = kv_tail(heads, head)
+    assert paged_decode.reads_in_place(tail, tail, heads, head) is taken
+
+
+@pytest.mark.parametrize("kv_heads, dk, dv, taken", [
+    pytest.param(4, 192, 128, True,
+                 id="MiMo-V2-Flash's full layers: 768 over 512"),
+    pytest.param(8, 192, 128, True,
+                 id="MiMo-V2-Flash's window layers' heads: 1536 over 1024"),
+    pytest.param(2, 64, 128, True,
+                 id="a key head of half a tile on an axis of one"),
+    pytest.param(3, 128, 256, True, id="values wider than keys"),
+    pytest.param(4, 128, 256, False,
+                 id="4 heads of whole tiles each keep their own axis"),
+    pytest.param(4, 192, 64, False, id="a value head of 64"),
+    pytest.param(2, 96, 128, False,
+                 id="K's axis of 192 is no whole count of lane tiles"),
+    pytest.param(2, 24, 16, False, id="the tiny mimo_v2_flash"),
+])
+def test_the_rule_takes_keys_and_values_of_unequal_width(kv_heads, dk, dv,
+                                                         taken):
+    """K's and V's tails as ``kv_pool.kv_tails`` lays such a family's pool
+    out: each on one axis; the value head decides, the key head need not
+    be whole tiles if its axis is."""
+    k_tail, v_tail = kv_tails(kv_heads, dk, dv)
     assert paged_decode.reads_in_place(
-        kv_tail(heads, head), heads, head) is taken
+        k_tail, v_tail, kv_heads, dk, dv) is taken
+
+
+@pytest.mark.parametrize("k_tail, v_tail", [
+    pytest.param((1664,), (1664,), id="GPT-2 XL's padded axis, 25 x 64"),
+    pytest.param((4, 128), (4, 128), id="a per-head tail"),
+    pytest.param((768,), (4, 128), id="V alone keeps its heads apart"),
+    pytest.param((768,), (640,), id="V's axis padded"),
+])
+def test_tails_the_rule_refuses_whatever_the_heads(k_tail, v_tail):
+    for kv_heads, dk, dv in ((25, 64, 64), (4, 128, 128), (4, 192, 128)):
+        assert not paged_decode.reads_in_place(k_tail, v_tail, kv_heads,
+                                               dk, dv)
 
 
 def test_a_group_is_two_megabytes_of_blocks_and_no_more_than_the_table():
@@ -134,3 +287,5 @@ def test_a_group_is_two_megabytes_of_blocks_and_no_more_than_the_table():
     assert paged_decode._group_blocks(512, block) == 16
     assert paged_decode._group_blocks(8, block) == 8
     assert paged_decode._group_blocks(512, 64 << 20) == 1
+    # MiMo-V2-Flash's block of K, the wider array: 24,576 bytes, 64 blocks
+    assert paged_decode._group_blocks(512, 16 * 768 * 2) == 64

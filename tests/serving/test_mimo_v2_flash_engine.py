@@ -175,6 +175,33 @@ def test_capacity_counts_the_full_layers_and_says_the_rings_bytes(
     assert served["shapes"]["k"] == served["shapes"]["v"] == (2, 32, 16, 128)
 
 
+@pytest.mark.parametrize("widths, taken", [
+    pytest.param({}, True,
+                 id="published: 4 x 192 on 768 over 4 x 128 on 512"),
+    pytest.param({"v_head_dim": 64}, False, id="a value head of 64"),
+    pytest.param({"num_kv_heads": 1}, False,
+                 id="one K/V head: K's axis of 192 is no whole tile"),
+    pytest.param({"head_dim": 128, "num_kv_heads": 8}, False,
+                 id="equal heads of whole tiles keep their own axis"),
+    pytest.param({"hidden_size": 64, "num_heads": 8, "num_kv_heads": 2,
+                  "head_dim": 24, "v_head_dim": 16}, False,
+                 id="the tiny configuration"),
+])
+def test_decode_reads_in_place_follows_the_paged_kernels_rule(widths, taken):
+    """The family's flag, which the engine's count of a step's reads
+    follows, is the rule the module's full layers ask
+    (``ops/paged_decode.reads_in_place``) on the tails the pool is built
+    with: no argument and no switch."""
+    from sparkdl_tpu.models.mimo_v2_flash import MimoV2FlashConfig
+    from sparkdl_tpu.ops import paged_decode
+
+    fam = MimoV2FlashConfig(**widths).serving_family()
+    assert fam.decode_reads_in_place is taken
+    assert paged_decode.reads_in_place(
+        fam.kv_tail, fam.v_tail, fam.kv_heads, fam.head_dim,
+        fam.v_head_dim) is taken
+
+
 def test_the_ring_gauge_says_the_rings_bytes_while_the_engine_lives(family):
     _, cfg, variables, _ = family
     gauge = registry().get("sparkdl_window_ring_bytes")

@@ -1,6 +1,8 @@
 """``ContinuousGPTEngine`` over a family whose one-token step reads K and V
 in the pool (``ops/paged_decode.py``, ISSUE 35): a tiny ``olmo_hybrid``
-configuration with three heads of 128, so that the rule by which the kernel
+configuration with three heads of 128 and (ISSUE 37) a tiny ``mimo_v2_flash``
+with four query heads over two K/V heads, keys of 64 on an axis of 128 under
+values of 128 on one of 256, so that the rule by which the kernel
 is taken holds on the CPU (under the Pallas interpreter). Greedy tokens are
 those of the same engine with the rule forced false (the gather and the
 merged-axis attention, what every configuration ran before); the step's
@@ -15,9 +17,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sparkdl_tpu.models import afmoe, olmo_hybrid
+from sparkdl_tpu.models import afmoe, mimo_v2_flash, olmo_hybrid
 from sparkdl_tpu.models.afmoe import AfmoeConfig, AfmoeLMHeadModel
 from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel
+from sparkdl_tpu.models.mimo_v2_flash import (
+    MimoV2FlashConfig,
+    MimoV2FlashLMHeadModel,
+)
 from sparkdl_tpu.models.olmo_hybrid import (
     OlmoHybridConfig,
     OlmoHybridLMHeadModel,
@@ -65,20 +71,37 @@ def _prompts(vocab):
     return [rng.integers(0, vocab, n).astype(np.int32) for n in LENGTHS]
 
 
-@pytest.fixture(scope="module")
-def hybrid():
-    cfg = OlmoHybridConfig.tiny(
+def _olmo():
+    return OlmoHybridConfig.tiny(
         num_heads=3, head_dim=128,
-        layer_types=(olmo_hybrid.LINEAR, olmo_hybrid.FULL))
+        layer_types=(olmo_hybrid.LINEAR, olmo_hybrid.FULL)
+    ), OlmoHybridLMHeadModel
+
+
+def _mimo():
+    # a full dense layer, a window layer (a ring of 16 a slot, which the
+    # kernel is not for) and a full expert layer
+    return MimoV2FlashConfig.tiny(
+        num_heads=4, num_kv_heads=2, swa_num_kv_heads=2, head_dim=64,
+        v_head_dim=128, partial_rotary_factor=0.5,
+        hybrid_layer_pattern=(mimo_v2_flash.FULL, mimo_v2_flash.WINDOW,
+                              mimo_v2_flash.FULL),
+        moe_layer_freq=(0, 1, 1)), MimoV2FlashLMHeadModel
+
+
+@pytest.fixture(scope="module", params=[_olmo, _mimo],
+                ids=["olmo_hybrid", "mimo_v2_flash"])
+def hybrid(request):
+    cfg, model = request.param()
     assert cfg.serving_family().decode_reads_in_place
     prompts = _prompts(cfg.vocab_size)
-    in_place = _serve(cfg, OlmoHybridLMHeadModel, prompts)
+    in_place = _serve(cfg, model, prompts)
     with pytest.MonkeyPatch.context() as mp:
         # the rule forced false HERE: the module takes the gather and the
         # merged-axis attention, the engine counts as it did
         mp.setattr(paged_decode, "reads_in_place", lambda *a: False)
         assert not cfg.serving_family().decode_reads_in_place
-        gathered = _serve(cfg, OlmoHybridLMHeadModel, prompts)
+        gathered = _serve(cfg, model, prompts)
     return in_place, gathered
 
 

@@ -440,10 +440,12 @@ def test_the_hybrid_step_reads_k_and_v_in_the_pool_through_the_kernel(
     buffers, and no row is gathered out of them."""
     pool = compiled_hybrid["pool"]
     text = compiled_hybrid["step"].as_text()
-    # one call a full layer, handed the step's K and V parameters as they
-    # are (no copy, no slice, no other layout in between)
+    # one call a full layer (thirty heads' own 128 columns side by side on
+    # one row since ISSUE 37, as the output projection takes them), handed
+    # the step's K and V parameters as they are (no copy, no slice, no other
+    # layout in between)
     calls = re.findall(
-        r"%paged_decode[.\d]* = \(f32\[16,30,128\]\S*, f32\[16,30,1\]\S*, "
+        r"%paged_decode[.\d]* = \(f32\[16,1,3840\]\S*, f32\[16,30,1\]\S*, "
         r"f32\[16,30,1\]\S*\) custom-call\(([^)]*)\), "
         r"custom_call_target=\"tpu_custom_call\"", text)
     assert len(calls) == 1
@@ -496,34 +498,56 @@ def test_a_mid_chunk_hands_the_running_state_on_in_its_own_buffers(
 
 # -- the mimo_v2_flash family at its published widths (ISSUE 36) ------------------
 
-@pytest.fixture(scope="module")
-def compiled_mimo(one_chip, for_the_chip, monkeypatch_module):
-    """The decode step (gathers at 512 blocks a row), a MID chunk (the rings
-    go in and come out) and a FINAL chunk (it installs K/V into the slot's
-    blocks and the rings into the slot's row) of a 32 x 16384 engine over a
-    full dense layer, a window expert layer and a full expert layer at
-    MiMo-V2-Flash's widths, 16 of 256 experts held, compiled for the chip:
-    keys of 192 over values of 128, K on one axis of 768 and V on one of
-    512, the window layer's last 128 columns a slot in a ring."""
+def _mimo_engine(monkeypatch_module):
+    """A 32 x 16384 engine over a full dense layer, a window expert layer
+    and a full expert layer at MiMo-V2-Flash's widths, 16 of 256 experts
+    held, on abstract variables; and those variables."""
     from sparkdl_tpu.models.mimo_v2_flash import (
         FULL,
         WINDOW,
         MimoV2FlashConfig,
         MimoV2FlashLMHeadModel,
     )
+    from sparkdl_tpu.ops import paged_decode
     from sparkdl_tpu.parallel import moe_dropless
 
-    # the grouped product the CHIP runs (this process's backend is the CPU)
+    # the grouped product and the paged attention the CHIP runs (this
+    # process's backend is the CPU)
     monkeypatch_module.setattr(moe_dropless, "auto_interpret", lambda: False)
+    monkeypatch_module.setattr(paged_decode, "auto_interpret", lambda: False)
     cfg = MimoV2FlashConfig(
         vocab_size=512, hybrid_layer_pattern=(FULL, WINDOW, FULL),
         moe_layer_freq=(0, 1, 1), experts_held=16, dtype=jnp.bfloat16)
     variables = jax.eval_shape(
         lambda: MimoV2FlashLMHeadModel(cfg).init(
             jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
-    slots, max_len = 32, 16384
-    eng = ContinuousGPTEngine(cfg, variables, n_slots=slots, max_len=max_len,
-                              auto_start=False)
+    return ContinuousGPTEngine(cfg, variables, n_slots=32, max_len=16384,
+                               auto_start=False), variables
+
+
+def _mimo_step(eng, variables, one_chip):
+    """The engine's decode step at 512 blocks a row, compiled."""
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    slots = eng.n_slots
+    return eng._paged_step_fn.lower(
+        _on(one_chip, variables), _on(one_chip, eng._pool_kv),
+        ints(slots, eng._mb), ints(slots), ints(slots), ints(slots),
+        1, 512).compile()
+
+
+@pytest.fixture(scope="module")
+def compiled_mimo(one_chip, for_the_chip, monkeypatch_module):
+    """The decode step (512 blocks a row), a MID chunk (the rings
+    go in and come out) and a FINAL chunk (it installs K/V into the slot's
+    blocks and the rings into the slot's row) of a 32 x 16384 engine over a
+    full dense layer, a window expert layer and a full expert layer at
+    MiMo-V2-Flash's widths, 16 of 256 experts held, compiled for the chip:
+    keys of 192 over values of 128, K on one axis of 768 and V on one of
+    512, the window layer's last 128 columns a slot in a ring."""
+    eng, variables = _mimo_engine(monkeypatch_module)
+    max_len = 16384
     try:
         pool = eng._pool_kv
 
@@ -539,12 +563,11 @@ def compiled_mimo(one_chip, for_the_chip, monkeypatch_module):
             (1, 1) + a.shape[2:], a.dtype, sharding=one_chip)
             for name, a in pool.items() if name in ("win_k", "win_v")}
         head = (_on(one_chip, variables), _on(one_chip, pool))
-        step = (ints(slots, mb), ints(slots), ints(slots), ints(slots))
         return {
             "pool": pool,
             "stored": {name: _device_layout(one_chip, a)
                        for name, a in pool.items()},
-            "step": eng._paged_step_fn.lower(*head, *step, 1, 512).compile(),
+            "step": _mimo_step(eng, variables, one_chip),
             "mid": eng._chunk_mid_fn.lower(
                 head[0], private["k"], private["v"], ints(), ints(1, 256),
                 8192, ints(), rec).compile(),
@@ -554,6 +577,22 @@ def compiled_mimo(one_chip, for_the_chip, monkeypatch_module):
         }
     finally:
         eng.close()
+
+
+@pytest.fixture(scope="module")
+def gathered_mimo_step(one_chip, for_the_chip):
+    """The same engine's decode step as it was before the paged kernel took
+    grouped heads of unequal size (ISSUE 37): the rule made to refuse, so
+    that the full layers gather their rows at 512 blocks a row."""
+    from sparkdl_tpu.ops import paged_decode
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(paged_decode, "reads_in_place", lambda *a: False)
+        eng, variables = _mimo_engine(mp)
+        try:
+            return _mimo_step(eng, variables, one_chip)
+        finally:
+            eng.close()
 
 
 def test_keys_of_192_and_values_of_128_each_lie_on_one_axis_row_major(
@@ -601,34 +640,99 @@ def test_neither_the_unequal_pool_nor_the_rings_are_copied_around_a_write(
     # every pool array, K, V and both rings, goes out in the buffer it came in
     stats = compiled_mimo[which].memory_analysis()
     assert stats.alias_size_in_bytes == sum(a.nbytes for a in pool.values())
-    # what is held beside them is a full layer's gathered rows (step: 32
-    # rows x 8,192 columns of K and of V, 0.67 GB) or a chunk's scores
-    # (final: 64 heads x 256 queries x 8,192 keys in float32, 0.54 GB, and
-    # their exponentials), not a pool
-    assert stats.temp_size_in_bytes < 0.6 * pool["k"].nbytes
+    # what is held beside them is a chunk's scores (final: 64 heads x 256
+    # queries x 8,192 keys in float32, 0.54 GB, and their exponentials), not
+    # a pool; the step reads K and V where they lie and holds no gathered
+    # row (0.67 GB a full layer until ISSUE 37: 0.78 GB of temporaries then,
+    # 0.11 now, the query projection's kernel in the other order)
+    assert stats.temp_size_in_bytes < {"step": 1 / 8, "final": 0.6}[
+        which] * pool["k"].nbytes
 
 
-def test_the_mimo_step_gathers_its_rows_as_stored_and_runs_the_grouped_kernel(
-        compiled_mimo):
+def _layers_that_make_a_rings_scores(text):
+    """The layers (``layers_<n>`` of the instruction's ``op_name``) whose
+    instructions of the ENTRY computation, which are what a device trace
+    holds an event for, make a result ``[slots, heads, window]`` = ``[32,
+    64, 128]``: what ``benchmark/readers_mimo_v2_flash.is_ring_op`` books
+    as a window layer's scores, whatever made them."""
+    entry = text[text.index("\nENTRY "):]
+    layers = []
+    for line in entry.splitlines():
+        made = line.split(" = ", 1)[-1]
+        opcode = re.search(r"[\])}] ([a-z-]+)\(", made)
+        if (opcode is None or opcode.group(1) == "get-tuple-element"
+                or not re.search(r"[a-z0-9]+\[32,64,128\]",
+                                 made[:opcode.start() + 1])):
+            continue
+        layers.append(re.search(r"op_name=\"[^\"]*/(layers_\d+)/",
+                                line).group(1))
+    return sorted(layers)
+
+
+def test_the_mimo_step_reads_k_and_v_in_the_pool_through_the_grouped_kernel(
+        compiled_mimo, gathered_mimo_step):
+    """Keys of 192 on an axis of 768 over values of 128 on one of 512, 64
+    query heads over 4 K/V heads, meet the rule since ISSUE 37: each full
+    layer's one-token attention is the paged kernel, handed the pool's own
+    buffers, and no row is gathered out of them."""
     text = compiled_mimo["step"].as_text()
-    # the full layers' rows come through the table on their merged axes and
-    # are never split into heads of 192 (a padded copy of every row)
+    # one call a full layer: 16 query heads' 128 columns of each of 4 K/V
+    # heads side by side, the running maximum and sum a head
+    calls = re.findall(
+        r"%paged_decode[.\d]* = \(f32\[32,16,512\]\S*, f32\[32,64,1\]\S*, "
+        r"f32\[32,64,1\]\S*\) custom-call\(([^)]*)\), "
+        r"custom_call_target=\"tpu_custom_call\"", text)
+    assert len(calls) == 2
+    for call in calls:
+        operands = [a.strip() for a in call.split(",")]
+        for name, width in zip(operands[-2:], (768, 512)):
+            assert re.search(
+                r"%s = bf16\[2,32768,16,%d\]\{3,2,1,0:T\(8,128\)\(2,1\)\} "
+                r"parameter\(" % (re.escape(name), width), text), name
+    # what the step gathered until then, 32 rows x 512 blocks a full layer,
+    # is made in no spelling, nor are heads of 192 split off a row
+    for tail in (768, 512):
+        for rows in ((32, 8192, tail), (32, 512, 16, tail),
+                     (32 * 512, 16, tail)):
+            assert _made(text, rows) == [], rows
+    for apart in ((32, 8192, 4, 192), (32, 8192, 4, 128),
+                  (32, 128, 8, 192), (32, 128, 8, 128)):
+        assert _made(text, apart) == [], apart
+    # the benchmark's reader finds a ring's scores by their shape, [slots,
+    # heads, window] = [32, 64, 128], which is also [slots, heads, a value
+    # head]: neither the kernel nor the join after it hands a result over in
+    # that shape, so what the reader books is the window layer's alone (the
+    # gathered step's full layers, 0 and 2, each made one such result: their
+    # heads' own columns summed out of the merged axis)
+    assert _layers_that_make_a_rings_scores(text) == ["layers_1"] * 2
+    assert _layers_that_make_a_rings_scores(gathered_mimo_step.as_text()) == [
+        "layers_0", "layers_1", "layers_1", "layers_2"]
+    # and the step holds less beside the pool than the gathers did
+    assert (compiled_mimo["step"].memory_analysis().temp_size_in_bytes
+            < gathered_mimo_step.memory_analysis().temp_size_in_bytes / 4)
+    # three products an expert layer over the 16 HELD experts: 32 rows x 8
+    # pairs padded to row tiles of 128, whichever experts the pairs went to
+    calls = re.findall(r"%gmm[.\d]* = bf16\[256,(\d+)\]", text)
+    assert sorted(calls) == ["2048", "2048", "2048", "2048", "4096", "4096"]
+    assert re.search(r"bf16\[16,4096,2048\]\S* parameter\(", text)
+
+
+def test_a_mimo_step_the_rule_refuses_gathers_its_rows_as_stored(
+        gathered_mimo_step):
+    """Where the rule does not hold the full layers keep ``layer_rows`` and
+    ``merged_sink_attention``: the rows come through the table on their
+    merged axes, are never split into heads of 192 (a padded copy of every
+    row), and no kernel is called."""
+    text = gathered_mimo_step.as_text()
     for tail in (768, 512):
         gathered = (_made(text, (32, 8192, tail))
                     + _made(text, (32, 512, 16, tail))
                     + _made(text, (32 * 512, 16, tail)))
         assert gathered, tail
         assert not {op for op, _ in gathered} & {"copy", "copy-start"}, tail
-    for apart in ((32, 8192, 4, 192), (32, 8192, 4, 128),
-                  (32, 128, 8, 192), (32, 128, 8, 128)):
+    for apart in ((32, 8192, 4, 192), (32, 8192, 4, 128)):
         assert _made(text, apart) == [], apart
-    # ``paged_decode.reads_in_place`` is false for a head of 192: no kernel
     assert not re.search(r"%paged_decode[.\d]* = ", text)
-    # three products an expert layer over the 16 HELD experts: 32 rows x 8
-    # pairs padded to row tiles of 128, whichever experts the pairs went to
-    calls = re.findall(r"%gmm[.\d]* = bf16\[256,(\d+)\]", text)
-    assert sorted(calls) == ["2048", "2048", "2048", "2048", "4096", "4096"]
-    assert re.search(r"bf16\[16,4096,2048\]\S* parameter\(", text)
 
 
 def test_a_mimo_mid_chunk_hands_the_rings_on_in_their_own_buffers(

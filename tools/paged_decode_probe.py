@@ -1,17 +1,27 @@
 """The paged one-query attention kernel alone, beside what it takes the place
-of, at ``olmo-hybrid-long-backlog``'s shapes: 16 rows over a pool
-``bf16[2, 8192, 16, 3840]``, a table of 512 entries a row, depths drawn as
-the cell draws its prompts (lognormal, median 2048, clipped 128-7168).
+of, at a cell's shapes:
 
-    chiprun -- python tools/paged_decode_probe.py
+``--family olmo`` (``olmo-hybrid-long-backlog``): 16 rows of 30 heads of 128
+over a pool ``bf16[2, 8192, 16, 3840]``, depths drawn as the cell draws its
+prompts (lognormal, median 2048, clipped 128-7168);
+``--family mimo`` (``mimo-flash-reasoning-backlog``): 32 rows of 64 query
+heads over 4 K/V heads, keys of 192 over values of 128, K ``bf16[2, 32768,
+16, 768]`` and V ``bf16[2, 32768, 16, 512]`` (median 1024, clipped
+128-6596, the deepest prompt the cell's strata reach, and up to 750 tokens of
+an answer under way).
+
+A table of 512 entries a row in both.
+
+    chiprun -- python tools/paged_decode_probe.py --family mimo
 
 prints, for each draw of depths, milliseconds a layer of
-``kv_pool.layer_rows`` + ``gpt.merged_axis_attention`` and of
+``kv_pool.layer_rows`` + the family's attention over gathered rows and of
 ``ops/paged_decode.paged_decode_attention``, what the live columns' bytes
 need at the chip's peak, and the largest difference between the two
 results. A measurement: no TPU is an error.
 """
 
+import argparse
 import math
 import sys
 import time
@@ -25,20 +35,27 @@ sys.path.insert(0, ".")
 from benchmark.peaks import peak_for  # noqa: E402
 from sparkdl_tpu.models.gpt import merged_axis_attention  # noqa: E402
 from sparkdl_tpu.models.kv_pool import layer_rows  # noqa: E402
+from sparkdl_tpu.models.mimo_v2_flash import merged_sink_attention  # noqa: E402
 from sparkdl_tpu.ops.paged_decode import paged_decode_attention  # noqa: E402
 from sparkdl_tpu.runtime.chip import require_tpu  # noqa: E402
 
-ROWS, HEADS, HEAD, BS, BLOCKS, NB, LAYERS = 16, 30, 128, 16, 8192, 512, 2
+BS, NB, LAYERS = 16, 512, 2
+#: rows, query heads, K/V heads, key head, value head, blocks of the pool,
+#: median and clip of a prompt, tokens of an answer under way
+FAMILIES = {
+    "olmo": (16, 30, 30, 128, 128, 8192, 2048, (128, 7168), 300),
+    "mimo": (32, 64, 4, 192, 128, 32768, 1024, (128, 6596), 750),
+}
 
 
-def draw(seed):
+def draw(seed, rows, blocks, median, clip, answer):
     """A table and depths as a tick of the cell holds them."""
     rng = np.random.default_rng(seed)
-    prompts = np.clip(np.exp(math.log(2048) + rng.standard_normal(ROWS)),
-                      128, 7168)
-    depth = (prompts + rng.integers(0, 300, ROWS)).astype(np.int32)
-    table = np.full((ROWS, NB), BLOCKS, np.int32)
-    perm, at = rng.permutation(BLOCKS), 0
+    prompts = np.clip(np.exp(math.log(median) + rng.standard_normal(rows)),
+                      *clip)
+    depth = (prompts + rng.integers(0, answer, rows)).astype(np.int32)
+    table = np.full((rows, NB), blocks, np.int32)
+    perm, at = rng.permutation(blocks), 0
     for s, d in enumerate(depth):
         n = -(-int(d) // BS)
         table[s, :n] = perm[at:at + n]
@@ -48,7 +65,13 @@ def draw(seed):
 
 def gathered(q, k, v, table, idx, k_new, v_new):
     k_old, v_old = layer_rows({"k": k, "v": v}, 1, table, q.dtype)
-    return merged_axis_attention(q, k_old, v_old, k_new, v_new, idx)
+    if k.shape == v.shape and k.shape[-1] == q.shape[2] * q.shape[3]:
+        out = merged_axis_attention(q, k_old, v_old, k_new, v_new, idx)
+        return out.reshape(q.shape[0], 1, -1)
+    seen = jnp.arange(k_old.shape[1])[None, :] < idx[:, None]
+    return merged_sink_attention(
+        q[:, 0], k_old, v_old, k_new[:, 0], v_new[:, 0], seen, None,
+        k.shape[-1] // q.shape[-1])[:, None]
 
 
 def in_place(q, k, v, table, idx, k_new, v_new):
@@ -65,22 +88,28 @@ def ms_a_call(fn, args, calls=20):
 
 
 def main():
+    parser = argparse.ArgumentParser()
+    parser.add_argument("--family", choices=sorted(FAMILIES), default="olmo")
+    family = parser.parse_args().family
+    rows, heads, kv_heads, dk, dv, blocks, median, clip, answer = FAMILIES[
+        family]
     require_tpu()
     peak = peak_for(jax.devices()[0].device_kind).hbm_bytes_per_s
     keys = jax.random.split(jax.random.PRNGKey(0), 5)
-    pool = (LAYERS, BLOCKS, BS, HEADS * HEAD)
-    k = jax.random.normal(keys[0], pool, jnp.bfloat16)
-    v = jax.random.normal(keys[1], pool, jnp.bfloat16)
-    q = jax.random.normal(keys[2], (ROWS, 1, HEADS, HEAD), jnp.bfloat16)
-    k_new = jax.random.normal(keys[3], (ROWS, 1, HEADS * HEAD), jnp.bfloat16)
-    v_new = jax.random.normal(keys[4], (ROWS, 1, HEADS * HEAD), jnp.bfloat16)
+    pool = (LAYERS, blocks, BS)
+    k = jax.random.normal(keys[0], pool + (kv_heads * dk,), jnp.bfloat16)
+    v = jax.random.normal(keys[1], pool + (kv_heads * dv,), jnp.bfloat16)
+    q = jax.random.normal(keys[2], (rows, 1, heads, dk), jnp.bfloat16)
+    k_new = jax.random.normal(keys[3], (rows, 1, kv_heads * dk), jnp.bfloat16)
+    v_new = jax.random.normal(keys[4], (rows, 1, kv_heads * dv), jnp.bfloat16)
     fns = {"gathered": jax.jit(gathered), "in_place": jax.jit(in_place)}
     for seed in (1, 2, 3):
-        table, idx = draw(seed)
+        table, idx = draw(seed, rows, blocks, median, clip, answer)
         live = int(np.asarray(idx).sum())
-        need = live * HEADS * HEAD * 2 * 2 / peak * 1e3
-        line = {"seed": seed, "live_cols": live,
+        need = live * kv_heads * (dk + dv) * 2 / peak * 1e3
+        line = {"family": family, "seed": seed, "live_cols": live,
                 "deepest": int(np.asarray(idx).max()),
+                "blocks": int(np.sum(-(-np.asarray(idx) // BS))),
                 "bytes_need_ms": round(need, 3)}
         outs = {}
         for name, fn in fns.items():
