@@ -11,8 +11,7 @@ stdout piping). The TPU build makes this first-class, around one spine:
   ``registry().snapshot()`` is the one-call JSON view, and
   :func:`snapshot_across_hosts` rolls it up over a multi-host job;
 * :mod:`sparkdl_tpu.observability.exporters` — Prometheus ``/metrics``
-  endpoint (opt-in via ``SPARKDL_TPU_METRICS_PORT``) and a periodic
-  logline emitter;
+  endpoint (opt-in via ``SPARKDL_TPU_METRICS_PORT``);
 * :mod:`sparkdl_tpu.observability.tracing` — ``span("decode", ...)``
   request/step tracing with contextvar propagation and Chrome
   ``trace_event`` JSON export (Perfetto-loadable, next to
@@ -40,7 +39,6 @@ stdout piping). The TPU build makes this first-class, around one spine:
 
 from sparkdl_tpu.observability.exporters import (
     MetricsServer,
-    PeriodicLogEmitter,
     maybe_start_metrics_server,
 )
 from sparkdl_tpu.observability.flight import (
@@ -89,7 +87,6 @@ __all__ = [
     "HealthReport",
     "MetricsRegistry",
     "MetricsServer",
-    "PeriodicLogEmitter",
     "SLO",
     "SLOTracker",
     "StackProfile",

@@ -1,6 +1,6 @@
-"""Registry exporters: Prometheus HTTP endpoint and periodic logline.
+"""Registry exporters: the Prometheus HTTP endpoint.
 
-Three ways out of :func:`sparkdl_tpu.observability.registry.registry`:
+Two ways out of :func:`sparkdl_tpu.observability.registry.registry`:
 
 * :class:`MetricsServer` — stdlib ``http.server`` serving the Prometheus
   text exposition on ``/metrics`` (and the JSON snapshot on
@@ -12,10 +12,7 @@ Three ways out of :func:`sparkdl_tpu.observability.registry.registry`:
   a serving host or TPU worker becomes scrape-able with zero
   dependencies;
 * ``registry().snapshot()`` — the JSON form benches and
-  ``dryrun_multichip`` embed in their artifacts (no exporter needed);
-* :class:`PeriodicLogEmitter` — a daemon thread logging a compact
-  snapshot line every N seconds, the "no scraper, just logs" fallback
-  that still beats grepping executor stdout.
+  ``dryrun_multichip`` embed in their artifacts (no exporter needed).
 """
 
 from __future__ import annotations
@@ -30,7 +27,6 @@ from sparkdl_tpu.observability.registry import MetricsRegistry, registry
 
 __all__ = [
     "MetricsServer",
-    "PeriodicLogEmitter",
     "maybe_start_metrics_server",
 ]
 
@@ -205,48 +201,3 @@ def maybe_start_metrics_server(port_offset: int = 0) -> "MetricsServer | None":
             return None
         logger.info("serving /metrics on port %d", _autostarted.port)
         return _autostarted
-
-
-class PeriodicLogEmitter:
-    """Log a compact registry snapshot every ``interval_s`` seconds.
-
-    One JSON object per line under the ``sparkdl_tpu.metrics`` logger —
-    greppable from Spark executor logs, which is exactly the observability
-    floor the reference left us at (SURVEY.md §5), now structured.
-    """
-
-    def __init__(self, interval_s: float = 60.0,
-                 log: "logging.Logger | None" = None,
-                 reg: "MetricsRegistry | None" = None):
-        if interval_s <= 0:
-            raise ValueError(f"interval_s must be > 0, got {interval_s}")
-        self.interval_s = interval_s
-        self._log = log if log is not None else \
-            logging.getLogger("sparkdl_tpu.metrics")
-        self._registry = reg if reg is not None else registry()
-        self._stop = threading.Event()
-        self._thread = threading.Thread(
-            target=self._loop, name="sparkdl-metrics-log", daemon=True
-        )
-        self._thread.start()
-
-    def _loop(self) -> None:
-        while not self._stop.wait(self.interval_s):
-            self.emit()
-
-    def emit(self) -> None:
-        snap = self._registry.snapshot()
-        if snap:
-            self._log.info("metrics %s", json.dumps(snap, sort_keys=True))
-
-    def close(self, *, final_emit: bool = True) -> None:
-        self._stop.set()
-        self._thread.join(timeout=2)
-        if final_emit:
-            self.emit()
-
-    def __enter__(self) -> "PeriodicLogEmitter":
-        return self
-
-    def __exit__(self, *exc) -> None:
-        self.close()

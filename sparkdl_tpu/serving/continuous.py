@@ -356,6 +356,11 @@ class _Prefill:
     #: after the chunks so far, beside ``ck``/``cv`` (empty for any other)
     rec: "list[Any]" = dataclasses.field(default_factory=list)
     chunks: int = 0
+    #: engine ticks this prompt spent prefilling, the ones that gave it no
+    #: chunk included (``serving.prefill`` says both; ``admitted_at`` is
+    #: where that span starts, in ``time.monotonic()`` seconds)
+    ticks: int = 0
+    admitted_at: float = 0.0
     #: sequence-parallel staging blocks (sp > 1): the prompt's K/V
     #: accumulate in these SeqShardedBlockPool blocks — sharded across
     #: the sp chips — instead of the private dense cache, until the
@@ -1436,6 +1441,7 @@ class ContinuousGPTEngine:
             shared=m.full_blocks, owned=owned,
             gather_ids=gids, install_ids=inst,
             cow_block=cow, sp_blocks=sp_blocks,
+            admitted_at=time.monotonic(),
         )
         self._pool.reset_deferral_streak()
         if self.sp > 1:
@@ -1683,6 +1689,8 @@ class ContinuousGPTEngine:
             pivot = self._prefill_rr % len(slots)
             slots = slots[pivot:] + slots[:pivot]
         self._prefill_rr += 1
+        for st in self._prefilling.values():
+            st.ticks += 1  # whether or not the budget reaches it this tick
         tick_tokens = 0
         for slot in slots:
             st = self._prefilling[slot]
@@ -1724,11 +1732,6 @@ class ContinuousGPTEngine:
         # width, cols and program name the compiled shape: an xla.compile
         # under this span says which one was first seen while serving
         fam = self._family
-        # (token, expert) pairs an expert layer computes for this chunk,
-        # pad rows and all; which experts they hit stays on the device (a
-        # chunk's span ends at its dispatch, and no read waits for it)
-        experts = ({"expert_rows": wc * fam.experts_per_token}
-                   if fam.expert_layers else {})
         # a family with state layers: the chunk's real token count reaches
         # the program (a recurrence has no causal mask to hide the pad
         # behind), the running state rides beside ck/cv, and the last chunk
@@ -1740,7 +1743,7 @@ class ContinuousGPTEngine:
         with span("serving.prefill_chunk", parent=st.req.trace_ctx,
                   request_id=st.req.request_id, slot=slot,
                   start=c0, tokens=r, first=first, final=final,
-                  width=wc, cols=cols, program=program, **experts, **scan):
+                  width=wc, cols=cols, program=program, **scan):
             if first and final:
                 logits, self._pool_kv = self._chunk_one_fn(
                     self.variables, self._pool_kv,
@@ -1803,6 +1806,16 @@ class ContinuousGPTEngine:
 
     def _finish_prefill(self, slot: int, st: _Prefill,
                         first: int) -> None:
+        if tracing.tracing_enabled():
+            # the paged twin of the dense path's span, recorded now that
+            # the first token is on the host: admission to this instant,
+            # ``ticks`` engine ticks of which ``chunks`` gave it a chunk
+            # (the others went to other prompts' turns at the budget)
+            tracing.record_span(
+                "serving.prefill", st.admitted_at, time.monotonic(),
+                parent=st.req.trace_ctx, request_id=st.req.request_id,
+                slot=slot, prompt_len=len(st.prompt),
+                cached_tokens=st.hit, chunks=st.chunks, ticks=st.ticks)
         n_shared = len(st.shared)
         nb_total = n_shared + len(st.owned)
         row = np.full((self._mb,), self._pool.sentinel, np.int32)

@@ -96,7 +96,8 @@ def test_a_decode_tick_counts_its_experts_and_its_gathers_by_layer_kind(
                         for a in deep)
     chunks = [e["args"] for e in served["events"]
               if e["name"] == "serving.prefill_chunk"]
-    assert chunks and all(a["expert_rows"] == 2 * a["width"] for a in chunks)
+    # (a constant of the width the span carries: gone with ISSUE 38)
+    assert chunks and all("expert_rows" not in a for a in chunks)
     m = served["snap"]
     assert m["expert_rows"] == sum(4 * a["expert_rows"] for a in steps)
     assert m["experts_hit"] == round(sum(4 * a["experts_hit"] for a in steps))

@@ -421,6 +421,172 @@ def test_a_profiler_capture_holds_the_engines_spans(bundle, traced,
     assert host["serving.prefill_chunk"][0]["program"] == "chunk_one"
 
 
+# -- the prefill path measures itself (ISSUE 38) -----------------------------
+
+#: two prompts longer than the chunk of 8, on two slots at once: they take
+#: turns at one tick's budget; then the first prompt again, alone, all of
+#: which but its last token the prefix cache still holds
+SHARING = ((20, 3), (17, 2))
+
+
+@pytest.fixture(scope="module")
+def shared(bundle):
+    tracing.clear_trace()
+    tracing.enable_tracing()
+    try:
+        eng = _engine(bundle, kv_block_size=8)
+        try:
+            prompts = _prompts(bundle[0], SHARING, seed=38)
+            futs, _ = _serve(eng, prompts)
+            again, _ = _serve(eng, [(prompts[0][0], 2)])
+            rids = [f.request_id for f in futs + again]
+            traces = {rid: eng.trace(rid) for rid in rids}
+        finally:
+            eng.close()
+        events = tracing.trace_events()
+        return {"rids": rids, "traces": traces,
+                "prompt_len": dict(zip(rids, (20, 17, 20))),
+                "spans": lambda *names: _spans(*names, events=events)}
+    finally:
+        tracing.disable_tracing()
+        tracing.clear_trace()
+
+
+def _of(shared, name, rid):
+    return [e for e in shared["spans"](name)
+            if e["args"].get("request_id") == rid]
+
+
+def test_a_paged_request_leaves_one_prefill_span_on_its_own_trace(shared):
+    for rid in shared["rids"]:
+        (e,) = _of(shared, "serving.prefill", rid)
+        a = e["args"]
+        # the request's root context: its trace id IS its request id
+        assert a["trace_id"] == a["parent_id"] == rid
+        assert a["prompt_len"] == shared["prompt_len"][rid]
+        (admit,) = _of(shared, "serving.admit", rid)
+        assert a["slot"] == admit["args"]["slot"]
+        assert a["cached_tokens"] == admit["args"]["cached_tokens"]
+        # it starts inside the admission and holds every chunk
+        assert admit["ts"] <= e["ts"] <= _end(admit)
+        for c in _of(shared, "serving.prefill_chunk", rid):
+            assert e["ts"] <= c["ts"] and _end(c) <= _end(e)
+
+
+def test_prefill_counts_its_chunks_and_the_ticks_it_stood_by(shared):
+    spans = [_of(shared, "serving.prefill", rid)[0]["args"]
+             for rid in shared["rids"]]
+    for a in spans:
+        uncached = a["prompt_len"] - a["cached_tokens"]
+        assert a["chunks"] == -(-uncached // 8)
+        assert a["chunks"] == len(_of(shared, "serving.prefill_chunk",
+                                      a["request_id"]))
+        assert a["ticks"] >= a["chunks"]
+    # 20 and 17 tokens under a budget of 8 a tick: somebody waits its turn
+    assert [a["cached_tokens"] for a in spans] == [0, 0, 19]
+    assert any(a["ticks"] > a["chunks"] for a in spans[:2])
+    # ...and a prompt alone, most of it cached, waits for nobody
+    assert spans[2]["ticks"] == spans[2]["chunks"] == 1
+
+
+def test_prefill_ends_with_the_first_token_inside_the_same_tick(shared):
+    ticks = shared["spans"]("serving.tick")
+    for rid in shared["rids"]:
+        (e,) = _of(shared, "serving.prefill", rid)
+        (first,) = _of(shared, "serving.first_token", rid)
+        assert _end(first) <= _end(e)
+        (tick,) = [t for t in ticks
+                   if t["ts"] <= first["ts"] and _end(first) <= _end(t)]
+        assert _end(e) <= _end(tick)
+
+
+def test_a_ticks_chunks_stay_inside_the_budget_and_say_their_pad(shared):
+    """What a tick dispatched is read off its ``serving.prefill_chunk``
+    spans (``tokens`` real, ``width`` with the pad): the tick itself
+    carries no count of them."""
+    ticks = shared["spans"]("serving.tick")
+    chunks = shared["spans"]("serving.prefill_chunk")
+    per_tick = []
+    for t in ticks:
+        assert not {"chunk_tokens", "chunk_width"} & set(t["args"])
+        mine = [c["args"] for c in chunks
+                if t["ts"] <= c["ts"] and _end(c) <= _end(t)]
+        assert sum(c["tokens"] for c in mine) <= 8
+        per_tick.append(mine)
+    assert sum(len(mine) for mine in per_tick) == len(chunks)
+    assert sum(c["args"]["tokens"] for c in chunks) == 20 + 17 + 1
+    assert all(c["args"]["width"] >= c["args"]["tokens"] for c in chunks)
+    # a last chunk of 1 token ran in a program 8 wide: the pad is there
+    assert any(c["args"]["width"] > c["args"]["tokens"] for c in chunks)
+    assert any(not mine for mine in per_tick)
+
+
+def test_a_paged_requests_trace_reads_without_a_hole(shared):
+    """Queue wait, admission, its chunks, the first token and the prefill
+    that ends with it, its decode steps, the request: by the instant each
+    was over."""
+    for rid in shared["rids"]:
+        trace = sorted((e for e in shared["traces"][rid]
+                        if e["args"].get("request_id") == rid
+                        or rid in e["args"].get("links", ())),
+                       key=_end)
+        names = [e["name"] for e in trace
+                 if e["name"] not in ("serving.tick", "serving.retire",
+                                      "serving.decode_dispatch",
+                                      "serving.decode_wait")]
+        n_chunks = names.count("serving.prefill_chunk")
+        n_steps = names.count("serving.decode_step")
+        assert n_chunks >= 1 and n_steps >= 1
+        assert names == (["serving.queue_wait", "serving.admit"]
+                         + ["serving.prefill_chunk"] * n_chunks
+                         + ["serving.first_token", "serving.prefill"]
+                         + ["serving.decode_step"] * n_steps
+                         + ["serving.request"])
+        by_name = {e["name"]: e for e in trace}
+        # no hole: the wait ends where the admission starts, the prefill
+        # starts inside it and runs to the first token
+        assert _end(by_name["serving.queue_wait"]) <= \
+            by_name["serving.admit"]["ts"]
+        assert by_name["serving.prefill"]["ts"] <= \
+            _end(by_name["serving.admit"])
+        assert by_name["serving.request"]["ts"] <= \
+            by_name["serving.queue_wait"]["ts"]
+
+
+def test_the_dense_layouts_prefill_span_is_as_it_was(bundle, traced):
+    eng = _engine(bundle, kv_layout="dense")
+    try:
+        _serve(eng, _prompts(bundle[0], ((6, 2),)))
+    finally:
+        eng.close()
+    (e,) = _spans("serving.prefill")
+    assert {"prompt_len", "bucket", "slot", "request_id"} <= set(e["args"])
+    assert "ticks" not in e["args"]
+
+
+def test_prefill_counts_with_tracing_off_and_records_nothing(bundle,
+                                                             monkeypatch):
+    tracing.disable_tracing()
+    tracing.clear_trace()
+    eng = _engine(bundle)
+    counted = []
+    finish = eng._finish_prefill
+
+    def watched(slot, st, first):
+        counted.append((st.chunks, st.ticks))
+        finish(slot, st, first)
+
+    monkeypatch.setattr(eng, "_finish_prefill", watched)
+    try:
+        _serve(eng, _prompts(bundle[0], SHARING, seed=38))
+    finally:
+        eng.close()
+    assert sorted(c for c, _ in counted) == [3, 3]
+    assert all(t >= c for c, t in counted)
+    assert any(t > c for c, t in counted)
+    assert tracing.trace_events() == []
+
+
 # -- what the paged decode reads through the table (ISSUE 27) ----------------
 
 def _hand_count(slots, block, steps_of):
