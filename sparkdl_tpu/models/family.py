@@ -71,6 +71,11 @@ class ServingFamily:
     #: every row's ``nb`` blocks: what the engine's count of a step's reads
     #: follows
     decode_reads_in_place: bool = False
+    #: the chunkwise recurrence of the state layers solves its sub-chunks'
+    #: triangular systems in the kernel (``ops/delta_solve.py``, by its rule
+    #: ``solves_in_kernel`` on the family's widths) where it would hand them
+    #: to ``solve_triangular``: what a chunk's span says of its program
+    scan_solved_in_kernel: bool = False
     #: layers that keep a recurrent state a SLOT, whatever the context's
     #: length, and the arrays each keeps, ``(name, shape a slot, dtype)``:
     #: the pool holds ``name`` as ``[state_layers, n_slots, *shape]``. No

@@ -27,12 +27,14 @@ test_olmo_hybrid.py`` holds both to the token-by-token one in float64):
 :func:`gated_delta_step` advances a state by one token (decode);
 :func:`gated_delta_chunked` advances it by a whole chunk in sub-chunks of
 :data:`SUB_CHUNK` tokens, the WY / UT form: inside a sub-chunk the
-interactions are one triangular solve and a few matrix products, between
-sub-chunks the state is carried; decays are accumulated in log space, so no
-factor ever exceeds one. A token past the chunk's real count (the engine
-pads a chunk to a power-of-two width) has ``beta = 0`` and ``g = 0``: it
-leaves the state untouched bit for bit, and the convolutions' tails are
-taken at the last REAL token.
+interactions are one triangular system a head, solved by forward
+substitution by blocks in ``ops/delta_solve`` (by ``solve_triangular``, the
+definition, where that module's rule refuses the shapes), and a few matrix
+products, between sub-chunks the state is carried; decays are accumulated
+in log space, so no factor ever exceeds one. A token past the chunk's real
+count (the engine pads a chunk to a power-of-two width) has ``beta = 0`` and
+``g = 0``: it leaves the state untouched bit for bit, and the convolutions'
+tails are taken at the last REAL token.
 
 The module keeps :class:`~sparkdl_tpu.models.gpt.GPTLMHeadModel`'s cache
 contracts, with the state beside the K/V:
@@ -76,7 +78,7 @@ from sparkdl_tpu.models.kv_pool import (
     kv_tail,
     layer_rows,
 )
-from sparkdl_tpu.ops import paged_decode
+from sparkdl_tpu.ops import delta_solve, paged_decode
 
 LINEAR, FULL = "linear_attention", "full_attention"
 #: tokens a sub-chunk of the chunkwise recurrence: one triangular system of
@@ -156,6 +158,9 @@ class OlmoHybridConfig:
             kv_layers=self.layers_of(FULL),
             decode_reads_in_place=paged_decode.reads_in_place(
                 tail, tail, self.num_heads, self.head_dim),
+            scan_solved_in_kernel=delta_solve.solves_in_kernel(
+                SUB_CHUNK, self.linear_key_head_dim
+                + self.linear_value_head_dim, jnp.float32),
             state_layers=self.layers_of(LINEAR),
             state_arrays=(
                 ("state", (h, self.linear_key_head_dim,
@@ -242,12 +247,14 @@ def gated_delta_chunked(q, k, v, g, beta, state, sub: int = SUB_CHUNK):
 
     Inside a sub-chunk, with ``c_i`` the running sum of ``g`` and ``A`` the
     strictly lower triangle of ``beta_i (k_i . k_j) exp(c_i - c_j)``: ``T =
-    (I + A)^-1`` gives every token's corrected value in one triangular
-    solve (``u = T beta v``, ``w = T beta k exp(c)``; forward substitution,
-    not a product of powers of ``A``, which cancel catastrophically where
-    keys repeat). With the state ``S`` at the sub-chunk's start: ``v_new = u
-    - w S``; ``o = (q exp(c)) S + tril(q k^T exp(c_i - c_j)) v_new``; ``S <-
-    exp(c_last) S + (k exp(c_last - c))^T v_new``.
+    (I + A)^-1`` gives every token's corrected value (``u = T beta v``, ``w
+    = T beta k exp(c)``) by forward substitution by blocks in
+    ``ops/delta_solve`` where its rule takes the shapes and by
+    ``solve_triangular`` where not; never by a product of powers of ``A``,
+    which cancel catastrophically where keys repeat. With the state ``S`` at
+    the sub-chunk's start: ``v_new = u - w S``; ``o = (q exp(c)) S + tril(q
+    k^T exp(c_i - c_j)) v_new``; ``S <- exp(c_last) S + (k exp(c_last -
+    c))^T v_new``.
     """
     with jax.named_scope(SCAN_SCOPE):
         f32 = jnp.float32
@@ -275,8 +282,12 @@ def gated_delta_chunked(q, k, v, g, beta, state, sub: int = SUB_CHUNK):
                       kk * decay, 0.0)
         rhs = jnp.concatenate(
             [kb * jnp.exp(c)[..., None], v * beta[..., None]], axis=-1)
-        solved = jax.scipy.linalg.solve_triangular(
-            a + jnp.eye(sub, dtype=f32), rhs, lower=True, unit_diagonal=True)
+        if delta_solve.solves_in_kernel(sub, rhs.shape[-1], f32):
+            solved = delta_solve.delta_solve(a, rhs)
+        else:
+            solved = jax.scipy.linalg.solve_triangular(
+                a + jnp.eye(sub, dtype=f32), rhs, lower=True,
+                unit_diagonal=True)
         w, u = solved[..., :dk], solved[..., dk:]
         qk = jnp.einsum("...id,...jd->...ij", q, k,
                         precision=_HIGHEST) * decay

@@ -1739,7 +1739,9 @@ class ContinuousGPTEngine:
         state = bool(fam.state_layers)
         n = (jnp.asarray(r, jnp.int32),) if state else ()
         row = (jnp.asarray(slot, jnp.int32),) if state else ()
-        scan = ({"scan_tokens": r, "pad_tokens": wc - r} if state else {})
+        scan = ({"scan_tokens": r, "pad_tokens": wc - r,
+                 "scan_solved_in_kernel": int(fam.scan_solved_in_kernel)}
+                if state else {})
         with span("serving.prefill_chunk", parent=st.req.trace_ctx,
                   request_id=st.req.request_id, slot=slot,
                   start=c0, tokens=r, first=first, final=final,

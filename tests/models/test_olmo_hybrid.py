@@ -2,7 +2,11 @@
 rehearsal size, float32, seeded random weights: the three forms of the gated
 delta rule against each other, the module against the plain token-by-token
 reference (``benchmark/reference_olmo_hybrid.py``) through each cache
-contract, chunk splits, the pad mask, and the variants it refuses."""
+contract, chunk splits, the pad mask, and the variants it refuses. The
+chunkwise form solves its triangular systems on one of two paths
+(``ops/delta_solve.py``: its kernel where its rule takes the widths,
+``solve_triangular`` where not): what holds of the form is a case of each
+(:data:`PATHS`)."""
 
 import jax
 import jax.numpy as jnp
@@ -26,10 +30,17 @@ from sparkdl_tpu.models.olmo_hybrid import (
     gated_delta_step,
     init_olmo_hybrid_cache,
 )
+from sparkdl_tpu.ops import delta_solve
 
 SEED = 2**31 + 31
 TOL = 5e-5   # float32 on the CPU (the chunkwise form rounds in another order than
              # the recurrence); the logits' standard deviation is 0.16
+
+
+#: (d_k, d_v) of a linear head: the tiny configuration's, which the solve
+#: kernel's rule refuses, and the narrowest it takes (one lane tile)
+PATHS = [pytest.param((8, 16), id="solve_triangular"),
+         pytest.param((32, 96), id="delta_solve kernel")]
 
 
 def rehearsal_hf() -> dict:
@@ -42,9 +53,7 @@ def rehearsal_hf() -> dict:
     return serve_olmo_hybrid.hf_config(run.config())
 
 
-@pytest.fixture(scope="module")
-def bundle():
-    hf = rehearsal_hf()
+def _bundle(hf):
     cfg = config_from_hf_olmo_hybrid(hf)
     model = OlmoHybridLMHeadModel(cfg)
     variables = serve_olmo_hybrid.program_variables(model, hf, "float32",
@@ -56,11 +65,31 @@ def bundle():
     return hf, cfg, model, variables, ids, want
 
 
+@pytest.fixture(scope="module")
+def bundle():
+    return _bundle(rehearsal_hf())
+
+
+@pytest.fixture(scope="module")
+def kernel_bundle():
+    """The rehearsal's configuration with linear heads of 32 x 96: the
+    narrowest whose chunkwise form solves in the kernel."""
+    dk, dv = PATHS[1].values[0]
+    return _bundle({**rehearsal_hf(), "linear_key_head_dim": dk,
+                    "linear_value_head_dim": dv})
+
+
 # -- the rule's three forms -----------------------------------------------------
 
-def _rule_inputs(length, b=2, h=3, dk=8, dv=16, dtype=np.float64, seed=0):
+def _rule_inputs(length, b=2, h=3, dk=8, dv=16, dtype=np.float64, seed=0,
+                 dims=None):
     """q, k L2-normalised; decays from 0.2 to 0.999 a token; beta in (0, 2),
-    a good half of it over 1 (negative eigenvalues of the transition)."""
+    a good half of it over 1 (negative eigenvalues of the transition).
+    ``dims`` is ``(dk, dv)`` as :data:`PATHS` gives them."""
+    if dims is not None:
+        dk, dv = dims
+        assert delta_solve.solves_in_kernel(
+            SUB_CHUNK, dk + dv, jnp.float32) is (dims != (8, 16))
     rng = np.random.default_rng(seed)
 
     def unit(x):
@@ -82,11 +111,13 @@ def float64():
     jax.config.update("jax_enable_x64", False)
 
 
-def test_chunkwise_equals_one_token_equals_the_recurrence_in_float64(float64):
+@pytest.mark.parametrize("dims", PATHS)
+def test_chunkwise_equals_one_token_equals_the_recurrence_in_float64(
+        float64, dims):
     """200 tokens (three whole sub-chunks and a part of a fourth) from a
     state that is not zero: the WY form, the one-token update applied 200
     times, and a recurrence written out here in numpy."""
-    q, k, v, g, beta, state = _rule_inputs(200)
+    q, k, v, g, beta, state = _rule_inputs(200, dims=dims)
     assert float((beta > 1).mean()) > 0.3
     want_o = np.zeros(v.shape)
     s = np.array(state)
@@ -109,12 +140,14 @@ def test_chunkwise_equals_one_token_equals_the_recurrence_in_float64(float64):
     assert chunk_s.dtype == jnp.float32 and chunk_o.shape == v.shape
 
 
-def test_repeated_keys_and_beta_two_do_not_blow_the_solve_up():
+@pytest.mark.parametrize("dims", PATHS)
+def test_repeated_keys_and_beta_two_do_not_blow_the_solve_up(dims):
     """Every key of a sub-chunk the same and beta at its largest: the
     triangular system is 2 x the all-ones lower triangle, whose powers grow
     as 2^k while its inverse stays bounded. Forward substitution keeps to
     the recurrence; a product of powers would not."""
-    q, k, v, g, beta, state = _rule_inputs(SUB_CHUNK, dtype=np.float32)
+    q, k, v, g, beta, state = _rule_inputs(SUB_CHUNK, dtype=np.float32,
+                                           dims=dims)
     k = jnp.broadcast_to(k[:, :1], k.shape)
     beta = jnp.full_like(beta, 2.0)
     g = jnp.zeros_like(g)
@@ -128,11 +161,12 @@ def test_repeated_keys_and_beta_two_do_not_blow_the_solve_up():
                                atol=1e-3 * scale)
 
 
+@pytest.mark.parametrize("dims", PATHS)
 @pytest.mark.parametrize("cut", [1, 17, 63, 64, 65, 100, 128, 199])
-def test_a_chunk_split_anywhere_gives_the_whole_prompts_state(cut):
+def test_a_chunk_split_anywhere_gives_the_whole_prompts_state(cut, dims):
     """The state carried across a chunk boundary at every kind of offset of
     a sub-chunk (its first token, its last, the one after, in between)."""
-    q, k, v, g, beta, state = _rule_inputs(200, dtype=np.float32)
+    q, k, v, g, beta, state = _rule_inputs(200, dtype=np.float32, dims=dims)
     whole_o, whole_s = gated_delta_chunked(q, k, v, g, beta, state)
     head = [x[:, :cut] for x in (q, k, v, g, beta)]
     tail = [x[:, cut:] for x in (q, k, v, g, beta)]
@@ -181,7 +215,9 @@ def _dense_cache(cfg, model, variables, ids):
     for t in range(50, ids.shape[1]):
         logits, cache = step(cache, jnp.asarray(ids[:, t:t + 1]))
         out.append(logits)
-    assert cache["state"].shape == (6, ids.shape[0], 4, 8, 16)
+    assert cache["state"].shape == (
+        6, ids.shape[0], 4, cfg.linear_key_head_dim,
+        cfg.linear_value_head_dim)
     assert cache["k"].shape[0] == 2      # the FULL layers alone
     return jnp.concatenate(out, axis=1)
 
@@ -239,10 +275,14 @@ def _paged_cache(cfg, model, variables, ids):
     return [jnp.concatenate(x, axis=0) for x in logits]
 
 
-@pytest.mark.parametrize("contract", ["none", "dense", "paged"])
+@pytest.mark.parametrize("which, contract", [
+    ("bundle", "none"), ("bundle", "dense"), ("bundle", "paged"),
+    ("kernel_bundle", "dense")])
 def test_logits_equal_the_references_through_each_cache_contract(
-        bundle, contract):
-    hf, cfg, model, variables, ids, want = bundle
+        request, which, contract):
+    hf, cfg, model, variables, ids, want = request.getfixturevalue(which)
+    assert cfg.serving_family().scan_solved_in_kernel is (
+        which == "kernel_bundle")
     got = {"none": _no_cache, "dense": _dense_cache,
            "paged": _paged_cache}[contract](cfg, model, variables, ids)
     for r in range(2):
@@ -251,11 +291,12 @@ def test_logits_equal_the_references_through_each_cache_contract(
         np.testing.assert_allclose(np.asarray(got[r]), want[r, :n], atol=TOL)
 
 
-def test_masked_tokens_behind_a_chunk_leave_the_state_bit_for_bit():
+@pytest.mark.parametrize("dims", PATHS)
+def test_masked_tokens_behind_a_chunk_leave_the_state_bit_for_bit(dims):
     """The rule alone: 100 tokens, and the same 100 with 28 more behind them
     whose beta and g are 0 and whose q, k, v are anything: the state after
     both is the same bits (the masked tokens add exact zeros)."""
-    q, k, v, g, beta, state = _rule_inputs(128, dtype=np.float32)
+    q, k, v, g, beta, state = _rule_inputs(128, dtype=np.float32, dims=dims)
     real = (jnp.arange(128) < 100)[None, :, None]
     _, alone = gated_delta_chunked(
         *(x[:, :100] for x in (q, k, v, g, beta)), state)
@@ -267,15 +308,16 @@ def test_masked_tokens_behind_a_chunk_leave_the_state_bit_for_bit():
     assert bool(jnp.isfinite(o).all())
 
 
+@pytest.mark.parametrize("which", ["bundle", "kernel_bundle"])
 def test_a_padded_chunk_leaves_the_state_and_tails_of_the_unpadded_one(
-        bundle):
+        request, which):
     """100 real tokens alone, and the same 100 at the head of a chunk padded
     to 128 with token 0 and the real count handed over. The first layer's
     state and convolution tails are the same BITS (its inputs are); the
     layers over it are given inputs from products of another shape, which
     round in another order, and agree to float32's last digits. Without the
     count the pad moves every layer's state and tails."""
-    hf, cfg, model, variables, ids, _ = bundle
+    hf, cfg, model, variables, ids, _ = request.getfixturevalue(which)
     row = np.concatenate([ids[0], ids[1]])[None, :100]
     padded = np.zeros((1, 128), np.int32)
     padded[:, :100] = row

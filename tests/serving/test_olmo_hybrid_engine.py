@@ -124,6 +124,9 @@ def test_the_spans_count_state_rows_scan_tokens_and_matches_passed_up(
     for a in chunks:
         assert a["scan_tokens"] == a["tokens"]
         assert a["pad_tokens"] == a["width"] - a["tokens"]
+        # linear heads of 8 x 16: the solve kernel's rule refuses them
+        assert a["scan_solved_in_kernel"] == 0
+    assert not fam.scan_solved_in_kernel
     assert any(a["pad_tokens"] for a in chunks)
     assert any(a["pad_tokens"] == 0 and a["final"] for a in chunks)
     # prompts 0-2 came a second time: the first 3 of 5 tokens' block is not
@@ -137,6 +140,45 @@ def test_the_spans_count_state_rows_scan_tokens_and_matches_passed_up(
     scanned = registry().get("sparkdl_linear_scan_tokens_total")
     assert sum(scanned.snapshot_values().values()) >= sum(
         a["scan_tokens"] for a in chunks)
+
+
+def test_an_engine_whose_widths_the_rule_takes_solves_in_the_kernel():
+    """Linear heads of 32 x 96 (the narrowest ``ops/delta_solve``'s rule
+    takes): the chunk programs solve through the kernel (the interpreter
+    here), the chunks' spans say so, and the tokens are the reference's, a
+    prompt of several chunks with a padded last one among them. The
+    published widths are taken too."""
+    from benchmark.runners import serve_olmo_hybrid
+    from sparkdl_tpu.models.olmo_hybrid import OlmoHybridConfig
+
+    assert OlmoHybridConfig().serving_family().scan_solved_in_kernel
+    hf = {**rehearsal_hf(), "linear_key_head_dim": 32,
+          "linear_value_head_dim": 96}
+    cfg = config_from_hf_olmo_hybrid(hf)
+    assert cfg.serving_family().scan_solved_in_kernel
+    variables = serve_olmo_hybrid.program_variables(
+        OlmoHybridLMHeadModel(cfg), hf, "float32", SEED)
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (70, 9)]
+    tracing.enable_tracing()
+    tracing.clear_trace()
+    try:
+        with ContinuousGPTEngine(cfg, variables, n_slots=2, max_len=128,
+                                 prefill_chunk=32) as eng:
+            got = [np.asarray(f.result(timeout=600))
+                   for f in [eng.submit(p, 4) for p in prompts]]
+        events = tracing.trace_events()
+    finally:
+        tracing.disable_tracing()
+    chunks = [e["args"] for e in events
+              if e["name"] == "serving.prefill_chunk"]
+    assert len(chunks) == 4
+    assert all(a["scan_solved_in_kernel"] == 1 for a in chunks)
+    want, margins = _reference_greedy(hf, prompts, 4)
+    assert margins.min() > 1e-4
+    for g, w in zip(got, want):
+        assert g.tolist() == w.tolist()
 
 
 def test_capacity_counts_the_layers_that_keep_kv_and_says_the_states_bytes(

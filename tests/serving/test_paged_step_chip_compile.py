@@ -13,6 +13,7 @@ the worker that is handed this file loads the TPU's library.
 """
 
 import dataclasses
+import math
 import re
 
 import jax
@@ -341,10 +342,12 @@ def compiled_hybrid(one_chip, for_the_chip, monkeypatch_module):
         OlmoHybridConfig,
         OlmoHybridLMHeadModel,
     )
-    from sparkdl_tpu.ops import paged_decode
+    from sparkdl_tpu.ops import delta_solve, paged_decode
 
-    # the paged attention the CHIP runs (this process's backend is the CPU)
+    # the paged attention and the triangular solve the CHIP runs (this
+    # process's backend is the CPU)
     monkeypatch_module.setattr(paged_decode, "auto_interpret", lambda: False)
+    monkeypatch_module.setattr(delta_solve, "auto_interpret", lambda: False)
     cfg = OlmoHybridConfig(vocab_size=512, layer_types=(LINEAR, FULL),
                            dtype=jnp.bfloat16)
     variables = jax.eval_shape(
@@ -490,10 +493,110 @@ def test_a_mid_chunk_hands_the_running_state_on_in_its_own_buffers(
     state = 30 * 96 * 256 * 4      # as the chip pads it
     assert stats.alias_size_in_bytes >= 2 * private + state
     text = compiled_hybrid["mid"].as_text()
-    # the triangular systems are solved by the chip's blocked inverse and a
-    # product at full precision, one a linear layer
-    assert text.count("InvertDiagBlocksLowerTriangular") >= 1
+    # the triangular systems are solved by the kernel (ISSUE 39), one call a
+    # linear layer, not by the chip's 64-step inverse of a block of order
+    # 64; the products around it are at full precision
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    assert len(re.findall(r"%delta_solve[.\d]* = f32\[1,30,4,64,288\]", text)) == 1
     assert "operand_precision={highest,highest}" in text
+
+
+@pytest.fixture(scope="module")
+def hybrid_scan(one_chip, for_the_chip, compiled_hybrid):
+    """A MID chunk of 256 tokens over TWO linear-attention layers and a full
+    one at Olmo-Hybrid-7B's widths, as lowered and as compiled for the chip
+    (``compiled_hybrid`` has turned the kernels' interpreter off)."""
+    from sparkdl_tpu.models.olmo_hybrid import (
+        FULL,
+        LINEAR,
+        OlmoHybridConfig,
+        OlmoHybridLMHeadModel,
+    )
+
+    cfg = OlmoHybridConfig(vocab_size=512, layer_types=(LINEAR, LINEAR, FULL),
+                           dtype=jnp.bfloat16)
+    variables = jax.eval_shape(
+        lambda: OlmoHybridLMHeadModel(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    eng = ContinuousGPTEngine(cfg, variables, n_slots=16, max_len=8192,
+                              auto_start=False)
+    try:
+        def ints(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+        private = jax.ShapeDtypeStruct(
+            (1, 1, eng._wp, 3840), jnp.bfloat16, sharding=one_chip)
+        rec = {name: jax.ShapeDtypeStruct(
+            (2, 1) + a.shape[2:], a.dtype, sharding=one_chip)
+            for name, a in eng._pool_kv.items() if name in ("state", "conv")}
+        lowered = eng._chunk_mid_fn.lower(
+            _on(one_chip, variables), private, private, ints(),
+            ints(1, 256), 4096, ints(), rec)
+        return {"lowered": lowered.as_text(),
+                "compiled": lowered.compile().as_text()}
+    finally:
+        eng.close()
+
+
+def test_two_linear_layers_solve_in_one_lowered_kernel(hybrid_scan):
+    """Each linear layer's sub-chunks are solved by ``ops/delta_solve``'s
+    kernel, and no program lowers it: the kernel is lowered once a process
+    for each shape and kept as text (``jax.export``), which both layers of
+    this program call as ONE function and which the other fixture's mid
+    chunk of the same width took as it was (a kernel lowered in each program
+    cost this one 0.35-0.8 s of every start of every chunk program, PERF.md
+    section 6, PR 39). The chip's row-by-row inverse is gone."""
+    from sparkdl_tpu.ops import delta_solve
+
+    lowered, text = hybrid_scan["lowered"], hybrid_scan["compiled"]
+    assert lowered.count("func.func private @call_exported__delta_solve") == 1
+    assert lowered.count("call @call_exported__delta_solve") == 2
+    assert lowered.count("tpu_custom_call") == 1
+    # two shapes in both fixtures' programs, each lowered once: one
+    # sub-chunk (the eight tokens the variables are shaped from) and the
+    # four of a chunk of 256 (three programs, four layers)
+    info = delta_solve._lowered_for_the_chip.cache_info()
+    assert info.misses == 2 and info.hits >= 3
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    calls = re.findall(
+        r"%delta_solve[.\d]* = f32\[1,30,4,64,288\]\S* custom-call\(", text)
+    assert len(calls) == 2
+
+
+def test_the_benchmarks_reader_finds_every_operation_of_the_scan(hybrid_scan):
+    """The device trace carries no scope, so the benchmark finds the
+    chunkwise recurrence's operations by the SHAPES of their results
+    (``benchmark/readers_olmo_hybrid.is_scan_op``, imported as it stands).
+    Every fusion, custom call and loop of the compiled chunk that the
+    program made under its ``gated_delta_scan`` scope is one the reader's
+    rule takes, the kernel among them, but for the masks of a sub-chunk and
+    a number a head and sub-chunk (nothing of a system's size, and none of
+    them new): ``delta_scan_device_ms.hybrid`` reads the whole of the new
+    path."""
+    import json
+    import os
+
+    import benchmark
+    from benchmark.readers_olmo_hybrid import is_scan_op
+
+    # the cell's own configuration: the keys the reader takes its sizes from
+    with open(os.path.join(os.path.dirname(benchmark.__file__), "configs",
+                           "olmo-hybrid-7b-serve.json")) as f:
+        hf = json.load(f)
+    entry = hybrid_scan["compiled"].split("\nENTRY ", 1)[1]
+    scoped = [ln.strip() for ln in entry.splitlines()
+              if "gated_delta_scan" in ln
+              and re.search(r" (fusion|custom-call|while)\(", ln)]
+    assert len(scoped) > 10
+    assert any(ln.startswith("%delta_solve") for ln in scoped)
+    missed = [ln.split(", metadata=")[0] for ln in scoped
+              if not is_scan_op(ln.split(", metadata=")[0], hf)]
+    assert len(missed) < len(scoped) // 4
+    for ln in missed:
+        made = re.findall(r"\w+\[([\d,]*)\]", ln.split(" fusion(")[0])
+        assert made and all(
+            math.prod(int(d) for d in dims.split(",")) <= 64 * 64
+            for dims in made), ln[:160]
 
 
 # -- the mimo_v2_flash family at its published widths (ISSUE 36) ------------------
