@@ -7,7 +7,7 @@ shaped, is the family's to say. A configuration answers
 nothing else off the configuration. ``GPTConfig`` answers with its own
 fields (so GPT-2's programs are what they were); a new family answers from
 its own module (``models/afmoe.py``, ``models/olmo_hybrid.py``,
-``models/mimo_v2_flash.py``).
+``models/mimo_v2_flash.py``, ``models/lfm2_moe.py``).
 
 The module's contract is :class:`~sparkdl_tpu.models.gpt.GPTLMHeadModel`'s:
 ``module.apply(variables, ids, cache=None | dense | paged, positions=...)``
@@ -19,9 +19,11 @@ in their trailing shape), and the caller writes them into its pool.
 ``layers`` there are the layers that KEEP K/V a token
 (:attr:`ServingFamily.pool_layers`): a family may keep, in some layers,
 arrays by SLOT instead (:attr:`ServingFamily.state_layers`), whose size does
-not grow with the context: a recurrent state (``models/olmo_hybrid.py``), or
+not grow with the context: a recurrent state (``models/olmo_hybrid.py``),
 the last ``window`` columns of a window layer's K and V kept as a ring
-(``models/mimo_v2_flash.py``, :attr:`ServingFamily.ring_columns`). The pool
+(``models/mimo_v2_flash.py``, :attr:`ServingFamily.ring_columns`), or the
+last few inputs of a short convolution and nothing else
+(``models/lfm2_moe.py``, :attr:`ServingFamily.tail_columns`). The pool
 holds them by slot beside its blocks and the module hands them back whole.
 """
 
@@ -91,6 +93,10 @@ class ServingFamily:
     #: (0: the arrays by slot are no ring). Not ``window_layers``: those
     #: keep every block and gather the entries their window covers
     ring_columns: int = 0
+    #: the arrays by slot are TAILS of this many columns: each state layer
+    #: is a short convolution that keeps its last ``tail_columns`` inputs a
+    #: slot, in the compute dtype, and nothing else (0: they are no tail)
+    tail_columns: int = 0
 
     @property
     def pool_layers(self) -> int:

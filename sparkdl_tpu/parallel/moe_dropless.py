@@ -52,11 +52,13 @@ def grouped_dot(xs: jax.Array, kernels: jax.Array,
 def route_sigmoid_topk(h: jax.Array, router_kernel: jax.Array,
                        expert_bias: jax.Array, k: int, *,
                        route_norm: bool = True,
-                       route_scale: float = 1.0
+                       route_scale: float = 1.0,
+                       norm_eps: float = 1e-20
                        ) -> "tuple[jax.Array, jax.Array]":
     """Sigmoid scores in float32, the top ``k`` by score PLUS bias, weights
-    from the unbiased scores (normalised to sum to one if ``route_norm``,
-    then scaled). ``h`` [T, H] -> ``(sel [T, k] int32, w [T, k] float32)``."""
+    from the unbiased scores (normalised if ``route_norm``: divided by their
+    sum plus ``norm_eps``, which is the family's own; then scaled). ``h``
+    [T, H] -> ``(sel [T, k] int32, w [T, k] float32)``."""
     # float32 at full precision: a TPU's default would round both operands
     # to bfloat16, and the 8th and 9th of 128 scores are a hair apart
     s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32),
@@ -65,7 +67,7 @@ def route_sigmoid_topk(h: jax.Array, router_kernel: jax.Array,
     _, sel = jax.lax.top_k(s + expert_bias.astype(jnp.float32), k)
     w = jnp.take_along_axis(s, sel, axis=-1)
     if route_norm:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + norm_eps)
     return sel.astype(jnp.int32), w * route_scale
 
 

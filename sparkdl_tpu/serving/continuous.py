@@ -205,6 +205,11 @@ _M_RING_BYTES = registry().gauge(
     "device bytes of window layers' rings held by slot (the last "
     "``window`` columns of K and V a slot a window layer, whatever the "
     "contexts' lengths), all engines")
+_M_TAIL_BYTES = registry().gauge(
+    "sparkdl_conv_tail_bytes",
+    "device bytes of short convolutions' tails held by slot (the last "
+    "inputs of a gated short convolution a slot a layer, whatever the "
+    "contexts' lengths), all engines")
 _M_SCAN_TOKENS = registry().counter(
     "sparkdl_linear_scan_tokens_total",
     "real prompt tokens taken through the chunkwise recurrence of a "
@@ -703,9 +708,10 @@ class ContinuousGPTEngine:
             self._install_blocks_fn = jax.jit(
                 programs._install_blocks, donate_argnums=(0,))
             # what the family holds by slot: rings of a window's columns,
-            # or a recurrent state
+            # tails of a short convolution's inputs, or a recurrent state
             self._g_state = GaugeShare(
-                _M_RING_BYTES if fam.ring_columns else _M_STATE_BYTES)
+                _M_RING_BYTES if fam.ring_columns
+                else _M_TAIL_BYTES if fam.tail_columns else _M_STATE_BYTES)
             self._g_state.set(n_slots * fam.state_bytes_per_slot)
             if sp_val > 1:
                 self._init_sp(sp_val, sp_kv_blocks)

@@ -59,7 +59,8 @@ def bundle():
 
 
 def _no_cache(cfg, model, variables, ids):
-    return model.apply(variables, jnp.asarray(ids))[0]
+    return jax.jit(lambda ids: model.apply(variables, ids)[0])(
+        jnp.asarray(ids))
 
 
 def _dense_cache(cfg, model, variables, ids):
@@ -101,12 +102,14 @@ def _paged_cache(cfg, model, variables, ids):
                 for name in ("k", "v"):
                     pool[name][:, blk, off] = np.asarray(new[name][:, j, t])
 
+    prefill = jax.jit(lambda ids, cache: model.apply(variables, ids,
+                                                     cache=cache))
     for r, n in enumerate(lens):
         idx = np.zeros((1,), np.int32)
-        out, new = model.apply(
-            variables, jnp.asarray(ids[r:r + 1, :n]),
-            cache={"k": jnp.asarray(pool["k"]), "v": jnp.asarray(pool["v"]),
-                   "table": jnp.asarray(table[r:r + 1]), "idx": idx})
+        out, new = prefill(
+            jnp.asarray(ids[r:r + 1, :n]),
+            {"k": jnp.asarray(pool["k"]), "v": jnp.asarray(pool["v"]),
+             "table": jnp.asarray(table[r:r + 1]), "idx": idx})
         write(new, [r], idx, n)
         logits[r] = [out[0]]
     step = jax.jit(lambda pool, table, idx, tok: model.apply(

@@ -70,7 +70,8 @@ def bundle():
 
 
 def _no_cache(cfg, model, variables, ids):
-    return model.apply(variables, jnp.asarray(ids))[0]
+    return jax.jit(lambda ids: model.apply(variables, ids)[0])(
+        jnp.asarray(ids))
 
 
 #: (real tokens, program width) of the dense contract's calls: a chunk of 40
@@ -113,10 +114,11 @@ def _paged_cache(cfg, model, variables, ids):
     table[1, :6] = [5, 0, 11, 2, 13, 8]
     lens = [40, 56]
     logits = [None, None]
+    prefill = jax.jit(lambda ids, cache: model.apply(variables, ids,
+                                                     cache=cache))
     for r, n in enumerate(lens):
-        out, cache = model.apply(
-            variables, jnp.asarray(ids[r:r + 1, :n]),
-            cache=init_mimo_v2_flash_cache(cfg, 1, 64))
+        out, cache = prefill(jnp.asarray(ids[r:r + 1, :n]),
+                             init_mimo_v2_flash_cache(cfg, 1, 64))
         for pos in range(n):
             for name in ("k", "v"):
                 pool[name][:, table[r, pos // bs], pos % bs] = np.asarray(
@@ -173,21 +175,23 @@ def test_the_ring_after_a_chunk_is_the_last_window_of_real_columns(
     _, cfg, model, variables, ids, _ = bundle
     row = ids[:1]
     names = ("k", "v", "win_k", "win_v")
-    _, cache = model.apply(
-        variables, jnp.asarray(row[:, :start + n]),
-        cache=init_mimo_v2_flash_cache(cfg, 1, 128))
+    # (one compiled program a shape: the same calls, eagerly, take four times
+    # as long)
+    apply = jax.jit(lambda ids, cache: model.apply(variables, ids,
+                                                   cache=cache))
+    _, cache = apply(jnp.asarray(row[:, :start + n]),
+                     init_mimo_v2_flash_cache(cfg, 1, 128))
     want = {name: np.asarray(cache[name]) for name in ("win_k", "win_v")}
     cache = init_mimo_v2_flash_cache(cfg, 1, 128)
     if start:
-        _, cache = model.apply(variables, jnp.asarray(row[:, :start]),
-                               cache=cache)
+        _, cache = apply(jnp.asarray(row[:, :start]), cache)
     chunk = np.full((1, width), 7, np.int32)     # the pad is a real token id
     chunk[:, :n] = row[:, start:start + n]
-    _, got = model.apply(
-        variables, jnp.asarray(chunk),
-        cache=dict({k: cache[k] for k in names},
-                   idx=jnp.asarray(start, jnp.int32),
-                   n=jnp.asarray(n, jnp.int32)))
+    _, got = apply(
+        jnp.asarray(chunk),
+        dict({k: cache[k] for k in names},
+             idx=jnp.asarray(start, jnp.int32),
+             n=jnp.asarray(n, jnp.int32)))
     for name in want:
         np.testing.assert_allclose(np.asarray(got[name]), want[name],
                                    atol=1e-6, err_msg=name)
@@ -364,8 +368,9 @@ def test_the_reference_is_given_the_same_share_as_the_program(bundle):
 
 def test_a_rows_result_does_not_depend_on_who_shares_its_batch(bundle):
     _, _, model, variables, ids, _ = bundle
-    both = model.apply(variables, jnp.asarray(ids[:, :48]))[0]
-    alone = model.apply(variables, jnp.asarray(ids[1:, :48]))[0]
+    apply = jax.jit(lambda ids: model.apply(variables, ids)[0])
+    both, alone = apply(jnp.asarray(ids[:, :48])), apply(
+        jnp.asarray(ids[1:, :48]))
     np.testing.assert_allclose(np.asarray(alone[0]), np.asarray(both[1]),
                                atol=1e-6)
 

@@ -193,7 +193,8 @@ def test_one_token_is_the_step_and_a_masked_token_moves_nothing():
 # -- the module against the reference ---------------------------------------------
 
 def _no_cache(cfg, model, variables, ids):
-    return model.apply(variables, jnp.asarray(ids))[0]
+    return jax.jit(lambda ids: model.apply(variables, ids)[0])(
+        jnp.asarray(ids))
 
 
 def _dense_cache(cfg, model, variables, ids):
@@ -242,10 +243,11 @@ def _paged_cache(cfg, model, variables, ids):
     table[1, :6] = [5, 0, 11, 2, 13, 8]
     lens = [40, 56]
     logits = [None, None]
+    prefill = jax.jit(lambda ids, cache: model.apply(variables, ids,
+                                                     cache=cache))
     for r, n in enumerate(lens):
-        out, cache = model.apply(
-            variables, jnp.asarray(ids[r:r + 1, :n]),
-            cache=init_olmo_hybrid_cache(cfg, 1, 64))
+        out, cache = prefill(jnp.asarray(ids[r:r + 1, :n]),
+                             init_olmo_hybrid_cache(cfg, 1, 64))
         logits[r] = [out[0]]
         for pos in range(n):
             blk, off = table[r, pos // bs], pos % bs
@@ -354,8 +356,9 @@ def test_negative_eigenvalues_are_exercised_at_the_seeded_weights(bundle):
 
 def test_a_rows_result_does_not_depend_on_who_shares_its_batch(bundle):
     hf, cfg, model, variables, ids, _ = bundle
-    both = model.apply(variables, jnp.asarray(ids[:, :48]))[0]
-    alone = model.apply(variables, jnp.asarray(ids[1:, :48]))[0]
+    apply = jax.jit(lambda ids: model.apply(variables, ids)[0])
+    both, alone = apply(jnp.asarray(ids[:, :48])), apply(
+        jnp.asarray(ids[1:, :48]))
     np.testing.assert_allclose(np.asarray(both[1]), np.asarray(alone[0]),
                                atol=TOL)
 

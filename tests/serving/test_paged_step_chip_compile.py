@@ -844,3 +844,151 @@ def test_a_mimo_mid_chunk_hands_the_rings_on_in_their_own_buffers(
     private = 2 * (16384 + 256) * (768 + 512) * 2
     rings = 128 * (1536 + 1024) * 2
     assert stats.alias_size_in_bytes >= private + rings
+
+
+# -- the lfm2_moe family at its published widths (ISSUE 42) -------------------------
+
+@pytest.fixture(scope="module")
+def compiled_lfm2(one_chip, for_the_chip, monkeypatch_module):
+    """``get(which)``: the decode step (256 blocks a row), a MID chunk (the
+    tails go in and come out) or a FINAL chunk (it installs K/V into the
+    slot's blocks and the tails into the slot's row) of the benchmark's 64 x
+    4096 engine over ALL TEN layers of its cut at LFM2-24B-A2B's widths
+    (every expert held), compiled for the chip when first asked for: 8 K/V
+    heads of 64 on one axis of 512 in the two attention layers, the eight
+    convolutions' last two inputs a slot."""
+    from sparkdl_tpu.models.lfm2_moe import Lfm2MoeConfig, Lfm2MoeLMHeadModel
+    from sparkdl_tpu.parallel import moe_dropless
+
+    # the grouped product the CHIP runs (this process's backend is the CPU)
+    monkeypatch_module.setattr(moe_dropless, "auto_interpret", lambda: False)
+    full = Lfm2MoeConfig(dtype=jnp.bfloat16)
+    cfg = dataclasses.replace(full, layer_types=full.layer_types[:10])
+    variables = jax.eval_shape(
+        lambda: Lfm2MoeLMHeadModel(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    eng = ContinuousGPTEngine(cfg, variables, n_slots=64, max_len=4096,
+                              auto_start=False)
+    pool, mb = eng._pool_kv, 4096 // 16
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    private = {name: jax.ShapeDtypeStruct(
+        (2, 1, eng._wp) + pool[name].shape[3:], jnp.bfloat16,
+        sharding=one_chip) for name in ("k", "v")}
+    rec = {"conv": jax.ShapeDtypeStruct(
+        (8, 1) + pool["conv"].shape[2:], jnp.bfloat16, sharding=one_chip)}
+    head = (_on(one_chip, variables), _on(one_chip, pool))
+    lower = {
+        "step": lambda: eng._paged_step_fn.lower(
+            *head, ints(64, mb), ints(64), ints(64), ints(64), 1, 256),
+        "mid": lambda: eng._chunk_mid_fn.lower(
+            head[0], private["k"], private["v"], ints(), ints(1, 256), 4096,
+            ints(), rec),
+        "final": lambda: eng._chunk_final_fn.lower(
+            *head, private["k"], private["v"], ints(), ints(1, 256),
+            ints(mb), 4096, ints(), rec, ints()),
+    }
+    done = {}
+
+    def get(which):
+        if which not in done:
+            done[which] = lower[which]().compile()
+        return done[which]
+
+    get.pool = pool
+    try:
+        yield get
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("which", ["step", "mid", "final"])
+def test_neither_the_head_64_pool_nor_the_tails_are_held_twice(
+        compiled_lfm2, which):
+    """K and V ``bf16[2,16384,16,512]`` row-major and the tails
+    ``bf16[8,64,2,2048]`` go out in the buffers they came in, in every
+    program that writes them, and nothing the size of a pool is held beside
+    them."""
+    pool = compiled_lfm2.pool
+    assert {k: a.shape for k, a in pool.items()} == {
+        "k": (2, 16384, 16, 512), "v": (2, 16384, 16, 512),
+        "conv": (8, 64, 2, 2048)}
+    compiled = compiled_lfm2(which)
+    text, stats = compiled.as_text(), compiled.memory_analysis()
+    if which == "mid":
+        # the prompt's private cache and its running tails, not the pool
+        private = 2 * 2 * (4096 + 256) * 512 * 2
+        assert stats.alias_size_in_bytes >= private + 8 * 2 * 2048 * 2
+        return
+    for name in ("k", "v"):
+        made = _made(text, pool[name].shape)
+        ops = {op for op, _ in made}
+        assert ops & {"scatter", "dynamic-update-slice"}, (name, sorted(ops))
+        assert not ops & {"copy", "copy-start", "copy-done", "transpose"}, (
+            name, sorted(ops))
+        assert {order for _, order in made} == {(3, 2, 1, 0)}, name
+    assert stats.alias_size_in_bytes == sum(a.nbytes for a in pool.values())
+    # the tails (4.2 MB, all eight layers') are the one array the chip
+    # re-lays for the step: slots in the sublanes while it works on them
+    # (``{3,1,2,0}``), back to the stored order at the end, one copy each
+    # way in fast memory; a chunk's last program writes one slot's row
+    tails = _made(text, pool["conv"].shape)
+    copies = [order for op, order in tails if op == "copy"]
+    assert len(copies) <= 2, copies
+    # what is held beside them: the step's gathered rows of one layer at a
+    # time (64 x 4096 x 512 x 2 bytes = 0.27 GB for K and again for V: the
+    # head of 64 keeps the gathers, ``reads_in_place`` refuses it) and the
+    # scores; a chunk's scores and logits
+    assert stats.temp_size_in_bytes < {"step": 0.6e9, "final": 0.25e9}[which]
+
+
+def test_the_lfm2_step_gathers_its_rows_and_runs_the_grouped_matmul(
+        compiled_lfm2):
+    """What the cell will say of this path: no paged kernel in the step (a
+    value head of 64 is no whole lane tile), both attention layers gather
+    every slot's rows at the bucket's depth as stored; the experts' three
+    products a layer are the grouped matmul kernel, at an expert width of
+    1,536 that its tile of 1,024 columns does not divide."""
+    text = compiled_lfm2("step").as_text()
+    assert "paged_decode" not in text
+    rows = _made(text, (64, 256, 16, 512)) + _made(text, (64, 4096, 512))
+    assert rows and {order[0] for _, order in rows} == {
+        len(order) - 1 for _, order in rows}
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) == 8 * 3
+    # both forms of the convolution carry their scope in the compiled text
+    assert "short_conv_step" in text and "short_conv_chunk" not in text
+    assert "short_conv_chunk" in compiled_lfm2("mid").as_text()
+
+
+def test_the_benchmarks_reader_finds_the_convolutions_operations(
+        compiled_lfm2):
+    """``benchmark/readers_lfm2_moe.is_conv_op`` (imported as it stands) on
+    the instructions of the step's ENTRY computation, which are what a
+    device trace holds an event for: it takes the operations scoped
+    ``short_conv_step`` that touch a tail, the eight splits, and nothing of
+    the attention or the experts."""
+    from benchmark import readers_lfm2_moe
+
+    hf = {"hidden_size": 2048, "num_attention_heads": 32,
+          "num_key_value_heads": 8, "num_hidden_layers": 10,
+          "layer_types": ["conv", "conv"] + [
+              "full_attention", "conv", "conv", "conv"] * 2,
+          "num_dense_layers": 2, "conv_L_cache": 3,
+          "intermediate_size": 11776, "moe_intermediate_size": 1536,
+          "num_experts": 64, "num_experts_per_tok": 4, "vocab_size": 65536,
+          "rope_parameters": {"rope_theta": 1000000}}
+    text = compiled_lfm2("step").as_text()
+    entry = text[text.index("\nENTRY "):]
+    taken = [ln.strip() for ln in entry.splitlines()
+             if " = " in ln and readers_lfm2_moe.is_conv_op(
+                 ln.strip().split(", metadata=")[0], hf, 64)]
+    assert len(taken) >= 8 * 2
+    # the split comes out of each convolution's input projection as
+    # ``bf16[64,1,6144]``, once a layer
+    assert sum("6144]" in ln.split(" = ")[1].split(" ")[0]
+               and " fusion(" in ln for ln in taken) == 8
+    for ln in taken:
+        assert "gmm" not in ln.split(" = ")[0]
+        assert "self_attn" not in ln and "moe" not in ln, ln[:200]
