@@ -68,8 +68,9 @@ class ServingFamily:
     #: it, each row's own blocks and no more (``ops/paged_decode.py``, by
     #: its rule ``reads_in_place`` on :attr:`kv_tail` and :attr:`v_tail`:
     #: K's and V's heads each side by side on one unpadded axis of whole
-    #: lane tiles, a value head whole tiles; the two may differ in width
-    #: and a group of query heads may share each), where it would gather
+    #: lane tiles; the two may differ in width, a head of either may be
+    #: under a tile and a group of query heads may share each), where it
+    #: would gather
     #: every row's ``nb`` blocks: what the engine's count of a step's reads
     #: follows
     decode_reads_in_place: bool = False

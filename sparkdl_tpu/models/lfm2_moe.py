@@ -391,8 +391,9 @@ class Lfm2Attention(nn.Module):
             v_new = v[:, 0].reshape(b, ng * hd)
             if paged_decode.reads_in_place(tail, cache["v"].shape[3:], ng,
                                            hd):
-                # (a value head of whole lane tiles: not this family's 64,
-                # which the rule refuses)
+                # (8 heads of 64 on one unpadded axis of 512: whole lane
+                # tiles, which the rule takes; a tiny configuration's axis
+                # of 16 it does not)
                 ctx = paged_decode.paged_decode_attention(
                     q, cache["k"], cache["v"], self.kv_index,
                     cache["table"], idx, k_new[:, None], v_new[:, None])

@@ -16,18 +16,20 @@ result.
 Which pools it takes is :func:`reads_in_place`'s to say, a rule on shapes
 alone, asked with K's and V's trailing shapes: each array keeps a token's
 heads side by side on ONE unpadded axis whose width is a whole count of lane
-tiles, so that a block ``pool[layer, b]`` is one contiguous piece of bytes,
-and a VALUE head is a whole count of lane tiles (a head keeps its own ``Dv``
-columns of a row of the result: whole tiles of it). K and V need not be
-alike: a key head may be wider than a value head (MiMo-V2-Flash: keys of
-192 on an axis of 768 over values of 128 on one of 512), a key head need
-not be whole tiles, and a GROUP of query heads may share each K/V head (64
+tiles, so that a block ``pool[layer, b]`` is one contiguous piece of bytes.
+K and V need not be alike: a key head may be wider than a value head
+(MiMo-V2-Flash: keys of 192 on an axis of 768 over values of 128 on one of
+512), NEITHER head need be whole tiles (LFM2's 8 x 64 on an axis of 512,
+K and V alike), and a GROUP of query heads may share each K/V head (64
 over 4). A group's scores and weighted sum are TWO products over the merged
 axes, against the query laid block-diagonally OUTSIDE the kernel (row ``h``
 holds head ``h``'s query in the columns of its own K/V head ``g(h) = h //
 (H // G)`` and zeros elsewhere, so other heads add exact zeros; a boundary
 at 192 is a mask in ``jnp`` and never a slice of a tile in here), of which
-head ``h`` keeps K/V head ``g(h)``'s columns at the row's end. With as many
+head ``h`` keeps K/V head ``g(h)``'s columns at the row's end: a column
+slice a K/V head where a value head is whole tiles, and where it is not
+(a slice of 64 would cut a tile) a select by lane over whole rows of V's
+axis. With as many
 K/V heads as query heads and one head size (Olmo-Hybrid's 30 x 128) that
 is a diagonal of single rows: the special case, not a second path. The
 matrix unit's time is the loading of K's and V's tiles, the same whether
@@ -83,17 +85,18 @@ def reads_in_place(k_tail: "tuple[int, ...]", v_tail: "tuple[int, ...]",
     """Whether the paged decode attention reads a pool whose K and V have
     these trailing shapes in place (THE rule, asked by the module that calls
     the kernel and by the engine's count of what a step reads): each array's
-    ``kv_heads`` heads side by side on one axis with no pad, that axis a
-    whole count of lane tiles, and a value head a whole count of lane tiles.
-    Olmo-Hybrid's 30 x 128 is, and MiMo-V2-Flash's 4 x 192 over 4 x 128;
-    GPT-2 XL's 25 x 64 padded to 1664, a per-head pool ``(4, 128)`` and a
-    value head of 64 are not, and keep ``layer_rows`` and their own
-    attention. How many query heads share a K/V head is not the rule's."""
+    ``kv_heads`` heads side by side on one axis with no pad, and that axis
+    a whole count of lane tiles. Olmo-Hybrid's 30 x 128 is, MiMo-V2-Flash's
+    4 x 192 over 4 x 128, and LFM2's 8 x 64 on 512 (a head of either array
+    may be under a tile if its axis is whole tiles); GPT-2 XL's 25 x 64
+    padded to 1664, a per-head pool ``(4, 128)`` and an axis of 32 are not,
+    and keep ``layer_rows`` and their own attention. How many query heads
+    share a K/V head is not the rule's."""
     v_head_dim = head_dim if v_head_dim is None else v_head_dim
     return (tuple(k_tail) == (kv_heads * head_dim,)
             and tuple(v_tail) == (kv_heads * v_head_dim,)
             and k_tail[0] % _LANE_TILE == 0
-            and v_head_dim % _LANE_TILE == 0)
+            and v_tail[0] % _LANE_TILE == 0)
 
 
 def _group_blocks(nb: int, block_bytes: int) -> int:
@@ -194,9 +197,21 @@ def _kernel(table_ref, depth_ref, qbd_ref, k_hbm, v_hbm,
     # the query heads of K/V head g keep g's columns of their rows of the
     # merged accumulator: the diagonal blocks, side by side
     share = heads // kv_heads
-    for g in range(kv_heads):
-        cols = slice(g * v_head_dim, (g + 1) * v_head_dim)
-        o_ref[0, :, cols] = acc_scr[g * share:(g + 1) * share, cols]
+    if v_head_dim % _LANE_TILE == 0:
+        for g in range(kv_heads):
+            cols = slice(g * v_head_dim, (g + 1) * v_head_dim)
+            o_ref[0, :, cols] = acc_scr[g * share:(g + 1) * share, cols]
+    else:
+        # a value head under a lane tile (8 x 64 on an axis of 512): a
+        # column slice would cut a tile, so the diagonal is taken by whole
+        # rows of the axis: from K/V head g's first lane on, g's rows take
+        # the place of the heads' before it
+        lane = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape[1:], 1)
+        out = acc_scr[:share]
+        for g in range(1, kv_heads):
+            out = jnp.where(lane >= g * v_head_dim,
+                            acc_scr[g * share:(g + 1) * share], out)
+        o_ref[0] = out
     m_ref[0] = m_scr[:heads]
     l_ref[0] = l_scr[:heads]
 

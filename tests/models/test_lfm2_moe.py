@@ -247,10 +247,11 @@ def test_what_a_convolution_keeps_does_not_grow_with_the_context():
     assert fam.state_arrays == (("conv", (2, 2048), jnp.bfloat16),)
     assert (fam.tail_columns, fam.ring_columns) == (2, 0)
     assert fam.state_bytes_per_slot == 30 * 2 * 2048 * 2
-    # 8 K/V heads of 64: no whole lane tile a head, so one merged axis, which
-    # the paged kernel's rule refuses for its value heads of 64
+    # 8 K/V heads of 64: no whole lane tile a head, so one merged axis, and
+    # that axis of 512 is whole tiles with no pad: the paged kernel's rule
+    # takes it (a value head under a tile is taken by lane at a row's end)
     assert fam.kv_tail == fam.v_tail == (512,)
-    assert fam.decode_reads_in_place is False and fam.paged_only
+    assert fam.decode_reads_in_place is True and fam.paged_only
     assert (fam.expert_layers, fam.experts, fam.experts_per_token) == (
         38, 64, 4)
     cut = dataclasses.replace(cfg, layer_types=cfg.layer_types[:10])

@@ -6,7 +6,10 @@ blocks (the kernel derives a group from its shapes and a byte count: the
 count is made small here, so that a table of eight blocks is four groups).
 Since ISSUE 37 a GROUP of query heads may share each K/V head and a key head
 may differ from a value head in size: those shapes beside a plain softmax a
-head (:func:`_plain`), which knows nothing of merged axes."""
+head (:func:`_plain`), which knows nothing of merged axes. Since ISSUE 43 a
+VALUE head may be under a lane tile if V's axis is whole tiles (LFM2's 8 x 64
+on 512): the row's end is then a select by lane, beside the same plain
+softmax."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -143,6 +146,22 @@ def test_stored_bfloat16_keeps_the_merged_axis_attentions_precisions(
     np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
 
 
+def _beside_the_plain_softmax(monkeypatch, shape, depths, free=(),
+                              dtype=jnp.float32, tol=1e-5):
+    """The kernel at ``shape`` over eight blocks a row in groups of two,
+    layer 1, against :func:`_plain`."""
+    heads, kv_heads, dk, dv = shape
+    _small_groups(monkeypatch, kv_heads, dk)
+    case = _case(depths, 8, free=free, dtype=dtype, shape=shape)
+    pool, table, idx, q, k_new, v_new = case
+    got = paged_decode.paged_decode_attention(
+        q, pool["k"], pool["v"], 1, table, idx, k_new, v_new)
+    assert got.shape == (ROWS, 1, heads * dv) and got.dtype == dtype
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32).reshape(ROWS, heads, dv),
+        _plain(1, *case), rtol=tol, atol=tol)
+
+
 @pytest.mark.parametrize("depths, free", [
     pytest.param((127, 3, 64, 17), (), id="rows of very unequal depth"),
     pytest.param((0, 5, 0, 100), (),
@@ -157,16 +176,38 @@ def test_grouped_query_heads_over_keys_and_values_of_unequal_width(
         monkeypatch, shape, depths, free):
     """The kernel against a plain softmax a head: each of a K/V head's
     query heads keeps that head's columns of its own row and no other's."""
-    heads, kv_heads, dk, dv = shape
-    _small_groups(monkeypatch, kv_heads, dk)
-    case = _case(depths, 8, free=free, shape=shape)
-    pool, table, idx, q, k_new, v_new = case
-    got = paged_decode.paged_decode_attention(
-        q, pool["k"], pool["v"], 1, table, idx, k_new, v_new)
-    assert got.shape == (ROWS, 1, heads * dv)
-    np.testing.assert_allclose(
-        np.asarray(got).reshape(ROWS, heads, dv), _plain(1, *case),
-        rtol=1e-5, atol=1e-5)
+    _beside_the_plain_softmax(monkeypatch, shape, depths, free=free)
+
+
+#: value heads of half a lane tile: the row's end takes the diagonal by lane
+HALF_TILE = [
+    pytest.param((32, 8, 64, 64),
+                 id="LFM2's: 32 query heads over 8 K/V heads of 64 on 512"),
+    pytest.param((16, 4, 192, 64),
+                 id="4 a K/V head, keys of 192 over values of 64 on 256"),
+]
+
+
+@pytest.mark.parametrize("dtype, tol", [
+    pytest.param(jnp.float32, 1e-5, id="float32"),
+    pytest.param(jnp.bfloat16, 2e-2, id="bfloat16"),
+])
+@pytest.mark.parametrize("depths", [
+    pytest.param((0, 1, 9, 16), id="depth 0, one column, part of a block, "
+                                   "exactly a block"),
+    pytest.param((33, 64, 100, 127), id="more than one group, part and "
+                                        "whole"),
+])
+@pytest.mark.parametrize("shape", HALF_TILE)
+def test_value_heads_under_a_lane_tile_keep_their_own_columns_by_lane(
+        monkeypatch, shape, depths, dtype, tol):
+    """A value head of 64 on an axis of whole tiles: a column slice a K/V
+    head would cut a tile, so each K/V head's query heads' rows are taken
+    where the lane is one of that head's. Against the plain softmax a head,
+    with the limits the 128-wide cases are held to (stored bfloat16: the
+    weights are cast before the product with V, a bfloat16 step)."""
+    _beside_the_plain_softmax(monkeypatch, shape, depths, dtype=dtype,
+                              tol=tol)
 
 
 def test_the_grouped_kernel_is_the_gather_and_mimos_merged_attention(
@@ -207,10 +248,12 @@ def test_a_pool_with_another_dtype_or_shape_raises():
         paged_decode.paged_decode_attention(
             q, pool["k"].astype(jnp.int8), pool["v"].astype(jnp.int8), 0,
             table, idx, k_new, v_new)
+    # two heads of 96: an axis of 192, a tile and a half (heads of 64 on
+    # this pool's axis of 256 are taken since ISSUE 43)
     with pytest.raises(ValueError, match="whole lane tiles"):
         paged_decode.paged_decode_attention(
-            q.reshape(ROWS, 1, 2 * HEADS, HEAD // 2), pool["k"], pool["v"],
-            0, table, idx, k_new, v_new)
+            q[..., :96], pool["k"][..., :192], pool["v"][..., :192],
+            0, table, idx, k_new[..., :192], v_new[..., :192])
 
 
 @pytest.mark.parametrize("which", ["k", "v"])
@@ -239,6 +282,10 @@ def test_query_heads_that_no_count_of_kv_heads_divides_raise():
     pytest.param(4, 128, False, id="Trinity: a per-head pool (4, 128)"),
     pytest.param(32, 128, False, id="heads that fill sublane tiles"),
     pytest.param(4, 16, False, id="a tiny configuration"),
+    pytest.param(8, 64, True,
+                 id="LFM2: 8 x 64 on one unpadded axis of 512, whole tiles"),
+    pytest.param(2, 64, True, id="2 x 64: an axis of one tile"),
+    pytest.param(9, 64, False, id="9 x 64 padded to 640"),
 ])
 def test_the_rule_by_which_a_pool_is_read_in_place(heads, head, taken):
     tail = kv_tail(heads, head)
@@ -255,7 +302,10 @@ def test_the_rule_by_which_a_pool_is_read_in_place(heads, head, taken):
     pytest.param(3, 128, 256, True, id="values wider than keys"),
     pytest.param(4, 128, 256, False,
                  id="4 heads of whole tiles each keep their own axis"),
-    pytest.param(4, 192, 64, False, id="a value head of 64"),
+    pytest.param(4, 192, 64, True,
+                 id="a value head of 64: V's axis of 256 is whole tiles"),
+    pytest.param(2, 192, 32, False,
+                 id="V's 2 x 32 padded to an axis of 128"),
     pytest.param(2, 96, 128, False,
                  id="K's axis of 192 is no whole count of lane tiles"),
     pytest.param(2, 24, 16, False, id="the tiny mimo_v2_flash"),
@@ -263,8 +313,8 @@ def test_the_rule_by_which_a_pool_is_read_in_place(heads, head, taken):
 def test_the_rule_takes_keys_and_values_of_unequal_width(kv_heads, dk, dv,
                                                          taken):
     """K's and V's tails as ``kv_pool.kv_tails`` lays such a family's pool
-    out: each on one axis; the value head decides, the key head need not
-    be whole tiles if its axis is."""
+    out: each on one axis; each AXIS decides, neither head need be whole
+    tiles if its axis is."""
     k_tail, v_tail = kv_tails(kv_heads, dk, dv)
     assert paged_decode.reads_in_place(
         k_tail, v_tail, kv_heads, dk, dv) is taken
@@ -275,9 +325,12 @@ def test_the_rule_takes_keys_and_values_of_unequal_width(kv_heads, dk, dv,
     pytest.param((4, 128), (4, 128), id="a per-head tail"),
     pytest.param((768,), (4, 128), id="V alone keeps its heads apart"),
     pytest.param((768,), (640,), id="V's axis padded"),
+    pytest.param((640,), (640,), id="8 x 64 padded to 640"),
+    pytest.param((48,), (32,), id="2 x 24 over 2 x 16: an axis of 32"),
 ])
 def test_tails_the_rule_refuses_whatever_the_heads(k_tail, v_tail):
-    for kv_heads, dk, dv in ((25, 64, 64), (4, 128, 128), (4, 192, 128)):
+    for kv_heads, dk, dv in ((25, 64, 64), (4, 128, 128), (4, 192, 128),
+                             (8, 64, 64), (2, 24, 16)):
         assert not paged_decode.reads_in_place(k_tail, v_tail, kv_heads,
                                                dk, dv)
 

@@ -178,7 +178,8 @@ def test_capacity_counts_the_full_layers_and_says_the_rings_bytes(
 @pytest.mark.parametrize("widths, taken", [
     pytest.param({}, True,
                  id="published: 4 x 192 on 768 over 4 x 128 on 512"),
-    pytest.param({"v_head_dim": 64}, False, id="a value head of 64"),
+    pytest.param({"v_head_dim": 64}, True,
+                 id="a value head of 64: V's axis of 256 is whole tiles"),
     pytest.param({"num_kv_heads": 1}, False,
                  id="one K/V head: K's axis of 192 is no whole tile"),
     pytest.param({"head_dim": 128, "num_kv_heads": 8}, False,

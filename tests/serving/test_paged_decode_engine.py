@@ -1,8 +1,10 @@
 """``ContinuousGPTEngine`` over a family whose one-token step reads K and V
 in the pool (``ops/paged_decode.py``, ISSUE 35): a tiny ``olmo_hybrid``
-configuration with three heads of 128 and (ISSUE 37) a tiny ``mimo_v2_flash``
+configuration with three heads of 128, (ISSUE 37) a tiny ``mimo_v2_flash``
 with four query heads over two K/V heads, keys of 64 on an axis of 128 under
-values of 128 on one of 256, so that the rule by which the kernel
+values of 128 on one of 256, and (ISSUE 43) a tiny ``lfm2_moe`` with eight
+query heads over two K/V heads of 64, K and V each on an axis of 128 (a value
+head of half a lane tile), so that the rule by which the kernel
 is taken holds on the CPU (under the Pallas interpreter). Greedy tokens are
 those of the same engine with the rule forced false (the gather and the
 merged-axis attention, what every configuration ran before); the step's
@@ -20,6 +22,7 @@ import pytest
 from sparkdl_tpu.models import afmoe, mimo_v2_flash, olmo_hybrid
 from sparkdl_tpu.models.afmoe import AfmoeConfig, AfmoeLMHeadModel
 from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel
+from sparkdl_tpu.models.lfm2_moe import Lfm2MoeConfig, Lfm2MoeLMHeadModel
 from sparkdl_tpu.models.mimo_v2_flash import (
     MimoV2FlashConfig,
     MimoV2FlashLMHeadModel,
@@ -89,8 +92,14 @@ def _mimo():
         moe_layer_freq=(0, 1, 1)), MimoV2FlashLMHeadModel
 
 
-@pytest.fixture(scope="module", params=[_olmo, _mimo],
-                ids=["olmo_hybrid", "mimo_v2_flash"])
+def _lfm2():
+    # the tiny pattern (a dense convolution, an attention layer, three
+    # expert convolutions) with heads of 64: an axis of 2 x 64 = 128
+    return Lfm2MoeConfig.tiny(head_dim=64), Lfm2MoeLMHeadModel
+
+
+@pytest.fixture(scope="module", params=[_olmo, _mimo, _lfm2],
+                ids=["olmo_hybrid", "mimo_v2_flash", "lfm2_moe"])
 def hybrid(request):
     cfg, model = request.param()
     assert cfg.serving_family().decode_reads_in_place
@@ -159,6 +168,8 @@ def test_kv_cols_read_is_each_riding_rows_depth_rounded_up_to_its_blocks(
     pytest.param(GPTConfig.tiny(), GPTLMHeadModel, id="gpt"),
     pytest.param(AfmoeConfig.tiny(layer_types=(afmoe.SLIDING, afmoe.FULL)),
                  AfmoeLMHeadModel, id="afmoe"),
+    # 2 K/V heads of 8: an axis of 16, padded to a lane tile
+    pytest.param(Lfm2MoeConfig.tiny(), Lfm2MoeLMHeadModel, id="lfm2_moe"),
 ])
 def test_a_family_the_rule_leaves_alone_counts_what_it_counted(cfg, model):
     fam = cfg.serving_family()

@@ -858,10 +858,13 @@ def compiled_lfm2(one_chip, for_the_chip, monkeypatch_module):
     heads of 64 on one axis of 512 in the two attention layers, the eight
     convolutions' last two inputs a slot."""
     from sparkdl_tpu.models.lfm2_moe import Lfm2MoeConfig, Lfm2MoeLMHeadModel
+    from sparkdl_tpu.ops import paged_decode
     from sparkdl_tpu.parallel import moe_dropless
 
-    # the grouped product the CHIP runs (this process's backend is the CPU)
+    # the grouped product and the paged attention the CHIP runs (this
+    # process's backend is the CPU)
     monkeypatch_module.setattr(moe_dropless, "auto_interpret", lambda: False)
+    monkeypatch_module.setattr(paged_decode, "auto_interpret", lambda: False)
     full = Lfm2MoeConfig(dtype=jnp.bfloat16)
     cfg = dataclasses.replace(full, layer_types=full.layer_types[:10])
     variables = jax.eval_shape(
@@ -937,25 +940,40 @@ def test_neither_the_head_64_pool_nor_the_tails_are_held_twice(
     tails = _made(text, pool["conv"].shape)
     copies = [order for op, order in tails if op == "copy"]
     assert len(copies) <= 2, copies
-    # what is held beside them: the step's gathered rows of one layer at a
-    # time (64 x 4096 x 512 x 2 bytes = 0.27 GB for K and again for V: the
-    # head of 64 keeps the gathers, ``reads_in_place`` refuses it) and the
-    # scores; a chunk's scores and logits
-    assert stats.temp_size_in_bytes < {"step": 0.6e9, "final": 0.25e9}[which]
+    # what is held beside them: a step's logits and its experts' rows (the
+    # paged kernel reads K and V in the pool since ISSUE 43: the gathered
+    # rows of a layer, 64 x 4096 x 512 x 2 bytes = 0.27 GB for K and again
+    # for V, are gone); a chunk's scores and logits
+    assert stats.temp_size_in_bytes < {"step": 0.06e9, "final": 0.25e9}[which]
 
 
-def test_the_lfm2_step_gathers_its_rows_and_runs_the_grouped_matmul(
+def test_the_lfm2_step_reads_k_and_v_in_the_pool_and_runs_the_grouped_matmul(
         compiled_lfm2):
-    """What the cell will say of this path: no paged kernel in the step (a
-    value head of 64 is no whole lane tile), both attention layers gather
-    every slot's rows at the bucket's depth as stored; the experts' three
-    products a layer are the grouped matmul kernel, at an expert width of
-    1,536 that its tile of 1,024 columns does not divide."""
+    """What the cell will say of this path: 8 K/V heads of 64 on one axis of
+    512 meet the rule since ISSUE 43 (V's AXIS is whole lane tiles, a value
+    head need not be), so each attention layer's one-token attention is the
+    paged kernel, handed the pool's own buffers, and no slot's rows are
+    gathered at the bucket's depth; the experts' three products a layer are
+    the grouped matmul kernel, at an expert width of 1,536 that its tile of
+    1,024 columns does not divide."""
     text = compiled_lfm2("step").as_text()
-    assert "paged_decode" not in text
-    rows = _made(text, (64, 256, 16, 512)) + _made(text, (64, 4096, 512))
-    assert rows and {order[0] for _, order in rows} == {
-        len(order) - 1 for _, order in rows}
+    # one call an attention layer: 4 query heads' 64 columns of each of 8
+    # K/V heads side by side on a row of 512, the running maximum and sum a
+    # head
+    calls = re.findall(
+        r"%paged_decode[.\d]* = \(f32\[64,4,512\]\S*, f32\[64,32,1\]\S*, "
+        r"f32\[64,32,1\]\S*\) custom-call\(([^)]*)\), "
+        r"custom_call_target=\"tpu_custom_call\"", text)
+    assert len(calls) == 2
+    for call in calls:
+        for name in [a.strip() for a in call.split(",")][-2:]:
+            assert re.search(
+                r"%s = bf16\[2,16384,16,512\]\{3,2,1,0:T\(8,128\)\(2,1\)\} "
+                r"parameter\(" % re.escape(name), text), name
+    # what the step gathered until then, 64 rows x 256 blocks a layer (0.27
+    # GB for K and again for V), is made in no spelling
+    for rows in ((64, 256, 16, 512), (64, 4096, 512), (64 * 256, 16, 512)):
+        assert _made(text, rows) == [], rows
     assert len(re.findall(r"%gmm[.\d]* = ", text)) == 8 * 3
     # both forms of the convolution carry their scope in the compiled text
     assert "short_conv_step" in text and "short_conv_chunk" not in text
