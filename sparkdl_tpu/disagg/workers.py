@@ -69,6 +69,12 @@ def _refuse_state(engine: ContinuousGPTEngine, what: str) -> None:
             f": {fam.state_layers} of its layers keep arrays by slot (a "
             "recurrent state, or a window's last columns), which a handoff "
             "of K/V blocks does not carry")
+    if fam.block_arrays:
+        raise NotImplementedError(
+            f"{what} is not implemented for {type(engine.config).__name__}"
+            ": what it keeps a token is "
+            f"{' and '.join(name for name, _, _ in fam.block_arrays)}, each "
+            "over its own layers, and a handoff's payload is K and V")
 
 
 def _require_paged(kwargs: dict, who: str) -> None:

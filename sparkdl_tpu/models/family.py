@@ -7,7 +7,8 @@ shaped, is the family's to say. A configuration answers
 nothing else off the configuration. ``GPTConfig`` answers with its own
 fields (so GPT-2's programs are what they were); a new family answers from
 its own module (``models/afmoe.py``, ``models/olmo_hybrid.py``,
-``models/mimo_v2_flash.py``, ``models/lfm2_moe.py``).
+``models/mimo_v2_flash.py``, ``models/lfm2_moe.py``,
+``models/glm_moe_dsa.py``).
 
 The module's contract is :class:`~sparkdl_tpu.models.gpt.GPTLMHeadModel`'s:
 ``module.apply(variables, ids, cache=None | dense | paged, positions=...)``
@@ -25,6 +26,14 @@ the last ``window`` columns of a window layer's K and V kept as a ring
 last few inputs of a short convolution and nothing else
 (``models/lfm2_moe.py``, :attr:`ServingFamily.tail_columns`). The pool
 holds them by slot beside its blocks and the module hands them back whole.
+
+What a token keeps BY BLOCK need not be a K and a V: a family names its own
+two arrays (:attr:`ServingFamily.block_arrays`; ``models/glm_moe_dsa.py``
+keeps ``latent``, one compressed column a token in every layer, and
+``index_k``, an indexer's key in some of them: each with its own count of
+layers and its own trailing axes). The engine's programs carry the pair in
+that order wherever they carried ``k`` and ``v``, and the module's cached
+calls take and hand back the arrays under those names.
 """
 
 from __future__ import annotations
@@ -98,6 +107,24 @@ class ServingFamily:
     #: is a short convolution that keeps its last ``tail_columns`` inputs a
     #: slot, in the compute dtype, and nothing else (0: they are no tail)
     tail_columns: int = 0
+    #: the two arrays a token keeps BY BLOCK where they are not ``k`` and
+    #: ``v`` over :attr:`pool_layers`: ``(name, layers, trailing axes)``
+    #: each, the pool's ``name`` being ``[layers, blocks, block_size,
+    #: *axes]`` in the compute dtype (no compressed storage). Empty: ``k``
+    #: and ``v``, shaped by the heads above
+    block_arrays: "tuple[tuple[str, int, tuple[int, ...]], ...]" = ()
+    #: the module's one-token step attends over at most this many columns a
+    #: row a layer, which it selects itself and reads one by one (a learned
+    #: sparse attention), whatever the row's depth (0: over every column)
+    selected_columns: int = 0
+
+    @property
+    def pool_arrays(self) -> "tuple[tuple[str, int, tuple[int, ...]], ...]":
+        """``(name, layers, trailing axes)`` of the two arrays the pool
+        keeps by block, in the order the programs carry them."""
+        return self.block_arrays or (
+            ("k", self.pool_layers, self.kv_tail),
+            ("v", self.pool_layers, self.v_tail))
 
     @property
     def pool_layers(self) -> int:

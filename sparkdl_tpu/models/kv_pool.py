@@ -25,15 +25,26 @@ them; the reads below gather from it, the write loops carry it alone and
 hand the slot arrays back as they came. ``layers`` of ``k`` and ``v`` are
 the layers that keep K/V, which for such a family are not all of them.
 
+A family may also NAME the two arrays it keeps by block
+(``ServingFamily.block_arrays``: ``models/glm_moe_dsa.py`` keeps ``latent``
+in every layer and ``index_k`` in some, where another keeps ``k`` and ``v``
+over the same layers): each array then has its own count of layers and its
+own trailing axes, and everything below takes an array's layers, like its
+tail, off that array. The names are the family's alone: the functions here
+that must tell the arrays by block from those by slot take them as
+``names`` (:data:`KV` where a caller gives none), in the order the programs
+carry the pair (``PagedSizes.arrays``, which the engine fills from
+``ServingFamily.pool_arrays``).
+
 Every model family (``models/gpt.py``, ``models/afmoe.py``,
 ``models/olmo_hybrid.py``, ``models/mimo_v2_flash.py``) reads a layer's
 rows through :func:`layer_rows`; the serving engine's programs
 (``serving/paged_programs.py``) read and write whole blocks and columns
 through the functions below it. Pure functions of a pool and indices: what
 the engine's closures once took from their enclosing scope is read off the
-pool itself (``layers`` and ``blocks`` are ``pool["k"].shape[:2]``, an
-array's tail is its own ``shape[3:]``, an int8 pool is the one that has
-``k_scale``).
+pool itself (an array's layers and tail are its own ``shape[0]`` and
+``shape[3:]``, the blocks are :func:`n_blocks`, an int8 array is one that
+has a ``<name>_scale`` beside it).
 Nothing here imports the rest of the package; the host's bookkeeping (free
 list, refcounts, tables) is :mod:`~sparkdl_tpu.serving.kv_blocks`'s.
 """
@@ -137,7 +148,9 @@ def init_block_pool(config, n_blocks: int,
     :func:`kv_tails` of the family's heads (``config.serving_family()``; V's
     are its own where a value head is not a key head's size), so
     that the chip keeps layers and blocks major and a block's bytes
-    together (GPT-2 XL: ``{3,2,1,0:T(8,128)(2,1)}``).
+    together (GPT-2 XL: ``{3,2,1,0:T(8,128)(2,1)}``). A family that names
+    its own two arrays (``ServingFamily.block_arrays``) gets those, each
+    over its own layers.
 
     Unlike ``models.gpt.init_cache`` (one dense row per batch slot), the
     pool's capacity is ``n_blocks x block_size`` TOKENS shared by every
@@ -161,20 +174,17 @@ def init_block_pool(config, n_blocks: int,
     required): never compressed, never indexed by block.
     """
     fam = config.serving_family()
-    shape = (fam.pool_layers, n_blocks, block_size) + fam.kv_tail
-    v_shape = shape[:3] + fam.v_tail
     store = {"fp32": fam.dtype, "bf16": jnp.bfloat16,
              "int8": jnp.int8}.get(dtype)
     if store is None:
         raise ValueError(
             f"unknown KV pool dtype {dtype!r} ({' | '.join(KV_DTYPES)})")
-    pool = {
-        "k": jnp.zeros(shape, store),
-        "v": jnp.zeros(v_shape, store),
-    }
-    if dtype == "int8":
-        pool["k_scale"] = jnp.zeros(shape[:3], jnp.float32)
-        pool["v_scale"] = jnp.zeros(shape[:3], jnp.float32)
+    pool = {}
+    for name, layers, tail in fam.pool_arrays:
+        pool[name] = jnp.zeros((layers, n_blocks, block_size) + tail, store)
+        if dtype == "int8":
+            pool[name + "_scale"] = jnp.zeros(
+                (layers, n_blocks, block_size), jnp.float32)
     if fam.state_layers:
         if n_slots is None:
             raise ValueError(
@@ -185,20 +195,30 @@ def init_block_pool(config, n_blocks: int,
     return pool
 
 
-_BLOCK_ARRAYS = ("k", "v", "k_scale", "v_scale")
+#: the pair of arrays a token keeps BY BLOCK where the family names none
+#: (``ServingFamily.block_arrays``)
+KV = ("k", "v")
 
 
-def block_arrays(pool: dict) -> dict:
-    """The pool's arrays that are indexed by BLOCK (``k``, ``v`` and their
+def n_blocks(pool: dict, names: "tuple[str, ...]" = KV) -> int:
+    """Blocks of the pool, which is also its sentinel block id."""
+    return pool[names[0]].shape[1]
+
+
+def block_arrays(pool: dict, names: "tuple[str, ...]" = KV) -> dict:
+    """The pool's arrays that are indexed by BLOCK (``names`` and their
     scales): all of it but a recurrent family's arrays by slot, which have
     another second axis and which no block-wise read or write touches."""
-    return {name: a for name, a in pool.items() if name in _BLOCK_ARRAYS}
+    by_block = {n for name in names for n in (name, name + "_scale")}
+    return {name: a for name, a in pool.items() if name in by_block}
 
 
-def slot_arrays(pool: dict) -> dict:
+def slot_arrays(pool: dict, names: "tuple[str, ...]" = KV) -> dict:
     """The pool's arrays indexed by SLOT: a recurrent state, or a window
-    layer's ring of columns."""
-    return {name: a for name, a in pool.items() if name not in _BLOCK_ARRAYS}
+    layer's ring of columns (whatever is neither of ``names`` nor a scale
+    of theirs)."""
+    by_block = block_arrays(pool, names)
+    return {name: a for name, a in pool.items() if name not in by_block}
 
 
 def install_slot(pool: dict, slot: jax.Array, rows: dict) -> dict:
@@ -255,11 +275,13 @@ def stored_as(pool: dict, name: str, vals: jax.Array) -> dict:
 
 # -- the one read through a table ---------------------------------------------
 
-def layer_rows(cache: dict, layer: int, table: jax.Array,
-               dtype: Any) -> "tuple[jax.Array, jax.Array]":
-    """One layer's K and V of a pool as per-slot rows ``[S, entries *
-    block_size, *tail]``, in the shape they are stored in, through the
-    table entries ``table`` ``[S, entries]`` (the live head of the block
+def layer_rows(cache: dict, layer: int, table: jax.Array, dtype: Any,
+               names: "tuple[str, ...]" = ("k", "v")
+               ) -> "tuple[jax.Array, ...]":
+    """One layer's K and V of a pool (or the arrays ``names``, ``layer``
+    being the row among each one's own layers) as per-slot rows ``[S,
+    entries * block_size, *tail]``, in the shape they are stored in, through
+    the table entries ``table`` ``[S, entries]`` (the live head of the block
     table, or a window layer's sub-table).
 
     ``cache`` holds the pool's arrays by name (a family's paged cache is
@@ -274,7 +296,7 @@ def layer_rows(cache: dict, layer: int, table: jax.Array,
     caller's masks hide.
     """
     at = (jnp.full_like(table, layer),
-          jnp.minimum(table, cache["k"].shape[1] - 1))
+          jnp.minimum(table, cache[names[0]].shape[1] - 1))
 
     def rows(name):
         x = cache[name][at]
@@ -284,42 +306,48 @@ def layer_rows(cache: dict, layer: int, table: jax.Array,
         return x.reshape(table.shape[0], table.shape[1] * x.shape[2],
                          *x.shape[3:])
 
-    return rows("k"), rows("v")
+    return tuple(rows(name) for name in names)
 
 
 # -- whole blocks, read and written -------------------------------------------
 
-def gather_blocks(pool: dict, ids: jax.Array) -> dict:
+def gather_blocks(pool: dict, ids: jax.Array,
+                  names: "tuple[str, ...]" = KV) -> dict:
     """Every array's blocks ``ids`` in storage dtype, ``[layers, len(ids),
     block, ...]``: ONE gather over (layer, block), read where the pool lies
     (sliced by layer first, the compiler copies the pool to another layout:
     1.4 GB of temporaries in a one-chunk prefill at 2.7 GB). A sentinel id
     clips to the last block."""
-    layers, blocks = pool["k"].shape[:2]
-    at = (jnp.arange(layers)[:, None],
-          jnp.minimum(ids, blocks - 1)[None, :])
-    return {name: a[at] for name, a in block_arrays(pool).items()}
+    # (one index a count of layers: a pool whose arrays all span the same
+    # layers lowers to the text it lowered to)
+    arrays = block_arrays(pool, names)
+    layers = {n: jnp.arange(n)[:, None] for n in dict.fromkeys(
+        a.shape[0] for a in arrays.values())}
+    blk = jnp.minimum(ids, n_blocks(pool, names) - 1)[None, :]
+    return {name: a[layers[a.shape[0]], blk] for name, a in arrays.items()}
 
 
-def gather_blocks_as(pool: dict, ids: jax.Array,
-                     dtype: Any) -> "tuple[jax.Array, jax.Array]":
-    """Blocks ``ids`` of K and V -> the compute dtype ``dtype``."""
-    raw = gather_blocks(pool, ids)
-    if "k_scale" in pool:
-        return tuple(
-            dequantize_kv(raw[name], raw[name + "_scale"], dtype)
-            for name in ("k", "v"))
-    return raw["k"].astype(dtype), raw["v"].astype(dtype)
+def gather_blocks_as(pool: dict, ids: jax.Array, dtype: Any,
+                     names: "tuple[str, ...]" = KV
+                     ) -> "tuple[jax.Array, jax.Array]":
+    """Blocks ``ids`` of K and V (of ``names``, in their order) -> the
+    compute dtype ``dtype``."""
+    raw = gather_blocks(pool, ids, names)
+    return tuple(
+        dequantize_kv(raw[name], raw[name + "_scale"], dtype)
+        if name + "_scale" in pool else raw[name].astype(dtype)
+        for name in names)
 
 
 def write_blocks(pool: dict, ids: jax.Array, vals: dict) -> dict:
     """Whole blocks ``vals`` (by array name, storage dtype, ``[layers,
     len(ids), block, ...]``) into the DONATED pool, one at a time and in
-    place. As ONE scatter the compiler re-lays the pool out and back (two
-    copies of each of K and V an install: 16 ms and 1.4 GB of temporaries
-    at 2.7 GB). A sentinel id rewrites what is there — no block
-    corrupted."""
-    blocks = pool["k"].shape[1]
+    place; ``vals`` names every array the pool keeps by block (the loop
+    carries those and nothing by slot). As ONE scatter the compiler re-lays
+    the pool out and back (two copies of each of K and V an install: 16 ms
+    and 1.4 GB of temporaries at 2.7 GB). A sentinel id rewrites what is
+    there — no block corrupted."""
+    blocks = n_blocks(pool, tuple(vals))
     live = ids < blocks
     blk = jnp.minimum(ids, blocks - 1)
 
@@ -333,40 +361,51 @@ def write_blocks(pool: dict, ids: jax.Array, vals: dict) -> dict:
                 pool[name], jnp.where(live[i], new, old), at)
         return out
 
-    return {**lax.fori_loop(0, ids.shape[0], body, block_arrays(pool)),
-            **slot_arrays(pool)}
+    return {**pool, **lax.fori_loop(
+        0, ids.shape[0], body, {name: pool[name] for name in vals})}
+
+
+def _stored(pool: dict, names: "tuple[str, ...]",
+            new: "tuple[jax.Array, ...]") -> dict:
+    """Values of the arrays ``names``, in their order, as the pool stores
+    them, by array name (:func:`stored_as`)."""
+    out = {}
+    for name, vals in zip(names, new, strict=True):
+        out.update(stored_as(pool, name, vals))
+    return out
 
 
 def write_kv_blocks(pool: dict, ids: jax.Array, newk: jax.Array,
-                    newv: jax.Array) -> dict:
-    """Whole blocks of K and V at the compute dtype into the pool (the
-    prefill install, the sp and disagg handoffs)."""
-    return write_blocks(pool, ids, {
-        **stored_as(pool, "k", newk),
-        **stored_as(pool, "v", newv)})
+                    newv: jax.Array, names: "tuple[str, ...]" = KV) -> dict:
+    """Whole blocks of K and V (of ``names``, in their order) at the compute
+    dtype into the pool (the prefill install, the sp and disagg handoffs)."""
+    return write_blocks(pool, ids, _stored(pool, names, (newk, newv)))
 
 
 # -- columns, written ---------------------------------------------------------
 
 def scatter_columns(pool: dict, blk: jax.Array, off: jax.Array,
-                    newk: jax.Array, newv: jax.Array) -> dict:
-    """Freshly written columns (``[layers, *blk.shape, *tail]``; blk/off
+                    newk: jax.Array, newv: jax.Array,
+                    names: "tuple[str, ...]" = KV) -> dict:
+    """Freshly written columns (``[layers, *blk.shape, *tail]`` of the
+    arrays ``names``, in their order, each over its own layers; blk/off
     share any index shape: ``[S]`` decode, ``[S, k]`` verify) into the
     DONATED pool, in place. Sentinel blocks write nothing — no block
     corrupted."""
-    layers, blocks = pool["k"].shape[:2]
-    cols = {**stored_as(pool, "k", newk),
-            **stored_as(pool, "v", newv)}
-    if all(pool[name].ndim == 4 for name in ("k", "v")):
+    blocks = n_blocks(pool, names)
+    cols = _stored(pool, names, (newk, newv))
+    if all(pool[name].ndim == 4 for name in names):
         # the merged axis: ONE scatter a pool array, indexed by (layer, block,
         # offset) with a column of ``tail`` the window. (With the layer axis
         # left a slice, ``.at[:, blk, off]``, the window spans the layers and
         # the chip's compiler re-lays the whole pool out with the layers in the
         # sublanes and back: seen in the compiled text, PERF.md section 6.)
-        at = (jnp.arange(layers).reshape(
-            (-1,) + (1,) * blk.ndim), blk[None], off[None])
+        at = {}
+        for vals in cols.values():
+            at.setdefault(vals.shape[0], (jnp.arange(vals.shape[0]).reshape(
+                (-1,) + (1,) * blk.ndim), blk[None], off[None]))
         return {**pool, **{
-            name: pool[name].at[at].set(vals, mode="drop")
+            name: pool[name].at[at[vals.shape[0]]].set(vals, mode="drop")
             for name, vals in cols.items()}}
     # a pool that keeps heads and head size apart (a head fills a lane tile) is
     # written as PR 29 measured it: its columns go in one at a time, a loop of
@@ -374,7 +413,7 @@ def scatter_columns(pool: dict, blk: jax.Array, off: jax.Array,
     # that whole pool; the indexed one above compiles in place for it too but
     # has not been measured on its cell, ROADMAP A12)
     cols = {name: vals.reshape(
-                (layers, -1) + vals.shape[1 + blk.ndim:])
+                (vals.shape[0], -1) + vals.shape[1 + blk.ndim:])
             for name, vals in cols.items()}
     blk, off = blk.reshape(-1), off.reshape(-1)
     live = blk < blocks
@@ -391,5 +430,5 @@ def scatter_columns(pool: dict, blk: jax.Array, off: jax.Array,
                 pool[name], jnp.where(live[c], col, old), at)
         return out
 
-    return {**lax.fori_loop(0, blk.shape[0], body, block_arrays(pool)),
-            **slot_arrays(pool)}
+    return {**pool, **lax.fori_loop(
+        0, blk.shape[0], body, {name: pool[name] for name in cols})}

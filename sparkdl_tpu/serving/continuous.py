@@ -210,6 +210,11 @@ _M_TAIL_BYTES = registry().gauge(
     "device bytes of short convolutions' tails held by slot (the last "
     "inputs of a gated short convolution a slot a layer, whatever the "
     "contexts' lengths), all engines")
+_M_LATENT_BYTES = registry().gauge(
+    "sparkdl_latent_pool_bytes",
+    "device bytes of the block arrays a family names itself (a latent "
+    "attention's one column a token a layer, an indexer's keys), all "
+    "engines")
 _M_SCAN_TOKENS = registry().counter(
     "sparkdl_linear_scan_tokens_total",
     "real prompt tokens taken through the chunkwise recurrence of a "
@@ -673,7 +678,8 @@ class ContinuousGPTEngine:
                 n_slots=n_slots, block_size=bs_kv, mb=mb, w=w, wp=wp,
                 max_pos=(fam.max_positions - 1
                          if fam.max_positions is not None else wp + chunk),
-                dtype=fam.dtype)
+                dtype=fam.dtype,
+                arrays=tuple(name for name, _, _ in fam.pool_arrays))
             # One binding a program, under the function's own name (what
             # the device trace shows and the benchmark reads). The jit
             # calls stay in THIS file: sparkdl-lint's donation-safety rule
@@ -702,7 +708,8 @@ class ContinuousGPTEngine:
                 bound(programs._chunk_final, sizes, model),
                 donate_argnums=(1,), static_argnums=(7,),
                 compiler_options=alike)
-            self._park_fetch_fn = jax.jit(programs._park_fetch)
+            self._park_fetch_fn = jax.jit(
+                bound(programs._park_fetch, sizes))
             self._unpark_install_fn = jax.jit(
                 programs._unpark_install, donate_argnums=(0,))
             self._install_blocks_fn = jax.jit(
@@ -713,6 +720,12 @@ class ContinuousGPTEngine:
                 _M_RING_BYTES if fam.ring_columns
                 else _M_TAIL_BYTES if fam.tail_columns else _M_STATE_BYTES)
             self._g_state.set(n_slots * fam.state_bytes_per_slot)
+            # the pool's arrays where the family names them itself
+            self._g_named = None
+            if fam.block_arrays:
+                self._g_named = GaugeShare(_M_LATENT_BYTES)
+                self._g_named.set(
+                    kv_blocks * bs_kv * kv_bytes_per_token(config, kv_dtype))
             if sp_val > 1:
                 self._init_sp(sp_val, sp_kv_blocks)
         else:
@@ -946,6 +959,8 @@ class ContinuousGPTEngine:
         if self.kv_layout == "paged":
             self._pool.close()
             self._g_state.set(0)
+            if self._g_named is not None:
+                self._g_named.set(0)
             if self.sp > 1:
                 self._sp_pool.close()
             if self._kv_tiers is not None:
@@ -1748,6 +1763,19 @@ class ContinuousGPTEngine:
         scan = ({"scan_tokens": r, "pad_tokens": wc - r,
                  "scan_solved_in_kernel": int(fam.scan_solved_in_kernel)}
                 if state else {})
+        if fam.selected_columns:
+            # a family that attends a selection: the columns this chunk's
+            # real queries attended, a layer (``sel_cols``: query ``i``
+            # keeps ``min(c0 + i + 1, selected_columns)``), and those its
+            # indexer scored to select, an indexer layer (``index_cols``:
+            # every column up to the query's own, once the program's
+            # width passes the selection)
+            upto = np.arange(c0 + 1, c0 + r + 1)
+            scan = dict(
+                scan,
+                sel_cols=int(np.minimum(upto, fam.selected_columns).sum()),
+                index_cols=(int(upto.sum())
+                            if cols > fam.selected_columns else 0))
         with span("serving.prefill_chunk", parent=st.req.trace_ctx,
                   request_id=st.req.request_id, slot=slot,
                   start=c0, tokens=r, first=first, final=final,
@@ -2023,18 +2051,38 @@ class ContinuousGPTEngine:
         that rides it (``slots``), which deepens by one a pass
         (``kv_cols_live``). A family whose step reads the pool in place
         (``decode_reads_in_place``) fetches, for each riding row, the
-        whole blocks its depth reaches and nothing for any other slot."""
+        whole blocks its depth reaches and nothing for any other slot; one
+        whose step attends a selection of its own (``selected_columns``)
+        fetches that many columns a slot once any row's table passes it."""
         depths = [int(self._pidx[s]) for s in slots]
         bs = self._kv_bs
-        if self._family.decode_reads_in_place:
+        fam = self._family
+        picks = fam.selected_columns and nb * bs > fam.selected_columns
+        if fam.decode_reads_in_place:
             read = sum(-(-(d + j) // bs) * bs
                        for d in depths for j in range(steps))
+        elif picks:
+            # the step attends a selection: every slot's rows fetch that
+            # many columns one by one, whatever the table's width
+            read = self.n_slots * fam.selected_columns * steps
         else:
             read = self.n_slots * nb * bs * steps
         live = steps * sum(depths) + len(depths) * steps * (steps - 1) // 2
         self.metrics.record_kv_read(read, live)
         out = {"kv_cols_read": read, "kv_cols_live": live}
-        fam = self._family
+        if fam.selected_columns:
+            # per layer: the columns the riding rows' attention attended
+            # (``sel_cols``: all of a row's while it has no more than the
+            # selection's size); per indexer layer: the columns of riding
+            # rows that had to be scored to select (``index_cols``: a row
+            # no deeper than the selection needs none; what the indexer
+            # FETCHES to do it is every slot's ``nb`` blocks where any
+            # row's table passes the selection, the span's ``nb`` says)
+            out["sel_cols"] = sum(min(d + j, fam.selected_columns)
+                                  for d in depths for j in range(steps))
+            out["index_cols"] = sum(
+                d + j for d in depths for j in range(steps)
+                if d + j > fam.selected_columns)
         if fam.state_layers:
             # the rows whose state the dispatch advances, once a pass, and
             # what each reads and writes of it: its state in every state

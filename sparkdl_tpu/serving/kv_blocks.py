@@ -103,10 +103,12 @@ def kv_bytes_per_token(config, dtype: str = "fp32") -> int:
     fam = config.serving_family()
     item = (np.dtype(fam.dtype).itemsize if dtype == "fp32"
             else _KV_ITEMSIZE[dtype])
-    per_layer = (math.prod(fam.kv_tail) + math.prod(fam.v_tail)) * item
-    if dtype == "int8":
-        per_layer += 2 * 4  # k_scale + v_scale, fp32, one per column
-    return fam.pool_layers * per_layer
+    # each array a token keeps by block, over its own layers (``k`` and
+    # ``v`` over ``pool_layers``, or the pair the family names); int8 adds
+    # one fp32 scale a column an array
+    return sum(
+        layers * (math.prod(tail) * item + (4 if dtype == "int8" else 0))
+        for _, layers, tail in fam.pool_arrays)
 
 
 def kv_capacity_ratio(config, dtype: str) -> float:

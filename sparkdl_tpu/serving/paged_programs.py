@@ -42,6 +42,12 @@ class PagedSizes:
     wp: int        #: a private prefill cache's width: ``w`` + one chunk
     max_pos: int   #: the last position a chunk's pad tail may claim
     dtype: Any     #: compute dtype (the pool may store another)
+    #: the two arrays a token keeps by block, as the family names them
+    #: (``ServingFamily.pool_arrays``): what ``ck`` and ``cv`` below are a
+    #: prompt's private rows of, what a module's cached call takes and
+    #: hands back its columns under, and what ``kv_pool`` is told apart
+    #: from the pool's arrays by slot by
+    arrays: "tuple[str, str]" = kv_pool.KV
 
 
 def bound(fn, *head):
@@ -95,8 +101,9 @@ def _paged_step(sizes, model, variables, pool, table, idx, tok, prev, k, nb):
     # one's ids. Every step hands its last tokens on the same way.
     sub = table[:, :nb]
     tok = jnp.where(tok >= 0, tok, prev)
-    recurrent = kv_pool.slot_arrays(pool)
-    live = ({"live": table[:, 0] < pool["k"].shape[1]} if recurrent else {})
+    recurrent = kv_pool.slot_arrays(pool, sizes.arrays)
+    live = ({"live": table[:, 0] < kv_pool.n_blocks(pool, sizes.arrays)}
+            if recurrent else {})
 
     def body(carry, _):
         pool, idx, tok = carry
@@ -109,7 +116,8 @@ def _paged_step(sizes, model, variables, pool, table, idx, tok, prev, k, nb):
         blk = table[rows, idx // sizes.block_size]
         off = idx % sizes.block_size
         pool = kv_pool.scatter_columns(
-            pool, blk, off, new["k"][:, :, 0], new["v"][:, :, 0])
+            pool, blk, off, *(new[name][:, :, 0] for name in sizes.arrays),
+            names=sizes.arrays)
         pool.update({name: new[name] for name in recurrent})
         out = ntok
         if "expert_counts" in new:
@@ -146,7 +154,9 @@ def _paged_verify(sizes, model, variables, pool, table, idx, toks, k, nb):
     pos = idx[:, None] + jnp.arange(k)[None, :]
     blk = table[rows, pos // sizes.block_size]
     off = pos % sizes.block_size
-    return out, kv_pool.scatter_columns(pool, blk, off, new["k"], new["v"])
+    return out, kv_pool.scatter_columns(
+        pool, blk, off, *(new[name] for name in sizes.arrays),
+        names=sizes.arrays)
 
 
 # -- chunked prefill ----------------------------------------------------------
@@ -159,16 +169,14 @@ def _gathered(sizes, pool, ids):
     # dequantize here: the private cache is compute-dtype, and the final
     # install requantizes — an exact round trip (quantize_kv absmax maps to
     # ±127), so a COW-shared block re-installs bit-identical to its donor.
-    layers = pool["k"].shape[0]
-
     def private(x):
         tail = x.shape[3:]
         pad = (((0, 0), (0, 0), (0, sizes.wp - sizes.w))
                + ((0, 0),) * len(tail))
-        return jnp.pad(x.reshape((layers, 1, sizes.w) + tail), pad)
+        return jnp.pad(x.reshape((x.shape[0], 1, sizes.w) + tail), pad)
 
-    return tuple(
-        private(x) for x in kv_pool.gather_blocks_as(pool, ids, sizes.dtype))
+    return tuple(private(x) for x in kv_pool.gather_blocks_as(
+        pool, ids, sizes.dtype, sizes.arrays))
 
 
 def _chunk_apply(sizes, model, variables, ck, cv, idx, ids, cols, n=None,
@@ -192,13 +200,14 @@ def _chunk_apply(sizes, model, variables, ck, cv, idx, ids, cols, n=None,
     # the state at token ``n`` back, which is this function's fourth result.
     positions = jnp.minimum(
         idx + jnp.arange(ids.shape[1])[None, :], sizes.max_pos)
-    cache = {"k": ck[:, :, :cols], "v": cv[:, :, :cols],
+    first, second = sizes.arrays
+    cache = {first: ck[:, :, :cols], second: cv[:, :, :cols],
              "idx": idx, **({} if n is None else dict(rec, n=n))}
     logits, cache = model.apply(
         variables, ids, cache=cache, positions=positions,
     )
-    ck = ck.at[:, :, :cols].set(cache["k"])
-    cv = cv.at[:, :, :cols].set(cache["v"])
+    ck = ck.at[:, :, :cols].set(cache[first])
+    cv = cv.at[:, :, :cols].set(cache[second])
     if n is None:
         return logits, ck, cv
     return logits, ck, cv, {name: cache[name] for name in rec}
@@ -209,14 +218,18 @@ def _fresh(sizes, pool):
     # state layers: no cached prefix is ever gathered for it (its blocks do
     # not hold the state at the boundary), so the cache starts as zeros and
     # not as a gather of sentinels, and the state as the sequence's start
-    head = (pool["k"].shape[0], 1, sizes.wp)
-    ck = jnp.zeros(head + pool["k"].shape[3:], sizes.dtype)
+    first, second = (pool[name] for name in sizes.arrays)
+
+    def zeros(a):
+        return jnp.zeros((a.shape[0], 1, sizes.wp) + a.shape[3:], sizes.dtype)
+
+    ck = zeros(first)
     # (ONE array for both where K and V are shaped alike: Olmo's programs
     # then lower to the text they lowered to)
-    cv = (ck if pool["v"].shape[3:] == pool["k"].shape[3:]
-          else jnp.zeros(head + pool["v"].shape[3:], sizes.dtype))
+    cv = (ck if (second.shape[0],) + second.shape[3:]
+          == (first.shape[0],) + first.shape[3:] else zeros(second))
     rec = {name: jnp.zeros((a.shape[0], 1) + a.shape[2:], a.dtype)
-           for name, a in kv_pool.slot_arrays(pool).items()}
+           for name, a in kv_pool.slot_arrays(pool, sizes.arrays).items()}
     return ck, cv, rec
 
 
@@ -229,10 +242,14 @@ def _installed(sizes, pool, ck, cv, ids, slot=None, rec=None):
     # (a recurrent state; a window layer's ring of its last columns) -> row
     # ``slot`` of the pool's arrays by slot, whole (whatever the row held, of
     # the sequence before it, is gone).
-    head = (pool["k"].shape[0], sizes.mb, sizes.block_size)
+    def blocks(rows, name):
+        a = pool[name]
+        return rows[:, 0, :sizes.w].reshape(
+            (a.shape[0], sizes.mb, sizes.block_size) + a.shape[3:])
+
+    first, second = sizes.arrays
     pool = kv_pool.write_kv_blocks(
-        pool, ids, ck[:, 0, :sizes.w].reshape(head + pool["k"].shape[3:]),
-        cv[:, 0, :sizes.w].reshape(head + pool["v"].shape[3:]))
+        pool, ids, blocks(ck, first), blocks(cv, second), sizes.arrays)
     return pool if rec is None else kv_pool.install_slot(pool, slot, rec)
 
 
@@ -287,13 +304,13 @@ def _chunk_final(sizes, model, variables, pool, ck, cv, idx, ids, inst,
 
 # -- whole blocks across a boundary: tiers, handoffs --------------------------
 
-def _park_fetch(pool, ids):
+def _park_fetch(sizes, pool, ids):
     # the D2H half of a park: the given blocks' RAW storage-dtype bytes (int8
     # codes + their scales, no dequantize) — raw is both the 4x cheaper
     # transfer the quantized layout bought and what makes a resumed session
     # bitwise-identical: unpark writes back the exact bytes decode would have
     # read. A prefill tier's export (disagg/workers.py) is this same gather.
-    return kv_pool.gather_blocks(pool, ids)
+    return kv_pool.gather_blocks(pool, ids, sizes.arrays)
 
 
 def _unpark_install(pool, ids, payload):

@@ -1010,3 +1010,171 @@ def test_the_benchmarks_reader_finds_the_convolutions_operations(
     for ln in taken:
         assert "gmm" not in ln.split(" = ")[0]
         assert "self_attn" not in ln and "moe" not in ln, ln[:200]
+
+
+# -- the glm_moe_dsa family at its published widths (ISSUE 44) ----------------------
+
+@pytest.fixture(scope="module")
+def compiled_glm(one_chip, for_the_chip, monkeypatch_module):
+    """``get(which)``: the decode step (1,024 blocks a row, the table's whole
+    width), a MID chunk and a FINAL chunk (it installs both arrays into the
+    slot's blocks) at 16,384 columns of the benchmark's 32 x 16384 engine
+    over ALL FIVE layers of its cut at GLM-5.2's widths (16 of 256 experts
+    held), compiled for the chip when first asked for. The engine here is
+    built over a pool of 64 blocks (nothing of the published pool's 3.6 GB
+    is allocated on this CPU); the programs are lowered for the benchmark's
+    32,768."""
+    from sparkdl_tpu.models.glm_moe_dsa import (
+        GlmMoeDsaConfig,
+        GlmMoeDsaLMHeadModel,
+    )
+    from sparkdl_tpu.parallel import moe_dropless
+
+    monkeypatch_module.setattr(moe_dropless, "auto_interpret", lambda: False)
+    cfg = GlmMoeDsaConfig(
+        vocab_size=19456,
+        indexer_types=("full", "shared", "shared", "shared", "full"),
+        mlp_layer_types=("dense",) + ("sparse",) * 4, experts_held=16,
+        dtype=jnp.bfloat16)
+    variables = jax.eval_shape(
+        lambda: GlmMoeDsaLMHeadModel(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    eng = ContinuousGPTEngine(cfg, variables, n_slots=32, max_len=16384,
+                              kv_blocks=64, auto_start=False)
+    mb, blocks = 16384 // 16, 32 * 1024
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    pool = {name: jax.ShapeDtypeStruct(
+        (a.shape[0], blocks) + a.shape[2:], a.dtype, sharding=one_chip)
+        for name, a in eng._pool_kv.items()}
+    private = [jax.ShapeDtypeStruct(
+        (pool[name].shape[0], 1, eng._wp) + pool[name].shape[3:],
+        jnp.bfloat16, sharding=one_chip) for name in ("latent", "index_k")]
+    head = (_on(one_chip, variables), pool)
+    lower = {
+        "step": lambda: eng._paged_step_fn.lower(
+            *head, ints(32, mb), ints(32), ints(32), ints(32), 1, mb),
+        "mid": lambda: eng._chunk_mid_fn.lower(
+            head[0], *private, ints(), ints(1, 256), 16384),
+        "final": lambda: eng._chunk_final_fn.lower(
+            *head, *private, ints(), ints(1, 256), ints(mb), 16384),
+    }
+    done = {}
+
+    def get(which):
+        if which not in done:
+            done[which] = lower[which]().compile()
+        return done[which]
+
+    get.pool = pool
+    try:
+        yield get
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("which", ["step", "mid", "final"])
+def test_the_latent_pool_and_the_indexers_keys_are_written_where_they_lie(
+        compiled_glm, which):
+    """``latent`` ``bf16[5,32768,16,640]`` and ``index_k``
+    ``bf16[2,32768,16,128]``, each over its OWN layers, lie row-major and go
+    out in the buffers they came in, in every program that writes them;
+    what is held beside them fits the chip with the 11.4 GB of weights and
+    pool."""
+    pool = compiled_glm.pool
+    assert {k: a.shape for k, a in pool.items()} == {
+        "latent": (5, 32768, 16, 640), "index_k": (2, 32768, 16, 128)}
+    compiled = compiled_glm(which)
+    text, stats = compiled.as_text(), compiled.memory_analysis()
+    pool_bytes = sum(math.prod(a.shape) * 2 for a in pool.values())
+    assert pool_bytes == 3_623_878_656
+    if which == "mid":
+        # the prompt's private rows of both arrays, not the pool
+        private = (5 * 640 + 2 * 128) * (16384 + 256) * 2
+        assert stats.alias_size_in_bytes >= private
+        assert stats.temp_size_in_bytes < 0.8e9
+        return
+    for name in ("latent", "index_k"):
+        made = _made(text, pool[name].shape)
+        ops = {op for op, _ in made}
+        assert ops & {"scatter", "dynamic-update-slice", "fusion"}, (
+            name, sorted(ops))
+        assert not ops & {"copy", "copy-start", "copy-done", "transpose"}, (
+            name, sorted(ops))
+        assert {order for _, order in made} == {(3, 2, 1, 0)}, name
+    assert stats.alias_size_in_bytes >= pool_bytes
+    # beside them: a step's gathered keys (32 x 16384 x 128), its selected
+    # columns of a layer (32 x 2048 x 640) and scores; a chunk's per-head K
+    # and V of 16 heads and their scores over 16 k columns
+    assert stats.temp_size_in_bytes < {"step": 0.8e9, "final": 1.3e9}[which]
+    assert (stats.argument_size_in_bytes + stats.temp_size_in_bytes
+            < 13.0e9)
+
+
+def test_the_glm_step_reads_selected_columns_alone_and_sorts_once_a_full_layer(
+        compiled_glm):
+    """What the cell will say of this path: each of the five layers reads
+    32 x 2,048 SELECTED columns of 640 one by one and never the rows' 16,384
+    (a whole row's ``latent`` through the table would be ``[32, 16384,
+    640]``: 0.67 GB a layer); the two ``full`` layers read their keys
+    through the table and sort a row's scores once; the experts' three
+    products a layer are the grouped matmul kernel."""
+    text = compiled_glm("step").as_text()
+    entry = text[text.index("\nENTRY "):]
+    assert len(re.findall(r"= bf16\[65536,640\]\S* fusion\(", entry)) == 5
+    for rows in ((32, 16384, 640), (32, 1024, 16, 640), (32768, 16, 640)):
+        assert _made(text, rows) == [], rows
+    assert len(re.findall(
+        r"= \(f32\[32,16384\]\S*, s32\[32,16384\]\S*\) sort\(", entry)) == 2
+    assert len(_made(text, (32, 16384, 128))) >= 2
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) == 4 * 3
+    # the stages carry their scopes in the compiled text
+    for scope in ("dsa_indexer", "dsa_select", "dsa_selected_read",
+                  "dsa_absorbed_attention"):
+        assert scope in text, scope
+    assert "dsa_expanded_attention" not in text
+    mid = compiled_glm("mid").as_text()
+    assert "dsa_expanded_attention" in mid and "dsa_indexer" in mid
+    # a chunk's selection is a count a bit, not a sort of 256 x 16,384
+    assert not re.search(r"f32\[256,16384\]\S*, s32\[256,16384\]\S*\) sort\(",
+                         mid)
+
+
+def test_the_benchmarks_readers_find_the_two_stages_operations(compiled_glm):
+    """``benchmark/readers_glm_moe_dsa.is_indexer_op`` and
+    ``is_sparse_attn_op`` (imported as they stand) on the instructions of
+    the step's ENTRY computation, which are what a device trace holds an
+    event for: the first takes the keys' gather, the scores and the sort,
+    the second the selected read, the scores over 2,048 and the mix; neither
+    takes the other's, the experts', the projections' or the logits."""
+    from benchmark import manifest as mf
+    from benchmark import readers_glm_moe_dsa as readers_g
+    from benchmark.runners import serve_glm_moe_dsa
+
+    hf = serve_glm_moe_dsa.hf_config(
+        mf.resolve_cell("glm52-sparse-agent-backlog").config)
+    text = compiled_glm("step").as_text()
+    entry = text[text.index("\nENTRY "):]
+    lines = [ln.strip().split(", metadata=")[0] for ln in entry.splitlines()
+             if " = " in ln and " parameter(" not in ln]
+    index = [ln for ln in lines if readers_g.is_indexer_op(ln, hf, 32)]
+    attend = [ln for ln in lines if readers_g.is_sparse_attn_op(ln, hf, 32)]
+    assert not set(index) & set(attend)
+    assert sum(" sort(" in ln for ln in index) == 2
+    # a loop's event spans its body's, which have their own: leaves only
+    assert not any(" while(" in ln for ln in index + attend)
+    # the keys' gather, whose first axis is slots x blocks and not slots
+    assert sum("bf16[32768,16,128]" in ln and "index_k" in ln
+               for ln in index) == 2
+    assert sum("f32[32,16384]" in ln and " fusion(" in ln
+               for ln in index) >= 2
+    assert sum("bf16[65536,640]" in ln for ln in attend) == 5
+    assert sum("f32[32,64,2048]" in ln and " fusion(" in ln
+               for ln in attend) == 5
+    assert sum("f32[32,64,640]" in ln for ln in attend) == 5
+    for ln in index + attend:
+        assert "gmm" not in ln.split(" = ")[0]
+        assert "19456" not in ln.split(" = ")[1].split(" ")[0], ln[:200]
+        assert "[32,16384]{1,0:T(8,128)(2,1)" not in ln, ln[:200]   # q itself
