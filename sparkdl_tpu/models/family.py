@@ -114,8 +114,13 @@ class ServingFamily:
     #: and ``v``, shaped by the heads above
     block_arrays: "tuple[tuple[str, int, tuple[int, ...]], ...]" = ()
     #: the module's one-token step attends over at most this many columns a
-    #: row a layer, which it selects itself and reads one by one (a learned
-    #: sparse attention), whatever the row's depth (0: over every column)
+    #: row a layer, which it selects itself (a learned sparse attention),
+    #: whatever the row's depth (0: over every column). What it FETCHES to
+    #: do so follows the width of the step's tables
+    #: (``ops/sparse_attention.attends_in_place``, which the engine's count
+    #: of a step's reads asks too): the riding rows' live blocks, whole,
+    #: under the selection as a mask while a table is no wider than a few
+    #: selections; the selected columns one by one past that
     selected_columns: int = 0
 
     @property

@@ -48,9 +48,15 @@ indexer, and attends under the mask of its own ``S_t`` (gathering each
 query's own 2,048 columns would make K and V once a query). The one-token
 step is ABSORBED: ``q~ = q^nope W_uk^T`` scores the stored columns as they
 lie, ``(sum_s p_s c^kv_s) W_uv`` is the output, and only the columns of
-``S_t`` are read from the pool, one by one: the step's attention reads
-``min(depth, index_topk)`` columns a row a layer, whatever the depth; its
-indexer reads the row's ``index_k`` through the table.
+``S_t`` are attended: ``min(depth, index_topk)`` columns a row a layer,
+whatever the depth. How the step READS them follows the width of its tables
+(``ops/sparse_attention.attends_in_place``): while a table is no wider than
+a few selections, ``S_t`` is a mask and the rows' live blocks are read where
+the pool keeps them, each row to its own depth (the paged one-query kernel
+over ``latent`` alone), every column outside ``S_t`` under a weight of
+exactly zero; past that width ``S_t`` is 2,048 positions a row and those
+columns are read from the pool one by one. Its indexer reads the row's
+``index_k`` through the table in both.
 
 The module keeps :class:`~sparkdl_tpu.models.gpt.GPTLMHeadModel`'s cache
 contracts under those names: none; dense ``{"latent", "index_k", "idx"}``
@@ -75,6 +81,8 @@ from sparkdl_tpu.models.family import ServingFamily
 from sparkdl_tpu.models.kv_pool import LANE_TILE, layer_rows
 from sparkdl_tpu.ops.sparse_attention import (
     absorbed_attention,
+    attend_in_place,
+    attends_in_place,
     index_scores,
     pick_columns,
     select_mask,
@@ -382,7 +390,9 @@ class GlmAttention(nn.Module):
     """One layer's latent attention and, in a ``full`` layer, its indexer.
     ``picked`` is the selection handed down by the nearest ``full`` layer
     before a ``shared`` one (a mask ``[B, L, W]`` in a call over many
-    tokens; ``(positions, taken)`` or None in a paged step). Returns ``(y,
+    tokens; in a paged step a mask ``[S, W]`` where it attends in place,
+    ``(positions, taken)`` where it does not, None while no table passes
+    the selection's size). Returns ``(y,
     entry, picked)``: ``entry`` is None without a cache, else ``(latent,
     index_k | None)``: this call's columns of a paged cache, the updated
     rows of a dense one."""
@@ -438,19 +448,28 @@ class GlmAttention(nn.Module):
             # one query a row, every row at its own depth
             table = cache["table"]
             width = table.shape[1] * cache["latent"].shape[2]
+            # (the rule, on the table's width: every layer of a step asks
+            # it of the same table, so a ``shared`` layer knows which form
+            # of selection it was handed)
+            in_place = attends_in_place(width, topk)
             if full:
                 picked = None
                 if width > topk:
-                    picked = self._pick_step(cache, q_i, k_i, w_i, idx, width)
+                    picked = self._pick_step(cache, q_i, k_i, w_i, idx, width,
+                                             as_mask=in_place)
                     self.sow("intermediates", "picked", picked)
-            old, seen, new_seen = selected_columns(
-                cache, self.layer_idx, picked, idx)
             q_abs = jnp.einsum("shn,chn->shc", q_nope[:, 0], w_uk)
             q_full = jnp.pad(
                 jnp.concatenate([q_abs, q_rope[:, 0]], -1).astype(c.dtype),
                 ((0, 0), (0, 0), (0, tail - c.latent_width)))
-            mix = absorbed_attention(q_full, old.astype(c.dtype), seen,
-                                     new[:, 0], new_seen, scale)
+            if in_place:
+                mix = attend_in_place(cache, self.layer_idx, q_full, picked,
+                                      idx, new[:, 0], scale)
+            else:
+                old, seen, new_seen = selected_columns(
+                    cache, self.layer_idx, picked, idx)
+                mix = absorbed_attention(q_full, old.astype(c.dtype), seen,
+                                         new[:, 0], new_seen, scale)
             ctx = jnp.einsum("shc,chv->shv", mix[..., :rank].astype(c.dtype),
                              w_uv).reshape(b, 1, nh * dv)
             entry = (new, None if k_i is None else k_i)
@@ -485,11 +504,12 @@ class GlmAttention(nn.Module):
         entry = None if cache is None else (rows, index_rows)
         return jnp.dot(ctx, out_proj), entry, picked
 
-    def _pick_step(self, cache, q_i, k_i, w_i, idx, width):
+    def _pick_step(self, cache, q_i, k_i, w_i, idx, width, as_mask):
         """A step's selection: each row's ``index_topk`` positions of
         largest ``I`` among its ``idx`` stored columns (its ``index_k``
         through the table) and this call's own, which sits at position
-        ``idx``. ``(positions [S, K], taken [S, K])``."""
+        ``idx``. ``(positions [S, K], taken [S, K])``, or where the step
+        attends in place (``as_mask``) the same set as a mask ``[S, W]``."""
         c = self.config
         keys, = layer_rows(cache, c.index_row(self.layer_idx),
                            cache["table"], c.dtype, names=("index_k",))
@@ -498,6 +518,8 @@ class GlmAttention(nn.Module):
         cols = jnp.arange(width)[None, :]
         scores = jnp.where(cols == idx[:, None], own[:, None],
                            jnp.where(cols < idx[:, None], scores, -jnp.inf))
+        if as_mask:
+            return select_mask(scores, c.index_topk)
         return pick_columns(scores, c.index_topk)
 
 

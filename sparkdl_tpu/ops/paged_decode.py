@@ -39,6 +39,18 @@ the copies alone, PERF.md section 6, PR 35) and is sixty products and sixty
 single-row stores a group to trace and lower, at every start of every
 program that holds the kernel.
 
+It also takes a LATENT pool: ONE array that is keys and values both (GLM's
+``[c^kv | k^r | 0]`` of 640 a token under 64 absorbed queries: one K/V head
+as wide as the axis). No V array is passed: a block is copied once and both
+products read that copy. With it come a per-row BIAS over the table's
+columns, added to every head's scores beside the depth's mask (a learned
+selection as a mask: 0 where a column is attended, ``-1e30`` where not),
+and the caller's SCALE (the stored width is not the width the model scales
+by). All three are resolved while the kernel is traced
+(:func:`paged_decode_partial`; ``ops/sparse_attention.attend_in_place`` is
+what calls it so): a call with K and V, no bias and no scale lowers to what
+it did before the kernel knew them.
+
 Same precisions as ``merged_axis_attention``: operands as stored, float32
 scores, maximum, sum and accumulator, the weights cast to the operands'
 dtype before the product with V. This call's own column is NOT the
@@ -107,13 +119,24 @@ def _group_blocks(nb: int, block_bytes: int) -> int:
     return min(1 << (g.bit_length() - 1), nb)
 
 
-def _kernel(table_ref, depth_ref, qbd_ref, k_hbm, v_hbm,
-            o_ref, m_ref, l_ref,
-            kbuf, vbuf, sems, first, acc_scr, m_scr, l_scr, *,
+def _kernel(table_ref, depth_ref, qbd_ref, *refs,
             layer: int, group: int, bs: int, heads: int, kv_heads: int,
-            head_dim: int, v_head_dim: int):
+            head_dim: int, v_head_dim: int, arrays: int, biased: bool,
+            scale: "float | None"):
+    """``refs``: the row's bias if ``biased``, the pool's ``arrays`` (K and
+    V, or ONE array that is both), the three results, a pair of buffers an
+    array and the scratch. All three parameters are resolved while tracing:
+    a call with K and V, no bias and no scale traces what it always did."""
     from jax.experimental.pallas import tpu as pltpu
 
+    refs = list(refs)
+    bias_ref = refs.pop(0) if biased else None
+    pool, (o_ref, m_ref, l_ref) = refs[:arrays], refs[arrays:arrays + 3]
+    bufs = refs[arrays + 3:2 * arrays + 3]
+    sems, first, acc_scr, m_scr, l_scr = refs[2 * arrays + 3:]
+    # one array: a block's copy is the keys of the scores AND the values
+    # of the weighted sum
+    kbuf, vbuf = bufs[0], bufs[-1]
     s = pl.program_id(0)
     rows = pl.num_programs(0)
     span = group * bs                     # tokens a group holds
@@ -130,7 +153,7 @@ def _kernel(table_ref, depth_ref, qbd_ref, k_hbm, v_hbm,
         def one(j, _):
             blk = table_ref[row, grp * group + j]
             at = pl.ds(pl.multiple_of(j * bs, bs), bs)
-            for hbm, buf, sem in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+            for sem, (hbm, buf) in enumerate(zip(pool, bufs)):
                 getattr(pltpu.make_async_copy(
                     hbm.at[layer, blk], buf.at[slot, at],
                     sems.at[sem, slot]), act)()
@@ -176,7 +199,11 @@ def _kernel(table_ref, depth_ref, qbd_ref, k_hbm, v_hbm,
         copies(s, g, slot, "wait")
         x = jax.lax.dot_general(
             qbd_ref[0], kbuf[slot], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) / math.sqrt(head_dim)
+            preferred_element_type=jnp.float32)
+        x = x / math.sqrt(head_dim) if scale is None else x * scale
+        if biased:
+            # the row's own bias over this group's columns, every head's
+            x = x + bias_ref[0, :, pl.ds(pl.multiple_of(g * span, span), span)]
         pos = g * span + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
         x = jnp.where(pos < depth, x, _NEG_INF)
         m_prev = m_scr[...]
@@ -223,12 +250,15 @@ def _block_diagonal(q, kv_heads: int, rows: int):
     zeros). ``q`` ``[S, H, Dk]`` -> ``[S, rows, kv_heads * Dk]``."""
     _, heads, head_dim = q.shape
     q = jnp.pad(q, ((0, 0), (0, rows - heads), (0, 0)))
+    if kv_heads == 1:
+        return q        # one K/V head: every column is every row's own
     own = (jnp.arange(kv_heads * head_dim)[None, :] // head_dim
            == (jnp.arange(rows) // (heads // kv_heads))[:, None])
     return jnp.where(own, jnp.tile(q, (1, 1, kv_heads)), 0)
 
 
-def paged_decode_partial(q, k_pool, v_pool, layer: int, table, depth):
+def paged_decode_partial(q, k_pool, v_pool, layer: int, table, depth, *,
+                         bias=None, scale: "float | None" = None):
     """Attention of one query a row over the OLD columns of a paged pool.
 
     ``q`` ``[S, H, Dk]``; ``k_pool`` ``[layers, blocks, block, G*Dk]`` and
@@ -242,53 +272,74 @@ def paged_decode_partial(q, k_pool, v_pool, layer: int, table, depth):
     1])``: the unnormalised weighted sum of V, the running maximum of the
     scores and the sum of the weights under it (``acc = 0``, ``m = -1e30``,
     ``l = 0`` for a row of depth 0).
+
+    ``v_pool`` None: ``k_pool`` is the values too (a LATENT pool: one
+    column a token that the scores and the weighted sum both read, GLM's
+    ``[c^kv | k^r | 0]`` of 640 under 64 absorbed queries). Each block is
+    copied ONCE and both products run over that copy. ``bias`` ``[S, nb *
+    block]`` float32, added to every head's scores of row ``s`` beside the
+    depth's mask (0 where a column is attended, ``-1e30`` where it is not:
+    a selection as a mask; a group none of whose columns is attended leaves
+    a sum that the next attended column's maximum multiplies by exactly
+    zero). ``scale`` multiplies the scores where it is not one over the
+    root of ``Dk`` (a stored column's width is not the width the model
+    scales by).
     """
     from jax.experimental.pallas import tpu as pltpu
 
     rows, heads, head_dim = q.shape
     bs, k_width = k_pool.shape[2:]
-    v_width = v_pool.shape[-1]
+    pools = (k_pool,) if v_pool is None else (k_pool, v_pool)
+    v_width = pools[-1].shape[-1]
     kv_heads = k_width // head_dim
     v_head_dim = v_width // max(kv_heads, 1)
     if (kv_heads < 1 or heads % kv_heads or not reads_in_place(
-            k_pool.shape[3:], v_pool.shape[3:], kv_heads, head_dim,
+            k_pool.shape[3:], pools[-1].shape[3:], kv_heads, head_dim,
             v_head_dim)):
         raise ValueError(
             f"the paged decode kernel reads K and V of whole lane tiles, "
             f"heads of {head_dim} side by side under {heads} query heads, "
-            f"not {k_pool.shape} and {v_pool.shape}")
-    if k_pool.dtype != q.dtype or v_pool.dtype != q.dtype:
+            f"not {' and '.join(str(a.shape) for a in pools)}")
+    if any(a.dtype != q.dtype for a in pools):
         raise ValueError(
             f"the paged decode kernel takes K and V as the query's dtype "
-            f"{q.dtype} with no scales, not {k_pool.dtype} and "
-            f"{v_pool.dtype}")
+            f"{q.dtype} with no scales, not "
+            f"{' and '.join(str(a.dtype) for a in pools)}")
     group = _group_blocks(
         table.shape[1],
         bs * max(k_width, v_width) * k_pool.dtype.itemsize)
+    span = group * bs
     share = heads // kv_heads
     hp = -(-heads // 16) * 16       # whole sublane tiles of rows
     qbd = _block_diagonal(q, kv_heads, hp)
+    operands, in_specs = [qbd], [
+        pl.BlockSpec((1, hp, k_width), lambda s, *_: (s, 0, 0))]
+    if bias is not None:
+        # whole groups of columns: the loop reads a group's span of it
+        bias = jnp.pad(bias.astype(jnp.float32),
+                       ((0, 0), (0, -bias.shape[1] % span)),
+                       constant_values=_NEG_INF)[:, None, :]
+        operands.append(bias)
+        in_specs.append(pl.BlockSpec((1, 1, bias.shape[2]),
+                                     lambda s, *_: (s, 0, 0)))
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(pools)
     acc, m, l = pl.pallas_call(
         functools.partial(_kernel, layer=layer, group=group, bs=bs,
                           heads=heads, kv_heads=kv_heads, head_dim=head_dim,
-                          v_head_dim=v_head_dim),
+                          v_head_dim=v_head_dim, arrays=len(pools),
+                          biased=bias is not None, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(rows,),
-            in_specs=[
-                pl.BlockSpec((1, hp, k_width), lambda s, *_: (s, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
+            in_specs=in_specs,
             out_specs=[
                 pl.BlockSpec((1, share, v_width), lambda s, *_: (s, 0, 0)),
                 pl.BlockSpec((1, heads, 1), lambda s, *_: (s, 0, 0)),
                 pl.BlockSpec((1, heads, 1), lambda s, *_: (s, 0, 0)),
             ],
             scratch_shapes=[
-                _vmem((2, group * bs, k_width), k_pool.dtype),
-                _vmem((2, group * bs, v_width), v_pool.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
+                *(_vmem((2, span, a.shape[-1]), a.dtype) for a in pools),
+                pltpu.SemaphoreType.DMA((len(pools), 2)),
                 _smem((2,), jnp.int32),
                 _vmem((hp, v_width), jnp.float32),
                 _vmem((hp, 1), jnp.float32),
@@ -304,7 +355,7 @@ def paged_decode_partial(q, k_pool, v_pool, layer: int, table, depth):
             dimension_semantics=("arbitrary",)),
         interpret=auto_interpret(),
         name="paged_decode",
-    )(table.astype(jnp.int32), depth.astype(jnp.int32), qbd, k_pool, v_pool)
+    )(table.astype(jnp.int32), depth.astype(jnp.int32), *operands, *pools)
     acc = acc.reshape(rows, share, kv_heads, v_head_dim).swapaxes(1, 2)
     return acc, m, l
 

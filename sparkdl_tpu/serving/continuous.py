@@ -139,6 +139,7 @@ from sparkdl_tpu.observability import slo as slo_mod
 from sparkdl_tpu.observability import tracing
 from sparkdl_tpu.observability.registry import GaugeShare, registry
 from sparkdl_tpu.observability.tracing import span
+from sparkdl_tpu.ops.sparse_attention import attends_in_place
 from sparkdl_tpu.reliability.faults import fault_point
 from sparkdl_tpu.runtime.batching import (
     default_buckets,
@@ -2053,12 +2054,16 @@ class ContinuousGPTEngine:
         (``decode_reads_in_place``) fetches, for each riding row, the
         whole blocks its depth reaches and nothing for any other slot; one
         whose step attends a selection of its own (``selected_columns``)
-        fetches that many columns a slot once any row's table passes it."""
+        fetches that many columns a slot once any row's table passes it,
+        or, while the table is narrow enough for the step to attend in
+        place under the selection as a mask (``attends_in_place``, the
+        rule the module's step asks), the riding rows' whole blocks too."""
         depths = [int(self._pidx[s]) for s in slots]
         bs = self._kv_bs
         fam = self._family
         picks = fam.selected_columns and nb * bs > fam.selected_columns
-        if fam.decode_reads_in_place:
+        if fam.decode_reads_in_place or attends_in_place(
+                nb * bs, fam.selected_columns):
             read = sum(-(-(d + j) // bs) * bs
                        for d in depths for j in range(steps))
         elif picks:

@@ -30,6 +30,7 @@ from sparkdl_tpu.models.glm_moe_dsa import (
     init_glm_moe_dsa_cache,
     rope_interleaved,
 )
+from sparkdl_tpu.ops import sparse_attention
 
 SEED = 2**31 + 44
 TOL = 2e-5   # float32 on the CPU; the logits' standard deviation is 0.16
@@ -165,10 +166,30 @@ def test_chunks_through_the_dense_cache_are_the_whole_forward(bundle):
     assert np.abs(got - want).max() < TOL
 
 
-def test_prefill_then_steps_through_the_pool_are_the_whole_forward(bundle):
+#: the two forms of a step past the selection's size, by the rule
+#: ``sparse_attention.attends_in_place``: the tables here are no wider than
+#: 8 selections (128 columns), which the rule as it stands takes IN PLACE
+#: (the selection a mask, the rows' live blocks read where the pool keeps
+#: them); with its constant at 1 no width is inside it and the step picks
+#: positions and reads them one by one
+FORMS = [pytest.param(None, id="in place under a mask"),
+         pytest.param(1, id="picked positions read one by one")]
+
+
+@pytest.fixture(params=FORMS)
+def in_place(request, monkeypatch):
+    if request.param is not None:
+        monkeypatch.setattr(sparse_attention, "IN_PLACE_SELECTIONS",
+                            request.param)
+    return request.param is None
+
+
+def test_prefill_then_steps_through_the_pool_are_the_whole_forward(
+        bundle, in_place):
     """A row 12 deep (under the selection) and one 56 deep (past it) step
     together through one pool, 30 tokens each: the absorbed form over the
-    selected columns gives the reference's expanded logits."""
+    selected columns gives the reference's expanded logits, in both forms
+    of the step."""
     hf, cfg, model, variables, ids, want = bundle
     got, lens = _paged_cache(cfg, model, variables, ids)
     for r, n in enumerate(lens):
@@ -180,10 +201,11 @@ def _picked_sets(mask_row):
     return set(np.nonzero(mask_row)[0].tolist())
 
 
-def test_the_selection_is_the_references_exactly(bundle):
+def test_the_selection_is_the_references_exactly(bundle, in_place):
     """``S_t`` of every query of both ``full`` layers, from the program's
-    whole forward (a mask) and from a paged step (positions), equals the
-    reference's plain ``top_k`` as a SET, exactly, in float32."""
+    whole forward (a mask) and from a paged step (a mask over the table's
+    columns where it attends in place, positions where it does not), equals
+    the reference's plain ``top_k`` as a SET, exactly, in float32."""
     hf, cfg, model, variables, ids, want = bundle
     with jax.default_matmul_precision("highest"):
         _, extras = ref.glm_hidden(SEED, hf, ids[:1], "float32",
@@ -214,10 +236,15 @@ def test_the_selection_is_the_references_exactly(bundle):
     for layer, kind in enumerate(kinds):
         if kind != FULL:
             continue
-        pos, taken = state["intermediates"][f"layers_{layer}"]["attn"][
+        picked = state["intermediates"][f"layers_{layer}"]["attn"][
             "picked"][0]
+        assert isinstance(picked, tuple) != in_place
         for r, n in enumerate(lens):
-            got = set(np.asarray(pos[r])[np.asarray(taken[r])].tolist())
+            if in_place:
+                got = _picked_sets(np.asarray(picked[r]))
+            else:
+                pos, taken = picked
+                got = set(np.asarray(pos[r])[np.asarray(taken[r])].tolist())
             assert got == _picked_sets(extras["picked"][layer][0][n]), (
                 layer, r)
 

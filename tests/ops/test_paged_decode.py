@@ -342,3 +342,107 @@ def test_a_group_is_two_megabytes_of_blocks_and_no_more_than_the_table():
     assert paged_decode._group_blocks(512, 64 << 20) == 1
     # MiMo-V2-Flash's block of K, the wider array: 24,576 bytes, 64 blocks
     assert paged_decode._group_blocks(512, 16 * 768 * 2) == 64
+
+
+# -- ONE array that is keys and values both, under a bias (ISSUE 45) ----------------
+
+def _latent_plain(pool, layer, table, depth, q, bias, scale):
+    """``(acc, m, l)`` a row and a head in float64 numpy: the row's blocks
+    of the ONE array through its table to its depth, each column the key of
+    its score and the value of the sum, the row's bias added to every
+    head's scores."""
+    rows, heads, width = q.shape
+    cols, q = np.asarray(pool[layer], np.float64), np.asarray(q, np.float64)
+    acc, m, l = (np.zeros((rows, heads, width)),
+                 np.full((rows, heads, 1), -1e30), np.zeros((rows, heads, 1)))
+    for s in range(rows):
+        d = int(depth[s])
+        if d == 0:
+            continue
+        seen = cols[np.asarray(table)[s, :-(-d // BS)]].reshape(-1, width)[:d]
+        x = q[s] @ seen.T * scale
+        if bias is not None:
+            x = x + np.asarray(bias, np.float64)[s, :d]
+        m[s, :, 0] = x.max(-1)
+        p = np.exp(x - m[s])
+        l[s, :, 0], acc[s] = p.sum(-1), p @ seen
+    return acc, m, l
+
+
+@pytest.mark.parametrize("depths, nb, biased, scale", [
+    pytest.param((127, 3, 64, 17), 8, False, None,
+                 id="no bias, the scale of the stored width"),
+    pytest.param((127, 3, 64, 17), 8, True, 0.25,
+                 id="a bias a row and the caller's scale"),
+    pytest.param((0, 33, 32, 31), 8, True, 0.25,
+                 id="a row of depth 0, rows on and past a group's edge"),
+    pytest.param((40, 47, 20, 5), 3, True, 0.25,
+                 id="a table that is no whole count of groups"),
+])
+def test_one_array_is_keys_and_values_both_under_a_bias(
+        monkeypatch, depths, nb, biased, scale):
+    """``paged_decode_partial`` with no V array: four query heads over ONE
+    K/V head of 256 that both products read, a bias ``[S, W]`` (0 on two
+    columns of three, ``-1e30`` on the third) added to every head's scores
+    and the caller's scale, beside the plain softmax's parts."""
+    heads, width = 4, 256
+    _small_groups(monkeypatch, 1, width)
+    rng = np.random.default_rng(1)
+    pool = jnp.asarray(rng.standard_normal((LAYERS, BLOCKS, BS, width)),
+                       jnp.float32)
+    table = np.full((ROWS, nb), BLOCKS - 1, np.int32)
+    perm, at = rng.permutation(BLOCKS), 0
+    for s, d in enumerate(depths):
+        n = -(-d // BS)
+        table[s, :n] = perm[at:at + n]
+        at += n
+    q = jnp.asarray(rng.standard_normal((ROWS, heads, width)), jnp.float32)
+    bias = None
+    if biased:
+        bias = jnp.where(jnp.arange(nb * BS)[None, :] % 3 < 2, 0.0, -1e30
+                         ) * jnp.ones((ROWS, 1))
+    depth = jnp.asarray(depths, jnp.int32)
+    acc, m, l = paged_decode.paged_decode_partial(
+        q, pool, None, 1, jnp.asarray(table), depth, bias=bias, scale=scale)
+    assert acc.shape == (ROWS, 1, heads, width) and acc.dtype == jnp.float32
+    want = _latent_plain(pool, 1, table, depths, q, bias,
+                         1 / np.sqrt(width) if scale is None else scale)
+    for got, ref in zip((acc[:, 0], m, l), want):
+        np.testing.assert_allclose(np.asarray(got), ref, rtol=2e-5,
+                                   atol=2e-5)
+
+
+def test_a_bias_that_leaves_a_whole_group_out_is_undone_by_the_next():
+    """No column of the first group (two blocks) is attended: what the
+    group left (weights of one under a maximum of ``-1e30``) is multiplied
+    by exactly zero when the first attended column comes."""
+    heads, width, depths = 4, 128, (100, 64, 33, 40)
+    rng = np.random.default_rng(2)
+    pool = jnp.asarray(rng.standard_normal((LAYERS, BLOCKS, BS, width)),
+                       jnp.float32)
+    table = jnp.asarray(rng.permutation(BLOCKS)[:ROWS * 8].reshape(ROWS, 8),
+                        jnp.int32)
+    q = jnp.asarray(rng.standard_normal((ROWS, heads, width)), jnp.float32)
+    bias = jnp.where(jnp.arange(8 * BS)[None, :] >= 2 * BS, 0.0, -1e30
+                     ) * jnp.ones((ROWS, 1))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(paged_decode, "_GROUP_BYTES", GROUP * BS * width * 4)
+        got = paged_decode.paged_decode_partial(
+            q, pool, None, 0, table, jnp.asarray(depths, jnp.int32),
+            bias=bias, scale=0.3)
+    want = _latent_plain(pool, 0, table, depths, q, bias, 0.3)
+    for a, b in zip((got[0][:, 0], got[1], got[2]), want):
+        np.testing.assert_allclose(np.asarray(a), b, rtol=2e-5, atol=2e-5)
+
+
+def test_one_array_of_another_shape_or_dtype_raises():
+    pool = jnp.zeros((LAYERS, BLOCKS, BS, 192), jnp.float32)
+    table, depth = jnp.zeros((ROWS, 2), jnp.int32), jnp.ones((ROWS,), jnp.int32)
+    with pytest.raises(ValueError, match="whole lane tiles"):
+        paged_decode.paged_decode_partial(
+            jnp.zeros((ROWS, 4, 192)), pool, None, 0, table, depth)
+    with pytest.raises(ValueError, match="no scales"):
+        paged_decode.paged_decode_partial(
+            jnp.zeros((ROWS, 4, 256), jnp.bfloat16),
+            jnp.zeros((LAYERS, BLOCKS, BS, 256), jnp.float32), None, 0,
+            table, depth)

@@ -7,6 +7,7 @@ table, token by token, with this call's own column standing in at its
 position."""
 
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -143,3 +144,178 @@ def test_the_selected_read_goes_through_the_table_token_by_token():
     np.testing.assert_array_equal(np.asarray(old[0, 4:8]),
                                   np.asarray(pool[2, 17]))
     assert bool(new_seen.all())
+
+
+# -- the step that attends in place (ISSUE 45) --------------------------------------
+
+ROWS, HEADS, CP, BS, BLOCKS, LAYERS, K = 4, 4, 128, 16, 48, 2, 16
+
+
+def _step_case(depths, nb, scores_of, free=(), seed=0):
+    """A ``latent`` pool of random columns, a table that scatters each live
+    row's blocks over it (rows in ``free`` hold the sentinel), one absorbed
+    query and one new column a row, and an indexer's scores over the
+    table's columns as ``scores_of(rng, depths, width)`` makes them, cut to
+    each row's depth with its own column at position ``idx``."""
+    rng = np.random.default_rng(seed)
+    pool = jnp.asarray(rng.standard_normal((LAYERS, BLOCKS, BS, CP)),
+                       jnp.float32)
+    table = np.full((ROWS, nb), BLOCKS, np.int32)
+    perm, at = rng.permutation(BLOCKS), 0
+    for s, d in enumerate(depths):
+        n = 0 if s in free else -(-(d + 1) // BS)
+        table[s, :n] = perm[at:at + n]
+        at += n
+    idx = np.asarray(depths, np.int32)
+    scores = np.asarray(scores_of(rng, idx, nb * BS), np.float32)
+    scores[np.arange(nb * BS)[None, :] > idx[:, None]] = -np.inf
+    cache = {"latent": pool, "table": jnp.asarray(table)}
+    q = jnp.asarray(rng.standard_normal((ROWS, HEADS, CP)), jnp.float32)
+    new = jnp.asarray(rng.standard_normal((ROWS, CP)), jnp.float32)
+    return cache, jnp.asarray(idx), jnp.asarray(scores), q, new
+
+
+def _random(rng, idx, width):
+    return rng.standard_normal((ROWS, width))
+
+
+def _tied(rng, idx, width):
+    # four values over 128 columns: the k-th place is always a tie
+    return rng.integers(0, 4, (ROWS, width))
+
+
+def _own(score):
+    def scores_of(rng, idx, width):
+        out = rng.standard_normal((ROWS, width))
+        out[np.arange(ROWS), idx] = score
+        return out
+    scores_of.own_picked = score > 0
+    return scores_of
+
+
+def _nothing_in_the_first_group(rng, idx, width):
+    # a group is two blocks here: no row picks any of its first 32 columns
+    out = rng.standard_normal((ROWS, width))
+    out[:, :2 * BS] -= 100
+    return out
+
+
+@pytest.mark.parametrize("depths, nb, scores_of, free", [
+    pytest.param((127, 3, 64, 40), 8, _random, (),
+                 id="rows at very different depths"),
+    pytest.param((10, 15, 16, 17), 8, _random, (),
+                 id="rows no deeper than the selection, and just past it"),
+    pytest.param((100, 33, 64, 16), 8, _tied, (),
+                 id="ties at the k-th score go to the lower position"),
+    pytest.param((100, 33, 64, 20), 8, _own(50.0), (),
+                 id="the own column picked"),
+    pytest.param((100, 33, 64, 20), 8, _own(-50.0), (),
+                 id="the own column not picked"),
+    pytest.param((100, 70, 64, 50), 8, _nothing_in_the_first_group, (),
+                 id="a first group that holds no picked column"),
+    pytest.param((100, 5, 64, 0), 8, _random, (1,),
+                 id="a row with no block, and one at depth 0"),
+    pytest.param((31, 32, 33, 47), 3, _random, (),
+                 id="a table that is no whole count of groups"),
+])
+@pytest.mark.parametrize("layer", [0, 1])
+def test_in_place_under_a_mask_is_the_selected_read_and_the_absorbed_attention(
+        monkeypatch, depths, nb, scores_of, free, layer):
+    """``attend_in_place`` (the rows' live blocks read where the pool keeps
+    them, the selection a mask) beside ``selected_columns`` +
+    ``absorbed_attention`` (the same selection as positions, read one by
+    one), float32 under the Pallas interpreter, groups of two blocks: the
+    same mix, whatever the rows' depths, ties, the own column's fate and
+    where in the table the picked columns lie."""
+    from sparkdl_tpu.ops import paged_decode, sparse_attention
+
+    monkeypatch.setattr(paged_decode, "_GROUP_BYTES", 2 * BS * CP * 4)
+    cache, idx, scores, q, new = _step_case(depths, nb, scores_of, free)
+    scale = 1 / math.sqrt(20)
+    mask = select_mask(scores, K)
+    pos, taken = pick_columns(scores, K)
+    want = absorbed_attention(
+        q, *selected_columns(dict(cache, idx=idx), layer, (pos, taken),
+                             idx)[:2], new,
+        (taken & (pos == idx[:, None])).any(-1), scale)
+    got = sparse_attention.attend_in_place(cache, layer, q, mask, idx, new,
+                                           scale)
+    assert got.shape == want.shape == (ROWS, HEADS, CP)
+    assert got.dtype == jnp.float32
+    live = [r for r in range(ROWS) if r not in free]
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               rtol=2e-5, atol=2e-5)
+    # the cases are what their names say
+    own_picked = np.asarray(mask)[np.arange(ROWS), np.asarray(idx)]
+    if scores_of is _tied:
+        kth = np.sort(np.asarray(scores), -1)[:, -K]
+        assert ((np.asarray(scores) == kth[:, None]).sum(-1)[:3] > 1).all()
+    if scores_of is _nothing_in_the_first_group:
+        assert not np.asarray(mask)[:, :2 * BS].any()
+    if hasattr(scores_of, "own_picked"):
+        # (row 3 is 20 deep, 21 columns with its own: over the selection's
+        # 16 either way; rows 0-2 are deeper)
+        assert (own_picked == scores_of.own_picked).all()
+    # a row that holds no block read nothing: its own column alone
+    for r in free:
+        np.testing.assert_allclose(
+            np.asarray(got)[r], np.broadcast_to(np.asarray(new)[r],
+                                                (HEADS, CP)), rtol=1e-6)
+
+
+@pytest.mark.parametrize("width, selection, taken", [
+    (2048, 2048, False),        # no wider than the selection: nothing picked
+    (4096, 2048, True), (16384, 2048, True),
+    (16400, 2048, False), (32768, 2048, False),
+    (64, 16, True), (128, 16, True), (144, 16, False),
+])
+def test_the_rule_is_a_tables_width_in_selections(width, selection, taken):
+    from sparkdl_tpu.ops.sparse_attention import (
+        IN_PLACE_SELECTIONS,
+        attends_in_place,
+    )
+
+    assert IN_PLACE_SELECTIONS == 8
+    assert attends_in_place(width, selection) is taken
+
+
+@pytest.mark.parametrize("nb, in_place", [(8, True), (16, False)])
+def test_a_step_holds_the_kernel_inside_the_rule_and_the_gather_past_it(
+        nb, in_place):
+    """The tiny family's paged step (a selection of 16 columns, blocks of
+    16) over a table of 8 selections and over one of 16, as traced: inside
+    the rule every layer calls the kernel and nothing gathers ``[rows x 16,
+    128]`` columns one by one or sorts; past it every layer gathers them,
+    each ``full`` layer sorts, and nothing calls the kernel."""
+    from sparkdl_tpu.models.glm_moe_dsa import (
+        GlmMoeDsaConfig,
+        GlmMoeDsaLMHeadModel,
+    )
+
+    cfg = GlmMoeDsaConfig.tiny()
+    model = GlmMoeDsaLMHeadModel(cfg)
+    variables = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    rows, blocks = 2, 64
+
+    def step(variables, latent, index_k, table, idx, tok):
+        return model.apply(variables, tok, cache={
+            "latent": latent, "index_k": index_k, "table": table,
+            "idx": idx})[0]
+
+    text = str(jax.make_jaxpr(step)(
+        variables,
+        jax.ShapeDtypeStruct((cfg.num_layers, blocks, 16, 128), jnp.float32),
+        jax.ShapeDtypeStruct((cfg.full_layers, blocks, 16, 16), jnp.float32),
+        jax.ShapeDtypeStruct((rows, nb), jnp.int32),
+        jax.ShapeDtypeStruct((rows,), jnp.int32),
+        jax.ShapeDtypeStruct((rows, 1), jnp.int32)))
+    kernels = text.count("pallas_call[")
+    picked_reads = len(re.findall(r":f32\[2,16,128\] = gather", text))
+    # (the selection's: the experts' routers take their top 2)
+    sorts = len(re.findall(r"= top_k\[axis=1 k=16\]", text))
+    if in_place:
+        assert (kernels, picked_reads, sorts) == (cfg.num_layers, 0, 0)
+    else:
+        assert (kernels, picked_reads, sorts) == (0, cfg.num_layers,
+                                                  cfg.full_layers)

@@ -131,6 +131,39 @@ def test_the_pool_holds_the_familys_own_arrays_over_their_own_layers(
     assert tuple(kv_pool.init_block_pool(cfg, 2, 4)) == NAMES
 
 
+@pytest.mark.parametrize("selections, read", [
+    # rows 5, 40 and 90 deep ride a table of 8 blocks (8 selections of 16)
+    pytest.param(None, 16 + 48 + 96, id="inside the rule: the riding rows' "
+                                        "whole blocks"),
+    pytest.param(4, 4 * 16, id="past it: the selection's size a slot"),
+])
+def test_the_count_of_a_steps_reads_follows_the_rule(family, monkeypatch,
+                                                     selections, read):
+    """``_count_kv_read`` asks ``sparse_attention.attends_in_place`` as the
+    module's step does: what a tick FETCHES follows the form, what it
+    ATTENDS and must score do not."""
+    from sparkdl_tpu.ops import sparse_attention
+
+    _, cfg, variables, _ = family
+    if selections is not None:
+        monkeypatch.setattr(sparse_attention, "IN_PLACE_SELECTIONS",
+                            selections)
+    eng = ContinuousGPTEngine(cfg, variables, n_slots=4, max_len=128,
+                              auto_start=False)
+    try:
+        eng._pidx[:3] = (5, 40, 90)
+        got = eng._count_kv_read(8, [0, 1, 2])
+        # one block: the table is no wider than the selection, every
+        # slot's is gathered whole whatever the rule says
+        shallow = eng._count_kv_read(1, [0])
+    finally:
+        eng.close()
+    assert got["kv_cols_read"] == read
+    assert (got["kv_cols_live"], got["sel_cols"], got["index_cols"]) == (
+        135, 5 + 16 + 16, 40 + 90)
+    assert shallow["kv_cols_read"] == 4 * 16
+
+
 def test_the_spans_count_selected_and_scored_columns(served, family):
     _, cfg, _, _ = family
     fam = cfg.serving_family()
@@ -145,19 +178,26 @@ def test_the_spans_count_selected_and_scored_columns(served, family):
         # scored: the whole context of the rows deeper than the selection
         assert 0 <= a["index_cols"] <= a["kv_cols_live"]
         assert "index_cols_read" not in a      # no reader: no counter
-        # fetched for the attention: the selection's size a slot once any
-        # row's table passes it, the table's width before
-        assert a["kv_cols_read"] == (4 * 16 if picks else 4 * a["nb"] * 16
-                                     ) * a["chain"]
+        # fetched for the attention: every slot's table before any row's
+        # passes the selection; past it (a table of this engine is at most
+        # 8 selections wide, which the step attends IN PLACE) the riding
+        # rows' whole blocks and no other slot's
+        if picks:
+            assert a["kv_cols_live"] <= a["kv_cols_read"] < (
+                a["kv_cols_live"] + 16 * a["slots"] * a["chain"])
+            assert a["kv_cols_read"] % 16 == 0
+        else:
+            assert a["kv_cols_read"] == 4 * a["nb"] * 16 * a["chain"]
         assert a["expert_pairs"] == 4 * 2 * a["chain"]
         assert 0 <= a["experts_hit"] <= fam.experts * a["chain"]
     deep = [a for a in steps if a["slots"] == 1 and a["chain"] == 1
             and a["kv_cols_live"] > 16]
     assert deep and all(a["sel_cols"] == 16 for a in deep)
     assert all(a["index_cols"] == a["kv_cols_live"] for a in deep)
-    # where the selection bites a step fetches FEWER columns than are live
+    # where the selection bites a step ATTENDS fewer columns than are live
+    # (and, in place, fetches them all)
     busy = [a for a in steps if a["kv_cols_live"] > 4 * 16 * a["chain"]]
-    assert busy and all(a["kv_cols_read"] < a["kv_cols_live"] for a in busy)
+    assert busy and all(a["sel_cols"] < a["kv_cols_live"] for a in busy)
     shallow = [a for a in steps if a["slots"] == 1 and a["nb"] == 1]
     assert shallow and all(a["index_cols"] == 0 and
                            a["sel_cols"] == a["kv_cols_live"]

@@ -370,11 +370,13 @@ def compiled_hybrid(one_chip, for_the_chip, monkeypatch_module):
             for name, a in pool.items() if name in ("state", "conv")}
         head = (_on(one_chip, variables), _on(one_chip, pool))
         step = (ints(slots, mb), ints(slots), ints(slots), ints(slots))
+        lowered = eng._paged_step_fn.lower(*head, *step, 1, mb)
         return {
             "pool": pool,
             "stored": {name: _device_layout(one_chip, a)
                        for name, a in pool.items()},
-            "step": eng._paged_step_fn.lower(*head, *step, 1, mb).compile(),
+            "step": lowered.compile(),
+            "step_lowered": lowered.as_text(),
             "mid": eng._chunk_mid_fn.lower(
                 head[0], private, private, ints(), ints(1, 256), 4096,
                 ints(), rec).compile(),
@@ -629,7 +631,7 @@ def _mimo_engine(monkeypatch_module):
 
 
 def _mimo_step(eng, variables, one_chip):
-    """The engine's decode step at 512 blocks a row, compiled."""
+    """The engine's decode step at 512 blocks a row, lowered."""
     def ints(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
@@ -637,7 +639,7 @@ def _mimo_step(eng, variables, one_chip):
     return eng._paged_step_fn.lower(
         _on(one_chip, variables), _on(one_chip, eng._pool_kv),
         ints(slots, eng._mb), ints(slots), ints(slots), ints(slots),
-        1, 512).compile()
+        1, 512)
 
 
 @pytest.fixture(scope="module")
@@ -666,11 +668,13 @@ def compiled_mimo(one_chip, for_the_chip, monkeypatch_module):
             (1, 1) + a.shape[2:], a.dtype, sharding=one_chip)
             for name, a in pool.items() if name in ("win_k", "win_v")}
         head = (_on(one_chip, variables), _on(one_chip, pool))
+        lowered = _mimo_step(eng, variables, one_chip)
         return {
             "pool": pool,
             "stored": {name: _device_layout(one_chip, a)
                        for name, a in pool.items()},
-            "step": _mimo_step(eng, variables, one_chip),
+            "step": lowered.compile(),
+            "step_lowered": lowered.as_text(),
             "mid": eng._chunk_mid_fn.lower(
                 head[0], private["k"], private["v"], ints(), ints(1, 256),
                 8192, ints(), rec).compile(),
@@ -693,7 +697,7 @@ def gathered_mimo_step(one_chip, for_the_chip):
         mp.setattr(paged_decode, "reads_in_place", lambda *a: False)
         eng, variables = _mimo_engine(mp)
         try:
-            return _mimo_step(eng, variables, one_chip)
+            return _mimo_step(eng, variables, one_chip).compile()
         finally:
             eng.close()
 
@@ -893,14 +897,15 @@ def compiled_lfm2(one_chip, for_the_chip, monkeypatch_module):
             *head, private["k"], private["v"], ints(), ints(1, 256),
             ints(mb), 4096, ints(), rec, ints()),
     }
-    done = {}
+    done, lowered = {}, {}
 
     def get(which):
         if which not in done:
-            done[which] = lower[which]().compile()
+            lowered[which] = lower[which]()
+            done[which] = lowered[which].compile()
         return done[which]
 
-    get.pool = pool
+    get.pool, get.lowered = pool, lowered
     try:
         yield get
     finally:
@@ -1017,20 +1022,25 @@ def test_the_benchmarks_reader_finds_the_convolutions_operations(
 @pytest.fixture(scope="module")
 def compiled_glm(one_chip, for_the_chip, monkeypatch_module):
     """``get(which)``: the decode step (1,024 blocks a row, the table's whole
-    width), a MID chunk and a FINAL chunk (it installs both arrays into the
-    slot's blocks) at 16,384 columns of the benchmark's 32 x 16384 engine
-    over ALL FIVE layers of its cut at GLM-5.2's widths (16 of 256 experts
-    held), compiled for the chip when first asked for. The engine here is
-    built over a pool of 64 blocks (nothing of the published pool's 3.6 GB
-    is allocated on this CPU); the programs are lowered for the benchmark's
-    32,768."""
+    width: 8 selections, which the rule ``sparse_attention.attends_in_place``
+    takes), the same step as it was before the rule (ISSUE 45:
+    ``step_gathered``, from a second engine built with the rule's constant
+    at 1, so that no width is inside it), a MID chunk and a FINAL chunk (it
+    installs both arrays into the slot's blocks) at 16,384 columns of the
+    benchmark's 32 x 16384 engine over ALL FIVE layers of its cut at
+    GLM-5.2's widths (16 of 256 experts held), compiled for the chip when
+    first asked for. The engines here are built over a pool of 64 blocks
+    (nothing of the published pool's 3.6 GB is allocated on this CPU); the
+    programs are lowered for the benchmark's 32,768."""
     from sparkdl_tpu.models.glm_moe_dsa import (
         GlmMoeDsaConfig,
         GlmMoeDsaLMHeadModel,
     )
+    from sparkdl_tpu.ops import paged_decode, sparse_attention
     from sparkdl_tpu.parallel import moe_dropless
 
     monkeypatch_module.setattr(moe_dropless, "auto_interpret", lambda: False)
+    monkeypatch_module.setattr(paged_decode, "auto_interpret", lambda: False)
     cfg = GlmMoeDsaConfig(
         vocab_size=19456,
         indexer_types=("full", "shared", "shared", "shared", "full"),
@@ -1053,9 +1063,26 @@ def compiled_glm(one_chip, for_the_chip, monkeypatch_module):
         (pool[name].shape[0], 1, eng._wp) + pool[name].shape[3:],
         jnp.bfloat16, sharding=one_chip) for name in ("latent", "index_k")]
     head = (_on(one_chip, variables), pool)
+
+    def step(engine):
+        return engine._paged_step_fn.lower(
+            *head, ints(32, mb), ints(32), ints(32), ints(32), 1, mb)
+
+    def step_gathered():
+        # (another engine: the first one's step is traced already)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(sparse_attention, "IN_PLACE_SELECTIONS", 1)
+            other = ContinuousGPTEngine(cfg, variables, n_slots=32,
+                                        max_len=16384, kv_blocks=64,
+                                        auto_start=False)
+            try:
+                return step(other)
+            finally:
+                other.close()
+
     lower = {
-        "step": lambda: eng._paged_step_fn.lower(
-            *head, ints(32, mb), ints(32), ints(32), ints(32), 1, mb),
+        "step": lambda: step(eng),
+        "step_gathered": step_gathered,
         "mid": lambda: eng._chunk_mid_fn.lower(
             head[0], *private, ints(), ints(1, 256), 16384),
         "final": lambda: eng._chunk_final_fn.lower(
@@ -1113,16 +1140,63 @@ def test_the_latent_pool_and_the_indexers_keys_are_written_where_they_lie(
             < 13.0e9)
 
 
-def test_the_glm_step_reads_selected_columns_alone_and_sorts_once_a_full_layer(
+def test_the_glm_step_attends_its_rows_live_blocks_in_the_pool_under_the_mask(
         compiled_glm):
-    """What the cell will say of this path: each of the five layers reads
-    32 x 2,048 SELECTED columns of 640 one by one and never the rows' 16,384
-    (a whole row's ``latent`` through the table would be ``[32, 16384,
-    640]``: 0.67 GB a layer); the two ``full`` layers read their keys
-    through the table and sort a row's scores once; the experts' three
-    products a layer are the grouped matmul kernel."""
+    """A table of 16,384 columns is 8 selections, inside the rule: each of
+    the five layers calls the paged kernel ONCE over the ``latent`` array
+    alone (the step's own parameter, layer by layer: no copy, no slice, no
+    V), under a bias ``f32[32,1,16384]`` from the selection's mask; no
+    layer reads 32 x 2,048 columns one by one, nothing sorts a row's
+    scores (the selection is the bisection's 32 passes), and what the step
+    holds beside the pool is less than the gathers'."""
     text = compiled_glm("step").as_text()
     entry = text[text.index("\nENTRY "):]
+    calls = re.findall(
+        r"%paged_decode[.\d]* = \(f32\[32,64,640\]\S*, f32\[32,64,1\]\S*, "
+        r"f32\[32,64,1\]\S*\) custom-call\(([^)]*)\), "
+        r"custom_call_target=\"tpu_custom_call\", "
+        r"operand_layout_constraints=\{(.*?)\}\}", text)
+    assert len(calls) == 5
+    for operands, layouts in calls:
+        operands = [a.strip() for a in operands.split(",")]
+        # table, depths, the absorbed query, the bias, the pool: one array
+        assert len(operands) == 5
+        assert re.search(
+            r"%s = bf16\[5,32768,16,640\]\{3,2,1,0:T\(8,128\)\(2,1\)\} "
+            r"parameter\(" % re.escape(operands[-1]), text), operands[-1]
+        assert "f32[32,1,16384]" in layouts and "bf16[32,64,640]" in layouts
+    assert not re.search(r"bf16\[65536,640\]", text)
+    assert not re.search(r"s32\[65536\]", entry)
+    for rows in ((32, 16384, 640), (32, 1024, 16, 640), (32768, 16, 640),
+                 (32, 2048, 640)):
+        assert _made(text, rows) == [], rows
+    assert not re.search(r"f32\[32,16384\]\S*, s32\[32,16384\]\S*\) sort\(",
+                         text)
+    # the keys still come through the table, twice
+    assert len(_made(text, (32, 16384, 128))) >= 2
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) == 4 * 3
+    for scope in ("dsa_indexer", "dsa_select", "dsa_attend_in_place"):
+        assert scope in text, scope
+    for scope in ("dsa_selected_read", "dsa_absorbed_attention",
+                  "dsa_expanded_attention"):
+        assert scope not in text, scope
+    assert (compiled_glm("step").memory_analysis().temp_size_in_bytes
+            <= compiled_glm("step_gathered").memory_analysis()
+            .temp_size_in_bytes)
+
+
+def test_a_glm_step_the_rule_refuses_reads_selected_columns_alone_and_sorts(
+        compiled_glm):
+    """What the step was until the rule, and is past it: each of the five
+    layers reads 32 x 2,048 SELECTED columns of 640 one by one and never
+    the rows' 16,384 (a whole row's ``latent`` through the table would be
+    ``[32, 16384, 640]``: 0.67 GB a layer); the two ``full`` layers read
+    their keys through the table and sort a row's scores once; the experts'
+    three products a layer are the grouped matmul kernel; no paged
+    kernel."""
+    text = compiled_glm("step_gathered").as_text()
+    entry = text[text.index("\nENTRY "):]
+    assert not re.search(r"%paged_decode[.\d]* = ", text)
     assert len(re.findall(r"= bf16\[65536,640\]\S* fusion\(", entry)) == 5
     for rows in ((32, 16384, 640), (32, 1024, 16, 640), (32768, 16, 640)):
         assert _made(text, rows) == [], rows
@@ -1135,6 +1209,7 @@ def test_the_glm_step_reads_selected_columns_alone_and_sorts_once_a_full_layer(
                   "dsa_absorbed_attention"):
         assert scope in text, scope
     assert "dsa_expanded_attention" not in text
+    assert "dsa_attend_in_place" not in text
     mid = compiled_glm("mid").as_text()
     assert "dsa_expanded_attention" in mid and "dsa_indexer" in mid
     # a chunk's selection is a count a bit, not a sort of 256 x 16,384
@@ -1142,11 +1217,57 @@ def test_the_glm_step_reads_selected_columns_alone_and_sorts_once_a_full_layer(
                          mid)
 
 
+def _entry_instructions(compiled):
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    return [ln.strip().split(", metadata=")[0] for ln in entry.splitlines()
+            if " = " in ln and " parameter(" not in ln]
+
+
+def test_the_benchmarks_readers_find_the_kernel_and_the_masks_operations(
+        compiled_glm):
+    """``benchmark/readers_glm_moe_dsa.is_sparse_attn_op`` and
+    ``is_indexer_op`` (imported as they stand) on the ENTRY instructions of
+    the step that attends in place: the first takes each layer's kernel
+    call (by its result ``f32[32,64,640]``), the absorbed query and the
+    mix, and nothing of 2,048; the second the keys' gather, the scores, the
+    bisection's passes and the bias made of the mask; neither takes the
+    other's, the experts', the projections' or the logits."""
+    from benchmark import manifest as mf
+    from benchmark import readers_glm_moe_dsa as readers_g
+    from benchmark.runners import serve_glm_moe_dsa
+
+    hf = serve_glm_moe_dsa.hf_config(
+        mf.resolve_cell("glm52-sparse-agent-backlog").config)
+    lines = _entry_instructions(compiled_glm("step"))
+    index = [ln for ln in lines if readers_g.is_indexer_op(ln, hf, 32)]
+    attend = [ln for ln in lines if readers_g.is_sparse_attn_op(ln, hf, 32)]
+    assert not set(index) & set(attend)
+    kernels = [ln for ln in lines if ln.startswith("%paged_decode")]
+    assert len(kernels) == 5 and all(ln in attend for ln in kernels)
+    assert not any("2048" in ln.split(" = ")[1].split(" ")[0]
+                   for ln in attend)
+    assert sum("f32[32,64,640]" in ln or "bf16[32,64,640]" in ln
+               for ln in attend) >= 10
+    assert not any(" sort(" in ln or " while(" in ln for ln in index + attend)
+    assert sum("bf16[32768,16,128]" in ln and "index_k" in ln
+               for ln in index) == 2
+    # a row's scores as the bisection orders them, and the bias made of
+    # the mask, each once a ``full`` layer
+    for made in ("u32[32,16384]", "f32[32,1,16384]"):
+        assert sum(ln.split(" = ")[1].startswith(made) and " fusion(" in ln
+                   for ln in index) == 2, made
+    for ln in index + attend:
+        assert "gmm" not in ln.split(" = ")[0]
+        assert "19456" not in ln.split(" = ")[1].split(" ")[0], ln[:200]
+        assert "[32,16384]{1,0:T(8,128)(2,1)" not in ln, ln[:200]   # q itself
+
+
 def test_the_benchmarks_readers_find_the_two_stages_operations(compiled_glm):
     """``benchmark/readers_glm_moe_dsa.is_indexer_op`` and
     ``is_sparse_attn_op`` (imported as they stand) on the instructions of
-    the step's ENTRY computation, which are what a device trace holds an
-    event for: the first takes the keys' gather, the scores and the sort,
+    the GATHERED step's ENTRY computation, which are what a device trace
+    holds an event for: the first takes the keys' gather, the scores and the sort,
     the second the selected read, the scores over 2,048 and the mix; neither
     takes the other's, the experts', the projections' or the logits."""
     from benchmark import manifest as mf
@@ -1155,10 +1276,7 @@ def test_the_benchmarks_readers_find_the_two_stages_operations(compiled_glm):
 
     hf = serve_glm_moe_dsa.hf_config(
         mf.resolve_cell("glm52-sparse-agent-backlog").config)
-    text = compiled_glm("step").as_text()
-    entry = text[text.index("\nENTRY "):]
-    lines = [ln.strip().split(", metadata=")[0] for ln in entry.splitlines()
-             if " = " in ln and " parameter(" not in ln]
+    lines = _entry_instructions(compiled_glm("step_gathered"))
     index = [ln for ln in lines if readers_g.is_indexer_op(ln, hf, 32)]
     attend = [ln for ln in lines if readers_g.is_sparse_attn_op(ln, hf, 32)]
     assert not set(index) & set(attend)
@@ -1178,3 +1296,67 @@ def test_the_benchmarks_readers_find_the_two_stages_operations(compiled_glm):
         assert "gmm" not in ln.split(" = ")[0]
         assert "19456" not in ln.split(" = ")[1].split(" ")[0], ln[:200]
         assert "[32,16384]{1,0:T(8,128)(2,1)" not in ln, ln[:200]   # q itself
+
+
+# -- the families that ran the paged kernel before it took one array (ISSUE 45) -----
+
+#: sha256 (first 16 hex digits) of the per-slot step's LOWERED text at each
+#: fixture's shapes, read at the PARENT of the PR that gave
+#: ``ops/paged_decode.py`` its one-array form (this installation's jax): the
+#: text as ``Lowered.as_text()`` gives it, each Mosaic kernel's body decoded
+#: and printed without its locations (a body holds the line numbers of the
+#: kernel's source, which moved)
+PARENT_STEP_TEXT = {
+    "olmo_hybrid": "b923a07e35d9284b",
+    "mimo_v2_flash": "8a128b807a1eb698",
+    "lfm2_moe": "e8950da616acd302",
+}
+_QUOTE = r'(?:\\22|\\?")'
+_BODY = re.compile(f"({_QUOTE}body{_QUOTE}: *{_QUOTE})([A-Za-z0-9+/=]+)"
+                   f"({_QUOTE})")
+
+
+def _without_locations(lowered_text):
+    """The lowered text with every Mosaic kernel's serialized body replaced
+    by its operations as text, locations dropped; and how many there were."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    def plain(match):
+        context = mlir.make_ir_context()
+        context.allow_unregistered_dialects = True
+        with context:
+            module = ir.Module.parse(base64.b64decode(match.group(2)))
+            return (match.group(1)
+                    + module.operation.get_asm(enable_debug_info=False)
+                    + match.group(3))
+
+    return _BODY.subn(plain, lowered_text)
+
+
+@pytest.mark.parametrize("family, fixture, kernels", [
+    ("olmo_hybrid", "compiled_hybrid", 1),
+    ("mimo_v2_flash", "compiled_mimo", 2),
+    ("lfm2_moe", "compiled_lfm2", 2),
+])
+def test_the_steps_that_ran_the_kernel_lower_to_the_parents_text(
+        request, family, fixture, kernels):
+    """Olmo-Hybrid's, MiMo-V2-Flash's and LFM2's steps call
+    ``paged_decode_partial`` with K and V, no bias and no scale: the
+    kernel's three new parameters are resolved while it is traced, and the
+    step programs are the parent's, operation for operation."""
+    import hashlib
+
+    held = request.getfixturevalue(fixture)
+    if callable(held):
+        held("step")
+        text = held.lowered["step"].as_text()
+    else:
+        text = held["step_lowered"]
+    plain, bodies = _without_locations(text)
+    assert bodies >= kernels
+    assert plain.count("module @paged_decode") == kernels
+    assert (hashlib.sha256(plain.encode()).hexdigest()[:16]
+            == PARENT_STEP_TEXT[family])
