@@ -10,8 +10,13 @@ results are un-sorted and summed per token in selection order. A token's
 result is a function of its own row alone, whoever shares the batch.
 
 The grouped product on a TPU is the Pallas grouped matmul (megablox
-``gmm``) at 128 x 1024 x 1024 tiles: on the v5e, at an expert layer of 128
-experts of 2048 x 1024 in bfloat16, it took 1.87 ms at a decode tick's 256
+``gmm``) at row tiles of 128 and the largest kernel block of at most 1,024
+x 1,024 elements whose sides DIVIDE the kernels' two dimensions
+(:func:`gmm_tiling`: 1,024 x 1,024 at widths of 1,024, 2,048, 4,096 and
+6,144; 2,048 x 512 and 512 x 2,048 at LFM2's 1,536, where a tile of 1,024
+was half pad along the columns and left the contraction a masked
+remainder). At 128 x 1024 x 1024, on the v5e, an expert layer of 128
+experts of 2048 x 1024 in bfloat16 took 1.87 ms at a decode tick's 256
 rows and 2.55 ms at a chunk's 2,048 where ``jax.lax.ragged_dot`` took 2.83
 and 5.34 (PERF.md section 6, PR 29; the kernels' 1.3-1.6 GB need 1.6-2.0
 ms). Under the explicit-CPU harness it is ``ragged_dot``, the plain XLA
@@ -30,8 +35,36 @@ import jax.numpy as jnp
 
 from sparkdl_tpu.ops._pallas import auto_interpret
 
-#: rows, contracted and output columns a tile of the grouped matmul
+#: the rows of a tile of the grouped matmul, and the sides of the LARGEST
+#: block of a kernel one takes (:func:`gmm_tiling`)
 GMM_TILING = (128, 1024, 1024)
+
+
+def gmm_tiling(k: int, n: int) -> "tuple[int, int, int]":
+    """The grouped matmul's tile for kernels ``[.., k, n]`` (multiples of
+    128 both): the row tile and, of the kernel blocks ``[tk, tn]`` whose
+    sides DIVIDE ``k`` and ``n`` and that hold no more than 1,024 x 1,024
+    elements, the largest; of equals the squarest. No column tile is then
+    part pad (the kernel multiplies a tile whole) and no contraction ends in
+    a remainder (which the kernel masks, both operands converted to float32
+    and back, on every expert visit). Every ``k`` and ``n`` that 1,024
+    divides keeps 1,024 x 1,024; LFM2's 2,048 x 1,536 gets 2,048 x 512 and
+    its 1,536 x 2,048 gets 512 x 2,048 (on the v5e each within 0.3% of
+    the fastest of eight dividing blocks, 10 and 19% under 1,024 x 1,024,
+    in the 2 MB a block that 1,024 x 1,024 takes: PERF.md section 6, PR
+    46). Where the largest such block is under half of what 1,024 x
+    1,024 cut to the kernel holds (both sides 128 x a prime), the tile is
+    that one with its pad and its remainder, as every shape's was: no shape
+    is worse off than it was."""
+    tm, tk_most, tn_most = GMM_TILING
+    tk, tn = max(((tk, tn) for tk in range(128, k + 1, 128) if k % tk == 0
+                  for tn in range(128, n + 1, 128) if n % tn == 0
+                  if tk * tn <= tk_most * tn_most),
+                 key=lambda b: (b[0] * b[1], -abs(b[0] - b[1])))
+    cut = min(tk_most, k), min(tn_most, n)
+    if 2 * tk * tn < cut[0] * cut[1]:
+        return tm, *cut
+    return tm, tk, tn
 
 
 def grouped_dot(xs: jax.Array, kernels: jax.Array,
@@ -40,13 +73,11 @@ def grouped_dot(xs: jax.Array, kernels: jax.Array,
     times ``kernels[g]`` [K, N] -> [M, N] in ``xs``'s dtype. ``M`` is a
     multiple of the row tile; rows past the runs' end hold no result."""
     k, n = kernels.shape[1:]
-    tm, tk, tn = GMM_TILING
-    if auto_interpret() or k % 128 or n % 128 or xs.shape[0] % tm:
+    if auto_interpret() or k % 128 or n % 128 or xs.shape[0] % GMM_TILING[0]:
         return jax.lax.ragged_dot(xs, kernels, group_sizes)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-    return gmm(xs, kernels, group_sizes, xs.dtype,
-               (tm, min(tk, k), min(tn, n)))
+    return gmm(xs, kernels, group_sizes, xs.dtype, gmm_tiling(k, n))
 
 
 def route_sigmoid_topk(h: jax.Array, router_kernel: jax.Array,

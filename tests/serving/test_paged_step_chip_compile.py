@@ -138,6 +138,17 @@ def _made(text, dims):
         r"= (\w+\[%s\]\{[^ ]*\}) ([\w\-]+)\(" % want, text)]
 
 
+def _gmm_kernel_blocks(lowered_text):
+    """The kernel block ``[tk, tn]`` of each grouped matmul a LOWERED text
+    holds (one for each shape of kernels: the calls of one shape share a
+    function), as ``"2048x512"``, read off the signature of its decoded
+    Mosaic body (megablox names its kernel ``kernel``)."""
+    plain, _ = _without_locations(lowered_text)
+    return re.findall(
+        r"module @kernel [^\n]*\n[^\n]*\n *\^bb0\([^\n]*?"
+        r"memref<1x(\d+x\d+)xbf16, #tpu\.memory_space<vmem>>", plain)
+
+
 def test_the_merged_axis_keeps_layers_and_blocks_major_on_the_chip(
         one_chip, compiled):
     pool = compiled["pool"]
@@ -276,13 +287,14 @@ def compiled_afmoe(one_chip, for_the_chip, monkeypatch_module):
         mb = max_len // 16
         private = jax.ShapeDtypeStruct(
             (2, 1, eng._wp, 4, 128), jnp.bfloat16, sharding=one_chip)
+        lowered = eng._paged_step_fn.lower(
+            _on(one_chip, variables), _on(one_chip, pool),
+            ints(slots, mb), ints(slots), ints(slots), ints(slots), 1, mb)
         return {
             "pool": pool["k"],
             "stored": _device_layout(one_chip, pool["k"]),
-            "step": eng._paged_step_fn.lower(
-                _on(one_chip, variables), _on(one_chip, pool),
-                ints(slots, mb), ints(slots), ints(slots), ints(slots), 1,
-                mb).compile(),
+            "step": lowered.compile(),
+            "step_lowered": lowered.as_text(),
             "one": eng._chunk_one_fn.lower(
                 _on(one_chip, variables), _on(one_chip, pool), ints(mb),
                 ints(), ints(1, 128), ints(mb), 128).compile(),
@@ -321,6 +333,9 @@ def test_the_expert_products_are_the_grouped_matmul_kernel(compiled_afmoe):
     # padded to a row tile of 128
     calls = re.findall(r"%gmm[.\d]* = bf16\[128,(\d+)\]", text)
     assert sorted(calls) == ["1024", "1024", "2048"]
+    # widths of 2,048 and 1,024 keep the kernel blocks they had (ISSUE 46)
+    assert _gmm_kernel_blocks(compiled_afmoe["step_lowered"]) == [
+        "1024x1024"] * 2
     # a sliding layer gathers the table entries its window covers, 129 of
     # the 512 a full layer gathers
     assert _made(text, (4, 129, 16, 4, 128)) and _made(text,
@@ -959,8 +974,8 @@ def test_the_lfm2_step_reads_k_and_v_in_the_pool_and_runs_the_grouped_matmul(
     head need not be), so each attention layer's one-token attention is the
     paged kernel, handed the pool's own buffers, and no slot's rows are
     gathered at the bucket's depth; the experts' three products a layer are
-    the grouped matmul kernel, at an expert width of 1,536 that its tile of
-    1,024 columns does not divide."""
+    the grouped matmul kernel, at an expert width of 1,536 that its tiles
+    of 512 divide since ISSUE 46 (``moe_dropless.gmm_tiling``)."""
     text = compiled_lfm2("step").as_text()
     # one call an attention layer: 4 query heads' 64 columns of each of 8
     # K/V heads side by side on a row of 512, the running maximum and sum a
@@ -980,6 +995,11 @@ def test_the_lfm2_step_reads_k_and_v_in_the_pool_and_runs_the_grouped_matmul(
     for rows in ((64, 256, 16, 512), (64, 4096, 512), (64 * 256, 16, 512)):
         assert _made(text, rows) == [], rows
     assert len(re.findall(r"%gmm[.\d]* = ", text)) == 8 * 3
+    # gate and up 2,048 x 1,536, down 1,536 x 2,048: no block of 1,024 x
+    # 1,024, whose second column tile was half pad and whose second
+    # contraction tile a masked remainder
+    blocks = _gmm_kernel_blocks(compiled_lfm2.lowered["step"].as_text())
+    assert sorted(blocks) == ["2048x512", "512x2048"]
     # both forms of the convolution carry their scope in the compiled text
     assert "short_conv_step" in text and "short_conv_chunk" not in text
     assert "short_conv_chunk" in compiled_lfm2("mid").as_text()
@@ -1088,14 +1108,15 @@ def compiled_glm(one_chip, for_the_chip, monkeypatch_module):
         "final": lambda: eng._chunk_final_fn.lower(
             *head, *private, ints(), ints(1, 256), ints(mb), 16384),
     }
-    done = {}
+    done, lowered = {}, {}
 
     def get(which):
         if which not in done:
-            done[which] = lower[which]().compile()
+            lowered[which] = lower[which]()
+            done[which] = lowered[which].compile()
         return done[which]
 
-    get.pool = pool
+    get.pool, get.lowered = pool, lowered
     try:
         yield get
     finally:
@@ -1175,6 +1196,9 @@ def test_the_glm_step_attends_its_rows_live_blocks_in_the_pool_under_the_mask(
     # the keys still come through the table, twice
     assert len(_made(text, (32, 16384, 128))) >= 2
     assert len(re.findall(r"%gmm[.\d]* = ", text)) == 4 * 3
+    # widths of 6,144 and 2,048 keep the kernel blocks they had (ISSUE 46)
+    assert _gmm_kernel_blocks(compiled_glm.lowered["step"].as_text()) == [
+        "1024x1024"] * 2
     for scope in ("dsa_indexer", "dsa_select", "dsa_attend_in_place"):
         assert scope in text, scope
     for scope in ("dsa_selected_read", "dsa_absorbed_attention",
@@ -1309,7 +1333,13 @@ def test_the_benchmarks_readers_find_the_two_stages_operations(compiled_glm):
 PARENT_STEP_TEXT = {
     "olmo_hybrid": "b923a07e35d9284b",
     "mimo_v2_flash": "8a128b807a1eb698",
-    "lfm2_moe": "e8950da616acd302",
+    # moved by ISSUE 46 (``e8950da616acd302`` until then, which the rule
+    # ``min(1024, dim)`` still lowers to): the two ``gmm`` bodies' blocks,
+    # 2,048 x 512 and 512 x 2,048 where both were 1,024 x 1,024, with them
+    # the down product's masked remainder gone, and the bytes the two
+    # calls' cost estimates count; nothing else. The other two families are
+    # the same PR's control: unedited
+    "lfm2_moe": "cf5439ebf765a5bd",
 }
 _QUOTE = r'(?:\\22|\\?")'
 _BODY = re.compile(f"({_QUOTE}body{_QUOTE}: *{_QUOTE})([A-Za-z0-9+/=]+)"
