@@ -37,15 +37,14 @@ completion layer exists to shrink) and `replica_count` next to
 `dispatch_count`/`overhead_share`.
 
 Continuous-GPT section (ISSUE 10): a shared-prefix chat workload is
-replayed through the paged (block pool + prefix cache + chunked
-prefill) AND dense continuous engines over the same weights.
+replayed through the continuous engine (block pool + prefix cache +
+chunked prefill).
 `BENCH_PREFIX_SHARE` (default 0.75) sets the fraction of each prompt
 that is a common prefix, `BENCH_PROMPT_LEN` (96) the prompt length,
 `BENCH_GPT_REQUESTS` (32; 0 disables the section). The JSON line gains
 `prefix_hit_rate` / `kv_blocks_used` / `prefill_chunks` and a
-`kv_paged` comparison block (per-layout wall + prefill-time share +
-bitwise verdict) — the prefill share dropping with the hit rate is the
-paged layout's headline win.
+`kv_paged` block (wall + prefill-time share): the prefill share drops
+with the hit rate.
 
 Speculative decoding + quantized KV section (ISSUE 12): a DECODE-HEAVY
 shared-prefix workload (short prompts, `BENCH_SPEC_NEW`=96 generated
@@ -145,8 +144,8 @@ def _replay(engine, arrivals):
 
 
 def _gpt_paged_section():
-    """Shared-prefix chat workload through the continuous GPT engine,
-    dense vs paged over the same weights: returns the `kv_paged` block
+    """Shared-prefix chat workload through the continuous GPT engine:
+    returns the `kv_paged` block
     plus the headline prefix/pool fields (None when disabled)."""
     import jax
     import jax.numpy as jnp
@@ -162,12 +161,7 @@ def _gpt_paged_section():
         raise ValueError(f"BENCH_PREFIX_SHARE must be in [0,1]: {share}")
     plen = int(os.environ.get("BENCH_PROMPT_LEN", "96"))
     max_new = 16
-    # the dense engine prefills at the prompt-length BUCKET (the shared
-    # pow2 policy), so max_len must cover bucket + budget for both
-    # layouts
-    from sparkdl_tpu.runtime.batching import pow2_bucket
-
-    max_len = pow2_bucket(plen) + max_new
+    max_len = plen + max_new
     cfg = GPTConfig(
         vocab_size=256, hidden_size=128, num_layers=3, num_heads=4,
         intermediate_size=256, max_seq_len=4 * max_len,
@@ -190,10 +184,10 @@ def _gpt_paged_section():
                    + rng.integers(1, cfg.vocab_size,
                                   plen - n_shared).tolist())
 
-    def run(layout):
+    def run():
         eng = ContinuousGPTEngine(
             cfg, variables, n_slots=8, max_len=max_len,
-            kv_layout=layout, kv_block_size=8,
+            kv_block_size=8,
             # engine-default prefill budget (256: above these prompts,
             # so a cold admission is one bucketed chunk and a
             # prefix-hit suffix is one fused dispatch); pin via
@@ -206,65 +200,41 @@ def _gpt_paged_section():
         # and in steady state the shared prefix IS cached — the cold
         # first requests are warmup, like the compile. The seeds cover
         # every bucketed chunk program the replay will hit (cold-width,
-        # suffix-width, full-hit-width). Dense ignores the seeds; it
-        # has no cache to warm.
+        # suffix-width, full-hit-width).
         eng.submit(warm, 2).result(timeout=120)
         eng.submit(prompts[0], max_new).result(timeout=120)
         eng.submit(warm_suffix, max_new).result(timeout=120)
         eng.submit(prompts[0], max_new).result(timeout=120)
         snap0 = eng.snapshot()
-        kv0 = snap0["kv"] or {}
+        kv0 = snap0["kv"]
         t0 = time.perf_counter()
         futs = [eng.submit(p, max_new) for p in prompts]
-        outs = [np.asarray(f.result(timeout=120)) for f in futs]
+        for f in futs:
+            f.result(timeout=120)
         wall = time.perf_counter() - t0
         snap = eng.snapshot()
-        kv = snap["kv"] or {}
+        kv = snap["kv"]
         eng.close()
         prefill_s = snap["prefill_seconds"] - snap0["prefill_seconds"]
-        hits = (kv.get("prefix_hits", 0) or 0) - (
-            kv0.get("prefix_hits", 0) or 0)
-        misses = (kv.get("prefix_misses", 0) or 0) - (
-            kv0.get("prefix_misses", 0) or 0)
+        hits = kv["prefix_hits"] - kv0["prefix_hits"]
+        misses = kv["prefix_misses"] - kv0["prefix_misses"]
         return {
-            "outs": outs,
-            "stats": {
-                "wall_s": round(wall, 4),
-                "req_s": round(len(prompts) / wall, 2),
-                "prefill_seconds": round(prefill_s, 4),
-                "prefill_share": round(prefill_s / wall, 4),
-                "prefix_hit_rate": (
-                    round(hits / (hits + misses), 4)
-                    if hits + misses else None),
-                "kv_blocks_used_peak": kv.get("blocks_used_peak"),
-                "prefill_chunks": kv.get("prefill_chunks"),
-            },
+            "wall_s": round(wall, 4),
+            "req_s": round(len(prompts) / wall, 2),
+            "prefill_seconds": round(prefill_s, 4),
+            "prefill_share": round(prefill_s / wall, 4),
+            "prefix_hit_rate": (
+                round(hits / (hits + misses), 4)
+                if hits + misses else None),
+            "kv_blocks_used_peak": kv["blocks_used_peak"],
+            "prefill_chunks": kv["prefill_chunks"],
         }
 
-    dense = run("dense")
-    paged = run("paged")
-    bitwise = all(
-        np.array_equal(a, b)
-        for a, b in zip(dense["outs"], paged["outs"])
-    )
-    d_share, p_share = (dense["stats"]["prefill_share"],
-                        paged["stats"]["prefill_share"])
-    d_pf, p_pf = (dense["stats"]["prefill_seconds"],
-                  paged["stats"]["prefill_seconds"])
     return {
         "prefix_share": share,
         "prompt_len": plen,
         "requests": n_req,
-        "dense": dense["stats"],
-        "paged": paged["stats"],
-        "paged_bitwise_vs_dense": bitwise,
-        # seconds spent prefilling, dense/paged (the compute the prefix
-        # cache eliminates) and the share-of-wall ratio (diluted when
-        # paged also wins the denominator: a faster total wall)
-        "prefill_seconds_ratio": (
-            round(d_pf / p_pf, 4) if p_pf else None),
-        "prefill_share_ratio": (
-            round(d_share / p_share, 4) if p_share else None),
+        "paged": run(),
     }
 
 
@@ -518,7 +488,7 @@ def _gpt_park_section():
     # worst-case blocks one session pins while decoding turn 2
     per_session = -(-(plen + turn1_new + turn2_new + 1) // kv_bs)
     device_live = kv_blocks // per_session
-    kw = dict(n_slots=2, max_len=max_len, kv_layout="paged",
+    kw = dict(n_slots=2, max_len=max_len,
               kv_block_size=kv_bs, idle_wait_s=0.0005)
 
     def pctl(xs, q):
@@ -1050,7 +1020,7 @@ def _disagg_section():
     int_prompts = [rng.integers(1, cfg.vocab_size, int_len).tolist()
                    for _ in range(n_int)]
     chunk_warm = rng.integers(1, cfg.vocab_size, 256).tolist()
-    kw = dict(max_len=max_len, kv_layout="paged", kv_block_size=16,
+    kw = dict(max_len=max_len, kv_block_size=16,
               prefill_chunk=256, kv_dtype=dtype, idle_wait_s=0.0005)
 
     def pctl(xs, q):

@@ -581,7 +581,7 @@ cfg = GPTConfig.tiny()
 model = GPTLMHeadModel(cfg)
 variables = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
 KW = dict(n_slots=2, max_len=48, kv_block_size=4, prefill_chunk=8,
-          kv_dtype="int8", kv_layout="paged")
+          kv_dtype="int8")
 rng = np.random.RandomState(3)
 base = rng.randint(1, 50, size=12).tolist()
 cases = [(base + rng.randint(1, 50, size=rng.randint(2, 6)).tolist(),
@@ -657,7 +657,7 @@ cfg = GPTConfig.tiny()
 model = GPTLMHeadModel(cfg)
 variables = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
 KW = dict(n_slots=2, max_len=48, kv_block_size=4, prefill_chunk=8,
-          kv_dtype="int8", kv_layout="paged")
+          kv_dtype="int8")
 pre = PrefillWorker(cfg, variables, host_id="pre-0", **KW)
 dec = DecodeWorker(cfg, variables, host_id="dec-0", **KW)
 srv_p = HostServer(pre)
@@ -761,9 +761,7 @@ assert slo["availability"]["burn_rate"] is not None, slo
 assert isinstance(rec["flight_events_total"], int), rec["flight_events_total"]
 assert rec["flight_events_total"] > 0, "flight ring saw no events"
 # ISSUE 10: paged-KV section — shared-prefix hit rate, block accounting,
-# chunked prefill, and the dense-vs-paged bitwise verdict
-kp = rec["kv_paged"]
-assert kp["paged_bitwise_vs_dense"] is True, kp
+# chunked prefill
 assert rec["prefix_hit_rate"] > 0.5, rec["prefix_hit_rate"]
 assert rec["kv_blocks_used"] > 0, rec["kv_blocks_used"]
 assert rec["prefill_chunks"] > 0, rec["prefill_chunks"]
@@ -923,13 +921,13 @@ print("bench_serving contract OK (snapshot + slo + flight + kv + spec "
 '
 
 # Paged-KV smoke (ISSUE 10): (a) a shared-prefix workload through the
-# paged engine must hit the prefix cache on >50% of prompt tokens and
-# stay BITWISE identical to the dense engine; (b) with a fault plan
+# engine must hit the prefix cache on >50% of prompt tokens and
+# stay BITWISE identical to generate; (b) with a fault plan
 # injecting kv.alloc exhaustion, admissions DEFER (no request fails),
 # /healthz degrades while the streak lasts, and the flight recorder
 # auto-writes a postmortem whose engine context carries the block-pool
-# state; (c) peak block usage stays token-bound, far under the dense
-# footprint.
+# state; (c) peak block usage stays token-bound, far under every slot
+# at max_len.
 FLIGHT_DIR=$(mktemp -d)
 JAX_PLATFORMS=cpu \
 SPARKDL_TPU_FAULT_PLAN="kv.alloc:RuntimeError@3*6" \
@@ -938,7 +936,7 @@ import glob, json, sys, time
 import numpy as np
 import jax; jax.config.update("jax_platforms", "cpu")
 import jax.numpy as jnp
-from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel
+from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel, generate
 from sparkdl_tpu.observability.flight import flight_recorder, healthz_report
 from sparkdl_tpu.serving import ContinuousGPTEngine
 
@@ -951,9 +949,9 @@ shared = rng.integers(1, cfg.vocab_size, 8).tolist()
 cases = [(shared + rng.integers(1, cfg.vocab_size, 3).tolist(), 5)
          for _ in range(8)]
 
-def run(layout):
+def run():
     eng = ContinuousGPTEngine(
-        cfg, variables, n_slots=2, max_len=32, kv_layout=layout,
+        cfg, variables, n_slots=2, max_len=32,
         kv_block_size=4, prefill_chunk=8, idle_wait_s=0.001)
     futs = [eng.submit(p, n) for p, n in cases]
     outs = [np.asarray(f.result(timeout=60)) for f in futs]
@@ -962,21 +960,22 @@ def run(layout):
     return outs, snap
 
 # (b) first, the fault plan: the 3rd+ allocations fail 6 times -> the
-# paged run below defers (streak >= 3 triggers the postmortem) yet
+# run below defers (streak >= 3 triggers the postmortem) yet
 # every request completes
-outs_p, snap_p = run("paged")
-outs_d, snap_d = run("dense")
-assert all(np.array_equal(a, b) for a, b in zip(outs_p, outs_d)), \
-    "paged diverged from dense"
-kv = snap_p["kv"]
+outs, snap = run()
+for (p, n), got in zip(cases, outs):
+    want = generate(model, variables, jnp.asarray([p], jnp.int32), n)
+    assert np.array_equal(got, np.asarray(want[0, len(p):])), \
+        "engine diverged from generate"
+kv = snap["kv"]
 hits, misses = kv["prefix_hits"], kv["prefix_misses"]
 hit_rate = hits / (hits + misses)
 assert hit_rate > 0.5, (hits, misses)
 assert kv["deferrals_total"] >= 3, kv
-# (c) token-bound memory: the dense equivalent is n_slots * max_len
-# columns; the paged peak is the live requests' worst case
-dense_equiv_blocks = 2 * (32 // 4)
-assert kv["blocks_used"] < dense_equiv_blocks, kv
+# (c) token-bound memory: every slot at max_len is n_slots * max_len
+# columns; the peak is the live requests' worst case
+all_slots_full = 2 * (32 // 4)
+assert kv["blocks_used"] < all_slots_full, kv
 # healthz while a streak is LIVE (deterministic manual ticks: a 2-block
 # pool, one request holding both, a second deferring): degraded — never
 # unhealthy, it self-recovers as the blocker retires
@@ -1008,7 +1007,7 @@ assert ctx_pools[0]["blocks_total"] > 0, ctx_pools
 evs = [e for e in bundle["events"] if e["kind"] == "kv.admission_deferred"]
 assert evs, "deferral events missing from the bundle ring"
 print(f"paged-KV smoke OK: hit_rate {hit_rate:.2f} > 0.5, bitwise vs "
-      f"dense, {kv['deferrals_total']} deferrals -> postmortem with pool "
+      f"generate, {kv['deferrals_total']} deferrals -> postmortem with pool "
       f"state, healthz degraded during streak")
 EOF
 rm -rf "$FLIGHT_DIR"
@@ -1089,7 +1088,7 @@ from sparkdl_tpu.serving import ContinuousGPTEngine
 cfg = GPTConfig.tiny()
 model = GPTLMHeadModel(cfg)
 variables = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
-kw = dict(n_slots=2, max_len=32, kv_block_size=4, kv_layout="paged",
+kw = dict(n_slots=2, max_len=32, kv_block_size=4,
           idle_wait_s=0.0005)
 rng = np.random.default_rng(18)
 prompts = [rng.integers(1, cfg.vocab_size, 9).tolist() for _ in range(8)]

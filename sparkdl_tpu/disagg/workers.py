@@ -77,13 +77,6 @@ def _refuse_state(engine: ContinuousGPTEngine, what: str) -> None:
             "over its own layers, and a handoff's payload is K and V")
 
 
-def _require_paged(kwargs: dict, who: str) -> None:
-    if kwargs.get("kv_layout", "paged") != "paged":
-        raise ValueError(
-            f"{who} requires kv_layout='paged': the block pool is the "
-            "unit the tier crossing transfers")
-
-
 class PrefillWorker(ContinuousGPTEngine):
     """A :class:`ContinuousGPTEngine` that ONLY prefills (see module
     docstring). ``submit()`` keeps the colocated signature; the Future
@@ -93,7 +86,6 @@ class PrefillWorker(ContinuousGPTEngine):
     colocated engine."""
 
     def __init__(self, config, variables, **kwargs):
-        _require_paged(kwargs, "PrefillWorker")
         auto_start = kwargs.pop("auto_start", True)
         super().__init__(config, variables, auto_start=False, **kwargs)
         self._handoffs = 0
@@ -215,7 +207,6 @@ class DecodeWorker(ContinuousGPTEngine):
     and the HTTP transport route ``{"handoff": ...}`` payloads to."""
 
     def __init__(self, config, variables, **kwargs):
-        _require_paged(kwargs, "DecodeWorker")
         auto_start = kwargs.pop("auto_start", True)
         super().__init__(config, variables, auto_start=False, **kwargs)
         self._installs = 0
@@ -287,7 +278,7 @@ class DecodeWorker(ContinuousGPTEngine):
 
     def _admit_handoff(self, slot: int, req: Request) -> bool:
         """Install a transferred handoff into this tier's pool and
-        start decode with NO re-prefill. Mirrors ``_admit_paged`` +
+        start decode with NO re-prefill. Mirrors ``_admit`` +
         ``_finish_prefill``: longest-prefix match first (full blocks
         only — the wire carries every block whole, so a partial-tail
         COW copy buys nothing), worst-case allocation under the same

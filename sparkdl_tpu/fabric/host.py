@@ -36,6 +36,7 @@ from concurrent.futures import Future
 from typing import Any
 
 from sparkdl_tpu.reliability.faults import fault_point
+from sparkdl_tpu.serving.continuous import ContinuousGPTEngine
 from sparkdl_tpu.serving.queue import EngineClosedError, Request
 
 __all__ = [
@@ -98,7 +99,7 @@ class HostHandle:
                             max_entries: int = 1024) -> "dict | None":
         """Journal of block-hash adds/removes since ``since_version``
         (ISSUE 19), or None when the host cannot produce one (no
-        journal, gap, dense layout) — the router then re-syncs with one
+        journal, gap) — the router then re-syncs with one
         wholesale :meth:`prefix_digest`. Defaulting to None keeps every
         pre-delta handle (and test fake) correct: they simply stay on
         the wholesale path."""
@@ -155,7 +156,7 @@ class InProcessHost(HostHandle):
                         else str(getattr(engine, "host_id", id(engine))))
         #: GPT engines take (prompt, max_new_tokens); micro-batching
         #: engines take the payload whole
-        self._gpt = hasattr(engine, "kv_layout")
+        self._gpt = isinstance(engine, ContinuousGPTEngine)
         self._drained = threading.Event()
 
     def submit(self, payload: "dict[str, Any]", *,

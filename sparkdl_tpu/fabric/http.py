@@ -24,10 +24,11 @@ Server endpoints (:class:`HostServer`, wrapping one engine):
   tier's wire cost stays ~4× under fp32's.
 * ``GET /fabric/snapshot`` → ``engine.snapshot()`` (host_id + capacity
   included — the router's weighting input).
-* ``GET /fabric/digest`` → ``engine.prefix_digest()`` (null for dense).
+* ``GET /fabric/digest`` → ``engine.prefix_digest()`` (null for an engine
+  with no prefix cache).
 * ``GET /fabric/digest_delta?since=N`` → ``{"delta": ...}`` — the
   block-hash journal since version N (ISSUE 19), null when the host
-  cannot produce one (gap, dense, no journal): the router re-syncs
+  cannot produce one (gap, no journal): the router re-syncs
   with one wholesale ``/fabric/digest``.
 * ``POST /fabric/migrate_out`` → ``{"bundle": ...}`` (the host's
   parked sessions, serialized through the handoff raw-storage codec)

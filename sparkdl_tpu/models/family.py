@@ -67,8 +67,9 @@ class ServingFamily:
     expert_layers: int = 0
     experts: int = 0
     experts_per_token: int = 0
-    #: the family has the paged path alone: the dense layout and the
-    #: sequence-parallel prefill refuse it at construction
+    #: the family is served at its native K/V dtype by the one-chip
+    #: prefill and the plain step alone: ``sp``, ``spec_k`` and
+    #: ``kv_dtype`` refuse it at construction
     paged_only: bool = False
     #: layers that keep K/V a token: the leading axis of the pool's ``k``
     #: and ``v`` (None: every layer does)

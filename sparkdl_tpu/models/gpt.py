@@ -139,8 +139,9 @@ def init_cache(config: GPTConfig, batch: int, max_len: int,
     Layout: k/v stacked over layers, [num_layers, B, max_len, H, D];
     ``idx`` is the number of positions already written — a scalar for the
     lockstep :func:`generate` path, or (``per_slot=True``) a per-row [B]
-    vector for continuous-batching serving where every batch row (slot)
-    decodes at its own depth (``serving.continuous``). Per-slot steps
+    vector where every batch row (slot) decodes at its own depth: the
+    dense twin of the paged cache ``serving.continuous`` decodes through
+    (tests/models/test_gpt_continuous.py). Per-slot steps
     write this call's L tokens at columns ``[idx[b], idx[b]+L)`` of each
     row — L=1 is the classic decode step, L=k is the speculative verify
     pass that scores a whole draft span in one dispatch. Prefill a
@@ -251,8 +252,8 @@ class GPTAttention(nn.Module):
             # depth, few queries a row (L=1 is the classic decode step; L=k
             # the speculative verify span and a chained step). ONE attention
             # for both layouts (merged_axis_attention), over K and V with
-            # the heads side by side on one axis, so the paged engine and
-            # its dense oracle compare like with like: a paged cache's rows
+            # the heads side by side on one axis, so a paged cache and a
+            # dense one compare like with like: a paged cache's rows
             # come through the block table as the pool stores them
             # (kv_pool.layer_rows: never a dense all-layer view of the
             # pool, never a reshape to heads; a pool whose heads fill
@@ -473,9 +474,7 @@ class GPTLMHeadModel(nn.Module):
     (``init_cache(..., per_slot=True)``, ``idx`` [B]) decodes every row at
     its own depth with a per-row causal mask and per-row K/V scatter —
     through :func:`merged_axis_attention`; L=1 is the classic decode step
-    and L=k scores a whole speculative draft span in one pass — which is
-    what lets ``serving.continuous`` admit and retire rows mid-stream and
-    verify k drafted tokens per dispatch.
+    and L=k scores a whole speculative draft span in one pass.
 
     A PAGED cache (it holds a ``table`` entry: ``{"k", "v"[, "k_scale",
     "v_scale"], "table", "idx"}``, the pool of

@@ -4,12 +4,12 @@ The lockstep ``generate`` path (models/gpt.py) starts a batch together
 and ends it together, so one long row holds every slot hostage and new
 arrivals wait for the whole batch to finish — fatal for online serving.
 This engine keeps ONE persistent decode batch of ``n_slots`` rows over a
-per-slot KV cache (``init_cache(per_slot=True)``: ``idx`` per row):
+pool of K/V blocks that each row reaches through its own block table:
 
 - a finished row frees its slot immediately;
-- a newly admitted prompt is prefilled ALONE (batch-1, bucketed prompt
-  length, the jit-cached left-padded ragged path) and its K/V row is
-  scattered into the free slot — the in-flight neighbors never notice;
+- a newly admitted prompt is prefilled ALONE (batch-1, in bounded chunks)
+  and its K/V installed into the blocks of the free slot's row — the
+  in-flight neighbors never notice;
 - every engine tick advances all live rows one token in a single jitted
   step whose per-row causal mask lets each row decode at its own depth.
 
@@ -54,24 +54,21 @@ still owned at the launch (admission reserves prompt + budget). Whatever
 touches a live row's blocks or ``produced`` outside that order (an
 expiring deadline, a preemption, ``close``, ``begin_drain``,
 ``park_cold``, ``export_parked_sessions``, ``snapshot``) first collects
-the step in flight (``_collect``). The dense layout and a speculative
-engine (``spec_k``) launch, read and retire in one tick, as ever.
+the step in flight (``_collect``). A speculative engine (``spec_k``)
+launches, reads and retires in one tick.
 
-KV layouts (``kv_layout=``): ``"paged"`` (default) maps each slot's columns
-onto refcounted ``block_size``-token blocks through a block table; the
-decode step hands the model the pool and the table's live head as a PAGED
-cache, so persistent KV memory is bounded by allocated tokens, not
-``n_slots x max_len``. Admission against an exhausted pool DEFERS
-(re-queues in order) instead of erroring. Prompts are prefilled
-right-aligned in bounded CHUNKS (``prefill_chunk`` tokens per engine tick,
-interleaved with decode ticks — a long prompt no longer freezes in-flight
+THE KV CACHE: each slot's columns map onto refcounted ``block_size``-token
+blocks through a block table; the decode step hands the model the pool and
+the table's live head as a PAGED cache, so persistent KV memory is bounded
+by allocated tokens, not ``n_slots x max_len``. Admission against an
+exhausted pool DEFERS (re-queues in order) instead of erroring. Prompts
+are prefilled right-aligned in bounded CHUNKS (``prefill_chunk`` tokens per engine tick,
+interleaved with decode ticks — a long prompt does not freeze in-flight
 decode latency), and a radix prefix cache
 (:mod:`~sparkdl_tpu.serving.prefix_cache`) lets a request reuse the cached
 K/V of its longest shared prompt prefix and prefill only the suffix
 (partial tail blocks shared copy-on-write). Greedy tokens stay
 oracle-identical on every path (tests/serving/test_kv_paged.py).
-``"dense"`` is the original one-dense-buffer-per-slot layout, kept as the
-parity suites' reference (ROADMAP names the condition under which it goes).
 
 Speculative multi-token decoding (``spec_k=``, ROADMAP item 3): a
 draft source (:mod:`~sparkdl_tpu.serving.spec_decode` — radix-trie
@@ -94,7 +91,7 @@ state to fall back to — it propagates like any decode-dispatch error
 (the engine loop fails every pending Future loudly rather than serving
 from a consumed cache).
 
-Quantized KV blocks (``kv_dtype=``): the paged pool can store ``"bf16"``
+Quantized KV blocks (``kv_dtype=``): the pool can store ``"bf16"``
 or ``"int8"`` (one fp32 scale per written column) instead of the compute
 dtype, 2-4x the capacity
 (:func:`~sparkdl_tpu.serving.kv_blocks.kv_capacity_ratio`); the rule is
@@ -109,7 +106,7 @@ sequence-sharded staging pool
 long context never has to fit one chip during prefill. ONE gather at
 the prefill→decode handoff (``sp.gather`` fault site) installs the
 staged K/V into the decode pool; the per-token loop — plain, chained,
-speculative — is the untouched single-device paged path, which is why
+speculative — is the untouched single-device path, which is why
 greedy tokens stay bitwise across sp∈{1,2} on every decode mode. An
 injected collective fault (``sp.permute``/``sp.gather``) re-queues the
 victim request instead of failing it (:class:`SpCollectiveError` in
@@ -132,7 +129,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from sparkdl_tpu.models.gpt import init_cache
 from sparkdl_tpu.models.kv_pool import init_block_pool
 from sparkdl_tpu.observability import flight as flight_mod
 from sparkdl_tpu.observability import slo as slo_mod
@@ -141,11 +137,7 @@ from sparkdl_tpu.observability.registry import GaugeShare, registry
 from sparkdl_tpu.observability.tracing import span
 from sparkdl_tpu.ops.sparse_attention import attends_in_place
 from sparkdl_tpu.reliability.faults import fault_point
-from sparkdl_tpu.runtime.batching import (
-    default_buckets,
-    pick_bucket,
-    pow2_bucket,
-)
+from sparkdl_tpu.runtime.batching import pow2_bucket
 from sparkdl_tpu.runtime.chip import alike_layers_options, watch_compiles
 from sparkdl_tpu.runtime.completion import start_fetch
 from sparkdl_tpu.runtime.dispatch import (
@@ -282,11 +274,8 @@ def _with_init_span(init):
         watch_compiles()
         with span("serving.engine_init") as sp:
             init(self, *args, **kwargs)
-            sp.set_attr(
-                n_slots=self.n_slots, max_len=self.max_len,
-                kv_layout=self.kv_layout,
-                kv_blocks=(self._pool.n_blocks
-                           if self.kv_layout == "paged" else 0))
+            sp.set_attr(n_slots=self.n_slots, max_len=self.max_len,
+                        kv_blocks=self._pool.n_blocks)
     return traced_init
 
 
@@ -300,23 +289,22 @@ class GenRequest:
 
 @dataclasses.dataclass
 class _InFlight:
-    """Host-side state of one occupied slot (the left-pad count lives in
-    the engine's ``_start`` array the decode step consumes; ``blocks``
-    are the paged layout's refcounted KV blocks, released on retire)."""
+    """Host-side state of one occupied slot (``blocks`` are the row's
+    refcounted KV blocks, released on retire)."""
 
     req: Request
     produced: list[int]
     max_new: int
-    blocks: "list[int] | None" = None
-    #: prompt ids (paged layout): the draft proposer's context is
-    #: prompt + produced — ids only, never device state
-    prompt: "np.ndarray | None" = None
+    blocks: "list[int]"
+    #: prompt ids: the draft proposer's context is prompt + produced —
+    #: ids only, never device state
+    prompt: np.ndarray
     #: the slot the row decodes in; None once it has given it back, which
     #: a row that ends by its budget does when its last step is LAUNCHED
     slot: "int | None" = None
     #: tokens a launched step is making for this row that the host has not
-    #: read yet (paged layout): they count against the budget, and while
-    #: there are any the row's newest token is on the device alone
+    #: read yet: they count against the budget, and while there are any
+    #: the row's newest token is on the device alone
     unread: int = 0
 
     @property
@@ -327,7 +315,7 @@ class _InFlight:
 
 @dataclasses.dataclass
 class _StepOut:
-    """One paged decode step that was launched and whose ids the host has
+    """One decode step that was launched and whose ids the host has
     not read: what it takes to read them, retire its rows and say, in
     ``serving.decode_step``, what the step was."""
 
@@ -342,7 +330,7 @@ class _StepOut:
 
 @dataclasses.dataclass
 class _Prefill:
-    """One slot mid-chunked-prefill (paged layout): the prompt's K/V are
+    """One slot mid-chunked-prefill: the prompt's K/V are
     accumulating in a private batch-1 dense cache (``ck``/``cv``),
     ``prefill_chunk`` tokens per engine tick, until installation into
     the slot's pool blocks. ``pos`` counts prompt tokens already in the
@@ -390,19 +378,15 @@ class ContinuousGPTEngine:
 
     ``submit(prompt_ids, max_new_tokens)`` returns a Future of the
     generated ids (prompt not included). Admission control is two-layer:
-    queue depth (QueueFullError) and cache capacity. Under
-    ``kv_layout="dense"`` a request whose BUCKETED prompt + budget
-    cannot fit ``max_len`` columns is rejected at submit, loudly,
-    because its cache writes would silently drop. Under the default
-    ``"paged"`` layout only what can NEVER fit rejects (raw prompt +
-    budget vs ``max_len``, worst-case blocks vs the whole pool); a
+    queue depth (QueueFullError) and cache capacity. Only what can NEVER
+    fit rejects at submit, loudly (raw prompt + budget vs ``max_len``,
+    worst-case blocks vs the whole pool); a
     request that merely cannot fit right now is admitted and DEFERRED
     at tick time — re-queued at the head, retried as slots retire and
-    free their blocks. ``kv_block_size``/``kv_blocks`` size the paged
-    pool (default: the dense worst case, so the default engine never
-    defers where dense admitted); ``prefill_chunk`` bounds the prompt
-    tokens prefilled per tick (pin via arg or
-    ``SPARKDL_TPU_PREFILL_CHUNK``).
+    free their blocks. ``kv_block_size``/``kv_blocks`` size the pool
+    (default: ``n_slots`` rows of ``max_len``, so the default engine never
+    defers); ``prefill_chunk`` bounds the prompt tokens prefilled per tick
+    (pin via arg or ``SPARKDL_TPU_PREFILL_CHUNK``).
 
     ``auto_start=False`` exposes :meth:`tick` for deterministic
     single-step tests; the default runs the loop on a daemon thread.
@@ -415,13 +399,13 @@ class ContinuousGPTEngine:
     Greedy tokens are identical at any k. None = auto-calibrate from the
     dispatch gap; 1 (default) = one token per dispatch.
 
-    The module docstring has the mechanisms of the rest: ``sp`` (paged
-    layout; pin via ``SPARKDL_TPU_SP``; a power of two, at most the
-    visible device count; ``sp_kv_blocks`` sizes the staging pool,
-    default = the decode pool rounded up to divide ``sp``; None/1 =
-    off), ``spec_k`` (paged layout; up to ``spec_k - 1`` drafts a slot
-    from ``draft_source``, default radix-trie + n-gram; None = off) and
-    ``kv_dtype`` ("fp32" | "bf16" | "int8", the paged pool's storage).
+    The module docstring has the mechanisms of the rest: ``sp`` (pin via
+    ``SPARKDL_TPU_SP``; a power of two, at most the visible device count;
+    ``sp_kv_blocks`` sizes the staging pool, default = the decode pool
+    rounded up to divide ``sp``; None/1 = off), ``spec_k`` (up to
+    ``spec_k - 1`` drafts a slot from ``draft_source``, default radix-trie
+    + n-gram; None = off) and ``kv_dtype`` ("fp32" | "bf16" | "int8", the
+    pool's storage).
     """
 
     @_with_init_span
@@ -430,7 +414,6 @@ class ContinuousGPTEngine:
                  eos_id: Optional[int] = None,
                  idle_wait_s: float = 0.005,
                  chain_tokens: "int | None" = 1,
-                 kv_layout: str = "paged",
                  kv_block_size: int = 16,
                  kv_blocks: "int | None" = None,
                  prefill_chunk: "int | None" = None,
@@ -453,27 +436,10 @@ class ContinuousGPTEngine:
             raise ValueError(
                 f"chain_tokens must be >= 1, got {chain_tokens}"
             )
-        if kv_layout not in ("paged", "dense"):
-            raise ValueError(
-                f"kv_layout must be 'paged' or 'dense', got {kv_layout!r}"
-            )
         if spec_k is not None and spec_k < 2:
             raise ValueError(
                 f"spec_k must be >= 2 (one draft + its verify), got "
                 f"{spec_k}; None disables speculative decoding"
-            )
-        if kv_layout != "paged" and (spec_k is not None
-                                     or kv_dtype != "fp32"):
-            raise ValueError(
-                "speculative decoding (spec_k) and quantized KV pools "
-                "(kv_dtype) require kv_layout='paged'; the dense layout "
-                "is the exact parity oracle"
-            )
-        if kv_layout != "paged" and host_kv_blocks is not None:
-            raise ValueError(
-                "tiered KV (host_kv_blocks) requires kv_layout='paged': "
-                "parking pages pool blocks, and the dense layout has "
-                "no block pool"
             )
         if disk_kv_blocks is not None and host_kv_blocks is None:
             raise ValueError(
@@ -489,18 +455,12 @@ class ContinuousGPTEngine:
                 f"disk_kv_blocks must be >= 0, got {disk_kv_blocks}")
         if sp is not None and sp < 1:
             raise ValueError(f"sp must be >= 1, got {sp}")
-        # Resolve the env pin HERE, before layout validation, so
-        # SPARKDL_TPU_SP=2 on a dense-layout engine raises exactly like
-        # sp=2 the argument would (pins are loud — a silently non-sp
-        # engine is the failure mode resolve_pin exists to prevent).
+        # Resolve the env pin HERE, before the family's validation, so
+        # SPARKDL_TPU_SP=2 is refused exactly as sp=2 the argument would
+        # be (pins are loud — a silently non-sp engine is the failure
+        # mode resolve_pin exists to prevent).
         from sparkdl_tpu.ingest.pipeline import resolve_pin
         sp_val, _, _ = resolve_pin(sp, "SPARKDL_TPU_SP", 1, what="sp")
-        if kv_layout != "paged" and sp_val > 1:
-            raise ValueError(
-                "sequence parallelism (sp) requires kv_layout='paged': "
-                "the sp prefill stages K/V in a sequence-sharded block "
-                "pool"
-            )
         # THE seam to the model (models/family.py): the module, and how
         # it shapes a token's K/V. Nothing below reads the configuration's
         # own fields.
@@ -510,12 +470,12 @@ class ContinuousGPTEngine:
                 f"max_len {max_len} exceeds the learned position table "
                 f"(max_seq_len={fam.max_positions})"
             )
-        if fam.paged_only and (kv_layout != "paged" or sp_val > 1
-                               or spec_k is not None or kv_dtype != "fp32"):
+        if fam.paged_only and (sp_val > 1 or spec_k is not None
+                               or kv_dtype != "fp32"):
             raise ValueError(
-                f"{type(config).__name__} is served on the paged path at "
-                "its native K/V dtype alone: kv_layout='dense', sp > 1, "
-                "spec_k and kv_dtype are not implemented for this family"
+                f"{type(config).__name__} is served by the plain step at "
+                "its native K/V dtype alone: sp > 1, spec_k and kv_dtype "
+                "are not implemented for this family"
             )
         if fam.state_layers and host_kv_blocks is not None:
             raise ValueError(
@@ -539,10 +499,9 @@ class ContinuousGPTEngine:
         self.eos_id = eos_id
         self.idle_wait_s = idle_wait_s
         self.chain_tokens = chain_tokens
-        self.kv_layout = kv_layout
         self.spec_k = spec_k
-        self.kv_dtype = kv_dtype if kv_layout == "paged" else "fp32"
-        self.sp = 1  # raised past 1 by _init_sp in the paged branch
+        self.kv_dtype = kv_dtype
+        self.sp = 1  # raised past 1 by _init_sp
         self._sp_handoffs = 0
         self._spec_policy = (SpecPolicy(max_k=spec_k)
                              if spec_k is not None else None)
@@ -565,11 +524,10 @@ class ContinuousGPTEngine:
         self._overload_next = 0.0
         self.metrics = metrics if metrics is not None else ServingMetrics()
         self._model = fam.module
-        self._len_buckets = default_buckets(max_len, min_bucket=8)
         self._inflight: dict[int, _InFlight] = {}
         self._prefilling: dict[int, _Prefill] = {}
         self._last_tok = np.zeros((n_slots,), np.int32)
-        #: the paged decode steps launched and not yet read, oldest first
+        #: the decode steps launched and not yet read, oldest first
         #: (the loop keeps ONE ahead of its own reads; two for the moment
         #: between a launch and the read before it), and when the last
         #: one was read
@@ -582,7 +540,7 @@ class ContinuousGPTEngine:
         self._prefill_chunks = 0
         self._deferrals = 0
         #: prompt tokens of prefix matches that a family with state layers
-        #: could not honour (_admit_paged)
+        #: could not honour (_admit)
         self._prefix_passed_up = 0
         #: host/disk tier store for parked cold sessions (ROADMAP
         #: item 1); None = flat single-tier cache (the default)
@@ -595,154 +553,139 @@ class ContinuousGPTEngine:
         self._thread: threading.Thread | None = None
 
         model = self._model
-        if kv_layout == "paged":
-            if kv_block_size < 1:
-                raise ValueError(
-                    f"kv_block_size must be >= 1, got {kv_block_size}")
-            # default 256: the chunk is a decode-LATENCY bound (one
-            # tick never prefills more than this many tokens), so it
-            # should sit well ABOVE typical prompts — throttling every
-            # cold admission to tiny chunks serializes admission for no
-            # latency benefit. Shrink it when long prompts must not
-            # stall live decode ticks.
-            chunk, _, _ = resolve_pin(
-                prefill_chunk, "SPARKDL_TPU_PREFILL_CHUNK", 256,
-                what="prefill_chunk",
-            )
-            if chunk < 1:
-                raise ValueError(
-                    f"prefill_chunk must be >= 1, got {chunk}")
-            self.prefill_chunk = chunk
-            bs_kv = kv_block_size
-            mb = -(-max_len // bs_kv)  # table width, blocks per sequence
-            w = mb * bs_kv  # gathered virtual-cache width (>= max_len)
-            # widest chunk PROGRAM ever built: chunks bucket to their
-            # real token count, and no chunk carries more than a whole
-            # prompt (<= w) even when the per-tick budget is larger
-            self._chunk_cap = min(chunk, w)
-            # private prefill cache is one max-width chunk wider than
-            # the table span: a chunk write must never clamp
-            wp = w + self._chunk_cap
-            if kv_blocks is None:
-                # default pool = the dense layout's worst case, so the
-                # default engine can never defer where dense admitted;
-                # shrink kv_blocks to make memory the real bound
-                kv_blocks = n_slots * mb
-            if kv_blocks < 1:
-                raise ValueError(
-                    f"kv_blocks must be >= 1, got {kv_blocks}")
-            self._kv_bs = bs_kv
-            self._mb = mb
-            self._w = w
-            self._wp = wp
-            if kv_dtype != "fp32":
-                # the bring-up of a COMPRESSED pool is a distinct
-                # failure surface (scale buffers, storage casts) the
-                # chaos harness must reach: an injected kv.quantize
-                # fault fails construction loudly BEFORE any
-                # process-wide registration leaks (gauges register
-                # below, EngineObservability last)
-                fault_point("kv.quantize")
-            self._pool = KVBlockPool(kv_blocks, bs_kv, dtype=kv_dtype)
-            #: which pool the last deferral was short on (_defer reads
-            #: it; the sp staging branch points it at _sp_pool)
-            self._defer_pool = self._pool
-            if host_kv_blocks is not None:
-                # disk overflow may only drop trie LEAVES — dropping
-                # an interior parked node would orphan its (parked)
-                # descendants' payloads
-                self._kv_tiers = kv_tiers_mod.TieredKVStore(
-                    host_kv_blocks, disk_kv_blocks or 0,
-                    spill_dir=kv_spill_dir,
-                    is_droppable=lambda node: not node.children)
-            self._prefix = PrefixCache(self._pool,
-                                       tiers=self._kv_tiers)
-            self._draft = (draft_source if draft_source is not None
-                           else default_draft_source(self._prefix))
-            # the device pool, shaped and stored as models/kv_pool.py says:
-            # the only compressed tensor (the programs below compute, and
-            # keep their private prefill caches, at the model dtype)
-            self._pool_kv = init_block_pool(config, kv_blocks, bs_kv,
-                                            dtype=kv_dtype, n_slots=n_slots)
-            # block tables: one row per slot, sentinel (= kv_blocks)
-            # marks empty entries — gather clips it, scatter drops it
-            self._table = np.full((n_slots, mb), self._pool.sentinel,
-                                  np.int32)
-            self._pidx = np.zeros((n_slots,), np.int32)
-            # every slot's newest token as the last step left it ON THE
-            # DEVICE: the next step's input for the rows whose ids the host
-            # has not read (device_put: jnp.zeros would compile a program)
-            self._dev_tok = jax.device_put(np.zeros((n_slots,), np.int32))
-            # what the programs close over (serving/paged_programs.py):
-            # derived here, set by nobody
-            sizes = self._sizes = programs.PagedSizes(
-                n_slots=n_slots, block_size=bs_kv, mb=mb, w=w, wp=wp,
-                max_pos=(fam.max_positions - 1
-                         if fam.max_positions is not None else wp + chunk),
-                dtype=fam.dtype,
-                arrays=tuple(name for name, _, _ in fam.pool_arrays))
-            # One binding a program, under the function's own name (what
-            # the device trace shows and the benchmark reads). The jit
-            # calls stay in THIS file: sparkdl-lint's donation-safety rule
-            # learns the donating handles from them and checks that every
-            # call site below rebinds self._pool_kv.
-            self._paged_step_fn = jax.jit(
-                bound(programs._paged_step, sizes, model),
-                donate_argnums=(1,), static_argnums=(6, 7))
-            self._paged_verify_fn = jax.jit(
-                bound(programs._paged_verify, sizes, model),
-                donate_argnums=(1,), static_argnums=(5, 6))
-            alike = alike_layers_options()
-            self._chunk_one_fn = jax.jit(
-                bound(programs._chunk_one, sizes, model),
-                donate_argnums=(1,), static_argnums=(6,),
-                compiler_options=alike)
-            self._chunk_first_fn = jax.jit(
-                bound(programs._chunk_first, sizes, model),
-                static_argnums=(5,), compiler_options=alike)
-            self._chunk_mid_fn = jax.jit(
-                bound(programs._chunk_mid, sizes, model),
-                # (a family with state layers: its running state too)
-                donate_argnums=(1, 2) + ((7,) if fam.state_layers else ()),
-                static_argnums=(5,), compiler_options=alike)
-            self._chunk_final_fn = jax.jit(
-                bound(programs._chunk_final, sizes, model),
-                donate_argnums=(1,), static_argnums=(7,),
-                compiler_options=alike)
-            self._park_fetch_fn = jax.jit(
-                bound(programs._park_fetch, sizes))
-            self._unpark_install_fn = jax.jit(
-                programs._unpark_install, donate_argnums=(0,))
-            self._install_blocks_fn = jax.jit(
-                programs._install_blocks, donate_argnums=(0,))
-            # what the family holds by slot: rings of a window's columns,
-            # tails of a short convolution's inputs, or a recurrent state
-            self._g_state = GaugeShare(
-                _M_RING_BYTES if fam.ring_columns
-                else _M_TAIL_BYTES if fam.tail_columns else _M_STATE_BYTES)
-            self._g_state.set(n_slots * fam.state_bytes_per_slot)
-            # the pool's arrays where the family names them itself
-            self._g_named = None
-            if fam.block_arrays:
-                self._g_named = GaugeShare(_M_LATENT_BYTES)
-                self._g_named.set(
-                    kv_blocks * bs_kv * kv_bytes_per_token(config, kv_dtype))
-            if sp_val > 1:
-                self._init_sp(sp_val, sp_kv_blocks)
-        else:
-            self._cache = init_cache(
-                config, n_slots, max_len, per_slot=True)
-            self._start = np.zeros((n_slots,), np.int32)
-            # the dense reference's programs
-            self._prefill_fn = jax.jit(
-                bound(programs._prefill, max_len, model))
-            self._scatter_fn = jax.jit(
-                programs._scatter, donate_argnums=(0,))
-            self._step_fn = jax.jit(
-                bound(programs._step, max_len, model), donate_argnums=(1,))
-            self._step_chain_fn = jax.jit(
-                bound(programs._step_chain, max_len, model),
-                donate_argnums=(1,), static_argnums=(3,))
+        if kv_block_size < 1:
+            raise ValueError(
+                f"kv_block_size must be >= 1, got {kv_block_size}")
+        # default 256: the chunk is a decode-LATENCY bound (one
+        # tick never prefills more than this many tokens), so it
+        # should sit well ABOVE typical prompts — throttling every
+        # cold admission to tiny chunks serializes admission for no
+        # latency benefit. Shrink it when long prompts must not
+        # stall live decode ticks.
+        chunk, _, _ = resolve_pin(
+            prefill_chunk, "SPARKDL_TPU_PREFILL_CHUNK", 256,
+            what="prefill_chunk",
+        )
+        if chunk < 1:
+            raise ValueError(
+                f"prefill_chunk must be >= 1, got {chunk}")
+        self.prefill_chunk = chunk
+        bs_kv = kv_block_size
+        mb = -(-max_len // bs_kv)  # table width, blocks per sequence
+        w = mb * bs_kv  # gathered virtual-cache width (>= max_len)
+        # widest chunk PROGRAM ever built: chunks bucket to their
+        # real token count, and no chunk carries more than a whole
+        # prompt (<= w) even when the per-tick budget is larger
+        self._chunk_cap = min(chunk, w)
+        # private prefill cache is one max-width chunk wider than
+        # the table span: a chunk write must never clamp
+        wp = w + self._chunk_cap
+        if kv_blocks is None:
+            # default pool = every slot at max_len, so the default
+            # engine never defers; shrink kv_blocks to make memory the
+            # real bound
+            kv_blocks = n_slots * mb
+        if kv_blocks < 1:
+            raise ValueError(
+                f"kv_blocks must be >= 1, got {kv_blocks}")
+        self._kv_bs = bs_kv
+        self._mb = mb
+        self._w = w
+        self._wp = wp
+        if kv_dtype != "fp32":
+            # the bring-up of a COMPRESSED pool is a distinct
+            # failure surface (scale buffers, storage casts) the
+            # chaos harness must reach: an injected kv.quantize
+            # fault fails construction loudly BEFORE any
+            # process-wide registration leaks (gauges register
+            # below, EngineObservability last)
+            fault_point("kv.quantize")
+        self._pool = KVBlockPool(kv_blocks, bs_kv, dtype=kv_dtype)
+        #: which pool the last deferral was short on (_defer reads
+        #: it; the sp staging branch points it at _sp_pool)
+        self._defer_pool = self._pool
+        if host_kv_blocks is not None:
+            # disk overflow may only drop trie LEAVES — dropping
+            # an interior parked node would orphan its (parked)
+            # descendants' payloads
+            self._kv_tiers = kv_tiers_mod.TieredKVStore(
+                host_kv_blocks, disk_kv_blocks or 0,
+                spill_dir=kv_spill_dir,
+                is_droppable=lambda node: not node.children)
+        self._prefix = PrefixCache(self._pool,
+                                   tiers=self._kv_tiers)
+        self._draft = (draft_source if draft_source is not None
+                       else default_draft_source(self._prefix))
+        # the device pool, shaped and stored as models/kv_pool.py says:
+        # the only compressed tensor (the programs below compute, and
+        # keep their private prefill caches, at the model dtype)
+        self._pool_kv = init_block_pool(config, kv_blocks, bs_kv,
+                                        dtype=kv_dtype, n_slots=n_slots)
+        # block tables: one row per slot, sentinel (= kv_blocks)
+        # marks empty entries — gather clips it, scatter drops it
+        self._table = np.full((n_slots, mb), self._pool.sentinel,
+                              np.int32)
+        self._pidx = np.zeros((n_slots,), np.int32)
+        # every slot's newest token as the last step left it ON THE
+        # DEVICE: the next step's input for the rows whose ids the host
+        # has not read (device_put: jnp.zeros would compile a program)
+        self._dev_tok = jax.device_put(np.zeros((n_slots,), np.int32))
+        # what the programs close over (serving/paged_programs.py):
+        # derived here, set by nobody
+        sizes = self._sizes = programs.PagedSizes(
+            n_slots=n_slots, block_size=bs_kv, mb=mb, w=w, wp=wp,
+            max_pos=(fam.max_positions - 1
+                     if fam.max_positions is not None else wp + chunk),
+            dtype=fam.dtype,
+            arrays=tuple(name for name, _, _ in fam.pool_arrays))
+        # One binding a program, under the function's own name (what
+        # the device trace shows and the benchmark reads). The jit
+        # calls stay in THIS file: sparkdl-lint's donation-safety rule
+        # learns the donating handles from them and checks that every
+        # call site below rebinds self._pool_kv.
+        self._paged_step_fn = jax.jit(
+            bound(programs._paged_step, sizes, model),
+            donate_argnums=(1,), static_argnums=(6, 7))
+        self._paged_verify_fn = jax.jit(
+            bound(programs._paged_verify, sizes, model),
+            donate_argnums=(1,), static_argnums=(5, 6))
+        alike = alike_layers_options()
+        self._chunk_one_fn = jax.jit(
+            bound(programs._chunk_one, sizes, model),
+            donate_argnums=(1,), static_argnums=(6,),
+            compiler_options=alike)
+        self._chunk_first_fn = jax.jit(
+            bound(programs._chunk_first, sizes, model),
+            static_argnums=(5,), compiler_options=alike)
+        self._chunk_mid_fn = jax.jit(
+            bound(programs._chunk_mid, sizes, model),
+            # (a family with state layers: its running state too)
+            donate_argnums=(1, 2) + ((7,) if fam.state_layers else ()),
+            static_argnums=(5,), compiler_options=alike)
+        self._chunk_final_fn = jax.jit(
+            bound(programs._chunk_final, sizes, model),
+            donate_argnums=(1,), static_argnums=(7,),
+            compiler_options=alike)
+        self._park_fetch_fn = jax.jit(
+            bound(programs._park_fetch, sizes))
+        self._unpark_install_fn = jax.jit(
+            programs._unpark_install, donate_argnums=(0,))
+        self._install_blocks_fn = jax.jit(
+            programs._install_blocks, donate_argnums=(0,))
+        # what the family holds by slot: rings of a window's columns,
+        # tails of a short convolution's inputs, or a recurrent state
+        self._g_state = GaugeShare(
+            _M_RING_BYTES if fam.ring_columns
+            else _M_TAIL_BYTES if fam.tail_columns else _M_STATE_BYTES)
+        self._g_state.set(n_slots * fam.state_bytes_per_slot)
+        # the pool's arrays where the family names them itself
+        self._g_named = None
+        if fam.block_arrays:
+            self._g_named = GaugeShare(_M_LATENT_BYTES)
+            self._g_named.set(
+                kv_blocks * bs_kv * kv_bytes_per_token(config, kv_dtype))
+        if sp_val > 1:
+            self._init_sp(sp_val, sp_kv_blocks)
         # process-wide registrations go LAST: a constructor failure above
         # (bad config, cache init OOM) must not leak a tracker/provider
         # bound to a half-built engine
@@ -872,46 +815,35 @@ class ContinuousGPTEngine:
             raise ValueError(
                 f"max_new_tokens must be >= 1, got {max_new_tokens}"
             )
-        if self.kv_layout == "paged":
-            # the paged layout stores tokens unpadded, so the true
-            # per-request bound is the RAW length (dense pays the
-            # prompt-length bucket) — and the pool: a request whose
-            # worst-case block count exceeds the whole pool can never
-            # fit and is rejected loudly; one that merely cannot fit
-            # NOW is admitted and deferred at tick time.
-            if len(prompt) + max_new_tokens > self.max_len:
+        # tokens are stored unpadded, so the per-request bound is the RAW
+        # length — and the pool: a request whose worst-case block count
+        # exceeds the whole pool can never fit and is rejected loudly;
+        # one that merely cannot fit NOW is admitted and deferred at tick
+        # time.
+        if len(prompt) + max_new_tokens > self.max_len:
+            raise ValueError(
+                f"prompt {len(prompt)} + max_new_tokens "
+                f"{max_new_tokens} exceeds cache max_len "
+                f"{self.max_len}: raise max_len or shorten the "
+                "request"
+            )
+        need = -(-(len(prompt)
+                   + self._admission_budget_tokens(max_new_tokens))
+                 // self._kv_bs)
+        if need > self._pool.n_blocks:
+            raise ValueError(
+                f"request needs {need} KV blocks but the pool holds "
+                f"{self._pool.n_blocks}: it can never fit — raise "
+                "kv_blocks or shorten the request"
+            )
+        if self.sp > 1:
+            nbp = -(-len(prompt) // self._kv_bs)
+            if nbp > self._sp_pool.n_blocks:
                 raise ValueError(
-                    f"prompt {len(prompt)} + max_new_tokens "
-                    f"{max_new_tokens} exceeds cache max_len "
-                    f"{self.max_len}: raise max_len or shorten the "
-                    "request"
-                )
-            need = -(-(len(prompt)
-                       + self._admission_budget_tokens(max_new_tokens))
-                     // self._kv_bs)
-            if need > self._pool.n_blocks:
-                raise ValueError(
-                    f"request needs {need} KV blocks but the pool holds "
-                    f"{self._pool.n_blocks}: it can never fit — raise "
-                    "kv_blocks or shorten the request"
-                )
-            if self.sp > 1:
-                nbp = -(-len(prompt) // self._kv_bs)
-                if nbp > self._sp_pool.n_blocks:
-                    raise ValueError(
-                        f"prompt needs {nbp} staging blocks but the "
-                        f"sp pool holds {self._sp_pool.n_blocks}: it "
-                        "can never prefill — raise sp_kv_blocks or "
-                        "shorten the prompt"
-                    )
-        else:
-            lp = pick_bucket(len(prompt), self._len_buckets)
-            if lp + max_new_tokens > self.max_len:
-                raise ValueError(
-                    f"prompt bucket {lp} + max_new_tokens "
-                    f"{max_new_tokens} exceeds cache max_len "
-                    f"{self.max_len}: raise max_len or shorten the "
-                    "request"
+                    f"prompt needs {nbp} staging blocks but the "
+                    f"sp pool holds {self._sp_pool.n_blocks}: it "
+                    "can never prefill — raise sp_kv_blocks or "
+                    "shorten the prompt"
                 )
         return self.queue.submit(
             GenRequest(prompt, max_new_tokens), timeout_s=timeout_s,
@@ -919,7 +851,7 @@ class ContinuousGPTEngine:
         )
 
     def _admission_budget_tokens(self, max_new_tokens: int) -> int:
-        """Decode-side tokens a paged admission must reserve blocks for
+        """Decode-side tokens an admission must reserve blocks for
         beyond the prompt. The colocated engine reserves the FULL token
         budget up front (decode can never hit mid-stream exhaustion);
         a prefill-tier worker (:mod:`sparkdl_tpu.disagg`) overrides this
@@ -957,15 +889,14 @@ class ContinuousGPTEngine:
         with self._lock:
             self._fail_inflight(EngineClosedError("engine shut down"))
         self._obs.close(drain=drain)
-        if self.kv_layout == "paged":
-            self._pool.close()
-            self._g_state.set(0)
-            if self._g_named is not None:
-                self._g_named.set(0)
-            if self.sp > 1:
-                self._sp_pool.close()
-            if self._kv_tiers is not None:
-                self._kv_tiers.close()
+        self._pool.close()
+        self._g_state.set(0)
+        if self._g_named is not None:
+            self._g_named.set(0)
+        if self.sp > 1:
+            self._sp_pool.close()
+        if self._kv_tiers is not None:
+            self._kv_tiers.close()
 
     def begin_drain(self) -> "list[Request]":
         """Graceful host drain, phase one (ISSUE 14): stop admission and
@@ -1001,15 +932,12 @@ class ContinuousGPTEngine:
             self.start()
         return self
 
-    def prefix_digest(self, max_entries: int = 1024) -> "dict | None":
+    def prefix_digest(self, max_entries: int = 1024) -> dict:
         """The compact prefix→host digest this host publishes
         (ISSUE 14): chained hashes of its cached block-aligned prompt
         prefixes, most-recently-used first, bounded. A router matches an
         incoming prompt's own block hashes against these to estimate
-        how many prefill blocks this host already holds. None under the
-        dense layout (no prefix cache — nothing to be affine to)."""
-        if self.kv_layout != "paged":
-            return None
+        how many prefill blocks this host already holds."""
         with self._lock:
             # version is the prefix cache's membership-mutation counter
             # (ISSUE 19), NOT a per-publish sequence: two wholesale
@@ -1033,8 +961,6 @@ class ContinuousGPTEngine:
         wholesale. The ``digest.delta`` fault site models a torn delta
         read — the router answers any error here the same way, with a
         wholesale re-sync."""
-        if self.kv_layout != "paged":
-            return None
         with self._lock:
             fault_point("digest.delta")
             delta = self._prefix.block_hash_delta(
@@ -1126,10 +1052,10 @@ class ContinuousGPTEngine:
                         deferred = True
                         break
                     admitted += 1
-                if (not deferred and self.kv_layout == "paged"
-                        and (self._pool.deferral_streak
-                             or (self.sp > 1
-                                 and self._sp_pool.deferral_streak))):
+                if not deferred and (
+                        self._pool.deferral_streak
+                        or (self.sp > 1
+                            and self._sp_pool.deferral_streak)):
                     # free slots existed and nothing deferred this tick
                     # (the deferred work admitted, or left the queue —
                     # e.g. expired): the exhaustion episode is over. A
@@ -1166,7 +1092,7 @@ class ContinuousGPTEngine:
 
     def _defer(self, reqs: "list[Request]") -> None:
         """KV pool exhaustion: re-queue in order, count the streak ON
-        THE POOL THAT ACTUALLY DEFERRED (``_admit_paged`` marks
+        THE POOL THAT ACTUALLY DEFERRED (``_admit`` marks
         ``_defer_pool`` — decode pool or the sp staging pool; a staging
         stall recorded against the decode pool would read healthy and
         never trip the postmortem), and after ``_EXHAUST_DUMP_STREAK``
@@ -1283,7 +1209,7 @@ class ContinuousGPTEngine:
     def _admit_traced(self, slot: int, req: Request) -> bool:
         """:meth:`_admit` inside the request's ``serving.admit`` span:
         what admission itself costs a tick (prefix match, block
-        allocation, a dense prefill) and what it found."""
+        allocation) and what it found."""
         with span("serving.admit", parent=req.trace_ctx,
                   request_id=req.request_id, slot=slot,
                   prompt_len=len(req.payload.prompt)) as sp:
@@ -1299,52 +1225,17 @@ class ContinuousGPTEngine:
                         self._prefix_passed_up - passed_up}
                        if self._family.state_layers else {}),
                     blocks=(len(st.all_blocks()) if st is not None
-                            else len(flight.blocks or ())
+                            else len(flight.blocks)
                             if flight is not None else 0))
             return placed
 
+    # -- admission + chunked prefill -----------------------------------------
     def _admit(self, slot: int, req: Request) -> bool:
-        """Place one taken request into ``slot``. Returns False when the
-        paged block pool cannot back it right now (caller defers)."""
-        if self.kv_layout == "paged":
-            return self._admit_paged(slot, req)
-        self._admit_dense(slot, req)
-        return True
-
-    def _admit_dense(self, slot: int, req: Request) -> None:
-        gen: GenRequest = req.payload
-        lp = pick_bucket(len(gen.prompt), self._len_buckets)
-        t0 = time.perf_counter()
-        with span("serving.prefill", parent=req.trace_ctx,
-                  prompt_len=len(gen.prompt), bucket=lp, slot=slot,
-                  request_id=req.request_id):
-            ids = np.zeros((1, lp), np.int32)
-            mask = np.zeros((1, lp), np.int32)
-            ids[0, lp - len(gen.prompt):] = gen.prompt
-            mask[0, lp - len(gen.prompt):] = 1
-            tok, row = self._prefill_fn(
-                self.variables, jnp.asarray(ids), jnp.asarray(mask)
-            )
-            self._cache = self._scatter_fn(
-                self._cache, row, jnp.asarray(slot, jnp.int32)
-            )
-            with self._first_token_span(req, slot):
-                first = int(tok[0])
-        self._prefill_seconds += time.perf_counter() - t0
-        self.metrics.record_tokens(1, phase="prefill")
-        self._start[slot] = lp - len(gen.prompt)
-        self._last_tok[slot] = first
-        flight = _InFlight(req, [first], gen.max_new_tokens, slot=slot)
-        self._inflight[slot] = flight
-        if self._is_done(flight):  # max_new_tokens=1, or instant eos
-            self._complete(flight)
-
-    # -- paged admission + chunked prefill -----------------------------------
-    def _admit_paged(self, slot: int, req: Request) -> bool:
-        """Match the longest cached prefix, allocate the request's
-        worst-case remaining blocks up front (so decode can never hit
-        mid-stream exhaustion), and queue the suffix for chunked
-        prefill. False = pool exhausted right now (defer)."""
+        """Place one taken request into ``slot``: match the longest
+        cached prefix, allocate the request's worst-case remaining blocks
+        up front (so decode can never hit mid-stream exhaustion), and
+        queue the suffix for chunked prefill. False = pool exhausted right
+        now (the caller defers)."""
         gen: GenRequest = req.payload
         prompt = np.asarray(gen.prompt, np.int32)
         plen = len(prompt)
@@ -1538,11 +1429,11 @@ class ContinuousGPTEngine:
             fault_point("kv.unpark")
             tree = {name: jnp.asarray(np.asarray(v)[:, None])
                     for name, v in payload.items()}
-            # sparkdl-lint: disable=lock-discipline -- only reachable from _admit_paged's restore_path callback, which the admission loop enters holding self._lock
+            # sparkdl-lint: disable=lock-discipline -- only reachable from _admit's restore_path callback, which the admission loop enters holding self._lock
             self._pool_kv = self._unpark_install_fn(
                 self._pool_kv, jnp.asarray([bid], jnp.int32), tree)
         except Exception as e:
-            # sparkdl-lint: disable=lock-discipline -- same reach as the install above: restore_path's caller (_admit_paged) already holds self._lock
+            # sparkdl-lint: disable=lock-discipline -- same reach as the install above: restore_path's caller (_admit) already holds self._lock
             self._park_fallbacks += 1
             kv_tiers_mod._M_FALLBACKS.inc(op="unpark")
             flight_mod.record_event(
@@ -1596,7 +1487,7 @@ class ContinuousGPTEngine:
         a torn export (``kv.migrate`` fault) skips that session, which
         simply re-prefills on resume (never lost, never duplicated).
         None when this engine has no tier store."""
-        if self.kv_layout != "paged" or self._kv_tiers is None:
+        if self._kv_tiers is None:
             return None
         from sparkdl_tpu.disagg.handoff import _enc
 
@@ -1662,8 +1553,7 @@ class ContinuousGPTEngine:
         cannot install here — re-prefill is the correct fallback), as
         is any session torn by the ``kv.migrate`` fault site. Returns
         sessions adopted."""
-        if (self.kv_layout != "paged" or self._kv_tiers is None
-                or not bundle):
+        if self._kv_tiers is None or not bundle:
             return 0
         from sparkdl_tpu.disagg.handoff import _dec
 
@@ -1844,8 +1734,8 @@ class ContinuousGPTEngine:
     def _finish_prefill(self, slot: int, st: _Prefill,
                         first: int) -> None:
         if tracing.tracing_enabled():
-            # the paged twin of the dense path's span, recorded now that
-            # the first token is on the host: admission to this instant,
+            # recorded now that the first token is on the host: admission
+            # to this instant,
             # ``ticks`` engine ticks of which ``chunks`` gave it a chunk
             # (the others went to other prompts' turns at the budget)
             tracing.record_span(
@@ -1876,11 +1766,11 @@ class ContinuousGPTEngine:
 
     def _join_decode(self, slot: int, flight: _InFlight,
                      depth: int) -> None:
-        """A row joins the decode batch (paged layout) with ``depth``
-        columns of K/V in its blocks and a first token the HOST knows,
-        ``flight.produced[-1]``: a prefill's, a handoff's, a resume's. No
-        launched step made that token, so the next step takes the host's
-        word for this row and not the device's (``_launch_step``)."""
+        """A row joins the decode batch with ``depth`` columns of K/V in its
+        blocks and a first token the HOST knows, ``flight.produced[-1]``:
+        a prefill's, a handoff's, a resume's. No launched step made that
+        token, so the next step takes the host's word for this row and not
+        the device's (``_launch_step``)."""
         self._pidx[slot] = depth
         self._last_tok[slot] = flight.produced[-1]
         flight.slot = slot
@@ -2006,9 +1896,8 @@ class ContinuousGPTEngine:
         if slot is None:
             return
         del self._inflight[slot]
-        if self.kv_layout == "paged":
-            self._table[slot] = self._pool.sentinel
-            self._pidx[slot] = 0
+        self._table[slot] = self._pool.sentinel
+        self._pidx[slot] = 0
 
     def _free(self, flight: _InFlight) -> None:
         """A decoding row ends, whichever way: its slot back, if it still
@@ -2280,17 +2169,13 @@ class ContinuousGPTEngine:
         return True
 
     def _decode_step(self) -> None:
-        """Advance the live rows. The paged loop keeps ONE step ahead of
-        its own reads: step n+1 is launched from step n's tokens while
-        they are still on the device, and only then are step n's ids
-        waited for, read and retired, so everything the host does between
-        two launches runs under a step. Two programs on other branches
-        stay synchronous: the dense reference (its ``_step_fn``), and a
-        speculative engine, whose verify needs the accepted count on the
-        host before its width is chosen."""
-        if self.kv_layout != "paged":
-            self._dense_step()
-            return
+        """Advance the live rows, in one of two shapes. The loop keeps ONE
+        step ahead of its own reads: step n+1 is launched from step n's
+        tokens while they are still on the device, and only then are step
+        n's ids waited for, read and retired, so everything the host does
+        between two launches runs under a step. A speculative engine stays
+        synchronous: its verify needs the accepted count on the host
+        before its width is chosen."""
         if self.spec_k is not None:
             self._read_first_tokens()
             if self._inflight and not self._spec_step():
@@ -2424,41 +2309,6 @@ class ContinuousGPTEngine:
         lock."""
         with self._lock:
             self._collect()
-
-    def _dense_step(self) -> None:
-        """The dense reference's step: launched, waited for, read and
-        retired in one tick."""
-        k = self._decode_chain_len(time.monotonic())
-        t0 = time.perf_counter()
-        links = ([f.req.request_id for f in self._inflight.values()]
-                 if tracing.tracing_enabled() else ())
-        with span("serving.decode_step", slots=len(self._inflight),
-                  chain=k, links=links):
-            with span("serving.decode_dispatch", k=k):
-                if k == 1:
-                    toks, self._cache = self._step_fn(
-                        self.variables, self._cache,
-                        jnp.asarray(self._last_tok),
-                        jnp.asarray(self._start),
-                    )
-                else:
-                    toks, self._cache = self._step_chain_fn(
-                        self.variables, self._cache,
-                        jnp.asarray(self._last_tok), k,
-                        jnp.asarray(self._start),
-                    )
-            fetch = start_fetch(toks, path="decode")
-            with span("serving.decode_wait"):
-                jax.block_until_ready(toks)
-            # sparkdl-lint: disable=blocking-in-hot-loop -- block_until_ready above completed the dispatch; only the already-enqueued D2H copy remains
-            toks = np.asarray(fetch.result())
-            if toks.ndim == 1:  # the unchained step: [S] -> [1, S]
-                toks = toks[None]
-        wall = time.perf_counter() - t0
-        record_dispatch("decode", k, wall)
-        self._chain_policy.record(wall, k)
-        self.metrics.record_batch(len(self._inflight), self.n_slots)
-        self._retire(toks, k, list(self._inflight.items()), links)
 
     def _retire(self, toks: np.ndarray, k: int,
                 rows: "list[tuple[int, _InFlight]]", links) -> None:
@@ -2634,9 +2484,7 @@ class ContinuousGPTEngine:
             pass
         return out
 
-    def _kv_snapshot(self) -> "dict[str, Any] | None":
-        if self.kv_layout != "paged":
-            return None
+    def _kv_snapshot(self) -> "dict[str, Any]":
         return {
             "block_size": self._kv_bs,
             "blocks_total": self._pool.n_blocks,
@@ -2705,11 +2553,9 @@ class ContinuousGPTEngine:
         out["active_slots"] = self.active_slots
         out["prefilling_slots"] = len(self._prefilling)
         out["inflight_request_ids"] = self.inflight_request_ids()
-        kv = self._kv_snapshot()
-        if kv is not None:
-            # healthz_report aggregates this shape: a nonzero
-            # exhaustion streak reads as degraded (self-recovering)
-            out["kv_pool"] = kv
+        # healthz_report aggregates this shape: a nonzero
+        # exhaustion streak reads as degraded (self-recovering)
+        out["kv_pool"] = self._kv_snapshot()
         spec = self._spec_snapshot()
         if spec is not None:
             out["spec"] = spec
@@ -2727,10 +2573,6 @@ class ContinuousGPTEngine:
         controller resizes, plus the engine lock that guards every
         pool mutation — ``AutoScaler(kv_pool=pool, kv_lock=lock)``
         then grows/shrinks without racing admission."""
-        if self.kv_layout != "paged":
-            raise RuntimeError(
-                "KV autoscaling needs kv_layout='paged' (the dense "
-                "layout has no block pool to resize)")
         return self._pool, self._lock
 
     def capacity(self) -> "dict[str, Any]":
@@ -2738,35 +2580,33 @@ class ContinuousGPTEngine:
         identity + room, instead of poking queue, pool, and slot state
         separately. Best-effort reads (no engine lock): routing weights
         tolerate a tick of staleness."""
-        paged = self.kv_layout == "paged"
         # parkable pressure split (ROADMAP item 1): cold = refcount-0
         # cached blocks that COULD page out on demand, parked = blocks
         # already in the host/disk tiers. A router that reads only
         # kv_blocks_free scores a host full when its pressure is
         # actually idle sessions — the headroom policy folds these in.
         cold = parked = sessions = None
-        if paged:
+        try:
+            cold = self._prefix.cold_blocks()
+        except RuntimeError:
+            cold = None  # racing registration: stale next refresh
+        if self._kv_tiers is not None:
+            s = self._kv_tiers.stats()
+            parked = s["host_blocks"] + s["disk_blocks"]
             try:
-                cold = self._prefix.cold_blocks()
+                sessions = self._prefix.parked_sessions()
             except RuntimeError:
-                cold = None  # racing registration: stale next refresh
-            if self._kv_tiers is not None:
-                s = self._kv_tiers.stats()
-                parked = s["host_blocks"] + s["disk_blocks"]
-                try:
-                    sessions = self._prefix.parked_sessions()
-                except RuntimeError:
-                    sessions = None
+                sessions = None
         return {
             "host_id": self.host_id,
             "replica_count": 1,
             "n_slots": self.n_slots,
             "free_slots": (self.n_slots - len(self._inflight)
                            - len(self._prefilling)),
-            "kv_blocks_free": self._pool.free_count if paged else None,
-            "kv_blocks_total": self._pool.n_blocks if paged else None,
-            "kv_bytes_per_token": (kv_bytes_per_token(
-                self.config, self.kv_dtype) if paged else None),
+            "kv_blocks_free": self._pool.free_count,
+            "kv_blocks_total": self._pool.n_blocks,
+            "kv_bytes_per_token": kv_bytes_per_token(
+                self.config, self.kv_dtype),
             "state_bytes": self.n_slots * self._family.state_bytes_per_slot,
             "kv_blocks_cold": cold,
             "kv_parked_blocks": parked,
@@ -2792,7 +2632,6 @@ class ContinuousGPTEngine:
         out["capacity"] = self.capacity()
         out["active_slots"] = self.active_slots
         out["n_slots"] = self.n_slots
-        out["kv_layout"] = self.kv_layout
         out["prefill_seconds"] = self._prefill_seconds
         out["kv"] = self._kv_snapshot()
         out["spec"] = self._spec_snapshot()

@@ -71,15 +71,15 @@ def bound(fn, *head):
 def _paged_step(sizes, model, variables, pool, table, idx, tok, prev, k, nb):
     # k tokens for every slot THROUGH the block table: the model takes the pool
     # itself as a paged cache (a ``table`` entry, models/gpt.py), so each layer
-    # gathers only its own live blocks into an [S, nb*bs] slice — same math
-    # over the same width as the dense layout, so greedy tokens stay
+    # gathers only its own live blocks into an [S, nb*bs] slice — the math
+    # ``generate`` does over its dense cache, so greedy tokens stay
     # bitwise-identical — and hands back the one new column per row, which is
     # the ONLY write to the donated pool (in place, at (block, offset)): no
     # all-layer dense view, no copy of the pool. ``nb`` (static, bucketed) is
     # the block count covering the DEEPEST live row through this chain — the
     # gather and attention touch only the live head of the table, often FEWER
-    # columns than the dense layout's fixed max_len (masked-width invariance
-    # keeps tokens bitwise). Rows are right-aligned (no left pad: column i
+    # columns than ``max_len`` (masked-width invariance keeps tokens
+    # bitwise). Rows are right-aligned (no left pad: column i
     # holds real token i), so the causal mask alone masks garbage columns and
     # positions need no start offset. Sentinel table entries clip on gather
     # (masked garbage) and write nothing (kv_pool.scatter_columns: no block
@@ -183,15 +183,15 @@ def _chunk_apply(sizes, model, variables, ck, cv, idx, ids, cols, n=None,
                  rec=None):
     # one bounded prefill chunk, right-aligned: writes K/V at columns [idx,
     # idx+width) of the private cache, where width = ids.shape[1] is the
-    # POWER-OF-2 BUCKET of this chunk's real token count (same compile-reuse
-    # trick as the dense path's prompt buckets: a 24-token suffix pays a
-    # 32-wide program, not a chunk-cap-wide one). ``cols`` (static, bucketed >=
+    # POWER-OF-2 BUCKET of this chunk's real token count (compile reuse: a
+    # 24-token suffix pays a 32-wide program, not a chunk-cap-wide one).
+    # ``cols`` (static, bucketed >=
     # idx+width) bounds the attention to the LIVE head of the buffer — every
     # column past it is causally masked garbage anyway, so slicing changes
     # nothing but the wasted FLOPs. The tail of the chunk is zero-padded on the
     # right; pad queries produce garbage columns PAST every real position, so
     # the causal mask hides them until real writes overwrite them — no
-    # attention_mask needed (vs the dense path's left-pad masking).
+    # attention_mask needed.
     #
     # A recurrence has no causal mask to hide a pad: a family with state
     # layers is handed ``n``, the chunk's count of REAL tokens, and ``rec``,
@@ -257,8 +257,8 @@ def _installed(sizes, pool, ck, cv, ids, slot=None, rec=None):
 # (dispatch gap dominates small programs — the ISSUE 3 lesson applied to
 # admission): the FIRST chunk fuses the prefix gather, the FINAL chunk fuses
 # the block install, so a suffix that fits one chunk is ONE device dispatch end
-# to end (vs dense's prefill + scatter pair). A chunk unrolls every layer: on
-# the chip the layers share their code (the engine compiles the four with
+# to end. A chunk unrolls every layer: on the chip the layers share their
+# code (the engine compiles the four with
 # runtime.chip.alike_layers_options), or a program that installs is twenty
 # times the size and loads as slowly.
 #
@@ -388,68 +388,3 @@ def _sp_prefix_fetch(sizes, pool, gids):
     # private cache)
     return kv_pool.gather_blocks_as(pool, gids, sizes.dtype)
 
-
-# -- the dense reference (kv_layout="dense") ----------------------------------
-
-def _prefill(max_len, model, variables, ids, mask):
-    # batch-1 left-padded prefill in a fresh scalar-idx cache of the SHARED
-    # buffer width, so columns line up at scatter time. jit's shape cache gives
-    # one compile per prompt-length bucket.
-    from sparkdl_tpu.models.gpt import init_cache
-
-    lp = ids.shape[1]
-    cache = init_cache(model.config, 1, max_len)
-    positions = jnp.clip(jnp.cumsum(mask, axis=1) - 1, 0)
-    key_valid = jnp.concatenate(
-        [mask.astype(bool),
-         jnp.ones((1, max_len - lp), bool)], axis=1,
-    )
-    logits, cache = model.apply(
-        variables, ids, cache=cache, positions=positions,
-        attention_mask=key_valid,
-    )
-    return jnp.argmax(logits[:, -1], axis=-1), cache
-
-
-# the engine donates the cache through scatter and step: it always discards the
-# old version, and without donation every token would materialize a second full
-# [layers, S, max_len, H, D] buffer (2x HBM peak + a copy per token at serving
-# sizes)
-def _scatter(cache, row, slot):
-    # install a prefilled row into slot (traced index: one compile)
-    return {
-        "k": jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], row["k"], slot, axis=1),
-        "v": jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], row["v"], slot, axis=1),
-        "idx": cache["idx"].at[slot].set(
-            row["idx"].astype(jnp.int32)),
-    }
-
-
-def _step(max_len, model, variables, cache, tok, start):
-    # one token for every slot; the per-slot cache gives each row its own
-    # causal depth, `start` masks its left-pad columns, and RoPE/learned
-    # positions count real tokens only
-    positions = (cache["idx"] - start)[:, None]
-    key_valid = jnp.arange(max_len)[None, :] >= start[:, None]
-    logits, cache = model.apply(
-        variables, tok[:, None], cache=cache, positions=positions,
-        attention_mask=key_valid,
-    )
-    return jnp.argmax(logits[:, -1], axis=-1), cache
-
-
-def _step_chain(max_len, model, variables, cache, tok, k, start):
-    # k tokens per dispatch: scan the single-step body carrying (cache, tok) —
-    # each step's argmax feeds the next, exactly the unchained sequence,
-    # amortizing the dispatch gap k-fold. The carried cache IS the iteration
-    # dependence (no CSE collapse possible) and rides the donated input buffer.
-    def body(carry, _):
-        tok, cache = _step(max_len, model, variables, *carry, start)
-        return (cache, tok), tok
-
-    (cache, _), toks = lax.scan(
-        body, (cache, tok), None, length=k
-    )
-    return toks, cache

@@ -253,7 +253,7 @@ def _gpt_fleet(n=2):
                            jnp.zeros((1, 8), jnp.int32))
     engines = [
         ContinuousGPTEngine(cfg, variables, n_slots=2, max_len=32,
-                            kv_layout="paged", kv_block_size=4,
+                            kv_block_size=4,
                             idle_wait_s=0.001, host_id=f"h{i}")
         for i in range(n)
     ]
@@ -298,7 +298,7 @@ def test_router_remove_host_drains_and_purges_then_add_host_rejoins():
         variables = GPTLMHeadModel(cfg).init(
             jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
         e2 = ContinuousGPTEngine(cfg, variables, n_slots=2, max_len=32,
-                                 kv_layout="paged", kv_block_size=4,
+                                 kv_block_size=4,
                                  idle_wait_s=0.001, host_id="h2")
         engines.append(e2)
         assert router.add_host(InProcessHost(e2, host_id="h2")) == "h2"

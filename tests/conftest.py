@@ -23,6 +23,7 @@ os.environ.setdefault("CUDA_VISIBLE_DEVICES", "-1")
 # linter's default walk skips the directory too — lint.core.EXCLUDED_DIRS)
 collect_ignore = ["lint_fixtures"]
 
+import functools  # noqa: E402
 import time  # noqa: E402
 
 import numpy as np  # noqa: E402
@@ -52,6 +53,31 @@ def wait_until(predicate, *, timeout_s=10.0, interval_s=0.01,
 @pytest.fixture(name="wait_until", scope="session")
 def wait_until_fixture():
     return wait_until
+
+
+@functools.cache
+def _generate_program():
+    import jax
+
+    from sparkdl_tpu.models.gpt import generate
+
+    # model and token budget are static: one compile a (model, prompt
+    # length, budget) a process, where the eager call traces its scan anew
+    # at every call
+    return jax.jit(generate, static_argnums=(0, 3))
+
+
+def oracle(model, variables, prompt, max_new):
+    """THE reference of the serving engine's parity suites: one prompt's
+    greedy tokens from the unbatched ``models.gpt.generate``, which shares
+    no scheduler, admission or cache code with the engine (prompt not
+    included, as an engine's Future resolves)."""
+    import jax.numpy as jnp
+
+    out = _generate_program()(
+        model, variables, jnp.asarray([prompt], jnp.int32), max_new
+    )
+    return np.asarray(out[0, len(prompt):])
 
 
 def fail_on_sleep_polls(root):

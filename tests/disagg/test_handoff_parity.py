@@ -202,14 +202,6 @@ def test_decode_worker_rejects_impossible_spans(bundle):
         dec.close()
 
 
-def test_workers_require_paged_layout(bundle):
-    cfg, variables = bundle
-    with pytest.raises(ValueError, match="paged"):
-        PrefillWorker(cfg, variables, **_kw(kv_layout="dense"))
-    with pytest.raises(ValueError, match="paged"):
-        DecodeWorker(cfg, variables, **_kw(kv_layout="dense"))
-
-
 def test_prefill_worker_reserves_prompt_blocks_only(bundle):
     """The prefill tier's admission budget is the PROMPT span: a pool
     the colocated engine would defer on (prompt + budget > pool)

@@ -11,8 +11,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import oracle
 from sparkdl_tpu.fabric import InProcessHost, Router
-from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel, generate
+from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel
 from sparkdl_tpu.observability.registry import registry
 from sparkdl_tpu.serving import ContinuousGPTEngine
 
@@ -27,12 +28,6 @@ def bundle():
     variables = model.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
     return cfg, model, variables
-
-
-def _oracle(model, variables, prompt, max_new):
-    out = generate(
-        model, variables, jnp.asarray([prompt], jnp.int32), max_new)
-    return np.asarray(out[0, len(prompt):])
 
 
 def _engine(cfg, variables, host_id, **kw):
@@ -106,7 +101,7 @@ def test_affinity_beats_round_robin_on_shared_prefixes(bundle):
     assert af_rate > 0.3
     _, followers = _workload()
     for p, got_af, got_rr in zip(followers, af_toks, rr_toks):
-        want = _oracle(model, variables, p, 3)
+        want = oracle(model, variables, p, 3)
         np.testing.assert_array_equal(got_af, want)
         np.testing.assert_array_equal(got_rr, want)
 
@@ -140,7 +135,7 @@ def test_stale_digest_degrades_to_load_routing(bundle):
         fut = router.submit(_payload(shared + [12]))
         got = fut.result(30)
     np.testing.assert_array_equal(
-        got, _oracle(model, variables, shared + [12], 3))
+        got, oracle(model, variables, shared + [12], 3))
     for e in (warm, cold):
         e.close()
 
@@ -176,7 +171,7 @@ def test_drain_transfers_unstarted_requests(bundle):
             b.tick()
         for (p, n), fut in zip(cases, futs):
             np.testing.assert_array_equal(
-                fut.result(0), _oracle(model, variables, p, n))
+                fut.result(0), oracle(model, variables, p, n))
         fut_extra.result(0)
     fam = registry().snapshot().get("sparkdl_requests_failed_total")
     assert fam is None or not any((fam.get("values") or {}).values())
@@ -210,7 +205,7 @@ def test_drain_transfers_despite_saturated_survivor(bundle):
         for i, fut in enumerate(futs):
             np.testing.assert_array_equal(
                 fut.result(0),
-                _oracle(model, variables, [i + 1, 2, 3], 2))
+                oracle(model, variables, [i + 1, 2, 3], 2))
     fam = registry().snapshot().get("sparkdl_requests_failed_total")
     assert fam is None or not any((fam.get("values") or {}).values())
     a.close(drain=False)
@@ -265,20 +260,6 @@ def test_explicit_host_id_wins_and_digest_names_it(bundle):
         # digest again must NOT advance it
         assert dig["version"] > 0
         assert eng.prefix_digest()["version"] == dig["version"]
-    finally:
-        eng.close(drain=False)
-
-
-def test_dense_engine_publishes_no_digest(bundle):
-    cfg, _, variables = bundle
-    eng = ContinuousGPTEngine(
-        cfg, variables, n_slots=1, max_len=MAX_LEN, kv_layout="dense",
-        host_id="dense-host", auto_start=False)
-    try:
-        assert eng.prefix_digest() is None
-        cap = eng.capacity()
-        assert cap["kv_blocks_total"] is None
-        assert cap["host_id"] == "dense-host"
     finally:
         eng.close(drain=False)
 

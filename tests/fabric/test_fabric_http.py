@@ -11,6 +11,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from conftest import oracle
 from sparkdl_tpu.fabric import (
     HostDrainingError,
     HostServer,
@@ -19,7 +20,7 @@ from sparkdl_tpu.fabric import (
     InProcessHost,
     Router,
 )
-from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel, generate
+from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel
 from sparkdl_tpu.serving import ContinuousGPTEngine
 
 MAX_LEN = 32
@@ -46,12 +47,6 @@ def served(bundle):
     eng.close(drain=False)
 
 
-def _oracle(model, variables, prompt, max_new):
-    out = generate(
-        model, variables, jnp.asarray([prompt], jnp.int32), max_new)
-    return np.asarray(out[0, len(prompt):])
-
-
 def test_http_submit_roundtrip_oracle(bundle, served):
     cfg, model, variables = bundle
     eng, server = served
@@ -60,7 +55,7 @@ def test_http_submit_roundtrip_oracle(bundle, served):
     prompt = [5, 1, 4, 4, 2]
     fut = handle.submit({"prompt": prompt, "max_new_tokens": 3})
     np.testing.assert_array_equal(
-        fut.result(30), _oracle(model, variables, prompt, 3))
+        fut.result(30), oracle(model, variables, prompt, 3))
     handle.close()
 
 
@@ -158,7 +153,7 @@ def test_http_drain_reroutes_to_survivor(bundle, wait_until):
             assert moved == 0  # transport drains fail-and-refail, not transfer
             for (p, n), fut in zip(cases, futs):
                 np.testing.assert_array_equal(
-                    fut.result(30), _oracle(model, variables, p, n))
+                    fut.result(30), oracle(model, variables, p, n))
             # the drained remote refuses new submits, typed
             fut = remote.submit({"prompt": [1, 2], "max_new_tokens": 1})
             with pytest.raises(HostDrainingError):

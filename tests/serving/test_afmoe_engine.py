@@ -122,10 +122,10 @@ def test_the_pool_is_shaped_by_the_familys_kv_heads_and_head_size(served):
 
 
 @pytest.mark.parametrize("option", [
-    {"kv_layout": "dense"}, {"sp": 2}, {"spec_k": 4}, {"kv_dtype": "int8"}])
+    {"sp": 2}, {"spec_k": 4}, {"kv_dtype": "int8"}])
 def test_what_the_family_has_no_path_for_is_refused_at_construction(
         served, option):
-    with pytest.raises(ValueError, match="paged path"):
+    with pytest.raises(ValueError, match="native K/V dtype alone"):
         ContinuousGPTEngine(served["cfg"], served["variables"],
                             auto_start=False, **option)
 

@@ -11,7 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel, generate
+from conftest import oracle
+from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel
 from sparkdl_tpu.runtime.dispatch import dispatch_count
 from sparkdl_tpu.serving import ContinuousGPTEngine
 
@@ -26,13 +27,6 @@ def bundle():
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
     )
     return cfg, model, variables
-
-
-def _oracle(model, variables, prompt, max_new):
-    out = generate(
-        model, variables, jnp.asarray([prompt], jnp.int32), max_new
-    )
-    return np.asarray(out[0, len(prompt):])
 
 
 def _engine(cfg, variables, **kw):
@@ -57,7 +51,7 @@ def test_chained_greedy_tokens_oracle_identical(bundle, chain_tokens):
     for (prompt, max_new), fut in zip(cases, futs):
         np.testing.assert_array_equal(
             fut.result(timeout=0),
-            _oracle(model, variables, prompt, max_new),
+            oracle(model, variables, prompt, max_new),
             err_msg=f"prompt {prompt} diverged under chain_tokens="
                     f"{chain_tokens}",
         )
@@ -94,7 +88,7 @@ def test_budget_bound_never_delays_retirement(bundle):
     assert fut.done()
     assert dispatch_count("decode") - before == 1
     np.testing.assert_array_equal(
-        fut.result(timeout=0), _oracle(model, variables, [5, 3, 9, 2, 7], 3)
+        fut.result(timeout=0), oracle(model, variables, [5, 3, 9, 2, 7], 3)
     )
     eng.close()
 
@@ -102,7 +96,7 @@ def test_budget_bound_never_delays_retirement(bundle):
 def test_eos_mid_chain_truncates_and_frees_slot(bundle):
     cfg, model, variables = bundle
     prompt = [16, 93, 39, 11, 38]  # its greedy stream opens on distinct ids
-    want = _oracle(model, variables, prompt, 8)
+    want = oracle(model, variables, prompt, 8)
     eos = int(want[2])  # fires mid-chain at chain_tokens=4
     assert eos not in want[:2], want  # the premise: eos FIRST fires at 3
     eng = _engine(cfg, variables, eos_id=eos, chain_tokens=4)
@@ -191,7 +185,7 @@ def test_the_chain_policy_is_fed_the_pace_of_the_loop_ahead(bundle,
     est = eng._chain_policy.program_s
     assert 0.7 * step_s < est < 2 * step_s
     np.testing.assert_array_equal(
-        fut.result(timeout=0), _oracle(model, variables, [5, 3, 9, 2, 7], 10))
+        fut.result(timeout=0), oracle(model, variables, [5, 3, 9, 2, 7], 10))
 
 
 def test_threaded_engine_with_chaining(bundle):
@@ -209,7 +203,7 @@ def test_threaded_engine_with_chaining(bundle):
     for (prompt, max_new), fut in zip(cases, futs):
         np.testing.assert_array_equal(
             fut.result(timeout=0),
-            _oracle(model, variables, prompt, max_new),
+            oracle(model, variables, prompt, max_new),
             err_msg=f"prompt {prompt}",
         )
     assert eng.snapshot()["completed"] == len(cases)

@@ -14,7 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel, generate
+from conftest import oracle
+from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel
 from sparkdl_tpu.serving import (
     ContinuousGPTEngine,
     DeadlineExceededError,
@@ -33,13 +34,6 @@ def bundle():
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
     )
     return cfg, model, variables
-
-
-def _oracle(model, variables, prompt, max_new):
-    out = generate(
-        model, variables, jnp.asarray([prompt], jnp.int32), max_new
-    )
-    return np.asarray(out[0, len(prompt):])
 
 
 def _engine(cfg, variables, **kw):
@@ -78,7 +72,7 @@ def test_join_leave_oracle_manual_ticks(bundle):
     eng.close()
     for (prompt, max_new), fut in zip(cases, futs):
         got = fut.result(timeout=0)
-        want = _oracle(model, variables, prompt, max_new)
+        want = oracle(model, variables, prompt, max_new)
         np.testing.assert_array_equal(
             got, want, err_msg=f"prompt {prompt} diverged from unbatched"
         )
@@ -99,7 +93,7 @@ def test_threaded_engine_oracle_and_drain(bundle):
     for (prompt, max_new), fut in zip(cases, futs):
         np.testing.assert_array_equal(
             fut.result(timeout=0),
-            _oracle(model, variables, prompt, max_new),
+            oracle(model, variables, prompt, max_new),
             err_msg=f"prompt {prompt}",
         )
     snap = eng.snapshot()
@@ -112,7 +106,7 @@ def test_threaded_engine_oracle_and_drain(bundle):
 def test_eos_frees_slot_early(bundle):
     cfg, model, variables = bundle
     prompt = [16, 93, 39, 11, 38]  # its greedy stream opens on distinct ids
-    want = _oracle(model, variables, prompt, 8)
+    want = oracle(model, variables, prompt, 8)
     eos = int(want[2])  # third generated token becomes the stop token
     assert eos not in want[:2], want  # the premise: eos FIRST fires at 3
     eng = _engine(cfg, variables, eos_id=eos)
@@ -164,7 +158,7 @@ def test_deadline_expiry_mid_queue(bundle):
     with pytest.raises(DeadlineExceededError):
         doomed.result(timeout=0)
     np.testing.assert_array_equal(
-        blocker.result(timeout=0), _oracle(model, variables, [9, 9], 6)
+        blocker.result(timeout=0), oracle(model, variables, [9, 9], 6)
     )
     eng.close()
 
@@ -221,7 +215,7 @@ def test_soak_many_requests_random_arrivals(bundle):
     for (prompt, max_new), fut in zip(cases, futs):
         np.testing.assert_array_equal(
             fut.result(timeout=0),
-            _oracle(model, variables, prompt, max_new),
+            oracle(model, variables, prompt, max_new),
             err_msg=f"prompt {prompt} x{max_new}",
         )
     assert eng.snapshot()["completed"] == 24

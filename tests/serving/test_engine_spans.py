@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import wait_until
 from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel
 from sparkdl_tpu.observability import profiling, tracing
 from sparkdl_tpu.observability.registry import registry
@@ -64,6 +65,10 @@ def _prompts(cfg, requests=REQUESTS, seed=0):
 
 def _serve(eng, prompts):
     futs = [eng.submit(p, m) for p, m in prompts]
+    if eng._thread is not None:  # the engine's own loop ticks
+        wait_until(lambda: all(f.done() for f in futs), timeout_s=120,
+                   desc="the engine's loop to finish")
+        return futs, [f.result(timeout=0) for f in futs]
     deadline = time.monotonic() + 120
     while not all(f.done() for f in futs):
         assert time.monotonic() < deadline, "engine did not finish"
@@ -85,15 +90,14 @@ def _end(e):
     return e["ts"] + e["dur"]
 
 
-@pytest.fixture(scope="module")
-def served(bundle):
-    """One traced run of REQUESTS through a manual-tick engine, and the
+def _traced_run(bundle, **kw):
+    """One traced run of REQUESTS through an engine, and the
     spans it left (the ring is copied, then cleared for the next test)."""
     tracing.clear_trace()
     tracing.enable_tracing()
     try:
         before = _tokens_counter()
-        eng = _engine(bundle)
+        eng = _engine(bundle, **kw)
         try:
             futs, outs = _serve(eng, _prompts(bundle[0]))
             snap = eng.snapshot()
@@ -106,6 +110,12 @@ def served(bundle):
     finally:
         tracing.disable_tracing()
         tracing.clear_trace()
+
+
+@pytest.fixture(scope="module")
+def served(bundle):
+    """:func:`_traced_run` through a manual-tick engine."""
+    return _traced_run(bundle)
 
 
 class TestTickTree:
@@ -173,7 +183,11 @@ class TestTickTree:
             assert {"inflight", "prefilling", "admitted",
                     "links"} <= set(t["args"])
 
-    def test_tokens_reconcile_spans_futures_and_counter(self, served):
+    @pytest.mark.parametrize("auto_start", [False, True])
+    def test_tokens_reconcile_spans_futures_and_counter(
+            self, bundle, served, auto_start):
+        if auto_start:  # the engine's own thread ticks, submits race it
+            served = _traced_run(bundle, auto_start=True)
         returned = sum(len(o) for o in served["outs"])
         from_spans = (sum(e["args"]["tokens"]
                           for e in served["spans"]("serving.retire"))
@@ -203,7 +217,7 @@ class TestTickTree:
 
     @pytest.mark.parametrize("name,keys", [
         ("serving.engine_init",
-         {"n_slots", "max_len", "kv_blocks", "kv_layout"}),
+         {"n_slots", "max_len", "kv_blocks"}),
         ("serving.admit", {"request_id", "slot", "prompt_len",
                            "cached_tokens", "blocks", "deferred"}),
         ("serving.prefill_chunk", {"width", "cols", "program", "tokens"}),
@@ -229,29 +243,19 @@ class TestTickTree:
             ["chunk_first", "chunk_mid", "chunk_final"]])
 
 
-@pytest.mark.parametrize("layout", ["paged", "dense"])
-def test_an_idle_engine_adds_no_span(bundle, traced, layout):
-    eng = _engine(bundle, kv_layout=layout, auto_start=True)
+@pytest.mark.parametrize("auto_start", [True, False])
+def test_an_idle_engine_adds_no_span(bundle, traced, auto_start):
+    eng = _engine(bundle, auto_start=auto_start)
     try:
         tracing.clear_trace()
-        time.sleep(0.2)  # some 40 idle ticks
+        if auto_start:
+            time.sleep(0.2)  # some 40 idle ticks
+        else:
+            for _ in range(40):
+                assert eng.tick() is False
         assert [e["name"] for e in tracing.trace_events()] == []
     finally:
         eng.close()
-
-
-def test_dense_layout_reconciles_too(bundle, traced):
-    before = _tokens_counter()
-    eng = _engine(bundle, kv_layout="dense")
-    try:
-        _, outs = _serve(eng, _prompts(bundle[0]))
-    finally:
-        eng.close()
-    returned = sum(len(o) for o in outs)
-    assert (sum(e["args"]["tokens"] for e in _spans("serving.retire"))
-            + len(_spans("serving.first_token"))) == returned
-    assert _tokens_counter() - before == returned
-    assert all("nb" not in e["args"] for e in _spans("serving.decode_step"))
 
 
 def test_chained_decode_counts_dropped_tokens_out(bundle, traced):
@@ -551,17 +555,6 @@ def test_a_paged_requests_trace_reads_without_a_hole(shared):
             _end(by_name["serving.admit"])
         assert by_name["serving.request"]["ts"] <= \
             by_name["serving.queue_wait"]["ts"]
-
-
-def test_the_dense_layouts_prefill_span_is_as_it_was(bundle, traced):
-    eng = _engine(bundle, kv_layout="dense")
-    try:
-        _serve(eng, _prompts(bundle[0], ((6, 2),)))
-    finally:
-        eng.close()
-    (e,) = _spans("serving.prefill")
-    assert {"prompt_len", "bucket", "slot", "request_id"} <= set(e["args"])
-    assert "ticks" not in e["args"]
 
 
 def test_prefill_counts_with_tracing_off_and_records_nothing(bundle,

@@ -318,9 +318,8 @@ def test_a_chain_of_four_is_four_single_steps(family):
 
 def test_what_the_family_has_no_path_for_is_refused_at_construction(family):
     _, cfg, variables, _ = family
-    for kw in ({"kv_layout": "dense"}, {"spec_k": 2}, {"kv_dtype": "int8"},
-               {"sp": 2}):
-        with pytest.raises(ValueError, match="paged path"):
+    for kw in ({"spec_k": 2}, {"kv_dtype": "int8"}, {"sp": 2}):
+        with pytest.raises(ValueError, match="native K/V dtype alone"):
             ContinuousGPTEngine(cfg, variables, n_slots=2, max_len=64,
                                 auto_start=False, **kw)
 

@@ -1,8 +1,8 @@
 """Paged KV cache: parity, prefix reuse, COW, eviction, deferral.
 
-The paged layout is a memory/scheduling decision, never a quality
+The paged cache is a memory/scheduling decision, never a quality
 decision: every test here ultimately pins greedy tokens against the
-dense engine and the unbatched ``generate`` oracle, while asserting the
+unbatched ``generate`` oracle, while asserting the
 paged machinery (block accounting, prefix hits, copy-on-write tail
 blocks, LRU eviction, deferred admission, chunk budgets) actually
 engaged.
@@ -15,7 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel, generate
+from conftest import oracle
+from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel
 from sparkdl_tpu.models.kv_pool import (
     init_block_pool,
     kv_per_head,
@@ -39,13 +40,6 @@ def bundle():
     return cfg, model, variables
 
 
-def _oracle(model, variables, prompt, max_new):
-    out = generate(
-        model, variables, jnp.asarray([prompt], jnp.int32), max_new
-    )
-    return np.asarray(out[0, len(prompt):])
-
-
 def _engine(cfg, variables, **kw):
     kw.setdefault("n_slots", 2)
     kw.setdefault("max_len", MAX_LEN)
@@ -67,11 +61,16 @@ def _counter(name):
 
 # -- parity ------------------------------------------------------------------
 
-def test_paged_bitwise_vs_dense_and_generate(bundle):
-    """Shared-prefix traffic through the paged engine must produce
-    greedy tokens bitwise-identical to BOTH the dense engine and the
-    unbatched oracle — across prefix hits, chunked prefill, and
-    mid-stream joins."""
+@pytest.mark.parametrize("kv_dtype", ["fp32", "bf16"])
+@pytest.mark.parametrize("kv_block_size, prefill_chunk", [
+    (4, 4), (16, None), (4, None), (16, 4)])
+def test_paged_tokens_are_generates(bundle, kv_block_size, prefill_chunk,
+                                    kv_dtype):
+    """Shared-prefix traffic through the engine must produce greedy
+    tokens bitwise-identical to the unbatched oracle — across prefix
+    hits, chunked and one-chunk prefill, blocks of a few tokens and blocks
+    wider than a prompt, and mid-stream joins. A ``bf16`` pool is held to
+    the oracle at the agreement ``test_kv_quant.py`` states."""
     cfg, model, variables = bundle
     shared = [5, 3, 9, 2, 7, 11, 4, 8]
     cases = [
@@ -80,23 +79,22 @@ def test_paged_bitwise_vs_dense_and_generate(bundle):
         ([6, 8, 6], 4),            # no shared prefix
         (shared + [1, 6], 3),      # full-prompt hit (minus last token)
     ]
-    outs = {}
-    for layout, kw in (
-        ("paged", dict(kv_block_size=4, prefill_chunk=4)),
-        ("dense", {}),
-    ):
-        eng = _engine(cfg, variables, kv_layout=layout, **kw)
-        futs = [eng.submit(p, n) for p, n in cases]
-        _drain(eng, futs)
-        eng.close()
-        outs[layout] = [f.result(timeout=0) for f in futs]
-    for (prompt, max_new), got_p, got_d in zip(
-            cases, outs["paged"], outs["dense"]):
-        want = _oracle(model, variables, prompt, max_new)
-        np.testing.assert_array_equal(
-            got_p, want, err_msg=f"paged diverged from oracle: {prompt}")
-        np.testing.assert_array_equal(
-            got_p, got_d, err_msg=f"paged diverged from dense: {prompt}")
+    eng = _engine(cfg, variables, kv_block_size=kv_block_size,
+                  prefill_chunk=prefill_chunk, kv_dtype=kv_dtype)
+    futs = [eng.submit(p, n) for p, n in cases]
+    _drain(eng, futs)
+    eng.close()
+    agree = total = 0
+    for (prompt, max_new), fut in zip(cases, futs):
+        got = fut.result(timeout=0)
+        want = oracle(model, variables, prompt, max_new)
+        if kv_dtype == "fp32":
+            np.testing.assert_array_equal(
+                got, want, err_msg=f"diverged from oracle: {prompt}")
+        assert len(got) == len(want)
+        agree += int((got == want).sum())
+        total += len(want)
+    assert agree / total > 0.8, (agree, total)
 
 
 # -- prefix reuse ------------------------------------------------------------
@@ -130,7 +128,7 @@ def test_prefix_hit_skips_prefill_of_cached_span(bundle):
         assert sum(s["args"]["tokens"] for s in spans1) == 10  # cold: all
         np.testing.assert_array_equal(
             f2.result(timeout=0),
-            _oracle(model, variables, shared + [2, 9], 4))
+            oracle(model, variables, shared + [2, 9], 4))
     finally:
         tracing.disable_tracing()
         tracing.clear_trace()
@@ -152,11 +150,11 @@ def test_cow_shared_partial_block_never_corrupts_sibling(bundle):
     eng.close()
     assert eng._prefix.hit_tokens == 4 + 2  # 1 full block + 2 partial
     np.testing.assert_array_equal(
-        fa.result(timeout=0), _oracle(model, variables, prefix, 8),
+        fa.result(timeout=0), oracle(model, variables, prefix, 8),
         err_msg="donor decode corrupted by COW sharer")
     np.testing.assert_array_equal(
         fb.result(timeout=0),
-        _oracle(model, variables, prefix + [1, 4], 6))
+        oracle(model, variables, prefix + [1, 4], 6))
 
 
 def test_lru_eviction_under_pool_pressure(bundle):
@@ -177,7 +175,7 @@ def test_lru_eviction_under_pool_pressure(bundle):
         _drain(eng, [fut])
         np.testing.assert_array_equal(
             fut.result(timeout=0),
-            _oracle(model, variables, prompt, 4))
+            oracle(model, variables, prompt, 4))
     eng.close()
     assert _counter("sparkdl_prefix_evictions_total") > ev0
     assert eng._prefix.evictions > 0
@@ -185,16 +183,11 @@ def test_lru_eviction_under_pool_pressure(bundle):
 
 # -- admission ---------------------------------------------------------------
 
-def test_paged_admission_bounds_raw_length_not_bucket(bundle):
-    """Dense rejects on the BUCKETED prompt length; paged stores tokens
-    unpadded, so it admits the same request and only rejects what can
-    truly never fit (raw length or whole-pool block need)."""
+def test_admission_bounds_raw_length_and_the_pool(bundle):
+    """Tokens are stored unpadded, so a request is admitted by its raw
+    length, and only what can truly never fit is rejected (raw length
+    or whole-pool block need)."""
     cfg, _, variables = bundle
-    # prompt 9 buckets to 16 under dense: 16 + 20 > 32 rejects
-    dense = _engine(cfg, variables, kv_layout="dense")
-    with pytest.raises(ValueError, match="exceeds cache max_len"):
-        dense.submit(list(range(1, 10)), 20)
-    dense.close()
     paged = _engine(cfg, variables)
     fut = paged.submit(list(range(1, 10)), 20)  # 9 + 20 <= 32: fits
     _drain(paged, [fut])
@@ -234,9 +227,9 @@ def test_deferred_admission_preserves_order(bundle):
     _drain(eng, [fb, fc])
     eng.close()
     np.testing.assert_array_equal(
-        fb.result(timeout=0), _oracle(model, variables, [1, 4], 4))
+        fb.result(timeout=0), oracle(model, variables, [1, 4], 4))
     np.testing.assert_array_equal(
-        fc.result(timeout=0), _oracle(model, variables, [2, 2], 4))
+        fc.result(timeout=0), oracle(model, variables, [2, 2], 4))
 
 
 def test_healthz_degraded_on_exhaustion_streak(bundle):
@@ -313,10 +306,10 @@ def test_long_prompt_admit_never_stalls_decode_beyond_chunk(bundle):
     _drain(eng, [short, longf])
     eng.close()
     np.testing.assert_array_equal(
-        short.result(timeout=0), _oracle(model, variables, [6, 8], 12))
+        short.result(timeout=0), oracle(model, variables, [6, 8], 12))
     np.testing.assert_array_equal(
         longf.result(timeout=0),
-        _oracle(model, variables, long_prompt, 3))
+        oracle(model, variables, long_prompt, 3))
     del produced_before
 
 
@@ -349,7 +342,7 @@ def test_soak_mixed_long_short_chunk_budget(bundle):
     for (prompt, max_new), fut in zip(cases, futs):
         np.testing.assert_array_equal(
             fut.result(timeout=0),
-            _oracle(model, variables, prompt, max_new),
+            oracle(model, variables, prompt, max_new),
             err_msg=f"prompt {prompt} x{max_new}",
         )
     assert eng._max_tick_prefill_tokens <= chunk
@@ -482,39 +475,40 @@ TABLE_CASES = {
 }
 
 
+@pytest.mark.parametrize("n_slots", [3, 2])
 @pytest.mark.parametrize("case", list(TABLE_CASES))
-def test_tokens_through_the_table_are_bitwise_the_dense_engines(bundle, case):
-    """Greedy tokens of the paged engine (one token a tick, chains of 4,
-    speculative verify spans of 4) against ``kv_layout="dense"``: three
+def test_tokens_through_the_table_are_bitwise_generates(bundle, case,
+                                                        n_slots):
+    """Greedy tokens of the engine (one token a tick, chains of 4,
+    speculative verify spans of 4) against ``generate``: three
     slots for four requests, so rows sit at different depths in one batch
-    and a slot idles on sentinel entries; the second request shares a
+    and a slot idles on sentinel entries (two slots: a queue forms behind
+    full slots); the second request shares a
     partial block with the first while the first still decodes into it."""
     cfg, model, variables = bundle
     prefix = [5, 3, 9, 2, 7, 11]
     # the repetitive last prompt gives the n-gram proposer drafts
     cases = [(prefix, 14), (prefix + [1, 4], 6), ([6, 8, 6], 12),
              ([1, 2, 3, 4] * 3, 10)]
-    outs = {}
-    for layout, kw in (("paged", dict(kv_block_size=4, prefill_chunk=8,
-                                      **TABLE_CASES[case])),
-                       ("dense", {})):
-        eng = _engine(cfg, variables, kv_layout=layout, n_slots=3, **kw)
-        futs = [eng.submit(*cases[0])]
-        eng.tick()
-        eng.tick()
-        assert not futs[0].done()
-        futs += [eng.submit(p, n) for p, n in cases[1:]]
-        _drain(eng, futs)
-        if layout == "paged":
-            assert eng._prefix.hit_tokens >= 4 + 2  # a block and a part
-            if "spec_k" in kw:
-                assert eng._spec_dispatches > 0
-        eng.close()
-        outs[layout] = [f.result(timeout=0) for f in futs]
-    for (prompt, n), got, want in zip(cases, outs["paged"], outs["dense"]):
+    kw = TABLE_CASES[case]
+    eng = _engine(cfg, variables, n_slots=n_slots, kv_block_size=4,
+                  prefill_chunk=8, **kw)
+    futs = [eng.submit(*cases[0])]
+    eng.tick()
+    eng.tick()
+    assert not futs[0].done()
+    futs += [eng.submit(p, n) for p, n in cases[1:]]
+    _drain(eng, futs)
+    assert eng._prefix.hit_tokens >= 4 + 2  # a block and a part
+    if "spec_k" in kw:
+        assert eng._spec_dispatches > 0
+    eng.close()
+    for (prompt, n), fut in zip(cases, futs):
+        got = fut.result(timeout=0)
         assert len(got) == n
         np.testing.assert_array_equal(
-            got, want, err_msg=f"{case}: paged diverged from dense: {prompt}")
+            got, oracle(model, variables, prompt, n),
+            err_msg=f"{case}: diverged from generate: {prompt}")
 
 
 @pytest.mark.parametrize("kw", [
@@ -525,25 +519,23 @@ def test_heads_that_fill_lane_tiles_keep_their_own_axis(kw):
     alone (``models/family.py``), so its pool keeps ``[.., heads, 128]``
     and its columns go in one at a time (the engine's loop, the afmoe
     family's path); the model merges the gathered rows itself. Same greedy
-    tokens as ``kv_layout="dense"``; int8 (one scale a column of both
+    tokens as ``generate``; int8 (one scale a column of both
     axes) serves its requests whole."""
     cfg = GPTConfig.tiny(hidden_size=256, num_heads=2)
     assert cfg.serving_family().kv_tail == (2, 128)
-    variables = GPTLMHeadModel(cfg).init(
+    model = GPTLMHeadModel(cfg)
+    variables = model.init(
         jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32))
     cases = [([5, 3, 9, 2, 7, 11], 9), ([5, 3, 9, 2, 7, 11, 1, 4], 5),
              ([1, 2, 3, 4] * 3, 8)]
-    outs = {}
-    for layout, extra in (("paged", dict(kv_block_size=4, prefill_chunk=8,
-                                         **kw)), ("dense", {})):
-        eng = _engine(cfg, variables, kv_layout=layout, **extra)
-        if layout == "paged":
-            assert eng._pool_kv["k"].shape[2:] == (4, 2, 128)
-        futs = [eng.submit(p, n) for p, n in cases]
-        _drain(eng, futs)
-        eng.close()
-        outs[layout] = [f.result(timeout=0) for f in futs]
-    for (_, n), got, want in zip(cases, outs["paged"], outs["dense"]):
+    eng = _engine(cfg, variables, kv_block_size=4, prefill_chunk=8, **kw)
+    assert eng._pool_kv["k"].shape[2:] == (4, 2, 128)
+    futs = [eng.submit(p, n) for p, n in cases]
+    _drain(eng, futs)
+    eng.close()
+    for (prompt, n), fut in zip(cases, futs):
+        got = fut.result(timeout=0)
         assert len(got) == n
         if "kv_dtype" not in kw:
-            np.testing.assert_array_equal(got, want)
+            np.testing.assert_array_equal(
+                got, oracle(model, variables, prompt, n))

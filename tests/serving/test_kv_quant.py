@@ -15,7 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel, generate
+from conftest import oracle
+from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel
 from sparkdl_tpu.models.kv_pool import dequantize_kv, quantize_kv
 from sparkdl_tpu.observability.flight import healthz_report
 from sparkdl_tpu.observability.registry import registry
@@ -38,13 +39,6 @@ def bundle():
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
     )
     return cfg, model, variables
-
-
-def _oracle(model, variables, prompt, max_new):
-    out = generate(
-        model, variables, jnp.asarray([prompt], jnp.int32), max_new
-    )
-    return np.asarray(out[0, len(prompt):])
 
 
 def _engine(cfg, variables, **kw):
@@ -142,7 +136,7 @@ def test_quantized_engine_deterministic_and_near_oracle(bundle, kv_dtype):
         np.testing.assert_array_equal(x, y)  # deterministic
     agree = total = 0
     for (p, n), got in zip(cases, a):
-        want = _oracle(model, variables, p, n)
+        want = oracle(model, variables, p, n)
         assert len(got) == len(want)
         agree += int((got == want).sum())
         total += len(want)
@@ -184,16 +178,12 @@ def test_quantized_cow_shared_partial_block(bundle):
     eng2.close()
 
 
-def test_fp32_default_unchanged_and_dense_rejects_quant(bundle):
+def test_fp32_default_unchanged_and_unknown_dtype_refused(bundle):
     cfg, model, variables = bundle
     cases = [([5, 3, 9, 2, 7], 6)]
     got = _run(cfg, variables, cases)  # default fp32: exact
     np.testing.assert_array_equal(
-        got[0], _oracle(model, variables, *cases[0]))
-    with pytest.raises(ValueError, match="require kv_layout='paged'"):
-        _engine(cfg, variables, kv_layout="dense", kv_dtype="int8")
-    with pytest.raises(ValueError, match="require kv_layout='paged'"):
-        _engine(cfg, variables, kv_layout="dense", spec_k=4)
+        got[0], oracle(model, variables, *cases[0]))
     with pytest.raises(ValueError, match="unknown KV"):
         _engine(cfg, variables, kv_dtype="fp8")
 
@@ -298,7 +288,7 @@ def test_healthz_degraded_clears_on_release_not_admission(bundle):
     _drain(eng, [fb])
     eng.close()
     np.testing.assert_array_equal(
-        fb.result(timeout=0), _oracle(model, variables, [1, 4], 4))
+        fb.result(timeout=0), oracle(model, variables, [1, 4], 4))
 
 
 # -- compressed pools through the block table (ISSUE 27) ---------------------

@@ -22,7 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel, generate
+from conftest import oracle
+from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel
 from sparkdl_tpu.observability.flight import (
     flight_recorder,
     healthz_report,
@@ -45,13 +46,6 @@ def bundle():
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
     )
     return cfg, model, variables
-
-
-def _oracle(model, variables, prompt, max_new):
-    out = generate(
-        model, variables, jnp.asarray([prompt], jnp.int32), max_new
-    )
-    return np.asarray(out[0, len(prompt):])
 
 
 def _engine(cfg, variables, **kw):
@@ -136,9 +130,9 @@ def test_park_resume_bitwise_identical_to_never_parked(
     if kv_dtype == "fp32" and mode == "plain":
         # fp32 plain additionally pins against the unbatched oracle
         for p, r2 in zip(prompts, got):
-            turn1 = _oracle(model, variables, p, 4).tolist()
+            turn1 = oracle(model, variables, p, 4).tolist()
             full = p + turn1 + [5]
-            assert r2 == _oracle(model, variables, full, 4).tolist()
+            assert r2 == oracle(model, variables, full, 4).tolist()
 
 
 # -- refcounted shares / COW donors never park --------------------------------
@@ -167,9 +161,9 @@ def test_live_blocks_and_cow_donor_pinned_while_decoding(bundle):
         assert freed == 0
         _drain(eng, [fa, fb])
         assert (fa.result(timeout=0).tolist()
-                == _oracle(model, variables, donor_prompt, 8).tolist())
+                == oracle(model, variables, donor_prompt, 8).tolist())
         assert (fb.result(timeout=0).tolist()
-                == _oracle(model, variables, donor_prompt + [1, 6],
+                == oracle(model, variables, donor_prompt + [1, 6],
                            4).tolist())
         # retired: the same sessions are now cold and DO park
         assert eng.park_cold() > 0
@@ -262,7 +256,7 @@ def test_torn_park_falls_back_to_eviction_zero_lost(bundle):
             _drain(eng, futs)
         for p, f in zip(prompts, futs):
             assert (f.result(timeout=0).tolist()
-                    == _oracle(model, variables, p, 4).tolist())
+                    == oracle(model, variables, p, 4).tolist())
         assert eng._kv_snapshot()["tiers"]["park_fallbacks"] >= 1
     assert _counter("sparkdl_kv_park_fallbacks_total") >= 1
     evs = [e for e in flight_recorder().events()
@@ -291,7 +285,7 @@ def test_corrupt_unpark_falls_back_to_reprefill_zero_lost(bundle):
                      for p, r in zip(prompts, replies)]
             _drain(eng, futs2)
         for p, r, f in zip(prompts, replies, futs2):
-            want = _oracle(model, variables, p + r + [5], 4).tolist()
+            want = oracle(model, variables, p + r + [5], 4).tolist()
             assert f.result(timeout=0).tolist() == want
         assert eng._kv_snapshot()["tiers"]["park_fallbacks"] >= 1
     evs = [e for e in flight_recorder().events()

@@ -303,12 +303,12 @@ def test_a_chain_of_four_is_four_single_steps(family):
 # -- refused, or passed up, by name: the code PR 34 wrote, no new refusal ----------
 
 @pytest.mark.parametrize("kw", [
-    {"kv_layout": "dense"}, {"spec_k": 2}, {"kv_dtype": "int8"}, {"sp": 2}],
+    {"spec_k": 2}, {"kv_dtype": "int8"}, {"sp": 2}],
     ids=lambda kw: next(iter(kw)))
 def test_what_the_family_has_no_path_for_is_refused_at_construction(
         family, kw):
     _, cfg, variables, _ = family
-    with pytest.raises(ValueError, match="Lfm2MoeConfig.*paged path"):
+    with pytest.raises(ValueError, match="Lfm2MoeConfig.*native K/V dtype alone"):
         ContinuousGPTEngine(cfg, variables, n_slots=2, max_len=64,
                             auto_start=False, **kw)
 

@@ -322,11 +322,11 @@ def test_a_chain_of_four_is_four_single_steps(family):
 # -- refused, or passed up, by name ------------------------------------------------
 
 @pytest.mark.parametrize("option", [
-    {"kv_layout": "dense"}, {"sp": 2}, {"spec_k": 4}, {"kv_dtype": "int8"}])
+    {"sp": 2}, {"spec_k": 4}, {"kv_dtype": "int8"}])
 def test_what_the_family_has_no_path_for_is_refused_at_construction(
         family, option):
     _, cfg, variables, _ = family
-    with pytest.raises(ValueError, match="paged path"):
+    with pytest.raises(ValueError, match="native K/V dtype alone"):
         ContinuousGPTEngine(cfg, variables, auto_start=False, **option)
 
 

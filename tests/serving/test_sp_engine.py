@@ -1,6 +1,6 @@
 """Sequence-parallel serving engine (ISSUE 13): spatial prefill chunks
-at sp=2 on the conftest CPU mesh, pinned bitwise against sp=1, the dense
-engine, and the unbatched oracle — across prefix hits, COW tails,
+at sp=2 on the conftest CPU mesh, pinned bitwise against sp=1
+and the unbatched oracle — across prefix hits, COW tails,
 chained decode, speculative decode, and quantized pools — plus the
 prefill→decode handoff bookkeeping and the sp.permute/sp.gather chaos
 contract (injected collective fault → typed flight event, request
@@ -13,7 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel, generate
+from conftest import oracle
+from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel
 from sparkdl_tpu.observability.flight import flight_recorder
 from sparkdl_tpu.reliability import faults
 from sparkdl_tpu.serving import ContinuousGPTEngine
@@ -54,12 +55,6 @@ def _run(cfg, variables, cases=CASES, **kw):
     return [np.asarray(f.result(timeout=0)) for f in futs], snap
 
 
-def _oracle(model, variables, prompt, max_new):
-    out = generate(
-        model, variables, jnp.asarray([prompt], jnp.int32), max_new)
-    return np.asarray(out[0, len(prompt):])
-
-
 # -- parity ------------------------------------------------------------------
 
 @pytest.mark.parametrize("decode_kw", [
@@ -77,20 +72,11 @@ def test_sp2_bitwise_vs_sp1_and_oracle(bundle, decode_kw):
     for (prompt, max_new), a, b in zip(CASES, sp1, sp2):
         np.testing.assert_array_equal(a, b)
         np.testing.assert_array_equal(
-            b, _oracle(model, variables, prompt, max_new))
+            b, oracle(model, variables, prompt, max_new))
     kv = snap["kv"]
     assert kv["sp"]["axis"] == 2
     assert kv["sp"]["handoffs"] == len(CASES)
     assert kv["prefix_hits"] > 0  # the hit survived the sharded gather
-
-
-def test_sp2_bitwise_vs_dense(bundle):
-    cfg, _, variables = bundle
-    dense, _ = _run(cfg, variables, kv_layout="dense",
-                    kv_block_size=16, prefill_chunk=None)
-    sp2, _ = _run(cfg, variables, sp=2)
-    for a, b in zip(dense, sp2):
-        np.testing.assert_array_equal(a, b)
 
 
 def test_sp2_quantized_pool_matches_sp1(bundle):
@@ -126,7 +112,7 @@ def test_sp_cow_partial_block_across_sharded_gather(bundle):
     for (prompt, max_new), fut in ((donor, f_donor), (sharer, f_sharer)):
         np.testing.assert_array_equal(
             np.asarray(fut.result(timeout=0)),
-            _oracle(model, variables, prompt, max_new))
+            oracle(model, variables, prompt, max_new))
     assert snap["kv"]["prefix_hits"] > 0
 
 
@@ -154,7 +140,7 @@ def test_sp_final_chunk_never_clamps_at_table_edge(bundle):
     assert snap["kv"]["prefix_hits"] >= 3  # the grid really is offset
     np.testing.assert_array_equal(
         np.asarray(f2.result(timeout=0)),
-        _oracle(model, variables, edge[0], edge[1]))
+        oracle(model, variables, edge[0], edge[1]))
 
 
 def test_sp_staging_exhaustion_defers_on_staging_pool(bundle):
@@ -212,24 +198,7 @@ def test_sp_non_divisible_chunk_cap_floors_to_sp_multiple(bundle):
     eng.close()
     np.testing.assert_array_equal(
         np.asarray(fut.result(timeout=0)),
-        _oracle(model, variables, prompt, 4))
-
-
-def test_sp_requires_paged_layout(bundle):
-    cfg, _, variables = bundle
-    with pytest.raises(ValueError, match="paged"):
-        ContinuousGPTEngine(cfg, variables, kv_layout="dense", sp=2,
-                            auto_start=False)
-
-
-def test_sp_env_pin_requires_paged_layout_too(bundle, monkeypatch):
-    # The env pin must be as loud as the argument: SPARKDL_TPU_SP=2 on
-    # a dense-layout engine raises, never a silently non-sp engine.
-    cfg, _, variables = bundle
-    monkeypatch.setenv("SPARKDL_TPU_SP", "2")
-    with pytest.raises(ValueError, match="paged"):
-        ContinuousGPTEngine(cfg, variables, kv_layout="dense",
-                            auto_start=False)
+        oracle(model, variables, prompt, 4))
 
 
 def test_sp_power_of_two_validated(bundle):
@@ -269,7 +238,7 @@ def test_sp_collective_fault_requeues_without_loss(bundle, site, plan):
         faults.disarm()
     for (prompt, max_new), got in zip(CASES, outs):
         np.testing.assert_array_equal(
-            got, _oracle(model, variables, prompt, max_new))
+            got, oracle(model, variables, prompt, max_new))
     evs = [e for e in flight_recorder().events()
            if e.get("kind") == "sp.collective_failed"]
     assert any(e["site"] == site for e in evs), (site, evs)
@@ -299,7 +268,7 @@ def test_sp_staging_alloc_fault_defers_without_leak(bundle):
     finally:
         faults.disarm()
     np.testing.assert_array_equal(
-        got, _oracle(model, variables, prompt, 3))
+        got, oracle(model, variables, prompt, 3))
     # no leak: the retired request's cached prompt blocks are all that
     # remain off the free list, and staging drained fully
     assert snap["blocks_used"] == snap["blocks_cached"], snap

@@ -14,7 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel, generate
+from conftest import oracle
+from sparkdl_tpu.models.gpt import GPTConfig, GPTLMHeadModel
 from sparkdl_tpu.observability.registry import registry
 from sparkdl_tpu.reliability.faults import inject
 from sparkdl_tpu.runtime.dispatch import SpecPolicy, dispatch_count
@@ -39,13 +40,6 @@ def bundle():
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
     )
     return cfg, model, variables
-
-
-def _oracle(model, variables, prompt, max_new):
-    out = generate(
-        model, variables, jnp.asarray([prompt], jnp.int32), max_new
-    )
-    return np.asarray(out[0, len(prompt):])
 
 
 def _engine(cfg, variables, **kw):
@@ -81,7 +75,7 @@ class _OracleDraft:
     def propose(self, context, k):
         key = tuple(int(t) for t in context)
         if key not in self._memo:
-            self._memo[key] = [int(t) for t in _oracle(
+            self._memo[key] = [int(t) for t in oracle(
                 self.model, self.variables, list(key), k)]
         return self._memo[key][:k]
 
@@ -124,7 +118,7 @@ def test_spec_bitwise_vs_plain_and_oracle(bundle, spec_k):
         outs[spec] = [f.result(timeout=0) for f in futs]
     for (prompt, max_new), got_s, got_p in zip(
             cases, outs[spec_k], outs[None]):
-        want = _oracle(model, variables, prompt, max_new)
+        want = oracle(model, variables, prompt, max_new)
         np.testing.assert_array_equal(
             got_s, want,
             err_msg=f"spec_k={spec_k} diverged from oracle: {prompt}")
@@ -146,7 +140,7 @@ def test_perfect_drafts_cut_decode_dispatches(bundle):
     eng.close()
     assert dispatch_count("decode") - before == 2
     np.testing.assert_array_equal(
-        fut.result(timeout=0), _oracle(model, variables, [5, 3, 9], 9))
+        fut.result(timeout=0), oracle(model, variables, [5, 3, 9], 9))
     snap = eng._spec_snapshot()
     assert snap["dispatches"] == 2
     assert snap["acceptance_rate"] == 1.0
@@ -166,7 +160,7 @@ def test_draft_rejected_at_position_0(bundle):
     eng.close()
     np.testing.assert_array_equal(
         fut.result(timeout=0),
-        _oracle(model, variables, [5, 3, 9, 2, 7], 10))
+        oracle(model, variables, [5, 3, 9, 2, 7], 10))
     assert eng._spec_dispatches >= 1
     assert eng._spec_accepted == 0
     assert eng._spec_proposed > 0
@@ -178,7 +172,7 @@ def test_eos_inside_accepted_span_truncates_and_frees(bundle):
     in that same tick — one verify dispatch end to end."""
     cfg, model, variables = bundle
     prompt = [16, 93, 39, 11, 38]  # its greedy stream opens on distinct ids
-    want = _oracle(model, variables, prompt, 8)
+    want = oracle(model, variables, prompt, 8)
     eos = int(want[3])  # inside the first spec_k=8 accepted span
     assert eos not in want[:3], want  # the premise: eos FIRST fires at 4
     eng = _engine(cfg, variables, eos_id=eos, spec_k=8,
@@ -206,7 +200,7 @@ def test_budget_bounds_verify_width(bundle):
     assert dispatch_count("decode") - before == 1
     np.testing.assert_array_equal(
         fut.result(timeout=0),
-        _oracle(model, variables, [5, 3, 9, 2, 7], 3))
+        oracle(model, variables, [5, 3, 9, 2, 7], 3))
     eng.close()
 
 
@@ -239,7 +233,7 @@ def test_deadline_shrinks_spec_to_single_token_mid_stream(bundle):
     _drain(eng, [fut])
     eng.close()
     np.testing.assert_array_equal(
-        fut.result(timeout=0), _oracle(model, variables, [3, 4], 9))
+        fut.result(timeout=0), oracle(model, variables, [3, 4], 9))
 
 
 # -- chaos: the spec.verify fault site ---------------------------------------
@@ -260,7 +254,7 @@ def test_injected_verify_failure_falls_back_single_token(bundle):
     for (prompt, max_new), fut in zip(cases, futs):
         np.testing.assert_array_equal(
             fut.result(timeout=0),
-            _oracle(model, variables, prompt, max_new))
+            oracle(model, variables, prompt, max_new))
     assert eng._spec_fallbacks == 2
     assert eng._spec_dispatches >= 1  # speculation resumed after
     assert _counter("sparkdl_spec_fallbacks_total") == fb0 + 2
