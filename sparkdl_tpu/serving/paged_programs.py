@@ -19,6 +19,7 @@ function renamed here reads as null there with every CPU test green;
 from __future__ import annotations
 
 import dataclasses
+import functools
 import inspect
 from typing import Any
 
@@ -27,6 +28,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from sparkdl_tpu.models import kv_pool
+from sparkdl_tpu.ops import delta_solve, paged_decode, sparse_attention
+from sparkdl_tpu.parallel import moe_dropless
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,12 +53,35 @@ class PagedSizes:
     arrays: "tuple[str, str]" = kv_pool.KV
 
 
+def _rules():
+    """What a module asks WHILE IT IS TRACED that no ``head`` holds: the
+    rules that pick a kernel or a gather, interpreted or not. Constants of a
+    process, unless a test replaces one to compile a step's other form."""
+    return (paged_decode.reads_in_place, sparse_attention.IN_PLACE_SELECTIONS,
+            delta_solve.solves_in_kernel, paged_decode.auto_interpret,
+            delta_solve.auto_interpret, moe_dropless.auto_interpret)
+
+
 def bound(fn, *head):
     """``fn`` with its leading arguments fixed, STILL under ``fn``'s name:
     ``jax.jit`` names a program after the function it is given, and a bare
     ``functools.partial`` has none (``jit__unknown`` on the device). The
     signature is what is left to pass (no ``__wrapped__``: jit would read
-    the unbound one off it), so the compiled parameters keep their names."""
+    the unbound one off it), so the compiled parameters keep their names.
+
+    ONE function a ``(fn, *head)`` a process: jax keys what it has traced,
+    lowered and compiled on the function a ``jax.jit`` was handed and that
+    jit's options, so engines of equal configuration (a pool's replicas, an
+    autoscaler's next one, a test's second) share one set of programs where
+    each used to trace and compile its own. That leans on ``head`` comparing
+    by value (:class:`PagedSizes` is frozen, a flax module hashes by its
+    fields) and on ``fn`` closing over nothing but its ``head`` and
+    :func:`_rules`: whatever else it read would stay the first engine's."""
+    return _bound(fn, head, _rules())
+
+
+@functools.cache
+def _bound(fn, head, rules):
     def program(*args):
         return fn(*head, *args)
 

@@ -102,7 +102,20 @@ def test_gathered_save_equals_sharded_save_values(tmp_path):
             np.asarray(params["dense"]["kernel"]))
 
 
-def test_corrupt_sharded_checkpoint_detected(tmp_path):
+@pytest.fixture
+def verdict_put_back():
+    """The manager publishes its last verdict process-wide
+    (``checkpoint_integrity``). A corrupt one left behind reads as
+    ``degraded`` in every later ``/healthz`` of this worker, and in
+    collection order ``tests/serving/test_brownout.py`` is later."""
+    from sparkdl_tpu.observability import flight
+
+    before = flight.health_facts().get("checkpoint_integrity")
+    yield
+    flight.set_health_fact("checkpoint_integrity", before)
+
+
+def test_corrupt_sharded_checkpoint_detected(tmp_path, verdict_put_back):
     """Integrity detection is layout-blind: flip a byte in a sharded
     save and restore must refuse it (pinned step -> typed error)."""
     import os

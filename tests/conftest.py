@@ -30,6 +30,17 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 
+def pytest_configure(config):
+    # pytest-xdist hands ``--dist loadfile`` files out by their COUNT of
+    # tests, largest first (``--loadscope-reorder``, on by default), which
+    # leaves the longest file of few tests (tests/benchmark/
+    # test_benchmark.py: nine tests, a third of the run's wall clock) for
+    # the middle of the run, to end alone. In collection order it starts
+    # with the run. A run without xdist has no such option and is untouched.
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
 def wait_until(predicate, *, timeout_s=10.0, interval_s=0.01,
                desc="condition"):
     """Deadline-bounded polling: the ONE sanctioned way to wait for an

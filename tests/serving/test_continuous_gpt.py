@@ -78,29 +78,40 @@ def test_join_leave_oracle_manual_ticks(bundle):
         )
 
 
-def test_threaded_engine_oracle_and_drain(bundle):
+@pytest.mark.parametrize("n_engines", [1, 2])
+def test_threaded_engine_oracle_and_drain(bundle, n_engines):
     """Background-thread mode: async submits, close(drain=True) finishes
-    every admitted request."""
+    every admitted request. Two engines of one configuration run the SAME
+    bound programs (``paged_programs.bound``), each on its own thread and
+    donating its own pool: what is shared is code, never a buffer."""
     cfg, model, variables = bundle
-    eng = ContinuousGPTEngine(cfg, variables, n_slots=2, max_len=MAX_LEN,
-                              idle_wait_s=0.001)
+    engines = [ContinuousGPTEngine(cfg, variables, n_slots=2,
+                                   max_len=MAX_LEN, idle_wait_s=0.001)
+               for _ in range(n_engines)]
     cases = [([7, 1, 3], 5), ([2, 9], 4), ([4, 4, 4, 4], 6), ([8], 3)]
-    futs = []
+    futs = [[] for _ in engines]
     for p, n in cases:
-        futs.append(eng.submit(p, n))
+        for eng, mine in zip(engines, futs):
+            mine.append(eng.submit(p, n))
         time.sleep(0.01)  # stagger arrivals into the running decode
-    eng.close(drain=True)  # shutdown with inflight + queued requests
-    for (prompt, max_new), fut in zip(cases, futs):
-        np.testing.assert_array_equal(
-            fut.result(timeout=0),
-            oracle(model, variables, prompt, max_new),
-            err_msg=f"prompt {prompt}",
-        )
-    snap = eng.snapshot()
-    assert snap["completed"] == len(cases)
-    assert snap["active_slots"] == 0
-    assert snap["latency_s"]["p99"] is not None
-    assert 0 < snap["batch_occupancy_pct"] <= 100
+    for a, b in zip(engines, engines[1:]):
+        assert a._paged_step_fn.__wrapped__ is b._paged_step_fn.__wrapped__
+        assert a._chunk_one_fn.__wrapped__ is b._chunk_one_fn.__wrapped__
+        assert a._pool_kv is not b._pool_kv
+    for eng in engines:
+        eng.close(drain=True)  # shutdown with inflight + queued requests
+    for eng, mine in zip(engines, futs):
+        for (prompt, max_new), fut in zip(cases, mine):
+            np.testing.assert_array_equal(
+                fut.result(timeout=0),
+                oracle(model, variables, prompt, max_new),
+                err_msg=f"prompt {prompt}",
+            )
+        snap = eng.snapshot()
+        assert snap["completed"] == len(cases)
+        assert snap["active_slots"] == 0
+        assert snap["latency_s"]["p99"] is not None
+        assert 0 < snap["batch_occupancy_pct"] <= 100
 
 
 def test_eos_frees_slot_early(bundle):

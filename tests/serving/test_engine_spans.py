@@ -287,16 +287,22 @@ def test_speculative_verify_counts_its_tokens(bundle, traced):
     assert _tokens_counter() - before == 12
 
 
-def test_a_new_prompt_width_compiles_under_the_chunk_that_paid(
-        bundle, traced):
-    """A fresh engine has fresh jitted programs, so its first chunk of a
-    width compiles whatever ran earlier in this process."""
-    eng = _engine(bundle)
+def _served_by_a_new_engine(bundle, request, **sizes):
+    eng = _engine(bundle, **sizes)
     try:
-        futs, _ = _serve(eng, _prompts(bundle[0], ((13, 2),)))
+        return _serve(eng, _prompts(bundle[0], (request,)))[0][0].request_id
     finally:
         eng.close()
-    rid = futs[0].request_id
+
+
+def test_a_new_prompt_width_compiles_under_the_chunk_that_paid(
+        bundle, traced):
+    """The FIRST engine of a shape in a process compiles its programs,
+    each under the span that paid; a SECOND engine of that shape is handed
+    the same bound functions (``paged_programs.bound``) and compiles
+    nothing. Three slots: no other test of this file builds that shape, so
+    what ran earlier in the worker does not decide which engine is first."""
+    rid = _served_by_a_new_engine(bundle, (13, 2), n_slots=3)
     chunks = {e["args"]["span_id"]: e
               for e in _spans("serving.prefill_chunk")
               if e["args"]["request_id"] == rid}
@@ -318,6 +324,12 @@ def test_a_new_prompt_width_compiles_under_the_chunk_that_paid(
         assert any(e["args"].get("parent_id") in chunks
                    for e in _spans(kind)), kind
 
+    tracing.clear_trace()
+    rid = _served_by_a_new_engine(bundle, (13, 2), n_slots=3)
+    assert [e["args"]["request_id"]
+            for e in _spans("serving.prefill_chunk")] == [rid, rid]
+    assert _spans("xla.trace", "xla.lower", "xla.compile") == []
+
 
 def test_compile_counters_count_with_tracing_off(bundle):
     tracing.disable_tracing()
@@ -325,20 +337,20 @@ def test_compile_counters_count_with_tracing_off(bundle):
     secs = registry().counter("sparkdl_compile_seconds_total",
                               labels=("kind",))
 
-    def read(fam):
-        return dict(fam.snapshot_values())
+    def read():
+        return (dict(count.snapshot_values()), dict(secs.snapshot_values()))
 
-    eng = _engine(bundle)
-    try:
-        n0, s0 = read(count), read(secs)
-        _serve(eng, _prompts(bundle[0], ((11, 2),)))
-    finally:
-        eng.close()
-    n1, s1 = read(count), read(secs)
+    # five slots: a shape of this test's own (see the test above)
+    n0, s0 = read()
+    _served_by_a_new_engine(bundle, (11, 2), n_slots=5)
+    n1, s1 = read()
     for kind in ("trace", "lower", "compile"):
         key = f'kind="{kind}"'
         assert n1[key] - n0.get(key, 0) >= 2, (kind, n0, n1)
         assert s1[key] > s0.get(key, 0.0)
+    # the second engine of the shape, constructor and all, moves none
+    _served_by_a_new_engine(bundle, (11, 2), n_slots=5)
+    assert read() == (n1, s1)
     assert tracing.trace_events() == []
 
 

@@ -7,8 +7,8 @@ first (pure fake-clock unit tests), then the queue's DRR schedule
 preemption (manual-tick ContinuousGPTEngine, success AND injected
 ``tenant.preempt`` fault — zero lost either way), and finally the
 storm soak: one flooder offered ~10x its quota against two compliant
-tenants, whose p95 and rolling SLO compliance must stay within 10% of
-their flooder-free baselines while the flooder's overage is shed as
+tenants, whose p95 latency IN SERVICE CYCLES must stay at its
+flooder-free baseline while the flooder's overage is shed as
 :class:`TenantThrottledError` — typed, at the door, never a timeout.
 """
 
@@ -359,10 +359,13 @@ class TestPreemption:
 class TestHotTenantStorm:
     """One flooder offered ~10x its quota against two compliant
     tenants on a shared ServingEngine. The quota + DRR + accounting
-    stack must hold: victims' p95 and SLO compliance within 10% of
-    their flooder-free baselines, the flooder's overage shed as
+    stack must hold: a victim waits no more service cycles under the
+    flood than alone, the flooder's overage is shed as
     :class:`TenantThrottledError` (typed, at the door — NEVER a
-    timeout), and zero accepted requests lost on either side."""
+    timeout), and zero accepted requests are lost on either side.
+    Nothing here is held to a wall clock: how long a cycle takes is the
+    machine's business (the suite's other workers share it), how many
+    a request waits is the scheduler's."""
 
     VICTIMS = ("acme", "zeta")
     N_PER_VICTIM = 48
@@ -373,33 +376,34 @@ class TestHotTenantStorm:
     FLOOD_PACE_S = 0.001  # ...offered at ~1000/s: >>10x over
 
     class _Runner:
-        """Latency must be dominated by a DETERMINISTIC term or the
-        10% isolation bound measures scheduler jitter, not isolation:
-        a fixed host-side sleep per batch makes every request cost
-        ~one service cycle. It has to live in a plain ``run_batch``
-        object — a sleep inside a BatchedRunner apply_fn is traced
-        ONCE by jit and compiled away — and the batch is sized (16) so
-        victims + the flooder's quota-capped residue can never
-        overflow it: the storm changes batch OCCUPANCY, never cycle
-        count."""
+        """A fixed host-side sleep per batch makes every request cost
+        ~one service cycle, and ``cycles`` counts them: a victim's
+        latency is how many batches STARTED between its submit and its
+        result. The sleep has to live in a plain ``run_batch`` object —
+        inside a BatchedRunner apply_fn it is traced ONCE by jit and
+        compiled away — and the batch is sized (16) so victims + the
+        flooder's quota-capped residue can never overflow it: the storm
+        changes batch OCCUPANCY, never cycle count."""
 
         chunk_size = 16
 
         def __init__(self, service_s):
             self._service_s = service_s
+            self.cycles = 0  # written by the batcher's one thread
 
         def run_batch(self, arrays):
+            self.cycles += 1
             time.sleep(self._service_s)
             return arrays["x"] * 2.0 + 1.0
 
     def _run(self, *, flood):
         from sparkdl_tpu.serving import ServingEngine
 
-        reg = TenantRegistry(latency_threshold_s=0.25, window_s=60.0)
+        reg = TenantRegistry(window_s=60.0)
         reg.configure("flood", rate=self.FLOOD_RATE,
                       burst=self.FLOOD_BURST)
         runner = self._Runner(self.SERVICE_S)
-        lats = {t: [] for t in self.VICTIMS}
+        waited = {t: [] for t in self.VICTIMS}
         shed, flood_futs, offered = [], [], [0]
         stop = threading.Event()
         row = np.ones((2,), np.float32)
@@ -424,11 +428,11 @@ class TestHotTenantStorm:
             try:
                 for _ in range(self.N_PER_VICTIM):
                     for tenant in self.VICTIMS:
-                        t0 = time.perf_counter()
+                        c0 = runner.cycles
                         f = eng.submit({"x": row}, tenant=tenant)
                         f.add_done_callback(
-                            lambda f, t=tenant, s=t0:
-                            lats[t].append(time.perf_counter() - s))
+                            lambda f, t=tenant, c=c0:
+                            waited[t].append(runner.cycles - c))
                         victim_futs.append(f)
                     time.sleep(self.PACE_S)
                 # zero accepted lost: every victim AND every admitted
@@ -444,18 +448,14 @@ class TestHotTenantStorm:
                 np.testing.assert_allclose(
                     f.result(timeout=30), row * 2.0 + 1.0)
             deadline = time.monotonic() + 5.0
-            while (any(len(lats[t]) < self.N_PER_VICTIM
+            while (any(len(waited[t]) < self.N_PER_VICTIM
                        for t in self.VICTIMS)
                    and time.monotonic() < deadline):
                 time.sleep(0.001)
         report = reg.slo_report()
-        p95 = {t: float(np.percentile(lats[t], 95))
-               for t in self.VICTIMS}
         return {
-            "p95": p95,
-            "compliance": {
-                t: report[t]["latency"]["compliance"]
-                for t in self.VICTIMS},
+            "p95_cycles": {t: float(np.percentile(waited[t], 95))
+                           for t in self.VICTIMS},
             "report": report,
             "offered": offered[0],
             "admitted": len(flood_futs),
@@ -484,13 +484,13 @@ class TestHotTenantStorm:
         # the queue tests; here: accepted flooder traffic all finished)
         assert flood_row["failed"] == 0
 
-        # isolation: each victim's p95 and rolling SLO compliance stay
-        # within 10% of its flooder-free baseline
+        # isolation: each victim's p95 wait, in service cycles, is its
+        # flooder-free one; the one cycle of grace is the batch that was
+        # already taken off the queue when a submit landed, which is
+        # timing and not the flood
         for t in self.VICTIMS:
-            assert storm["p95"][t] <= 1.10 * solo["p95"][t], (
-                t, storm["p95"], solo["p95"])
-            assert (storm["compliance"][t]
-                    >= 0.90 * solo["compliance"][t]), (
-                t, storm["compliance"], solo["compliance"])
+            assert (storm["p95_cycles"][t]
+                    <= solo["p95_cycles"][t] + 1), (
+                t, storm["p95_cycles"], solo["p95_cycles"])
             assert storm["report"][t]["failed"] == 0
             assert storm["report"][t]["completed"] >= self.N_PER_VICTIM
